@@ -18,6 +18,7 @@ import time
 import uuid
 from typing import Any, Dict, List, Optional
 
+from ...util.compile_cache import ensure_compile_cache
 from .engine import EngineConfig, InferenceEngine, Request, SamplingParams
 from .tokenizer import load_tokenizer
 
@@ -55,6 +56,7 @@ class LLMServerImpl:
     """The deployment class body (decorated at app-build time)."""
 
     def __init__(self, llm_config: Dict[str, Any]):
+        ensure_compile_cache()
         self._config = dict(llm_config)
         engine_kwargs = dict(self._config.get("engine_kwargs") or {})
         self.model_id = self._config.get("model_id", "default")
@@ -112,8 +114,8 @@ class LLMServerImpl:
     def _abort_off_loop(self, rid: str) -> None:
         """Fire an engine abort WITHOUT blocking the event loop:
         abort serializes against step() (engine._step_lock), and a
-        step is a device dispatch that can take hundreds of ms behind
-        a network tunnel — awaiting it in a stream's finally would
+        step is a whole device dispatch (a compiling one takes
+        seconds) — awaiting it in a stream's finally would
         freeze every other coroutine (and an async generator being
         closed cannot await at all). Fire-and-forget on the executor;
         abort never raises for an unknown/finished request, but a

@@ -233,8 +233,8 @@ def _attend(cfg: LlamaConfig, q, k, v, mesh: Optional[Mesh]):
             raise ValueError("ring attention requires a mesh")
         return ring_attention_sharded(q, k, v, mesh, causal=True)
     if impl == "ulysses":
-        return ulysses_attention(q, k, v, causal=True)
-    return attention_op(q, k, v, causal=True, impl=impl)
+        return ulysses_attention(q, k, v, causal=True, mesh=mesh)
+    return attention_op(q, k, v, causal=True, impl=impl, mesh=mesh)
 
 
 def decoder_layer(cfg: LlamaConfig, x: jax.Array, layer: Dict[str, jax.Array],

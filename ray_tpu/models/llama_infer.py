@@ -5,7 +5,7 @@ Two entry points, both designed to jit once and stay compiled:
 
 - prefill: full-prompt forward that also emits every layer's K/V and
   scatters them into the shared page pool (ops/paged_attention.py layout:
-  [n_layers, num_pages, n_kv_heads, page_size, head_dim]).
+  [n_layers, num_pages, page_size, n_kv_heads, pool_head_dim]).
 - decode_step: one token per active sequence, paged attention over the
   pool, new KV scattered in-place (donate the pools for true in-place
   HBM updates under jit).
@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
 from ..ops.attention import attention as attention_op
-# shard_map version shim: ONE shared implementation (ops/jax_compat)
-# so the compat logic cannot drift between consumers
-from ..ops.jax_compat import shard_map_compat as _shard_map
 from ..ops.paged_attention import (gather_kv, gather_kv_quant,
                                    paged_attention_on_gathered,
                                    paged_decode_with_new_token, scatter_kv,
@@ -268,7 +265,8 @@ def prefill_chunk(cfg: LlamaConfig, params: Dict[str, Any],
     # one dense gather of the cached context for all layers (layer-major)
     ctx_tables = (page_tables if ctx_pages < 0
                   else page_tables[:, :ctx_pages])
-    k_ctx_all, v_ctx_all = gather_kv(k_pages, v_pages, ctx_tables)
+    k_ctx_all, v_ctx_all = gather_kv(k_pages, v_pages, ctx_tables,
+                                     cfg.head_dim)
 
     def layer_fn(x, inp):
         layer, k_ctx, v_ctx, lora_l = inp
@@ -434,10 +432,11 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
                       else page_tables[:, :ctx_pages])
         if quantized:
             k_by_layer, v_by_layer = gather_kv_quant(
-                k_pages, v_pages, k_scales, v_scales, ctx_tables)
+                k_pages, v_pages, k_scales, v_scales, ctx_tables,
+                cfg.head_dim)
         else:
             k_by_layer, v_by_layer = gather_kv(k_pages, v_pages,
-                                               ctx_tables)
+                                               ctx_tables, cfg.head_dim)
 
     def layer_fn(x, inp):
         if kernel_quant:
@@ -481,10 +480,9 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
                     # scale blocks shard on kv heads like their pages
                     in_specs += [P(None, None, "tp"),     # k scales
                                  P(None, None, "tp")]     # v scales
-                kernel = _shard_map(
-                    kernel, mesh,
-                    in_specs=tuple(in_specs),
-                    out_specs=P(None, "tp", None))
+                kernel = jax.shard_map(
+                    kernel, mesh=mesh, in_specs=tuple(in_specs),
+                    out_specs=P(None, "tp", None), check_vma=False)
             args = (q, k_l, v_l, page_tables, slot_ids,
                     positions, valid, start, k, v)
             if kernel_quant:
@@ -588,10 +586,11 @@ def decode_step(cfg: LlamaConfig, params: Dict[str, Any],
         # One gather of the whole context for all layers, layer-major.
         if quantized:
             k_by_layer, v_by_layer = gather_kv_quant(
-                k_pages, v_pages, k_scales, v_scales, page_tables)
+                k_pages, v_pages, k_scales, v_scales, page_tables,
+                cfg.head_dim)
         else:
             k_by_layer, v_by_layer = gather_kv(k_pages, v_pages,
-                                               page_tables)
+                                               page_tables, cfg.head_dim)
 
     def layer_fn(x, inp):
         if kernel_quant:
@@ -635,10 +634,9 @@ def decode_step(cfg: LlamaConfig, params: Dict[str, Any],
                     # scale blocks shard on kv heads like their pages
                     in_specs += [P(None, None, "tp"),     # k scales
                                  P(None, None, "tp")]     # v scales
-                kernel = _shard_map(
-                    kernel, mesh,
-                    in_specs=tuple(in_specs),
-                    out_specs=P(None, "tp", None))
+                kernel = jax.shard_map(
+                    kernel, mesh=mesh, in_specs=tuple(in_specs),
+                    out_specs=P(None, "tp", None), check_vma=False)
             args = (q, k_l, v_l, page_tables, positions, k, v)
             if kernel_quant:
                 args += (ks_l, vs_l)

@@ -18,9 +18,9 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from . import llama
-from ..ops.jax_compat import set_mesh_compat
 from ..parallel.mesh import BATCH_AXES, AXIS_SP, AXIS_PP, mesh_shape
 from ..parallel.sharding import spec_for, tree_shardings
+from ..util.compile_cache import ensure_compile_cache
 
 
 def _path_key(entry) -> str:
@@ -76,6 +76,7 @@ class TrainStepBundle:
                  optimizer: Optional[optax.GradientTransformation] = None,
                  rules: Optional[Dict] = None,
                  donate_state: bool = True):
+        ensure_compile_cache()
         self.cfg = cfg
         self.mesh = mesh
         self.optimizer = optimizer or default_optimizer()
@@ -125,15 +126,23 @@ class TrainStepBundle:
 
     # public API -----------------------------------------------------------
     #
-    # Each call runs under `jax.set_mesh` (via the version shim) so the model's logical-axis
-    # sharding constraints (with_logical_constraint) resolve against this
-    # bundle's mesh at trace time — without the context they silently
-    # no-op, which both loses the intended activation shardings and (for
-    # MoE-inside-pipeline programs) trips an XLA SPMD partitioner
-    # check-fail ("Invalid binary instruction opcode copy").
+    # Each call runs with this bundle's mesh as the ambient ABSTRACT
+    # mesh, so the model's logical-axis sharding constraints
+    # (with_logical_constraint) resolve against it at trace time —
+    # without the context they no-op, which both loses the intended
+    # activation shardings and (for MoE-inside-pipeline programs) trips
+    # an XLA SPMD partitioner check-fail ("Invalid binary instruction
+    # opcode copy"). Not jax.set_mesh: that also installs the CONCRETE
+    # mesh, which shard_map lowering then prefers over the abstract one
+    # and which carries no Manual axis types — a Mosaic kernel nested in
+    # the pipeline's pp shard_map is then refused as "cannot be
+    # automatically partitioned" although every axis is manual.
+
+    def _mesh_ctx(self):
+        return jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh)
 
     def init_state(self, seed: int = 0):
-        with set_mesh_compat(self.mesh):
+        with self._mesh_ctx():
             return self._init(jax.random.PRNGKey(seed))
 
     def init_state_from_checkpoint(self, ckpt_dir: str):
@@ -144,18 +153,18 @@ class TrainStepBundle:
         from . import checkpoint_io
         params = checkpoint_io.load_llama_params(
             self.cfg, ckpt_dir, mesh=self.mesh)
-        with set_mesh_compat(self.mesh):
+        with self._mesh_ctx():
             opt_state = jax.jit(
                 self.optimizer.init,
                 out_shardings=self.opt_shardings)(params)
         return params, opt_state
 
     def step(self, state, tokens):
-        with set_mesh_compat(self.mesh):
+        with self._mesh_ctx():
             return self._step(state, tokens)
 
     def eval_loss(self, state, tokens):
-        with set_mesh_compat(self.mesh):
+        with self._mesh_ctx():
             return self._eval(state[0], tokens)
 
     def shard_batch(self, tokens):

@@ -23,6 +23,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
+
+from ..parallel.mesh import AXIS_TP, BATCH_AXES
 
 # 512-blocks measured ~1.7x faster than 128 end-to-end on v5e (the
 # (512, 512) f32 logits tile still fits VMEM comfortably); _resolve_blocks
@@ -47,6 +50,14 @@ def _repeat_kv(k: jax.Array, num_heads: int) -> jax.Array:
     return jnp.broadcast_to(
         k[:, :, :, None, :], (b, s, kvh, reps, d)
     ).reshape(b, s, num_heads, d)
+
+
+def _out_struct(shape, dtype, *like) -> jax.ShapeDtypeStruct:
+    """pallas_call out_shape that varies over the same manual mesh
+    axes as the kernel's inputs — shard_map's varying-axes check needs
+    to be told (a pallas_call cannot infer it)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in like))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -146,8 +157,8 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
         functools.partial(_flash_kernel, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
+            _out_struct((bh, sq, d), q.dtype, q, k, v),
+            _out_struct((bh, sq, 1), jnp.float32, q, k, v),
         ),
         grid=grid,
         in_specs=[
@@ -277,7 +288,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale,
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        out_shape=_out_struct((bh, sq, d), q.dtype, q, k, v, do),
         grid=(bh, n_qb, n_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -301,8 +312,8 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale,
         functools.partial(_flash_dkv_kernel, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k, n_qb=n_qb),
         out_shape=(
-            jax.ShapeDtypeStruct((bkvh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bkvh, sk, d), v.dtype),
+            _out_struct((bkvh, sk, d), k.dtype, q, k, v, do),
+            _out_struct((bkvh, sk, d), v.dtype, q, k, v, do),
         ),
         grid=(bkvh, n_kb, group * n_qb),
         in_specs=[
@@ -405,9 +416,96 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # Dispatcher
 # --------------------------------------------------------------------------
 
+def _mesh_specs(mesh: Mesh, head_axes):
+    """(shard_map mesh, manual axis names, activation spec, lse spec,
+    head split) for the flash kernel on a sharded program: batch
+    over dp/fsdp, heads over `head_axes`, full sequence per shard.
+    Axes an enclosing shard_map already made manual (the pipeline's
+    pp) are left alone, and a nested shard_map takes the enclosing
+    context's mesh (mesh=None) — only then does the Mosaic lowering
+    see the outer manual axes too."""
+    ctx = jax.sharding.get_abstract_mesh()
+    manual = set(ctx.manual_axes)
+    names = frozenset(mesh.axis_names) - manual
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    batch = tuple(a for a in BATCH_AXES if a in names) or None
+    heads = tuple(a for a in head_axes if a in names) or None
+    n_split = 1
+    for a in heads or ():
+        n_split *= sizes[a]
+    return (None if manual else mesh, names,
+            P(batch, None, heads, None), P(batch, heads, None, None),
+            n_split)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_on_mesh(q, k, v, causal: bool, interpret: bool, mesh: Mesh,
+                   head_axes):
+    """The flash kernel under shard_map over the batch and head axes.
+
+    A Mosaic kernel cannot be auto-partitioned ("Mosaic kernels cannot
+    be automatically partitioned. Please wrap the call in a
+    shard_map"), so wherever the caller's program is GSPMD-sharded the
+    kernel runs per shard. Attention is independent per (batch row,
+    head): no collectives inside. The custom_vjp sits OUTSIDE the
+    shard_maps — forward and backward are each one shard_map with
+    stated specs — because differentiating through a shard_map nested
+    in the pipeline's pp shard_map hands the residuals back with pp
+    ahead of the manual axes, which the partitioner refuses."""
+    return _flash_on_mesh_fwd(q, k, v, causal, interpret, mesh,
+                              head_axes)[0]
+
+
+def _flash_on_mesh_fwd(q, k, v, causal, interpret, mesh, head_axes):
+    sm_mesh, names, spec, lse_spec, _ = _mesh_specs(mesh, head_axes)
+
+    def local(q_, k_, v_):
+        out, (_, _, _, _, lse) = _flash_fwd_rule(
+            q_, k_, v_, causal, None, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K,
+            interpret)
+        b, sq, hl, _ = q_.shape
+        return out, lse.reshape(b, hl, sq, 1)
+
+    out, lse = jax.shard_map(
+        local, mesh=sm_mesh, in_specs=(spec, spec, spec),
+        out_specs=(spec, lse_spec), axis_names=names)(q, k, v)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_on_mesh_bwd(causal, interpret, mesh, head_axes, res, g):
+    q, k, v, out, lse = res
+    sm_mesh, names, spec, lse_spec, _ = _mesh_specs(mesh, head_axes)
+
+    def local(q_, k_, v_, o_, lse_, g_):
+        b, sq, hl, d = q_.shape
+        kvh, sk = k_.shape[2], k_.shape[1]
+        flat = lambda x, n, s_: x.transpose(0, 2, 1, 3).reshape(
+            b * n, s_, d)
+        res_l = (flat(q_, hl, sq), flat(k_, kvh, sk), flat(v_, kvh, sk),
+                 flat(o_, hl, sq), lse_.reshape(b * hl, sq, 1))
+        return _flash_bwd_rule(causal, None, DEFAULT_BLOCK_Q,
+                               DEFAULT_BLOCK_K, interpret, res_l, g_)
+
+    dq, dk, dv = jax.shard_map(
+        local, mesh=sm_mesh,
+        in_specs=(spec, spec, spec, spec, lse_spec, spec),
+        out_specs=(spec, spec, spec), axis_names=names)(
+            q, k, v, out, lse, g)
+    return dq, dk, dv
+
+
+_flash_on_mesh.defvjp(_flash_on_mesh_fwd, _flash_on_mesh_bwd)
+
+
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-              causal: bool = True, impl: str = "auto") -> jax.Array:
-    """Pick the best attention implementation for the current backend."""
+              causal: bool = True, impl: str = "auto",
+              mesh: Optional[Mesh] = None,
+              head_axes=(AXIS_TP,)) -> jax.Array:
+    """Pick the best attention implementation for the current backend.
+
+    mesh: the mesh the surrounding program is sharded over, if any —
+    the flash kernel then runs under shard_map (`_flash_on_mesh`);
+    the XLA reference partitions through GSPMD as is."""
     if impl == "auto":
         on_tpu = jax.devices()[0].platform == "tpu"
         sq, sk = q.shape[1], k.shape[1]
@@ -417,9 +515,14 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ok_shapes = (bq is not None and bk is not None and bq >= 128
                      and bk >= 128 and q.shape[-1] >= 64)
         impl = "pallas" if (on_tpu and ok_shapes) else "xla"
-    if impl == "pallas":
-        return flash_attention(q, k, v, causal)
-    if impl == "pallas_interpret":
-        return flash_attention(q, k, v, causal, None,
-                               DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, True)
+    if impl in ("pallas", "pallas_interpret"):
+        interpret = impl == "pallas_interpret"
+        if mesh is not None and mesh.devices.size > 1:
+            if k.shape[2] % _mesh_specs(mesh, head_axes)[-1]:
+                # too few kv heads to split: one per q head instead
+                k, v = _repeat_kv(k, q.shape[2]), _repeat_kv(v, q.shape[2])
+            return _flash_on_mesh(q, k, v, causal, interpret, mesh,
+                                  tuple(head_axes))
+        return flash_attention(q, k, v, causal, None, DEFAULT_BLOCK_Q,
+                               DEFAULT_BLOCK_K, interpret)
     return reference_attention(q, k, v, causal=causal)

@@ -12,8 +12,10 @@ KV writes flatten the pool to [L, P*page_size, KVH, D] and scatter on a
 SINGLE index dim (row = page*page_size + offset) — the
 two-index-dim form (.at[:, page_idx, :, offset]) lowers to a
 pathologically slow XLA scatter on TPU; and the kernel block's last two
-dims stay (KVH, head_dim), which satisfies the TPU lowering's
-(8, 128)-divisibility natively.
+dims stay (KVH, head_dim). The kernels' DMAs need that minor dim
+lane-aligned, so pools the kernels read are allocated with head_dim
+padded to 128 lanes (`pool_head_dim`); every function here takes the
+row width from the pool it is handed.
 
 Two decode paths:
 - XLA fallback: gather pages into dense [B, ctx] KV then masked attention
@@ -38,22 +40,78 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import kv_quant
 
+# TPU vector lanes. The kernels' manual DMAs move whole
+# [page, KVH, D] slabs out of the HBM pool, and Mosaic refuses a slab
+# whose minor dim is not lane-aligned ("Slice shape along dimension 3
+# must be aligned to tiling (128)").
+LANES = 128
+
+
+def pool_head_dim(head_dim: int, impl: str) -> int:
+    """Minor dim of the KV pool's rows — THE pool layout rule, applied
+    where the engine allocates its pools. The kernel impls store each
+    head's row zero-padded up to a whole number of 128-lane vectors
+    (head_dim 64 -> 128: the model keeps its published width, the
+    cache pays the padding); the gather impl stores head_dim as is.
+    Every reader/writer below takes the width from the pool it is
+    handed: writers zero-pad fresh rows (`_fit_lanes`), gather readers
+    slice back to head_dim, and the kernels pad q so the padded lanes
+    contribute exact zeros to every score and output."""
+    if impl in ("pallas", "pallas_interpret"):
+        return -(-head_dim // LANES) * LANES
+    return head_dim
+
+
+def kernel_layout_error(kv_kind: str, local_kv_heads: int,
+                        pool_dtype) -> "str | None":
+    """Why the compiled kernels cannot serve this cache geometry, in
+    the compiler's words (None = they can). Checked at engine
+    construction on a TPU so a refused geometry fails there, not at
+    the first tick's Mosaic compile; tests/test_tpu_aot_compile.py
+    holds each rule against the real compiler."""
+    if kv_kind != "f32":
+        return ("int8/fp8 KV pages keep per-(row, head) f32 scales as "
+                "[pages, page, KVH]; the kernels' scale DMA is refused: "
+                "'Slice shape along dimension 2 must be aligned to "
+                "tiling (128), but is KVH'")
+    packing = 4 // jnp.dtype(pool_dtype).itemsize
+    if local_kv_heads % packing:
+        return (f"{local_kv_heads} kv head(s) per shard in a "
+                f"{jnp.dtype(pool_dtype).name} pool: 'Slice shape along "
+                f"dimension 2 must be aligned to tiling ({packing}), "
+                f"but is {local_kv_heads}'")
+    return None
+
+
+def _fit_lanes(x: jax.Array, width: int) -> jax.Array:
+    """Zero-pad (or slice) x's minor dim to `width` — the one
+    conversion between a model's head_dim and a pool's row width."""
+    d = x.shape[-1]
+    if d == width:
+        return x
+    if d > width:
+        return x[..., :width]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - d)])
+
 
 def gather_kv(k_pages: jax.Array, v_pages: jax.Array,
-              page_tables: jax.Array) -> Tuple[jax.Array, jax.Array]:
+              page_tables: jax.Array, head_dim: int = -1
+              ) -> Tuple[jax.Array, jax.Array]:
     """page_tables: [B, max_pages] int32 →
     k/v: [n_layers, B, max_pages*page_size, n_kv_heads, head_dim]
-    (layer-major, ready for a scan over layers)."""
+    (layer-major, ready for a scan over layers). head_dim slices a
+    lane-padded pool (pool_head_dim) back to the model's width."""
     def one(pages):
         g = pages[:, page_tables]          # [L, B, P, page, KVH, D]
         l, b, p, s, h, d = g.shape
-        return g.reshape(l, b, p * s, h, d)
+        g = g.reshape(l, b, p * s, h, d)
+        return g if head_dim < 0 else _fit_lanes(g, head_dim)
     return one(k_pages), one(v_pages)
 
 
 def gather_kv_quant(k_pages: jax.Array, v_pages: jax.Array,
                     k_scales: jax.Array, v_scales: jax.Array,
-                    page_tables: jax.Array
+                    page_tables: jax.Array, head_dim: int = -1
                     ) -> Tuple[jax.Array, jax.Array]:
     """Quantized-pool gather (ISSUE 16): pools hold int8/fp8 values
     with per-(row, head) f32 scales ([L, P, page, KVH],
@@ -66,7 +124,8 @@ def gather_kv_quant(k_pages: jax.Array, v_pages: jax.Array,
         s = scales[:, page_tables]         # [L, B, P, page, KVH]
         l, b, p, sz, h, d = g.shape
         deq = g.astype(jnp.float32) * s.astype(jnp.float32)[..., None]
-        return deq.reshape(l, b, p * sz, h, d)
+        deq = deq.reshape(l, b, p * sz, h, d)
+        return deq if head_dim < 0 else _fit_lanes(deq, head_dim)
     return one(k_pages, k_scales), one(v_pages, v_scales)
 
 
@@ -82,8 +141,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     g_k = k_pages[layer][page_tables]      # [B, P, page, KVH, D]
     g_v = v_pages[layer][page_tables]
     b, p, s, h, d = g_k.shape
-    k = g_k.reshape(b, p * s, h, d)
-    v = g_v.reshape(b, p * s, h, d)
+    k = _fit_lanes(g_k.reshape(b, p * s, h, d), q.shape[-1])
+    v = _fit_lanes(g_v.reshape(b, p * s, h, d), q.shape[-1])
     return paged_attention_on_gathered(q, k, v, seq_lens)
 
 
@@ -323,12 +382,13 @@ def _paged_decode_kernel_mp(tables_ref, lens_ref, q_ref, k_hbm, v_hbm,
 
 def _paged_decode_multipage(q, k_pages, v_pages, page_tables, seq_lens,
                             ppb: int, interpret: bool = False,
-                            k_scales=None, v_scales=None):
-    b, h, d = q.shape
+                            k_scales=None, v_scales=None,
+                            scale: "float | None" = None):
+    b, h, d = q.shape                       # d: the POOL's row width
     _, page_size, kvh, _ = k_pages.shape
     max_pages = page_tables.shape[1]
     group = h // kvh
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     qg = q.reshape(b, kvh, group, d)
     n_blocks = max(-(-max_pages // ppb), 1)
     quantized = k_scales is not None
@@ -402,20 +462,31 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     `pl.when` skips the compute, so per-sequence work is proportional to
     ceil(seq_len / page_size), not max_pages.
     """
-    b, h, d = q.shape
-    _, page_size, kvh, _ = k_pages.shape
+    b, h, head_dim = q.shape
+    _, page_size, kvh, d = k_pages.shape
     max_pages = page_tables.shape[1]
     quantized = k_scales is not None
+    scale = head_dim ** -0.5
+    # a lane-padded pool (pool_head_dim): zero-padded q lanes score 0
+    # against any k lanes, and the pool's own pad lanes are zeros, so
+    # the padded output lanes are exact zeros — sliced off below
+    q = _fit_lanes(q, d)
+    # interpret mode stays on the one-page kernel: the engine-level CPU
+    # suites assert bf16 token-exactness against gather, which the
+    # multi-page kernel's accumulation order breaks by rounding alone
+    # (f32 logits agree to 2e-6). The multi-page kernel is held by
+    # test_multipage_kernel_matches_dense_gather (interpret), by
+    # tests/test_tpu_aot_compile.py (the real compiler) and by
+    # chip_smoke.py's on-chip logits check against gather.
     if not interpret and max_pages >= pages_per_block > 1:
         out, m, l = _paged_decode_multipage(
             q, k_pages, v_pages, page_tables, seq_lens, pages_per_block,
-            k_scales=k_scales, v_scales=v_scales)
-        out = out.reshape(b, h, d)
+            k_scales=k_scales, v_scales=v_scales, scale=scale)
+        out = _fit_lanes(out.reshape(b, h, d), head_dim)
         if return_stats:
             return out, m.reshape(b, h), l.reshape(b, h)
         return out
     group = h // kvh
-    scale = d ** -0.5
     qg = q.reshape(b, kvh, group, d)
 
     def page_index(bi, j, tables, lens):
@@ -467,7 +538,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         interpret=interpret,
     )(page_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       *inputs)
-    out = out.reshape(b, h, d)
+    out = _fit_lanes(out.reshape(b, h, d), head_dim)
     if return_stats:
         return out, m.reshape(b, h), l.reshape(b, h)
     return out
@@ -535,9 +606,10 @@ def scatter_kv(k_pages: jax.Array, v_pages: jax.Array,
     rows = page_idx * page_size + positions % page_size          # [N]
     flat = lambda p: p.reshape(l, num_pages * page_size, kvh, d)
     # a single advanced index keeps its position: the updated view is
-    # [L, N, KVH, D], so swap k_new's leading dims to match
-    k_rows = jnp.swapaxes(k_new, 0, 1)
-    v_rows = jnp.swapaxes(v_new, 0, 1)
+    # [L, N, KVH, D], so swap k_new's leading dims to match (rows
+    # zero-padded to a lane-padded pool's width)
+    k_rows = jnp.swapaxes(_fit_lanes(k_new, d), 0, 1)
+    v_rows = jnp.swapaxes(_fit_lanes(v_new, d), 0, 1)
     k_pages = flat(k_pages).at[:, rows].set(k_rows).reshape(k_pages.shape)
     v_pages = flat(v_pages).at[:, rows].set(v_rows).reshape(v_pages.shape)
     return k_pages, v_pages
@@ -566,6 +638,7 @@ def scatter_kv_quant(k_pages: jax.Array, v_pages: jax.Array,
     rows = page_idx * page_size + positions % page_size          # [N]
     kq, ks = kv_quant.quantize_rows(k_new, kind)   # [N,L,KVH,D]/[N,L,KVH]
     vq, vs = kv_quant.quantize_rows(v_new, kind)
+    kq, vq = _fit_lanes(kq, d), _fit_lanes(vq, d)
     flat = lambda p: p.reshape(l, num_pages * page_size, kvh, d)
     flat_s = lambda s: s.reshape(l, num_pages * page_size, kvh)
     k_pages = flat(k_pages).at[:, rows].set(

@@ -9,7 +9,11 @@ XLA:CPU has no lowering for the primitive ("HLO opcode
 `ragged-all-to-all` is not supported by XLA:CPU ThunkEmitter"), so the
 virtual-mesh tests and the driver's CPU dryrun run a semantics-exact
 emulation built from all_gather + masked scatter. Same interface, same
-offsets contract, chosen at trace time by backend.
+offsets contract, chosen by ONE explicit switch:
+``RAY_TPU_RAGGED_EMULATE=1``, which the CPU-mesh recipe
+(`_private/cpu_mesh.apply_cpu_mesh_env`) sets. Without it the op is the
+native collective on whatever backend runs — a backend that lacks it
+raises; nothing falls back by looking at the platform.
 
 Semantics (mirrors lax.ragged_all_to_all): for each peer j, rows
 ``operand[input_offsets[j] : input_offsets[j] + send_sizes[j]]`` land in
@@ -41,9 +45,8 @@ def exchange_offsets(send_sizes: jax.Array, axis_name: str):
 
 
 def _use_native() -> bool:
-    if os.environ.get("RAY_TPU_RAGGED_EMULATE", "0") in ("1", "true"):
-        return False
-    return jax.default_backend() == "tpu"
+    return os.environ.get("RAY_TPU_RAGGED_EMULATE", "0") not in (
+        "1", "true")
 
 
 def ragged_all_to_all(operand: jax.Array, output: jax.Array,

@@ -43,6 +43,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .paged_attention import _fit_lanes
+
 # default flash block sizes for the Pallas ragged kernel (shared with
 # the benches' analytic staging-size math — keep in one place)
 DEFAULT_Q_BLOCK = 8
@@ -128,8 +130,10 @@ def ragged_paged_prefill_decode_attention(
     g_k = k_pages[tables]                   # [B, P, page, KVH, D]
     g_v = v_pages[tables]
     b, p, s, kvh, d = g_k.shape
+    hd = q.shape[-1]                        # pool may be lane-padded
     return ragged_prefill_decode_attention(
-        q, g_k.reshape(b, p * s, kvh, d), g_v.reshape(b, p * s, kvh, d),
+        q, _fit_lanes(g_k.reshape(b, p * s, kvh, d), hd),
+        _fit_lanes(g_v.reshape(b, p * s, kvh, d), hd),
         k_new, v_new, slot_ids, positions, valid, start)
 
 
@@ -370,11 +374,17 @@ def ragged_paged_attention_pallas(
     beside their pages and fuses the dequant multiply into the
     streaming loop. k_new/v_new stay full-precision either way.
     """
-    t, h, d = q.shape
-    _, page_size, kvh, _ = k_pages.shape
+    t, h, head_dim = q.shape
+    _, page_size, kvh, d = k_pages.shape
     b = page_tables.shape[0]
     group = h // kvh
-    scale = d ** -0.5
+    scale = head_dim ** -0.5
+    # a lane-padded pool (paged_attention.pool_head_dim): the kernel
+    # runs at the pool's row width; zero-padded q/new-KV lanes add
+    # exact zeros to every score and output, sliced off at the end
+    q = _fit_lanes(q, d)
+    k_new = _fit_lanes(k_new, d)
+    v_new = _fit_lanes(v_new, d)
     tables = (page_tables if ctx_pages < 0
               else page_tables[:, :max(ctx_pages, 1)])
     n_ctx_pages = tables.shape[1] if ctx_pages != 0 else 0
@@ -458,6 +468,7 @@ def ragged_paged_attention_pallas(
     )(tables.astype(jnp.int32), start.astype(jnp.int32), qlen,
       *inputs)
 
-    flat = out[jnp.where(valid, slot_ids, 0), off]     # [T, H, D]
+    flat = _fit_lanes(out[jnp.where(valid, slot_ids, 0), off],
+                      head_dim)                        # [T, H, D]
     return jnp.where(valid[:, None, None], flat,
                      jnp.zeros_like(flat)).astype(q.dtype)

@@ -18,7 +18,7 @@ the engine and the fleet both need:
 
 Distinct from parallel/mesh.py (MeshSpec — the GSPMD auto-partitioning
 path): here sharding is explicit shard_map with hand-placed
-collectives, built via ops/jax_compat.shard_map_compat.
+collectives, built via jax.shard_map.
 
 Tier-1 testability: `XLA_FLAGS=--xla_force_host_platform_device_count=N`
 (`_private/cpu_mesh.py`) gives a virtual multi-chip CPU backend, so

@@ -35,8 +35,8 @@ _UL_RULES = {
 
 
 def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                      causal: bool = True,
-                      impl: str = "auto") -> jax.Array:
+                      causal: bool = True, impl: str = "auto",
+                      mesh=None) -> jax.Array:
     """q: (B, S, H, D); k/v: (B, S, KVH, D), sequence-sharded over sp.
 
     Returns (B, S, H, D) sequence-sharded. The two wlc pairs below are the
@@ -54,6 +54,9 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     k = wlc(k, "batch", "ul_seq", "ul_heads", "head_dim", rules=_UL_RULES)
     v = wlc(v, "batch", "ul_seq", "ul_heads", "head_dim", rules=_UL_RULES)
 
-    out = attention_op(q, k, v, causal=causal, impl=impl)
+    # the flash kernel (if impl resolves to it) runs per head shard:
+    # same (tp, sp) head split the constraints above just established
+    out = attention_op(q, k, v, causal=causal, impl=impl, mesh=mesh,
+                       head_axes=_UL_RULES["ul_heads"])
 
     return wlc(out, "batch", "seq", "heads", "head_dim")

@@ -64,39 +64,16 @@ def named_sharding(mesh: Mesh, *logical_axes: Optional[str],
 
 def with_logical_constraint(x, *logical_axes: Optional[str],
                             rules: Optional[Dict] = None):
-    """Annotate an intermediate value inside jit with its logical sharding."""
-    try:
-        mesh = get_abstract_mesh_or_none()
-        if mesh is None:
-            return x
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, spec_for(logical_axes, rules)))
-    except Exception:
+    """Annotate an intermediate value inside jit with its logical
+    sharding against the ambient mesh (`jax.set_mesh` /
+    `jax.sharding.use_abstract_mesh`). Outside any mesh context it is
+    the identity (single device); inside one, a constraint the
+    partitioner refuses raises."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
-
-
-def get_abstract_mesh_or_none():
-    """The mesh from the enclosing `jax.set_mesh` /
-    `ops.jax_compat.set_mesh_compat` context, if any. On the 0.4.x
-    line there is no abstract-mesh API; the ambient mesh lives in the
-    thread-local resource env a `with mesh:` context installs, so the
-    fallback reads it from there — without it every logical-axis
-    constraint silently no-ops on 0.4.x (which is exactly how the
-    training-path shardings regressed unnoticed)."""
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:
-        from jax._src import mesh as _mesh_lib
-        m = _mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, spec_for(logical_axes, rules)))
 
 
 def tree_shardings(tree_of_logical_axes: Any, mesh: Mesh,

@@ -45,9 +45,9 @@ import jax
 
 __all__ = ["GuardViolation", "GuardReport", "dispatch_guard"]
 
-# the jax-internal loggers that carry compile events (0.4.x: pxla logs
-# "Compiling <fn> with global shapes and types ..."; kept broad so a
-# jax upgrade moving the message keeps the sentinel alive)
+# the jax-internal loggers that carry compile events ("Compiling <fn>
+# with global shapes and types ..."; kept broad so a jax upgrade
+# moving the message keeps the sentinel alive)
 _COMPILE_LOGGERS = (
     "jax._src.interpreters.pxla",
     "jax._src.dispatch",

@@ -19,11 +19,13 @@ import pytest
 
 def pytest_configure(config):
     # tier-1 CI runs `-m 'not slow'` (ROADMAP.md): mark long-running
-    # benches and TPU-only compiled-kernel paths `slow`; every
-    # interpret-mode kernel equivalence gate stays un-marked (tier-1)
+    # benches `slow`; every interpret-mode kernel equivalence gate and
+    # the TPU AOT-compile gate (test_tpu_aot_compile.py) stay un-marked
+    # (tier-1). Nothing here needs a chip: what does lives in
+    # chip_smoke.py.
     config.addinivalue_line(
         "markers",
-        "slow: long-running or TPU-only; excluded from tier-1 CI")
+        "slow: long-running; excluded from tier-1 CI")
 
 
 @pytest.fixture()

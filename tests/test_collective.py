@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 import ray_tpu
-from ray_tpu.ops.jax_compat import shard_map_compat
 from ray_tpu.parallel import MeshSpec
 from ray_tpu.util.collective import xla as cx
 
@@ -83,12 +82,13 @@ def test_xla_collectives_in_mesh():
                                 src_rank=3)
         return total, gathered, rank_val
 
-    sharded = shard_map_compat(
-        fn, mesh,
+    sharded = jax.shard_map(
+        fn, mesh=mesh,
         in_specs=jax.sharding.PartitionSpec("dp"),
         out_specs=(jax.sharding.PartitionSpec("dp"),
                    jax.sharding.PartitionSpec("dp"),
-                   jax.sharding.PartitionSpec("dp")))
+                   jax.sharding.PartitionSpec("dp")),
+        check_vma=False)
     x = jnp.arange(8, dtype=jnp.float32)
     total, gathered, rank_val = sharded(x)
     np.testing.assert_allclose(np.asarray(total), np.full((8,), 28.0))
@@ -101,10 +101,10 @@ def test_xla_reducescatter():
     def fn(x):
         return cx.reducescatter(x, "dp", axis=0)
 
-    sharded = shard_map_compat(
-        fn, mesh,
+    sharded = jax.shard_map(
+        fn, mesh=mesh,
         in_specs=jax.sharding.PartitionSpec(),
-        out_specs=jax.sharding.PartitionSpec("dp"))
+        out_specs=jax.sharding.PartitionSpec("dp"), check_vma=False)
     x = jnp.ones((8, 2), jnp.float32)
     out = sharded(x)
     np.testing.assert_allclose(np.asarray(out), np.full((8, 2), 4.0))

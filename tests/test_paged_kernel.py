@@ -9,7 +9,6 @@ slices passed to the kernel are [num_pages, page_size, KVH, D].
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from ray_tpu.models import llama
 from ray_tpu.models.llama_infer import decode_step, prefill
@@ -104,30 +103,6 @@ def test_decode_step_kernel_matches_gather():
                                np.asarray(out_logits), atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(rk), np.asarray(ok),
                                atol=1e-4, rtol=1e-4)
-
-
-@pytest.mark.slow
-def test_paged_decode_kernel_compiled_tpu():
-    """Compiled decode kernel (the TPU hot path, ppb>1 manual-DMA
-    variant) vs the dense reference — needs real TPU hardware; the
-    interpret-mode gates above cover CPU CI."""
-    if jax.devices()[0].platform == "cpu":
-        pytest.skip("compiled Pallas kernel requires a TPU")
-    rng = np.random.default_rng(7)
-    B, H, KVH, D = 4, 16, 8, 128
-    num_pages, page_size, max_pages = 128, 16, 32
-    k_pages, v_pages = _pool(rng, num_pages, page_size, KVH, D)
-    tables = jnp.asarray(
-        rng.permutation(num_pages - 1)[:B * max_pages].reshape(
-            B, max_pages), jnp.int32)
-    seq_lens = jnp.asarray([1, 93, 256, 512], jnp.int32)
-    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    ref = pa.paged_attention_on_gathered(
-        q, _dense(k_pages, tables), _dense(v_pages, tables), seq_lens)
-    out = pa.paged_decode_attention(
-        q, k_pages, v_pages, tables, seq_lens, interpret=False)
-    np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                               atol=2e-3, rtol=2e-3)
 
 
 def test_multipage_kernel_matches_dense_gather():

@@ -96,6 +96,14 @@ def test_envelope_detection_and_override():
         detect_envelope(name="tpu-v99")
     # CPU backend autodetects the calibrated CPU envelope
     assert detect_envelope(jax.devices()[0]).name == "cpu"
+    # accelerators resolve by exact device_kind; one the peaks table
+    # does not name is an error, never a default
+    import types
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert detect_envelope(v5e) is ENVELOPES["tpu-v5e"]
+    with pytest.raises(ValueError, match="no peaks for device_kind"):
+        detect_envelope(types.SimpleNamespace(
+            platform="tpu", device_kind="TPU v99"))
 
 
 def test_accountant_window_and_totals():

@@ -170,21 +170,6 @@ def test_pallas_ragged_kernel_block_size_invariance():
                                    rtol=2e-3, atol=2e-3)
 
 
-@pytest.mark.slow
-def test_pallas_ragged_kernel_compiled_tpu():
-    """Compiled-kernel equivalence — needs real TPU hardware (the
-    interpret-mode gates above cover CPU CI)."""
-    if jax.devices()[0].platform == "cpu":
-        pytest.skip("compiled Pallas kernel requires a TPU")
-    rng = np.random.default_rng(13)
-    c = _ragged_case(rng, [(37, 1), (0, 24), (130, 1), (65, 9)],
-                     page_size=16, kvh=4, group=2, d=128)
-    out = _kernel_out(c, interpret=False, q_block=8, pages_per_block=4)
-    ref = _oracle_out(c)
-    np.testing.assert_allclose(out[c["valid"]], ref[c["valid"]],
-                               rtol=2e-3, atol=2e-3)
-
-
 def test_ragged_op_ctx_bucketing_matches_full_table():
     """ctx_pages bounds the gather to the pages that exist — same
     output as gathering the whole table."""
