@@ -5,8 +5,9 @@ python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_engine.py; here
 the engine itself is built TPU-first — SURVEY.md §7 hard part #1).
 
 Design:
-- ONE dispatch per tick: a tick with prefilling slots runs the unified
-  ragged step — one jitted program consuming a flat ragged token batch
+- ONE forward dispatch per tick (the key split and the position update
+  beside it are small programs of their own): a tick with prefilling
+  slots runs the unified ragged step — one jitted program consuming a flat ragged token batch
   (each decoding slot contributes 1 token, prefilling slots contribute
   chunks packed under a Sarathi-style token budget; Ragged Paged
   Attention, PAPERS.md). Pure-decode ticks run the device-resident
@@ -37,7 +38,7 @@ import itertools
 import json
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -388,6 +389,7 @@ class _InflightTick:
     tick was in flight has its over-generated token discarded."""
     tokens: Any                     # (B,) device array, copy in flight
     active: "np.ndarray"            # host active mask at dispatch
+    tick: int = 0                   # engine tick that dispatched it
 
 
 def derive_seed(request_id: str) -> int:
@@ -414,6 +416,7 @@ def _row_sample_keys(seeds, idx):
     )(seeds, idx)
 
 
+@jax.named_scope("sample")
 def _sample(logits, key, temps, top_ps, top_ks=None, rep_pens=None,
             seen=None, all_greedy: bool = False, row_keys=None):
     """logits: (B, V) f32; temps/top_ps/top_ks/rep_pens: (B,);
@@ -497,6 +500,84 @@ class _Stage:
             return jax.device_put(x, self.device)
         return jax.device_put(x, sharding if sharding is not None
                               else self.repl)
+
+
+# The named phases of a tick, in the order a tick meets them. Each is a
+# host span `engine.<phase>` on the profiler's clock and an entry of the
+# per-tick table behind stats()["tick_phases"] / ["tick_times"].
+TICK_PHASES = ("sched", "pack", "account", "dispatch", "readback_wait",
+               "fold", "refresh")
+
+
+class _Phase:
+    """One phase of a tick: a `jax.profiler.TraceAnnotation` named
+    `engine.<name>` — so the span lands on the `/host:CPU` plane of
+    whatever capture is running (the benchmark's traced run, an
+    operator's POST /debug/profile), on the same clock as the device's
+    events — and the same interval on `perf_counter`, added to the
+    engine's per-tick phase table. Phases nest (a drain inside
+    scheduling waits, folds and refreshes): a parent is charged only
+    what none of its children covers, so a tick's entries sum to at
+    most its wall. Entering returns the annotation, for
+    `set_metadata()` of what is known only at the end. Runs under the
+    step lock, like everything that opens one."""
+
+    __slots__ = ("eng", "name", "span", "t0")
+
+    def __init__(self, eng: "InferenceEngine", name: str,
+                 args: Dict[str, Any]):
+        self.eng = eng
+        self.name = name
+        self.span = jax.profiler.TraceAnnotation("engine." + name,
+                                                 **args)
+
+    def __enter__(self):
+        self.span.__enter__()
+        eng = self.eng
+        now = time.perf_counter()
+        stack = eng._phase_stack
+        if stack:
+            eng._phase_s[stack[-1].name] += now - stack[-1].t0
+        stack.append(self)
+        self.t0 = now
+        return self.span
+
+    def __exit__(self, *exc):
+        eng = self.eng
+        now = time.perf_counter()
+        eng._phase_s[self.name] += now - self.t0
+        stack = eng._phase_stack
+        stack.pop()
+        if stack:
+            stack[-1].t0 = now
+        self.span.__exit__(*exc)
+
+
+class _TickRecord(NamedTuple):
+    """One entry of the tick ring. The first three fields are the ring's
+    old (wall, host, device) triple; `host_ms` is the `fold` phase and
+    `device_ms` the `readback_wait` phase."""
+    wall_ms: float
+    host_ms: float
+    device_ms: float
+    start: float            # perf_counter (the monotonic clock) at entry
+    gap_ms: float           # previous step's end to this start, with work
+    kind: str               # "ragged" | "decode" | "" (no such dispatch)
+    T: int
+    ctx: int
+    rows: int
+    prefill_tokens: int
+    phases_ms: Dict[str, float]     # TICK_PHASES and "other"
+    compiles: int                   # programs built during the tick
+
+    def brief(self) -> Dict[str, Any]:
+        return {"start": self.start, "wall_ms": round(self.wall_ms, 3),
+                "gap_ms": round(self.gap_ms, 3), "kind": self.kind,
+                "T": self.T, "ctx": self.ctx, "rows": self.rows,
+                "prefill_tokens": self.prefill_tokens,
+                "phases_ms": {k: round(v, 3)
+                              for k, v in self.phases_ms.items()},
+                "compiles": self.compiles}
 
 
 class InferenceEngine:
@@ -980,11 +1061,31 @@ class InferenceEngine:
         # readback) ms over a sliding window + cumulative counters
         # (stats()["tick_times"]; BENCH_CORE.md "Tick pipelining
         # anatomy")
-        self._tick_times = collections.deque(maxlen=512)
+        # 1024 records: a 50 s window of 88 ms decode ticks fits
+        self._tick_times = collections.deque(maxlen=1024)
         self._lagged_ticks = 0          # ticks folded one tick late
         self._drains = 0                # structural-event barriers
-        self._tick_host_s = 0.0         # per-tick scratch accumulators
-        self._tick_dev_s = 0.0
+        # the per-tick phase table (_Phase adds to it; step() folds it
+        # into the ring and the cumulative totals, then zeroes it) and
+        # what the tick's one dispatch carried, for its ring record
+        self._phase_s = dict.fromkeys(TICK_PHASES, 0.0)
+        self._phase_stack: List[_Phase] = []
+        self._tick_carried: Optional[Dict[str, Any]] = None
+        # monotone totals behind stats()["tick_phases"]
+        self._phase_total_s = dict.fromkeys(TICK_PHASES + ("other",), 0.0)
+        self._phase_ticks = 0
+        self._gap_total_s = 0.0
+        self._step_end: Optional[float] = None   # set while work remains
+        # monotone totals behind stats()["prefill"]
+        self._admissions = 0            # slots claimed by _admit
+        self._prompt_tokens_admitted = 0
+        self._prefill_tokens_dispatched = 0
+        # stats()["self_captures"]: what the engine's own tracing did,
+        # by trigger / cause (its lock: dump_blackbox runs lock-free)
+        self._captures_lock = threading.Lock()
+        self._profiles_armed: Dict[str, int] = {}
+        self._profiles_started = 0
+        self._blackbox_dumps: Dict[str, int] = {}
         # serializes the mutating entry points (step/abort/LoRA
         # registration): the server runs step() on an executor thread
         # while abort() fires from the event loop on client
@@ -1387,19 +1488,25 @@ class InferenceEngine:
             self._d_tables_cache = (self._tables_version, arr)
         return arr
 
-    def _read_tokens(self, dev) -> "np.ndarray":
+    def _read_tokens(self, dev, of: int = 0) -> "np.ndarray":
         """THE engine's device->host sync point: every compiled-
         program readback funnels through here — lagged async folds,
         legacy sync readbacks, pp stage outputs and speculative
         cands/preds alike. jaxlint JL005 sanctions exactly this site;
         a bare np.asarray on a dispatch result anywhere else is
         flagged (tools/jaxlint/README.md). Time spent blocked here is
-        the tick's un-hidden device time (`device_ms` in
-        stats()["tick_times"])."""
-        t0 = time.perf_counter()
-        out = np.asarray(dev)  # jaxlint: disable=JL005 -- the one sanctioned readback: the async pipeline folds land here, a tick behind dispatch
-        self._tick_dev_s += time.perf_counter() - t0
-        return out
+        the tick's un-hidden device time (the `readback_wait` phase;
+        `device_ms` in stats()["tick_times"]). `of`: the tick whose
+        program's output this waits for (a lagged fold waits for the
+        tick before), so that a trace can hold each program's end
+        against the end of its own wait."""
+        with self._phase("readback_wait", of=of):
+            return np.asarray(dev)  # jaxlint: disable=JL005 -- the one sanctioned readback: the async pipeline folds land here, a tick behind dispatch
+
+    def _phase(self, name: str, **args) -> _Phase:
+        """Open phase `name` (one of TICK_PHASES) of the current tick;
+        `args` become the span's arguments in a trace."""
+        return _Phase(self, name, args)
 
     def _ragged_fn(self, t_bucket: int, ctx_pages: int,
                    all_greedy: bool):
@@ -1759,6 +1866,7 @@ class InferenceEngine:
         """One slot's prefill chunk (full-prompt or chunked, single-
         device or pp): fold the closed-form cost into the tick sample
         AND the slot's request receipt (ISSUE 13)."""
+        self._prefill_tokens_dispatched += n
         if self.perf is None:
             return
         c = self.perf.model.chunk_cost(start, n)
@@ -1767,125 +1875,152 @@ class InferenceEngine:
             self.attrib.charge(slot.request, c, prefill_tokens=n,
                                pages=len(slot.pages))
 
-    def _account_decode_batch(self, kind: str = "decode") -> None:
+    def _account_decode_batch(self, kind: str = "decode"
+                              ) -> Tuple[int, int]:
         """One whole-batch decode dispatch: every active slot advances
-        one token at its current context."""
-        if self.perf is None:
-            return
-        cm = self.perf.model
-        tot: Dict[str, float] = {}
-        ndec = 0
-        for s in self.slots:
-            if s.request is None or not s.ready \
-                    or not self._host_active[s.index]:
-                continue
-            c = cm.decode_cost(s.position + 1)
-            self._merge_cost(tot, c)
-            if self.attrib is not None:
-                # the SAME closed-form dict rides both sides, so the
-                # receipt sum conserves against the tick total exactly
-                self.attrib.charge(s.request, c, decode_tokens=1,
-                                   pages=len(s.pages))
-            ndec += 1
-        if ndec:
-            self.perf.add(kind, tot, decode_tokens=ndec)
+        one token at its current context. Returns (rows, the context
+        tokens their attention reads) for the dispatch span."""
+        with self._phase("account"):
+            cm = self.perf.model if self.perf is not None else None
+            tot: Dict[str, float] = {}
+            ndec = kv = 0
+            # the host's positions lag the device's by the tick in
+            # flight, if one is
+            ahead = 1 if self._inflight is not None else 0
+            for s in self.slots:
+                if s.request is None or not s.ready \
+                        or not self._host_active[s.index]:
+                    continue
+                ndec += 1
+                kv += s.position + 1 + ahead
+                if cm is None:
+                    continue
+                c = cm.decode_cost(s.position + 1)
+                self._merge_cost(tot, c)
+                if self.attrib is not None:
+                    # the SAME closed-form dict rides both sides, so
+                    # the receipt sum conserves against the tick total
+                    # exactly
+                    self.attrib.charge(s.request, c, decode_tokens=1,
+                                       pages=len(s.pages))
+            if ndec and cm is not None:
+                self.perf.add(kind, tot, decode_tokens=ndec)
+            return ndec, kv
 
     def _ragged_step(self, touched: List[Request]) -> None:
         """One unified tick: pack, dispatch the single ragged program,
         fold the one readback into slot state. Host->device traffic
         per tick: ONE (5, T) token-meta upload + ONE (4, B) slot-meta
         upload (page tables and sampling params ride their caches)."""
-        self._refresh_seen()      # early-outs when nothing is dirty
-        plan = self._pack_ragged()
-        B = self.config.max_batch_size
-        total = sum(n for _, n, _ in plan)
-        self.telemetry.on_tick_budget(total, self._tick_token_budget())
-        if self.perf is not None:
-            cm = self.perf.model
-            tot: Dict[str, float] = {}
-            ndec = npre = 0
-            for ps, pn, is_pref in plan:
+        with self._phase("pack"):
+            self._refresh_seen()      # early-outs when nothing is dirty
+            plan = self._pack_ragged()
+            B = self.config.max_batch_size
+            total = sum(n for _, n, _ in plan)
+            self.telemetry.on_tick_budget(total,
+                                          self._tick_token_budget())
+            T = self._token_bucket(total)
+            # rows: tokens / slot_ids / positions / valid / lora_idx
+            tok_meta = np.zeros((5, T), np.int32)
+            # rows: start / last_idx / emit / sampling seed
+            slot_meta = np.zeros((4, B), np.int32)
+            max_start = 0
+            cur = 0
+            ndec = npre = kv = 0
+            for s, n, is_pref in plan:
+                req = s.request
                 if is_pref:
-                    c = cm.chunk_cost(ps.prefill_pos, pn)
-                    npre += pn
+                    seg = req.prompt_tokens[
+                        s.prefill_pos:s.prefill_pos + n]
+                    pos0 = s.prefill_pos
+                    npre += n
                 else:
-                    c = cm.decode_cost(ps.position + 1)
+                    seg = [s.last_token]
+                    pos0 = s.position
                     ndec += 1
-                self._merge_cost(tot, c)
-                if self.attrib is not None:
-                    self.attrib.charge(
-                        ps.request, c,
-                        decode_tokens=0 if is_pref else 1,
-                        prefill_tokens=pn if is_pref else 0,
-                        pages=len(ps.pages))
-            self.perf.add("ragged", tot, decode_tokens=ndec,
-                          prefill_tokens=npre)
-        T = self._token_bucket(total)
-        # rows: tokens / slot_ids / positions / valid / lora_idx
-        tok_meta = np.zeros((5, T), np.int32)
-        # rows: start / last_idx / emit / sampling seed
-        slot_meta = np.zeros((4, B), np.int32)
-        max_start = 0
-        cur = 0
-        for s, n, is_pref in plan:
-            req = s.request
-            if is_pref:
-                seg = req.prompt_tokens[s.prefill_pos:s.prefill_pos + n]
-                pos0 = s.prefill_pos
+                kv += pos0 + n       # the context the row's last token reads
+                tok_meta[0, cur:cur + n] = seg
+                tok_meta[1, cur:cur + n] = s.index
+                tok_meta[2, cur:cur + n] = np.arange(pos0, pos0 + n)
+                tok_meta[3, cur:cur + n] = 1
+                tok_meta[4, cur:cur + n] = self._lora_names.get(
+                    req.lora, 0)
+                slot_meta[0, s.index] = pos0
+                slot_meta[1, s.index] = cur + n - 1
+                slot_meta[2, s.index] = ((not is_pref)
+                                         or s.prefill_pos + n
+                                         >= len(req.prompt_tokens))
+                slot_meta[3, s.index] = s.seed
+                max_start = max(max_start, pos0)
+                cur += n
+            samp, all_greedy = self._sampling_cache()
+            ctx = self._ctx_bucket(max_start)
+            built = self.compiles
+            fn = self._ragged_fn(T, ctx, all_greedy)
+            built = self.compiles - built
+        if self.perf is not None:
+            with self._phase("account"):
+                cm = self.perf.model
+                tot: Dict[str, float] = {}
+                for ps, pn, is_pref in plan:
+                    if is_pref:
+                        c = cm.chunk_cost(ps.prefill_pos, pn)
+                    else:
+                        c = cm.decode_cost(ps.position + 1)
+                    self._merge_cost(tot, c)
+                    if self.attrib is not None:
+                        self.attrib.charge(
+                            ps.request, c,
+                            decode_tokens=0 if is_pref else 1,
+                            prefill_tokens=pn if is_pref else 0,
+                            pages=len(ps.pages))
+                self.perf.add("ragged", tot, decode_tokens=ndec,
+                              prefill_tokens=npre)
+        self._prefill_tokens_dispatched += npre
+        # what this tick's one program carries: the dispatch span's
+        # arguments (only the host can say this of a `jit_run`) and the
+        # tick's ring record
+        carried = self._tick_carried = {
+            "tick": self.ticks, "kind": "ragged", "T": T, "ctx": ctx,
+            "rows": len(plan),
+            "decode_rows": ndec, "prefill_tokens": npre,
+            "kv_tokens": kv, "built": built}
+        with self._phase("dispatch", **carried):
+            self._key, sub = jax.random.split(self._key)
+            self.dispatches += 1
+            if self._kv_kind != "f32":
+                (toks, self.k_pages, self.v_pages, self.k_scales,
+                 self.v_scales, self._d_seen) = fn(
+                    self.params, self.k_pages, self.v_pages,
+                    self.k_scales, self.v_scales, self._d_seen,
+                    self._dev(jnp.asarray(tok_meta)),
+                    self._dev(jnp.asarray(slot_meta)),
+                    samp, self._device_tables(), sub,
+                    self._lora_stacks, all_greedy)
             else:
-                seg = [s.last_token]
-                pos0 = s.position
-            tok_meta[0, cur:cur + n] = seg
-            tok_meta[1, cur:cur + n] = s.index
-            tok_meta[2, cur:cur + n] = np.arange(pos0, pos0 + n)
-            tok_meta[3, cur:cur + n] = 1
-            tok_meta[4, cur:cur + n] = self._lora_names.get(req.lora, 0)
-            slot_meta[0, s.index] = pos0
-            slot_meta[1, s.index] = cur + n - 1
-            slot_meta[2, s.index] = ((not is_pref)
-                                     or s.prefill_pos + n
-                                     >= len(req.prompt_tokens))
-            slot_meta[3, s.index] = s.seed
-            max_start = max(max_start, pos0)
-            cur += n
-        samp, all_greedy = self._sampling_cache()
-        ctx = self._ctx_bucket(max_start)
-        self._key, sub = jax.random.split(self._key)
-        fn = self._ragged_fn(T, ctx, all_greedy)
-        self.dispatches += 1
-        if self._kv_kind != "f32":
-            (toks, self.k_pages, self.v_pages, self.k_scales,
-             self.v_scales, self._d_seen) = fn(
-                self.params, self.k_pages, self.v_pages,
-                self.k_scales, self.v_scales, self._d_seen,
-                self._dev(jnp.asarray(tok_meta)),
-                self._dev(jnp.asarray(slot_meta)),
-                samp, self._device_tables(), sub,
-                self._lora_stacks, all_greedy)
-        else:
-            toks, self.k_pages, self.v_pages, self._d_seen = fn(
-                self.params, self.k_pages, self.v_pages, self._d_seen,
-                self._dev(jnp.asarray(tok_meta)),
-                self._dev(jnp.asarray(slot_meta)),
-                samp, self._device_tables(), sub,
-                self._lora_stacks, all_greedy)
-        toks_host = self._read_tokens(toks)
+                toks, self.k_pages, self.v_pages, self._d_seen = fn(
+                    self.params, self.k_pages, self.v_pages,
+                    self._d_seen,
+                    self._dev(jnp.asarray(tok_meta)),
+                    self._dev(jnp.asarray(slot_meta)),
+                    samp, self._device_tables(), sub,
+                    self._lora_stacks, all_greedy)
+        toks_host = self._read_tokens(toks, of=self.ticks)
         # fold ALL slots from the one readback before any device-state
         # refresh (same ordering contract as _multi_decode)
-        t_h = time.perf_counter()
-        for s, n, is_pref in plan:
-            tok = int(toks_host[s.index])
-            if is_pref:
-                self.telemetry.on_prefill_chunk(s.request, n,
-                                                s.prefill_pos)
-                s.prefill_pos += n
-                if s.prefill_pos >= len(s.request.prompt_tokens):
-                    self._finish_prefill_host(s, tok, touched)
-            else:
-                s.position += 1
-                s.last_token = tok
-                self._append_token(s, tok, touched)
-        self._tick_host_s += time.perf_counter() - t_h
+        with self._phase("fold", tokens=len(plan)):
+            for s, n, is_pref in plan:
+                tok = int(toks_host[s.index])
+                if is_pref:
+                    self.telemetry.on_prefill_chunk(s.request, n,
+                                                    s.prefill_pos)
+                    s.prefill_pos += n
+                    if s.prefill_pos >= len(s.request.prompt_tokens):
+                        self._finish_prefill_host(s, tok, touched)
+                else:
+                    s.position += 1
+                    s.last_token = tok
+                    self._append_token(s, tok, touched)
         # the device-resident decode loop state (tokens/positions) is
         # stale after a ragged tick; the next pure-decode tick
         # refreshes lazily. _d_seen stays live: the program updated it
@@ -3712,82 +3847,140 @@ class InferenceEngine:
         fold (every step still dispatches exactly once, so progress
         and termination are unchanged)."""
         with self._step_lock:
+            # an armed capture starts before the tick's span opens and
+            # stops after it closes, so that it holds the span whole
             self._profile_tick_begin()
-            # tokens folded by an out-of-step drain (abort/LoRA
-            # registration) ride the NEXT step's touched list (hoisted
-            # out of the try so the MemoryError path below can still
-            # deliver them)
-            touched: List[Request] = self._pending_touched
-            self._pending_touched = []
-            try:
-                t0 = time.perf_counter()
-                self.ticks += 1
-                self._step_tick(touched)
-                wall = time.perf_counter() - t0
-                self._tick_times.append(
-                    (wall * 1e3, self._tick_host_s * 1e3,
-                     self._tick_dev_s * 1e3))
-                if self.perf is not None:
-                    # fold the tick's pending PerfSample (cost hooks
-                    # ran beside each dispatch above) into the rolling
-                    # MFU/MBU window, stamped with the tick wall
-                    sample = self.perf.commit(wall * 1e3)
-                    if sample is not None and self.attrib is not None:
-                        # split the tick's shared costs + times across
-                        # its per-request charges (ISSUE 13)
-                        self.attrib.commit(
-                            sample, host_ms=self._tick_host_s * 1e3,
-                            device_ms=self._tick_dev_s * 1e3)
-                    if sample is not None and self.anomaly is not None:
-                        ev = self.anomaly.observe(
-                            sample, wall * 1e3,
-                            self._tick_host_s * 1e3,
-                            self._tick_dev_s * 1e3, self.compiles,
-                            self.perf.envelope.peak_flops
-                            * self.perf.n_chips,
-                            self.perf.envelope.peak_bytes_per_s
-                            * self.perf.n_chips)
-                        if ev is not None:
-                            self._on_tick_anomaly(ev)
-                # reset AFTER the append (not at entry) so readback/
-                # fold cost from out-of-step drains lands in the next
-                # tick's record instead of vanishing from the telemetry
-                self._tick_host_s = 0.0
-                self._tick_dev_s = 0.0
-                self.last_step_at = time.monotonic()
-            except MemoryError as exc:
-                # page exhaustion is handled degradation, not a crash
-                # (ISSUE 10): the graceful paths (_grow_slots/_admit)
-                # never raise, so a raw MemoryError here is an
-                # uncovered allocator path — record the alert-hooked
-                # kv_exhausted event (it black-boxes a bundle), retire
-                # a victim with finish_reason="error", keep pumping
-                self._profile_abort()
-                if self.perf is not None:
-                    self.perf.abort_tick()
-                if self.attrib is not None:
-                    self.attrib.abort_tick()
-                self._handle_memory_error(exc, touched)
-                self.last_step_at = time.monotonic()
-            except BaseException as exc:
-                # a mid-tick raise (fold reservation assert,
-                # GuardViolation, allocator OOM, ...) must not leave an
-                # armed jax.profiler capture running forever — stop the
-                # trace and disarm so /debug/profile can be re-armed
-                self._profile_abort()
-                if self.perf is not None:
-                    self.perf.abort_tick()
-                if self.attrib is not None:
-                    self.attrib.abort_tick()
-                # black-box the replica's last moments (ISSUE 7):
-                # best-effort, lock-free gather — the step lock is
-                # HELD here, so the bundle builder must not re-enter
-                # stats()/step-lock paths
-                self.dump_blackbox("engine_crash", error=repr(exc))
-                raise
-            self._publish_counters_locked()
+            with jax.profiler.TraceAnnotation(
+                    "engine.step", tick=self.ticks + 1) as span:
+                touched = self._step_locked()
+                # work=0: the device idles after this tick for want of
+                # requests, not for the host
+                span.set_metadata(work=int(self._step_end is not None))
             self._profile_tick_end()
             return touched
+
+    def _step_locked(self) -> List[Request]:
+        """step()'s body, under the step lock and the tick's span."""
+        # tokens folded by an out-of-step drain (abort/LoRA
+        # registration) ride the NEXT step's touched list (hoisted
+        # out of the try so the MemoryError path below can still
+        # deliver them)
+        touched: List[Request] = self._pending_touched
+        self._pending_touched = []
+        try:
+            t0 = time.perf_counter()
+            self.ticks += 1
+            self.telemetry.tick = self.ticks
+            compiles0 = self.compiles
+            # phase time already in the table was spent by an
+            # out-of-step drain (abort, LoRA registration) since
+            # the last tick: it rides this tick's record
+            carry_s = sum(self._phase_s.values())
+            self._step_tick(touched)
+            # `wall` is what the cost model's detectors judge; the
+            # ring's record, closed below, also counts this accounting
+            # (the counters' publication walks the prefix cache)
+            wall = time.perf_counter() - t0
+            with self._phase("account"):
+                if self.perf is not None:
+                    self._commit_tick_costs(wall)
+                self._publish_counters_locked()
+            self._close_tick(t0, carry_s, compiles0)
+            self.last_step_at = time.monotonic()
+        except MemoryError as exc:
+            # page exhaustion is handled degradation, not a crash
+            # (ISSUE 10): the graceful paths (_grow_slots/_admit)
+            # never raise, so a raw MemoryError here is an
+            # uncovered allocator path — record the alert-hooked
+            # kv_exhausted event (it black-boxes a bundle), retire
+            # a victim with finish_reason="error", keep pumping
+            self._profile_abort()
+            if self.perf is not None:
+                self.perf.abort_tick()
+            if self.attrib is not None:
+                self.attrib.abort_tick()
+            self._handle_memory_error(exc, touched)
+            self._publish_counters_locked()
+            self._step_end = None
+            self.last_step_at = time.monotonic()
+        except BaseException as exc:
+            # a mid-tick raise (fold reservation assert,
+            # GuardViolation, allocator OOM, ...) must not leave an
+            # armed jax.profiler capture running forever — stop the
+            # trace and disarm so /debug/profile can be re-armed
+            self._profile_abort()
+            if self.perf is not None:
+                self.perf.abort_tick()
+            if self.attrib is not None:
+                self.attrib.abort_tick()
+            # black-box the replica's last moments (ISSUE 7):
+            # best-effort, lock-free gather — the step lock is
+            # HELD here, so the bundle builder must not re-enter
+            # stats()/step-lock paths
+            self.dump_blackbox("engine_crash", error=repr(exc))
+            raise
+        return touched
+
+    def _commit_tick_costs(self, wall: float) -> None:
+        """Fold the tick's pending PerfSample (cost hooks ran beside
+        each dispatch) into the rolling MFU/MBU window, stamped with
+        the tick wall, split it across the per-request receipts, and
+        let the anomaly detector judge the tick."""
+        host_ms = self._phase_s["fold"] * 1e3
+        device_ms = self._phase_s["readback_wait"] * 1e3
+        sample = self.perf.commit(wall * 1e3)
+        if sample is None:
+            return
+        if self.attrib is not None:
+            # split the tick's shared costs + times across its
+            # per-request charges (ISSUE 13)
+            self.attrib.commit(sample, host_ms=host_ms,
+                               device_ms=device_ms)
+        if self.anomaly is not None:
+            ev = self.anomaly.observe(
+                sample, wall * 1e3, host_ms, device_ms, self.compiles,
+                self.perf.envelope.peak_flops * self.perf.n_chips,
+                self.perf.envelope.peak_bytes_per_s * self.perf.n_chips)
+            if ev is not None:
+                self._on_tick_anomaly(ev)
+
+    def _close_tick(self, t0: float, carry_s: float,
+                    compiles0: int) -> None:
+        """End of a tick's wall: its ring record, the cumulative phase
+        totals and the gap clock; then the phase table is zeroed. The
+        table is zeroed here and not at entry, so that the readback and
+        fold of an out-of-step drain land in the next tick's record
+        (`carry_s`) and do not vanish. `other` is the wall no phase
+        covers."""
+        end = time.perf_counter()
+        wall = end - t0
+        ph = self._phase_s
+        other = max(wall + carry_s - sum(ph.values()), 0.0)
+        tot = self._phase_total_s
+        for k, v in ph.items():
+            tot[k] += v
+        tot["other"] += other
+        self._phase_ticks += 1
+        gap = 0.0
+        if self._step_end is not None:
+            # time between two ticks' walls while work remained: the
+            # pump's delivery, the event loop, this method's own
+            # epilogue and an armed profile capture's start and stop
+            gap = max(t0 - self._step_end, 0.0)
+            self._gap_total_s += gap
+        self._step_end = end if self.has_work() else None
+        c = self._tick_carried or {}
+        phases_ms = {k: v * 1e3 for k, v in ph.items()}
+        phases_ms["other"] = other * 1e3
+        self._tick_times.append(_TickRecord(
+            wall * 1e3, ph["fold"] * 1e3, ph["readback_wait"] * 1e3,
+            t0, gap * 1e3, c.get("kind", ""), c.get("T", 0),
+            c.get("ctx", 0), c.get("rows", 0),
+            c.get("prefill_tokens", 0), phases_ms,
+            self.compiles - compiles0))
+        self._tick_carried = None
+        for k in ph:
+            ph[k] = 0.0
 
     def _admit_possible(self) -> bool:
         """Could _admit place the head-of-line request this tick?
@@ -3850,33 +4043,38 @@ class InferenceEngine:
                     self.allocator.pages_needed(victim.position)))
 
     def _step_tick(self, touched: List[Request]) -> None:
-        # pick up last tick's spill copies (pure d2h, usually already
-        # streamed home — the page-migration analogue of lagged folds)
-        self._finalize_spills()
-        # deadline expiry first (ISSUE 9): an expired request must not
-        # consume this tick's budget, and an expired WAITING request
-        # must not claim the slot a live one could take
-        self._expire_deadlines(touched)
-        # admission and prefill are structural events: the in-flight
-        # tick (if any) folds BEFORE slot state moves. A backed-up
-        # waiting queue that CANNOT admit (no free slot, or pages
-        # short even with best-case prefix sharing) does not force a
-        # drain — otherwise queue pressure would degrade the pipeline
-        # to synchronous exactly in the saturated regime it targets;
-        # the retirement that eventually frees capacity drains on its
-        # own fold.
-        if self._admit_possible() \
-                or any(s.request is not None and not s.ready
-                       for s in self.slots):
-            self._drain(touched)
-        self._admit(touched)
-        # optimistic admission (ISSUE 10): extend reservations BEFORE
-        # the dispatch whose KV writes would cross them (no-op unless
-        # kv_watermark_tokens is set)
-        self._grow_slots(touched)
-        if self.config.unified_step and self.pp == 1 and any(
+        with self._phase("sched", waiting=len(self.waiting)) as span:
+            # pick up last tick's spill copies (pure d2h, usually
+            # already streamed home — the page-migration analogue of
+            # lagged folds)
+            self._finalize_spills()
+            # deadline expiry first (ISSUE 9): an expired request must
+            # not consume this tick's budget, and an expired WAITING
+            # request must not claim the slot a live one could take
+            self._expire_deadlines(touched)
+            # admission and prefill are structural events: the
+            # in-flight tick (if any) folds BEFORE slot state moves. A
+            # backed-up waiting queue that CANNOT admit (no free slot,
+            # or pages short even with best-case prefix sharing) does
+            # not force a drain — otherwise queue pressure would
+            # degrade the pipeline to synchronous exactly in the
+            # saturated regime it targets; the retirement that
+            # eventually frees capacity drains on its own fold.
+            if self._admit_possible() \
+                    or any(s.request is not None and not s.ready
+                           for s in self.slots):
+                self._drain(touched)
+            admitted = self._admissions
+            self._admit(touched)
+            # optimistic admission (ISSUE 10): extend reservations
+            # BEFORE the dispatch whose KV writes would cross them
+            # (no-op unless kv_watermark_tokens is set)
+            self._grow_slots(touched)
+            span.set_metadata(admitted=self._admissions - admitted)
+            ragged = self.config.unified_step and self.pp == 1 and any(
                 s.request is not None and not s.ready
-                for s in self.slots):
+                for s in self.slots)
+        if ragged:
             self._ragged_step(touched)
             return
         self._advance_prefill(touched)
@@ -4123,9 +4321,14 @@ class InferenceEngine:
                 self.allocator.free(shared)   # undo the match refs
                 break            # head-of-line admission control
             self.waiting.pop(0)
+            self._admissions += 1
             if req.restarts == 0:
                 # a requeued preemption victim counts once: its first
                 # admission already recorded queue-wait/prefix stats
+                # (and its prompt: what is prefilled again after that
+                # shows in stats()["prefill"] as work done twice)
+                self._prompt_tokens_admitted += (
+                    len(req.prompt_tokens) - matched)
                 self.allocator.record_match(matched,
                                             len(req.prompt_tokens))
                 self.telemetry.on_admitted(req, cached_tokens=matched)
@@ -4250,7 +4453,11 @@ class InferenceEngine:
         self._finish_prefill_host(slot, first_token, touched)
         self._refresh_device_state()
 
-    def _refresh_device_state(self) -> None:  # jaxlint: disable=JL006 -- admit/finish-time refresh (not per tick); the pp branches fan slot state out per stage by construction
+    def _refresh_device_state(self) -> None:
+        with self._phase("refresh"):
+            self._rebuild_device_state()
+
+    def _rebuild_device_state(self) -> None:  # jaxlint: disable=JL006 -- admit/finish-time refresh (not per tick); the pp branches fan slot state out per stage by construction
         """Re-upload slot state after an admit/finish. Between such
         events the decode loop is device-resident: tokens feed back from
         the previous step's output and positions advance on device, so a
@@ -4389,33 +4596,34 @@ class InferenceEngine:
         lagged=False for the retirement branch's SAME-step fold of
         the just-dispatched successor (counting it would double the
         lagged_ticks pipeline-health signal)."""
-        toks_host = self._read_tokens(rec.tokens)
+        toks_host = self._read_tokens(rec.tokens, of=rec.tick)
         if lagged:
             self._lagged_ticks += 1
-        t_h = time.perf_counter()
         page = self.allocator.page_size
         finished = False
-        for s in self.slots:
-            if not rec.active[s.index]:
-                continue
-            if s.request is None or not s.ready:
-                continue         # retired in flight: token discarded
-            s.position += 1
-            # +1-token headroom proof: admission reserves pages for
-            # prompt+max_tokens, and the pending-token invariant (the
-            # newest sampled token's KV is written one tick LATER)
-            # leaves exactly one reserved slot unused by a sync
-            # engine — the in-flight successor's write (at the new
-            # s.position) consumes it and can never pass the pages.
-            assert s.position + 1 <= len(s.pages) * page, (
-                "async fold write past allocated pages",
-                s.index, s.position, len(s.pages), page)
-            tok = int(toks_host[s.index])
-            s.last_token = tok
-            self._append_token(s, tok, touched)
-            if s.request is None:            # EOS/stop/length
-                finished = True
-        self._tick_host_s += time.perf_counter() - t_h
+        with self._phase("fold",
+                         tokens=int(np.count_nonzero(rec.active))):
+            for s in self.slots:
+                if not rec.active[s.index]:
+                    continue
+                if s.request is None or not s.ready:
+                    continue     # retired in flight: token discarded
+                s.position += 1
+                # +1-token headroom proof: admission reserves pages
+                # for prompt+max_tokens, and the pending-token
+                # invariant (the newest sampled token's KV is written
+                # one tick LATER) leaves exactly one reserved slot
+                # unused by a sync engine — the in-flight successor's
+                # write (at the new s.position) consumes it and can
+                # never pass the pages.
+                assert s.position + 1 <= len(s.pages) * page, (
+                    "async fold write past allocated pages",
+                    s.index, s.position, len(s.pages), page)
+                tok = int(toks_host[s.index])
+                s.last_token = tok
+                self._append_token(s, tok, touched)
+                if s.request is None:            # EOS/stop/length
+                    finished = True
         return finished
 
     def _decode(self, touched: List[Request]) -> None:
@@ -4430,45 +4638,56 @@ class InferenceEngine:
             # the lagged tick must land first
             self._drain(touched)
             return self._multi_decode(touched)
-        self._account_decode_batch("decode")
-        self._key, sub = jax.random.split(self._key)
-        self.dispatches += 1
-        if self._kv_kind != "f32":
-            (new_tokens, self.k_pages, self.v_pages, self.k_scales,
-             self.v_scales, self._d_seen) = self._decode_fn(
-                self.params, self.k_pages, self.v_pages,
-                self.k_scales, self.v_scales, self._d_seen,
-                self._d_tokens, self._d_positions, self._d_tables,
-                self._d_active, sub, self._d_temps, self._d_top_ps,
-                self._d_top_ks, self._d_rep_pens, self._d_seeds,
-                self._lora_stacks, self._d_lora_idx,
-                self._all_greedy)
-        else:
-            new_tokens, self.k_pages, self.v_pages, self._d_seen = \
-                self._decode_fn(
+        rows, kv = self._account_decode_batch("decode")
+        carried = self._tick_carried = {
+            "tick": self.ticks, "kind": "decode",
+            "T": self.config.max_batch_size,
+            "ctx": self.max_pages_per_seq, "rows": rows,
+            "decode_rows": rows, "prefill_tokens": 0,
+            "kv_tokens": kv, "built": 0}
+        with self._phase("dispatch", **carried):
+            self._key, sub = jax.random.split(self._key)
+            self.dispatches += 1
+            if self._kv_kind != "f32":
+                (new_tokens, self.k_pages, self.v_pages, self.k_scales,
+                 self.v_scales, self._d_seen) = self._decode_fn(
                     self.params, self.k_pages, self.v_pages,
-                    self._d_seen, self._d_tokens, self._d_positions,
-                    self._d_tables, self._d_active, sub,
-                    self._d_temps, self._d_top_ps, self._d_top_ks,
-                    self._d_rep_pens, self._d_seeds,
+                    self.k_scales, self.v_scales, self._d_seen,
+                    self._d_tokens, self._d_positions, self._d_tables,
+                    self._d_active, sub, self._d_temps, self._d_top_ps,
+                    self._d_top_ks, self._d_rep_pens, self._d_seeds,
                     self._lora_stacks, self._d_lora_idx,
                     self._all_greedy)
-        # device-side feedback for the next step
-        self._d_tokens = new_tokens
-        self._d_positions = self._d_positions + self._d_active
+            else:
+                new_tokens, self.k_pages, self.v_pages, self._d_seen = \
+                    self._decode_fn(
+                        self.params, self.k_pages, self.v_pages,
+                        self._d_seen, self._d_tokens,
+                        self._d_positions, self._d_tables,
+                        self._d_active, sub, self._d_temps,
+                        self._d_top_ps, self._d_top_ks,
+                        self._d_rep_pens, self._d_seeds,
+                        self._lora_stacks, self._d_lora_idx,
+                        self._all_greedy)
+            # device-side feedback for the next step
+            self._d_tokens = new_tokens
+            self._d_positions = self._d_positions + self._d_active
+            if self._async:
+                # two-deep pipeline: start the d2h copy of THIS tick
+                # without blocking; below, fold the PREVIOUS tick
+                # (whose copy has had a whole device step to complete)
+                # — the host fold and the device's current step
+                # overlap instead of serializing
+                start = getattr(new_tokens, "copy_to_host_async", None)
+                if start is not None:
+                    start()      # no-op cost; fold blocks if absent
         if not self._async:
-            self._post_decode(self._read_tokens(new_tokens), touched)
+            self._post_decode(
+                self._read_tokens(new_tokens, of=self.ticks), touched)
             return
-        # two-deep pipeline: start the d2h copy of THIS tick without
-        # blocking, then fold the PREVIOUS tick (whose copy has had a
-        # whole device step to complete) — the host fold and the
-        # device's current step overlap instead of serializing
-        start = getattr(new_tokens, "copy_to_host_async", None)
-        if start is not None:
-            start()              # no-op cost; fold blocks if absent
         prev = self._inflight
-        self._inflight = _InflightTick(new_tokens,
-                                       self._host_active.copy())
+        self._inflight = _InflightTick(
+            new_tokens, self._host_active.copy(), self.ticks)
         if prev is not None and self._fold_inflight(prev, touched):
             # retirement is structural: drain the successor dispatched
             # above (its token for the retired slot is the one-token
@@ -4554,39 +4773,40 @@ class InferenceEngine:
         # mid-loop refresh would roll device positions back under
         # tokens the host already emitted, desynchronizing KV from the
         # output stream
-        t_h = time.perf_counter()
         dirty = False
-        for i in range(toks_host.shape[0]):
-            for s in self.slots:
-                if s.request is None or not self._host_active[s.index]:
-                    continue
-                if budget[s.index] <= i:
-                    continue
-                s.position += 1
-                tok = int(toks_host[i, s.index])
-                s.last_token = tok
-                self._append_token(s, tok, touched)
-                if s.request is None:       # EOS/max_tokens this step
-                    dirty = True
-        self._tick_host_s += time.perf_counter() - t_h
+        with self._phase("fold", tokens=int(
+                np.minimum(budget, toks_host.shape[0]).sum())):
+            for i in range(toks_host.shape[0]):
+                for s in self.slots:
+                    if s.request is None \
+                            or not self._host_active[s.index]:
+                        continue
+                    if budget[s.index] <= i:
+                        continue
+                    s.position += 1
+                    tok = int(toks_host[i, s.index])
+                    s.last_token = tok
+                    self._append_token(s, tok, touched)
+                    if s.request is None:   # EOS/max_tokens this step
+                        dirty = True
         if dirty:
             self._refresh_device_state()
 
     def _post_decode(self, host_tokens: "np.ndarray",
                      touched: List[Request]) -> None:
         """Shared decode tail: fold the one readback into slot state."""
-        t_h = time.perf_counter()
         dirty = False
-        for s in self.slots:
-            if s.request is None or not self._host_active[s.index]:
-                continue
-            s.position += 1          # the fed token is now cached
-            tok = int(host_tokens[s.index])
-            s.last_token = tok
-            self._append_token(s, tok, touched)
-            if s.request is None:    # finished this step
-                dirty = True
-        self._tick_host_s += time.perf_counter() - t_h
+        with self._phase("fold", tokens=int(
+                np.count_nonzero(self._host_active))):
+            for s in self.slots:
+                if s.request is None or not self._host_active[s.index]:
+                    continue
+                s.position += 1      # the fed token is now cached
+                tok = int(host_tokens[s.index])
+                s.last_token = tok
+                self._append_token(s, tok, touched)
+                if s.request is None:    # finished this step
+                    dirty = True
         if dirty:
             self._refresh_device_state()
 
@@ -4711,9 +4931,16 @@ class InferenceEngine:
                 log_dir = tempfile.mkdtemp(prefix="ray_tpu_llm_prof_")
             self._profile = {"remaining": int(ticks), "dir": log_dir,
                              "cm": None}
+        self._count_capture(self._profiles_armed, "manual")
         self.telemetry.recorder.record(
             "profile_armed", ticks=int(ticks), log_dir=log_dir)
         return log_dir
+
+    def _count_capture(self, table: Dict[str, int], key: str) -> None:
+        """stats()["self_captures"]: one more profile armed (by
+        trigger) or black-box bundle dumped (by cause)."""
+        with self._captures_lock:
+            table[key] = table.get(key, 0) + 1
 
     def _profile_tick_begin(self) -> None:
         """Start the armed jax.profiler trace (called under the step
@@ -4731,6 +4958,7 @@ class InferenceEngine:
                                            error=repr(e))
             return
         ps["cm"] = cm
+        self._profiles_started += 1
 
     def _profile_tick_end(self) -> None:
         ps = self._profile
@@ -4780,6 +5008,7 @@ class InferenceEngine:
         log_dir = tempfile.mkdtemp(prefix="ray_tpu_llm_prof_")
         self._profile = {"remaining": int(ticks), "dir": log_dir,
                          "cm": None}
+        self._count_capture(self._profiles_armed, trigger)
         self.telemetry.recorder.record(
             "profile_armed", ticks=int(ticks), log_dir=log_dir,
             trigger=trigger)
@@ -4866,7 +5095,7 @@ class InferenceEngine:
                     "active": self.num_active(),
                     "waiting": len(self.waiting),
                 },
-                "tick_times_ms": [list(t) for t in ticks],
+                "tick_times_ms": [list(t[:3]) for t in ticks],
                 "flight_recorder": self.telemetry.recorder.events(),
                 "in_flight_requests": self.telemetry.live_snapshot(),
                 "waiting_requests": [r.request_id for r in self.waiting],  # racelint: disable=RL004 -- lock-free by contract: the crash path holds _step_lock; reads the published list reference
@@ -4906,6 +5135,7 @@ class InferenceEngine:
             }
             bid = self.blackbox.dump(cause, bundle)
             if bid is not None:
+                self._count_capture(self._blackbox_dumps, cause)
                 self.telemetry.recorder.record(
                     "blackbox_dump", cause=cause, bundle_id=bid)
             return bid
@@ -4969,6 +5199,10 @@ class InferenceEngine:
         dev = sum(t[2] for t in ticks)
         out = {
             "window": n,
+            # the clock of every `start` below, read now: a reader with
+            # two snapshots knows which records fall between them
+            "now": time.perf_counter(),
+            "longest": self._longest_ticks(ticks),
             "wall_ms_avg": round(wall / n, 3) if n else 0.0,
             "host_ms_avg": round(host / n, 3) if n else 0.0,
             "device_ms_avg": round(dev / n, 3) if n else 0.0,
@@ -4983,6 +5217,28 @@ class InferenceEngine:
             for q, tag in ((0.50, "p50"), (0.95, "p95"), (0.99, "p99")):
                 out[f"{name}_{tag}"] = round(self._pctl(vals, q), 3)
         return out
+
+    @staticmethod
+    def _longest_ticks(ticks, k: int = 8) -> Dict[str, Any]:
+        """The ring's k worst ticks by stall — a tick's wall over the
+        median wall of its kind (ragged, decode or neither) — and its k
+        longest between-tick gaps, each with its start, what it
+        carried, its phases and the programs built during it: enough
+        to say of one event what the tick was doing."""
+        by_kind: Dict[str, List[float]] = {}
+        for t in ticks:
+            by_kind.setdefault(t.kind, []).append(t.wall_ms)
+        median = {kind: sorted(v)[len(v) // 2]
+                  for kind, v in by_kind.items()}
+        stalls = sorted(ticks, key=lambda t: median[t.kind] - t.wall_ms)
+        gaps = sorted(ticks, key=lambda t: -t.gap_ms)
+        return {
+            "median_wall_ms": {kind: round(v, 3)
+                               for kind, v in median.items()},
+            "stalls": [{**t.brief(), "excess_ms": round(
+                t.wall_ms - median[t.kind], 3)} for t in stalls[:k]],
+            "gaps": [t.brief() for t in gaps[:k] if t.gap_ms > 0],
+        }
 
     def stats(self) -> Dict[str, Any]:
         # ONE _step_lock acquisition around the whole mutable-state
@@ -5000,8 +5256,11 @@ class InferenceEngine:
                 "free_pages": self.allocator.free_pages,
                 "total_pages": self.allocator.num_usable,
                 # unified-step telemetry: ticks counts step() calls,
-                # dispatches counts compiled-program executions — the
-                # ragged step's contract is a 1.0 ratio on work ticks
+                # dispatches counts the FORWARD programs the host
+                # launched — the ragged step's contract is a 1.0 ratio
+                # on work ticks. The device runs more per tick (the key
+                # split, the position update, refresh uploads: ~4 on
+                # the chip, PERF.md §5); only a trace counts those
                 "ticks": self.ticks,
                 "dispatches": self.dispatches,
                 "dispatches_per_step": round(
@@ -5033,7 +5292,29 @@ class InferenceEngine:
                 # tick-pipeline telemetry (ISSUE 4): wall vs host-fold
                 # vs blocked-readback per tick + lag/drain counters
                 "tick_times": self._tick_times_summary_locked(),
+                # where a tick's wall goes (ISSUE 25): monotone seconds
+                # per named phase (TICK_PHASES; `other` is wall that no
+                # phase covers) and between ticks while work remained
+                "tick_phases": {
+                    "ticks": self._phase_ticks,
+                    "seconds": {k: round(v, 6) for k, v
+                                in self._phase_total_s.items()},
+                    "gap_s": round(self._gap_total_s, 6)},
+                # prompt tokens owed (admitted, less the prefix
+                # cache's share) against prefill tokens run: the
+                # second over the first, less one, is work done twice
+                "prefill": {
+                    "prompt_tokens_admitted":
+                        self._prompt_tokens_admitted,
+                    "prefill_tokens_dispatched":
+                        self._prefill_tokens_dispatched},
             }
+            with self._captures_lock:
+                # what the engine's own tracing did, by trigger / cause
+                snap["self_captures"] = {
+                    "profiles_armed": dict(self._profiles_armed),
+                    "profiles_started": self._profiles_started,
+                    "blackbox_dumps": dict(self._blackbox_dumps)}
             alloc_stats = self.allocator.stats()
             spec = self._spec
             spec_snap = (None if spec is None or not spec["rounds"]

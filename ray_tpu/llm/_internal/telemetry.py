@@ -302,7 +302,7 @@ class _Timeline:
     __slots__ = ("rid", "tid", "queued", "admitted", "first_token",
                  "last_token", "finished", "reason", "prompt_len",
                  "cached_tokens", "n_tokens", "chunks", "lora",
-                 "trace", "batch")
+                 "trace", "batch", "admitted_tick", "first_token_tick")
 
     def __init__(self, rid: str, tid: int, queued: float,
                  prompt_len: int, lora: Optional[str],
@@ -319,7 +319,14 @@ class _Timeline:
         self.prompt_len = prompt_len
         self.cached_tokens = 0
         self.n_tokens = 0
-        self.chunks: List[tuple] = []     # (ts, n_tokens, start_pos)
+        # (ts, n_tokens, start_pos, engine tick)
+        self.chunks: List[tuple] = []
+        # the engine tick (`engine.step`'s `tick` argument) of the
+        # admission and of the first token: with the chunks' ticks, a
+        # request's wait reads as a list of ticks, and each tick's
+        # spans say what else it carried
+        self.admitted_tick: Optional[int] = None
+        self.first_token_tick: Optional[int] = None
         self.lora = lora
         # distributed trace context minted at the fleet ingress
         # ({"trace_id", "span_id", "flow_id"}): lifecycle spans carry
@@ -344,6 +351,9 @@ class _Timeline:
             "prompt_tokens": self.prompt_len,
             "cached_tokens": self.cached_tokens,
             "generated_tokens": self.n_tokens,
+            "admitted_tick": self.admitted_tick,
+            "prefill_ticks": [c[3] for c in self.chunks],
+            "first_token_tick": self.first_token_tick,
             "lora": self.lora,
             **({"trace_id": self.trace.get("trace_id")}
                if self.trace else {}),
@@ -367,6 +377,9 @@ class EngineTelemetry:
         self.slo_targets = dict(DEFAULT_SLO_TARGETS)
         self.slo_targets.update(slo_targets or {})
         self.recorder = FlightRecorder(enabled=enabled)
+        # the engine's tick counter, set by step(): what the timeline
+        # marks below record beside their time
+        self.tick = 0
         self._lock = threading.Lock()
         self._live: Dict[str, _Timeline] = {}
         self._done: "collections.deque" = collections.deque(
@@ -433,6 +446,7 @@ class EngineTelemetry:
             if t is None:
                 return
             t.admitted = now
+            t.admitted_tick = self.tick
             t.cached_tokens = cached_tokens
             wait = max(now - t.queued, 0.0)
             if t.batch:
@@ -462,7 +476,8 @@ class EngineTelemetry:
         with self._lock:
             t = self._live.get(req.request_id)
             if t is not None and len(t.chunks) < _MAX_CHUNK_MARKS:
-                t.chunks.append((_now(), n_tokens, start_pos))
+                t.chunks.append((_now(), n_tokens, start_pos,
+                                 self.tick))
 
     def on_token(self, req) -> None:
         """One host-visible output token (runs per token per fold —
@@ -484,10 +499,13 @@ class EngineTelemetry:
                 # latency families — a token held back by a
                 # preemption window is the lane yielding, not an SLO
                 # event
-                t.first_token = t.first_token or now
+                if t.first_token is None:
+                    t.first_token = now
+                    t.first_token_tick = self.tick
                 self._batch_tokens += 1
             elif t.first_token is None:
                 t.first_token = now
+                t.first_token_tick = self.tick
                 first = max(now - t.queued, 0.0)
                 self._sums["ttft"] += first
                 self._counts["ttft"] += 1
@@ -859,16 +877,18 @@ class EngineTelemetry:
                           "cached_tokens": t.cached_tokens,
                           **({"lora": t.lora} if t.lora else {}),
                           **trace_args}))
-            for ts, n, pos in t.chunks:
+            for ts, n, pos, tick in t.chunks:
                 events.append(tracing.instant_event(
                     "prefill_chunk", "request", _wall(ts), pid=pid,
                     tid=t.tid, args={"request_id": rid, "tokens": n,
-                                     "start_pos": pos, **trace_args}))
+                                     "start_pos": pos, "tick": tick,
+                                     **trace_args}))
             if t.first_token is not None:
                 events.append(tracing.instant_event(
                     "first_token", "request", _wall(t.first_token),
                     pid=pid, tid=t.tid,
-                    args={"request_id": rid, **trace_args}))
+                    args={"request_id": rid,
+                          "tick": t.first_token_tick, **trace_args}))
                 end_d = t.finished or now
                 events.append(tracing.complete_event(
                     "decode", "request", _wall(t.first_token),

@@ -244,30 +244,41 @@ def decoder_layer(cfg: LlamaConfig, x: jax.Array, layer: Dict[str, jax.Array],
     b, s, h = x.shape
     dt = cfg.dtype
 
-    # Attention block
-    y = rms_norm(x, layer["ln1"], cfg.norm_eps)
-    q = (y @ layer["wq"].astype(dt)).reshape(b, s, cfg.n_heads, cfg.head_dim)
-    k = (y @ layer["wk"].astype(dt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    v = (y @ layer["wv"].astype(dt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    q = wlc(apply_rope(q, cos, sin), "batch", "seq", "heads", "head_dim")
-    k = wlc(apply_rope(k, cos, sin), "batch", "seq", "kv_heads", "head_dim")
-    v = wlc(v, "batch", "seq", "kv_heads", "head_dim")
-    attn = _attend(cfg, q, k, v, mesh).reshape(b, s, cfg.q_dim)
-    x = x + wlc(attn @ layer["wo"].astype(dt), "batch", "seq", "act_embed")
+    # Attention block. The scope names (here, in loss_fn and in the
+    # train step) are what the benchmark's span tables key device time
+    # on; the transposed ops of the backward pass carry them too.
+    with jax.named_scope("attn"):
+        y = rms_norm(x, layer["ln1"], cfg.norm_eps)
+        q = (y @ layer["wq"].astype(dt)).reshape(
+            b, s, cfg.n_heads, cfg.head_dim)
+        k = (y @ layer["wk"].astype(dt)).reshape(
+            b, s, cfg.n_kv_heads, cfg.head_dim)
+        v = (y @ layer["wv"].astype(dt)).reshape(
+            b, s, cfg.n_kv_heads, cfg.head_dim)
+        q = wlc(apply_rope(q, cos, sin), "batch", "seq", "heads", "head_dim")
+        k = wlc(apply_rope(k, cos, sin), "batch", "seq", "kv_heads",
+                "head_dim")
+        v = wlc(v, "batch", "seq", "kv_heads", "head_dim")
+        attn = _attend(cfg, q, k, v, mesh).reshape(b, s, cfg.q_dim)
+        x = x + wlc(attn @ layer["wo"].astype(dt), "batch", "seq",
+                    "act_embed")
 
     # MLP block: dense SwiGLU or top-k expert mixture
-    y = rms_norm(x, layer["ln2"], cfg.norm_eps)
-    if cfg.n_experts:
-        out, aux = moe_ffn(
-            y, layer["router"], layer["wi"], layer["wg"], layer["wd"],
-            top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
-            dropless=cfg.moe_dropless, mesh=mesh)
-        x = x + wlc(out, "batch", "seq", "act_embed")
-        return x, aux
-    gate = jax.nn.silu(y @ layer["wg"].astype(dt))
-    up = y @ layer["wi"].astype(dt)
-    mlp = wlc(gate * up, "batch", "seq", "mlp")
-    x = x + wlc(mlp @ layer["wd"].astype(dt), "batch", "seq", "act_embed")
+    with jax.named_scope("mlp"):
+        y = rms_norm(x, layer["ln2"], cfg.norm_eps)
+        if cfg.n_experts:
+            out, aux = moe_ffn(
+                y, layer["router"], layer["wi"], layer["wg"], layer["wd"],
+                top_k=cfg.moe_top_k,
+                capacity_factor=cfg.moe_capacity_factor,
+                dropless=cfg.moe_dropless, mesh=mesh)
+            x = x + wlc(out, "batch", "seq", "act_embed")
+            return x, aux
+        gate = jax.nn.silu(y @ layer["wg"].astype(dt))
+        up = y @ layer["wi"].astype(dt)
+        mlp = wlc(gate * up, "batch", "seq", "mlp")
+        x = x + wlc(mlp @ layer["wd"].astype(dt), "batch", "seq",
+                    "act_embed")
     return x, jnp.zeros((), jnp.float32)
 
 
@@ -298,12 +309,13 @@ def hidden_states_with_aux(cfg: LlamaConfig, params: Dict[str, Any],
     # the partitioner emit the gather feature-sharded and then
     # "involuntarily rematerialize" (replicate + repartition) it into the
     # batch/seq activation layout the next constraint demands.
-    tokens = wlc(tokens, "batch", "seq")
-    table = wlc(params["embed"].astype(dt), "vocab", "act_embed")
-    x = table[tokens]
-    x = wlc(x, "batch", "seq", "act_embed")
-    positions = jnp.arange(s)
-    cos, sin = rope_frequencies(cfg, positions)
+    with jax.named_scope("embed"):
+        tokens = wlc(tokens, "batch", "seq")
+        table = wlc(params["embed"].astype(dt), "vocab", "act_embed")
+        x = table[tokens]
+        x = wlc(x, "batch", "seq", "act_embed")
+        positions = jnp.arange(s)
+        cos, sin = rope_frequencies(cfg, positions)
 
     layer_fn = lambda x, layer: decoder_layer(cfg, x, layer, cos, sin, mesh)
     if cfg.remat:
@@ -348,53 +360,54 @@ def loss_fn(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
     """
     b, s = tokens.shape
     x, moe_aux = hidden_states_with_aux(cfg, params, tokens, mesh)  # (B,S,h)
-    # shift: position i predicts token i+1; last position is masked out.
-    # The weight for position i is the TARGET's mask (mask[i+1]), so
-    # predictions of padding tokens never contribute.
-    targets = jnp.concatenate(
-        [tokens[:, 1:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
-    if mask is not None:
-        m = jnp.concatenate(
-            [mask[:, 1:].astype(jnp.float32),
-             jnp.zeros((b, 1), jnp.float32)], axis=1)
-    else:
-        m = jnp.ones((b, s), jnp.float32).at[:, -1].set(0.0)
+    with jax.named_scope("loss_head"):
+        # shift: position i predicts token i+1; last position is masked out.
+        # The weight for position i is the TARGET's mask (mask[i+1]), so
+        # predictions of padding tokens never contribute.
+        targets = jnp.concatenate(
+            [tokens[:, 1:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
+        if mask is not None:
+            m = jnp.concatenate(
+                [mask[:, 1:].astype(jnp.float32),
+                 jnp.zeros((b, 1), jnp.float32)], axis=1)
+        else:
+            m = jnp.ones((b, s), jnp.float32).at[:, -1].set(0.0)
 
-    # Largest divisor of s within the configured chunk bound, so chunking
-    # never silently disables on awkward sequence lengths (a full-vocab
-    # (B, S, V) logits tensor is an OOM cliff, not a fallback).
-    chunk = 0
-    if cfg.loss_chunk:
-        c = min(cfg.loss_chunk, s)
-        while c > 1 and s % c:
-            c -= 1
-        chunk = c
-    if chunk and s > chunk:
-        n = s // chunk
+        # Largest divisor of s within the configured chunk bound, so chunking
+        # never silently disables on awkward sequence lengths (a full-vocab
+        # (B, S, V) logits tensor is an OOM cliff, not a fallback).
+        chunk = 0
+        if cfg.loss_chunk:
+            c = min(cfg.loss_chunk, s)
+            while c > 1 and s % c:
+                c -= 1
+            chunk = c
+        if chunk and s > chunk:
+            n = s // chunk
 
-        def chunk_nll(x_c, t_c):
-            logits = _head_logits(cfg, x_c, params["lm_head"])
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            tgt = jnp.take_along_axis(logits, t_c[..., None], axis=-1)[..., 0]
-            return lse - tgt                               # (B, chunk)
+            def chunk_nll(x_c, t_c):
+                logits = _head_logits(cfg, x_c, params["lm_head"])
+                lse = jax.nn.logsumexp(logits, axis=-1)
+                tgt = jnp.take_along_axis(logits, t_c[..., None], axis=-1)[..., 0]
+                return lse - tgt                               # (B, chunk)
 
-        chunk_nll = jax.checkpoint(chunk_nll)              # drop chunk logits
+            chunk_nll = jax.checkpoint(chunk_nll)              # drop chunk logits
 
-        def body(_, xc_tc):
-            return None, chunk_nll(*xc_tc)
+            def body(_, xc_tc):
+                return None, chunk_nll(*xc_tc)
 
-        xs = x.reshape(b, n, chunk, -1).transpose(1, 0, 2, 3)
-        ts = targets.reshape(b, n, chunk).transpose(1, 0, 2)
-        _, nll = jax.lax.scan(body, None, (xs, ts))
-        nll = nll.transpose(1, 0, 2).reshape(b, s)
-    else:
-        logits = _head_logits(cfg, x, params["lm_head"])
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+            xs = x.reshape(b, n, chunk, -1).transpose(1, 0, 2, 3)
+            ts = targets.reshape(b, n, chunk).transpose(1, 0, 2)
+            _, nll = jax.lax.scan(body, None, (xs, ts))
+            nll = nll.transpose(1, 0, 2).reshape(b, s)
+        else:
+            logits = _head_logits(cfg, x, params["lm_head"])
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
 
-    total = jnp.sum(nll * m)
-    count = jnp.maximum(jnp.sum(m), 1.0)
-    ce = total / count
+        total = jnp.sum(nll * m)
+        count = jnp.maximum(jnp.sum(m), 1.0)
+        ce = total / count
     loss = ce
     metrics = {"loss": ce, "tokens": count,
                "ppl_proxy": jnp.exp(jnp.minimum(ce, 20.0))}

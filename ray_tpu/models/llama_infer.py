@@ -71,28 +71,33 @@ def _layer_body(cfg: LlamaConfig, dt, x, layer, lora_l, lora_idx,
     local view with n_heads/n_kv_heads divided by tp) the two residual
     projections produce PARTIAL sums — all-reduce them over the named
     axis before the residual add so activations stay replicated."""
-    y = rms_norm(x, layer["ln1"], cfg.norm_eps)
-    q = _proj(y, layer["wq"], lora_l, "wq", lora_idx, dt).reshape(
-        *lead_shape, cfg.n_heads, cfg.head_dim)
-    k = _proj(y, layer["wk"], lora_l, "wk", lora_idx, dt).reshape(
-        *lead_shape, cfg.n_kv_heads, cfg.head_dim)
-    v = _proj(y, layer["wv"], lora_l, "wv", lora_idx, dt).reshape(
-        *lead_shape, cfg.n_kv_heads, cfg.head_dim)
-    q = rope_fn(q)
-    k = rope_fn(k)
-    attn = attn_fn(q, k, v)
-    attn_out = _proj(attn.reshape(*lead_shape, cfg.q_dim), layer["wo"],
-                     lora_l, "wo", lora_idx, dt)
-    if psum_axis is not None:
-        attn_out = jax.lax.psum(attn_out, psum_axis)
-    x = x + attn_out
-    y = rms_norm(x, layer["ln2"], cfg.norm_eps)
-    gate = jax.nn.silu(y @ layer["wg"].astype(dt))
-    up = y @ layer["wi"].astype(dt)
-    mlp_out = (gate * up) @ layer["wd"].astype(dt)
-    if psum_axis is not None:
-        mlp_out = jax.lax.psum(mlp_out, psum_axis)
-    x = x + mlp_out
+    # the scope names are what the benchmark's span tables key device
+    # time on (benchmarks/lib/span_reduce.py); they change HLO metadata
+    # only
+    with jax.named_scope("attn"):
+        y = rms_norm(x, layer["ln1"], cfg.norm_eps)
+        q = _proj(y, layer["wq"], lora_l, "wq", lora_idx, dt).reshape(
+            *lead_shape, cfg.n_heads, cfg.head_dim)
+        k = _proj(y, layer["wk"], lora_l, "wk", lora_idx, dt).reshape(
+            *lead_shape, cfg.n_kv_heads, cfg.head_dim)
+        v = _proj(y, layer["wv"], lora_l, "wv", lora_idx, dt).reshape(
+            *lead_shape, cfg.n_kv_heads, cfg.head_dim)
+        q = rope_fn(q)
+        k = rope_fn(k)
+        attn = attn_fn(q, k, v)
+        attn_out = _proj(attn.reshape(*lead_shape, cfg.q_dim),
+                         layer["wo"], lora_l, "wo", lora_idx, dt)
+        if psum_axis is not None:
+            attn_out = jax.lax.psum(attn_out, psum_axis)
+        x = x + attn_out
+    with jax.named_scope("mlp"):
+        y = rms_norm(x, layer["ln2"], cfg.norm_eps)
+        gate = jax.nn.silu(y @ layer["wg"].astype(dt))
+        up = y @ layer["wi"].astype(dt)
+        mlp_out = (gate * up) @ layer["wd"].astype(dt)
+        if psum_axis is not None:
+            mlp_out = jax.lax.psum(mlp_out, psum_axis)
+        x = x + mlp_out
     return x, (k, v)
 
 
@@ -419,8 +424,9 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
     (t,) = tokens.shape
     dt = cfg.dtype
     quantized = kv_kind != "f32"
-    x = params["embed"].astype(dt)[tokens]              # (T, H)
-    cos, sin = rope_frequencies(cfg, positions)         # (T, D/2)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens]          # (T, H)
+        cos, sin = rope_frequencies(cfg, positions)     # (T, D/2)
     use_kernel = impl in ("pallas", "pallas_interpret")
     kernel_quant = use_kernel and quantized
     if use_kernel:
@@ -510,14 +516,15 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
         k_pages, v_pages = scatter_kv(k_pages, v_pages, k_rows, v_rows,
                                       page_tables[slot_ids], positions,
                                       valid)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = x[last_idx]                                  # (B, H)
-    if psum_axis is not None:
-        logits = _tp_head_logits(last, params["lm_head"], psum_axis,
-                                 logits_psum)
-    else:
-        logits = last.astype(jnp.float32) @ params["lm_head"].astype(
-            jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        last = x[last_idx]                              # (B, H)
+        if psum_axis is not None:
+            logits = _tp_head_logits(last, params["lm_head"],
+                                     psum_axis, logits_psum)
+        else:
+            logits = last.astype(jnp.float32) @ params[
+                "lm_head"].astype(jnp.float32)
     if quantized:
         return logits, k_pages, v_pages, k_scales, v_scales
     return logits, k_pages, v_pages
@@ -573,9 +580,10 @@ def decode_step(cfg: LlamaConfig, params: Dict[str, Any],
     b = tokens.shape[0]
     dt = cfg.dtype
     quantized = kv_kind != "f32"
-    x = (params["embed"].astype(dt)[tokens] if hidden is None
-         else hidden.astype(dt))                    # (B, H)
-    cos, sin = rope_frequencies(cfg, positions)     # (B, D/2)
+    with jax.named_scope("embed"):
+        x = (params["embed"].astype(dt)[tokens] if hidden is None
+             else hidden.astype(dt))                # (B, H)
+        cos, sin = rope_frequencies(cfg, positions)  # (B, D/2)
 
     use_kernel = impl in ("pallas", "pallas_interpret")
     kernel_quant = use_kernel and quantized
@@ -664,13 +672,14 @@ def decode_step(cfg: LlamaConfig, params: Dict[str, Any],
         if quantized:
             return x, k_pages, v_pages, k_scales, v_scales
         return x, k_pages, v_pages
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if psum_axis is not None:
-        logits = _tp_head_logits(x, params["lm_head"], psum_axis,
-                                 logits_psum)
-    else:
-        logits = x.astype(jnp.float32) @ params["lm_head"].astype(
-            jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if psum_axis is not None:
+            logits = _tp_head_logits(x, params["lm_head"], psum_axis,
+                                     logits_psum)
+        else:
+            logits = x.astype(jnp.float32) @ params["lm_head"].astype(
+                jnp.float32)
     if quantized:
         return logits, k_pages, v_pages, k_scales, v_scales
     return logits, k_pages, v_pages

@@ -101,6 +101,7 @@ class TrainStepBundle:
             donate_argnums=(0,) if donate_state else ())
         self._eval = jax.jit(
             lambda p, t: llama.loss_fn(self.cfg, p, t, self.mesh)[1])
+        self.steps = 0                # step() calls, for the step span
 
     def _init_impl(self, key):
         params = llama.init_params(self.cfg, key)
@@ -118,10 +119,12 @@ class TrainStepBundle:
                 lambda p: llama.loss_fn(self.cfg, p, tokens, self.mesh),
                 has_aux=True)
             (loss, metrics), grads = grad_fn(params)
-        updates, opt_state = self.optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        metrics = dict(metrics)
-        metrics["grad_norm"] = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = self.optimizer.update(
+                grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            metrics = dict(metrics)
+            metrics["grad_norm"] = optax.global_norm(grads)
         return (params, opt_state), metrics
 
     # public API -----------------------------------------------------------
@@ -160,7 +163,12 @@ class TrainStepBundle:
         return params, opt_state
 
     def step(self, state, tokens):
-        with self._mesh_ctx():
+        # the host's span of the step on the profiler's clock, numbered
+        # so that a trace viewer groups the device's events by step
+        self.steps += 1
+        with jax.profiler.StepTraceAnnotation("train.step",
+                                              step_num=self.steps), \
+                self._mesh_ctx():
             return self._step(state, tokens)
 
     def eval_loss(self, state, tokens):

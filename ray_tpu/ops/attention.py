@@ -176,6 +176,7 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -303,6 +304,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv: one pass per kv head; the innermost grid dim walks every
@@ -339,6 +341,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

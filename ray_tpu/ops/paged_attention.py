@@ -435,6 +435,7 @@ def _paged_decode_multipage(q, k_pages, v_pages, page_tables, seq_lens,
             jax.ShapeDtypeStruct((b, kvh, group, 1), jnp.float32),
         ),
         interpret=interpret,
+        name="paged_decode_mp",
     )(page_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       *inputs)
 
@@ -536,6 +537,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
             jax.ShapeDtypeStruct((b, kvh, group, 1), jnp.float32),
         ),
         interpret=interpret,
+        name="paged_decode",
     )(page_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       *inputs)
     out = _fit_lanes(out.reshape(b, h, d), head_dim)

@@ -465,6 +465,7 @@ def ragged_paged_attention_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((b, qp, h, d), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(tables.astype(jnp.int32), start.astype(jnp.int32), qlen,
       *inputs)
 

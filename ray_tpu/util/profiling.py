@@ -13,7 +13,7 @@ import contextlib
 import sys
 import threading
 import traceback
-from typing import Iterator, Optional
+from typing import Iterator
 
 
 def dump_stacks() -> str:
@@ -51,25 +51,19 @@ def memory_summary() -> dict:
 
 
 @contextlib.contextmanager
-def trace(log_dir: str, *, create_perfetto_link: bool = False
-          ) -> Iterator[None]:
-    """jax.profiler trace scope: XLA execution timeline + HLO ops land in
-    `log_dir` for TensorBoard/xprof (`tensorboard --logdir ...`)."""
+def trace(log_dir: str) -> Iterator[None]:
+    """jax.profiler trace scope: the device's timeline and the host's
+    `TraceAnnotation` spans (the engine's `engine.*` tick phases among
+    them) land in `log_dir` for TensorBoard/xprof (`tensorboard --logdir
+    ...`). The Python tracer is off: it stops the process it watches for
+    long enough to show in every latency it was meant to explain."""
     import jax
 
-    jax.profiler.start_trace(log_dir,
-                             create_perfetto_link=create_perfetto_link)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def profile_step(fn, *args, log_dir: str = "/tmp/ray_tpu/profile"):
-    """Run fn under a jax profiler trace; returns (result, log_dir)."""
-    with trace(log_dir):
-        result = fn(*args)
-        import jax
-
-        jax.block_until_ready(result)
-    return result, log_dir
