@@ -851,8 +851,9 @@ def bench_long_ctx(on_tpu: bool) -> dict:
     thousand-token contexts, gather vs Pallas ragged kernel. This is
     the regime where the gather path's per-layer transient —
     T x ctx x KVH x D floats of per-token gathered context — is the
-    dominant memory term and the kernel streams pages instead (its
-    staging is O(B x chunk x H x D)). Reports tokens/s per impl plus
+    dominant memory term and the kernel streams pages instead (it
+    reads and writes the flat batch in place: T plus one query block
+    of rows, O(T x H x D)). Reports tokens/s per impl plus
     the peak per-layer attention transient each path materializes.
 
     On CPU the kernel runs in interpreter mode (Python-speed grid
@@ -912,17 +913,17 @@ def bench_long_ctx(on_tpu: bool) -> dict:
 
     # peak per-layer attention transient (bytes), analytic: the gather
     # path materializes k_ctx[slot_ids] + v_ctx[slot_ids] in f32; the
-    # kernel stages padded per-slot Q/O/new-KV in model dtype and
+    # kernel reads Q / new-KV and writes O in place in the flat batch,
+    # each padded by one query block (nothing is staged per slot), and
     # streams context pages through a fixed VMEM block
-    from ray_tpu.ops.ragged_paged_attention import DEFAULT_Q_BLOCK
+    from ray_tpu.ops.ragged_paged_attention import ragged_q_block
     t_bucket = 1 << max(budget - 1, 1).bit_length()
     max_ctx_tokens = -(-cfg.max_seq // page) * page
     kvh, h, d = cfg.n_kv_heads, cfg.n_heads, cfg.head_dim
     dt_bytes = jnp.dtype(cfg.dtype).itemsize
     gather_bytes = 2 * t_bucket * max_ctx_tokens * kvh * d * 4
-    qb = DEFAULT_Q_BLOCK
-    qp = -(-min(t_bucket, chunk) // qb) * qb
-    kernel_bytes = (batch + 1) * qp * (h + 2 * kvh) * d * dt_bytes
+    kernel_bytes = ((t_bucket + ragged_q_block(t_bucket))
+                    * (2 * h + 2 * kvh) * d * dt_bytes)
     return {
         "gather": gather, "kernel": kernel,
         "kernel_impl": kernel_impl,

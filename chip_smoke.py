@@ -443,14 +443,12 @@ def _logits_check(eng) -> dict:
             cur += n
         arrs = tuple(jnp.asarray(a) for a in
                      (toks, slots, pos, valid, start, last))
-        return (arrs, eng._ctx_bucket(max(st for _, st, _ in plan)),
-                min(T, max(ec.max_prefill_tokens, 1)))
+        return arrs, eng._ctx_bucket(max(st for _, st, _ in plan))
 
     def ragged(impl, batch, k, v):
-        (toks, slots, pos, valid, start, last), ctx, seg = batch
+        (toks, slots, pos, valid, start, last), ctx = batch
         fn = jax.jit(functools.partial(
-            ragged_forward, cfg, ctx_pages=ctx, impl=impl,
-            max_seg_len=seg))
+            ragged_forward, cfg, ctx_pages=ctx, impl=impl))
         return fn(eng.params, toks, slots, pos, valid, start, last, k, v,
                   tables)
 

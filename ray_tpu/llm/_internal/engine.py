@@ -47,6 +47,7 @@ import numpy as np
 from ...models import llama
 from ...models.llama import LlamaConfig
 from ...models.llama_infer import decode_step, prefill
+from ...ops.ragged_paged_attention import ragged_work_counts
 from ...util import thread_sanitizer
 from .kv_cache import PageAllocator
 from .telemetry import EngineTelemetry
@@ -569,6 +570,8 @@ class _TickRecord(NamedTuple):
     prefill_tokens: int
     phases_ms: Dict[str, float]     # TICK_PHASES and "other"
     compiles: int                   # programs built during the tick
+    attn_items: int = 0             # ragged kernel: live work items
+    attn_kv_blocks: int = 0         # and the KV blocks they visit
 
     def brief(self) -> Dict[str, Any]:
         return {"start": self.start, "wall_ms": round(self.wall_ms, 3),
@@ -577,7 +580,9 @@ class _TickRecord(NamedTuple):
                 "prefill_tokens": self.prefill_tokens,
                 "phases_ms": {k: round(v, 3)
                               for k, v in self.phases_ms.items()},
-                "compiles": self.compiles}
+                "compiles": self.compiles,
+                "attn_items": self.attn_items,
+                "attn_kv_blocks": self.attn_kv_blocks}
 
 
 class InferenceEngine:
@@ -760,14 +765,19 @@ class InferenceEngine:
         impl = self._resolve_impl()
         pool_dt = (cfg.dtype if self._kv_kind == "f32"
                    else kv_quant.storage_dtype(self._kv_kind))
+        # kv heads ONE shard's kernel sees, read off the sharding
+        # the pools will carry (tp engines and pp stages alike)
+        kv_sh = (self.stages[0].kv_sharding if self.stages
+                 else self._kv_sharding)
+        local_kvh = (cfg.n_kv_heads if kv_sh is None else
+                     kv_sh.shard_shape(
+                         (1, 1, 1, cfg.n_kv_heads, 1))[3])
+        # what the ragged kernel's block sizes derive from, besides a
+        # tick's T and context bucket (ragged_work_counts)
+        self._attn_geometry = (
+            local_kvh, _pa.pool_head_dim(cfg.head_dim, impl),
+            jnp.dtype(pool_dt).itemsize)
         if impl == "pallas":
-            # kv heads ONE shard's kernel sees, read off the sharding
-            # the pools will carry (tp engines and pp stages alike)
-            kv_sh = (self.stages[0].kv_sharding if self.stages
-                     else self._kv_sharding)
-            local_kvh = (cfg.n_kv_heads if kv_sh is None else
-                         kv_sh.shard_shape(
-                             (1, 1, 1, cfg.n_kv_heads, 1))[3])
             why = _pa.kernel_layout_error(self._kv_kind, local_kvh,
                                           pool_dt)
             if why is not None:
@@ -1531,11 +1541,6 @@ class InferenceEngine:
             cfg = self.model_cfg
             impl = self._resolve_impl()
             mesh = self.mesh
-            # no slot segment outgrows the chunk cap: bounds the
-            # kernel's per-slot staging pad (decode rows cost one
-            # q block, not T)
-            max_seg = min(t_bucket,
-                          max(self.config.max_prefill_tokens, 1))
             from ...models.llama_infer import ragged_forward
 
             kind = self._kv_kind
@@ -1565,8 +1570,8 @@ class InferenceEngine:
                     valid, start, last_idx, k_pages, v_pages,
                     page_tables, ctx_pages=ctx_pages, lora=lora,
                     lora_idx=lora_idx, impl=impl, mesh=mesh_fwd,
-                    max_seg_len=max_seg, kv_kind=kind,
-                    k_scales=k_scales, v_scales=v_scales, **tp_kw)
+                    kv_kind=kind, k_scales=k_scales,
+                    v_scales=v_scales, **tp_kw)
                 if kind != "f32":
                     logits, k_pages, v_pages, k_scales, v_scales = out
                 else:
@@ -1927,6 +1932,7 @@ class InferenceEngine:
             max_start = 0
             cur = 0
             ndec = npre = kv = 0
+            segs = []                # (cached tokens, tokens) per row
             for s, n, is_pref in plan:
                 req = s.request
                 if is_pref:
@@ -1939,6 +1945,7 @@ class InferenceEngine:
                     pos0 = s.position
                     ndec += 1
                 kv += pos0 + n       # the context the row's last token reads
+                segs.append((pos0, n))
                 tok_meta[0, cur:cur + n] = seg
                 tok_meta[1, cur:cur + n] = s.index
                 tok_meta[2, cur:cur + n] = np.arange(pos0, pos0 + n)
@@ -1958,6 +1965,14 @@ class InferenceEngine:
             built = self.compiles
             fn = self._ragged_fn(T, ctx, all_greedy)
             built = self.compiles - built
+            # the attention kernel's grid this tick: live (slot, query
+            # block) items of a static bound, and the KV blocks they
+            # sweep (context plus in-batch); the kernel reads the whole
+            # page table, whatever the context bucket
+            items, kv_blocks = ragged_work_counts(
+                segs, T, self.config.page_size,
+                self.max_pages_per_seq if ctx else 0,
+                *self._attn_geometry)
         if self.perf is not None:
             with self._phase("account"):
                 cm = self.perf.model
@@ -1984,7 +1999,8 @@ class InferenceEngine:
             "tick": self.ticks, "kind": "ragged", "T": T, "ctx": ctx,
             "rows": len(plan),
             "decode_rows": ndec, "prefill_tokens": npre,
-            "kv_tokens": kv, "built": built}
+            "kv_tokens": kv, "built": built,
+            "attn_items": items, "attn_kv_blocks": kv_blocks}
         with self._phase("dispatch", **carried):
             self._key, sub = jax.random.split(self._key)
             self.dispatches += 1
@@ -3977,7 +3993,8 @@ class InferenceEngine:
             t0, gap * 1e3, c.get("kind", ""), c.get("T", 0),
             c.get("ctx", 0), c.get("rows", 0),
             c.get("prefill_tokens", 0), phases_ms,
-            self.compiles - compiles0))
+            self.compiles - compiles0, c.get("attn_items", 0),
+            c.get("attn_kv_blocks", 0)))
         self._tick_carried = None
         for k in ph:
             ph[k] = 0.0

@@ -393,10 +393,12 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
 
     mesh: optional tp Mesh — the gather impl partitions via GSPMD as
     before; the kernel impl is wrapped in shard_map over 'tp'
-    (attention is per-head: no collectives inside). max_seg_len
-    (static) bounds any one slot's token count this tick (the engine
-    passes its chunk cap so the kernel's per-slot staging doesn't pad
-    decode-heavy batches to T); -1 = no bound.
+    (attention is per-head: no collectives inside). The kernel's work
+    list (`ragged_work_list`: one (slot, query block) item per grid
+    step) is built here once for all layers. max_seg_len bounds
+    nothing since the kernel stages nothing per slot; it is accepted
+    and ignored because benchmarks/lib/checks.py, which this tree may
+    not edit, still passes it.
 
     kv_kind/k_scales/v_scales: quantized pools (ISSUE 16). With
     kv_kind in ("int8", "fp8") the pools hold narrow values and
@@ -419,8 +421,10 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
     position.
     """
     from ..ops.ragged_paged_attention import (
-        ragged_paged_attention_pallas, ragged_prefill_decode_attention)
+        ragged_paged_attention_pallas, ragged_prefill_decode_attention,
+        ragged_q_block, ragged_work_list)
 
+    del max_seg_len
     (t,) = tokens.shape
     dt = cfg.dtype
     quantized = kv_kind != "f32"
@@ -433,6 +437,9 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
         # pool stays layer-major in HBM: the scan slices one layer's
         # [pages, page, KVH, D] and the kernel streams pages from it
         k_by_layer, v_by_layer = k_pages, v_pages
+        # the kernel's grid: the same list for every layer
+        work = ragged_work_list(slot_ids, valid, start,
+                                ragged_q_block(t))
     else:
         ctx_tables = (page_tables if ctx_pages < 0
                       else page_tables[:, :ctx_pages])
@@ -456,18 +463,14 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
                 return ragged_prefill_decode_attention(
                     q, k_l, v_l, k, v, slot_ids, positions, valid,
                     start)
-            base = functools.partial(
-                ragged_paged_attention_pallas, ctx_pages=ctx_pages,
-                max_seg_len=max_seg_len,
-                interpret=(impl == "pallas_interpret"))
-            if kernel_quant:
-                # positional wrapper so shard_map's in_specs line up
-                def kernel(q_, kp, vp, tb, si, po, va, st, kn, vn,
-                           ksl, vsl):
-                    return base(q_, kp, vp, tb, si, po, va, st, kn, vn,
-                                k_scales=ksl, v_scales=vsl)
-            else:
-                kernel = base
+            # positional wrapper so shard_map's in_specs line up
+            def kernel(q_, kp, vp, tb, si, po, va, st, kn, vn, items,
+                       segs, ksl=None, vsl=None):
+                return ragged_paged_attention_pallas(
+                    q_, kp, vp, tb, si, po, va, st, kn, vn,
+                    ctx_pages=ctx_pages, k_scales=ksl, v_scales=vsl,
+                    work=(items, segs),
+                    interpret=(impl == "pallas_interpret"))
             if mesh is not None and mesh.shape.get("tp", 1) > 1:
                 # per-head attention: each tp shard streams pages for
                 # its local kv heads, no cross-shard comms
@@ -481,7 +484,9 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
                             P(None),                      # valid
                             P(None),                      # start
                             P(None, "tp", None),          # new k
-                            P(None, "tp", None)]          # new v
+                            P(None, "tp", None),          # new v
+                            P(None, None),                # work items
+                            P(None, None)]                # slot segments
                 if kernel_quant:
                     # scale blocks shard on kv heads like their pages
                     in_specs += [P(None, None, "tp"),     # k scales
@@ -490,7 +495,7 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
                     kernel, mesh=mesh, in_specs=tuple(in_specs),
                     out_specs=P(None, "tp", None), check_vma=False)
             args = (q, k_l, v_l, page_tables, slot_ids,
-                    positions, valid, start, k, v)
+                    positions, valid, start, k, v, *work)
             if kernel_quant:
                 args += (ks_l, vs_l)
             return kernel(*args)

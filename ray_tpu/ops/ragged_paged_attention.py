@@ -25,11 +25,18 @@ Two implementations:
   transient per layer.
 - Pallas kernel (`ragged_paged_attention_pallas`): flash-style online
   softmax that STREAMS each slot's KV pages through VMEM (manual DMA
-  off the scalar-prefetched page table, the
-  `_paged_decode_kernel_mp` scaffolding) and applies the per-slot
-  causal rule blockwise — no [T, ctx] score or gathered-context
-  tensor ever exists. Decode rows (1 token) and prefill chunks
-  (C tokens) share the one program.
+  off the scalar-prefetched page table, double-buffered) and applies
+  the per-slot causal rule blockwise — no [T, ctx] score or
+  gathered-context tensor ever exists. Its grid is a WORK LIST: one
+  step per (slot, block of up to 128 of the slot's tokens) that the
+  tick holds, built on the device from the token counts the tick
+  uploads (`ragged_work_list`) and padded to a static bound of
+  ceil(T / 128) + slots; a step loops over the context blocks that
+  exist for its slot and the in-batch blocks up to the causal
+  diagonal, so a tick costs its tokens and their contexts, not its
+  bucket. Decode rows (1 token) and prefill chunks (C tokens) share
+  the one program; q, new K/V and the output are read and written in
+  place in the flat [T, ...] arrays.
 """
 
 from __future__ import annotations
@@ -44,11 +51,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .paged_attention import _fit_lanes
-
-# default flash block sizes for the Pallas ragged kernel (shared with
-# the benches' analytic staging-size math — keep in one place)
-DEFAULT_Q_BLOCK = 8
-DEFAULT_PAGES_PER_BLOCK = 8
 
 
 def ragged_prefill_decode_attention(
@@ -184,154 +186,323 @@ def ragged_attention_dense_oracle(
 
 # ----------------------------------------------------- Pallas ragged kernel
 
-def _ragged_paged_kernel(tables_ref, start_ref, qlen_ref, q_ref, k_hbm,
-                         v_hbm, *rest, page_size: int,
-                         ppb: int, n_ctx_blocks: int, q_blk: int,
+# One work item is (slot, block of up to Q_BLOCK of its tokens), and an
+# item sweeps its KV KV_BLOCK keys at a time: both the MXU's width.
+# `ragged_block_sizes` shrinks them to what a small tick or a narrow
+# table holds; nothing else sets them.
+Q_BLOCK = 128
+KV_BLOCK = 128
+# an item with at most this many tokens (a decode row, the tail of a
+# chunk) runs its flash steps on this many query rows instead of q_blk
+SMALL_ROWS = 8
+# VMEM the two double-buffered K and V page blocks may take
+_KV_VMEM_BYTES = 4 << 20
+
+
+def ragged_q_block(t: int) -> int:
+    """Query rows per work item for a tick of `t` flat tokens."""
+    return max(min(Q_BLOCK, t), 1)
+
+
+def ragged_block_sizes(t: int, page_size: int, n_ctx_pages: int,
+                       kvh: int = 1, row_width: int = 128,
+                       itemsize: int = 2) -> Tuple[int, int]:
+    """(query rows per item, pages per context block) for a tick of
+    `t` flat tokens over a table `n_ctx_pages` wide: 128 rows and 128
+    keys where the tick and the table hold that many, fewer keys where
+    `kvh` heads of `row_width` lanes would outgrow the kernel's VMEM."""
+    keys = min(KV_BLOCK, _KV_VMEM_BYTES // (4 * kvh * row_width * itemsize))
+    ppb = max(min(keys // page_size, n_ctx_pages), 1)
+    return ragged_q_block(t), ppb
+
+
+def ragged_item_bound(t: int, n_slots: int, q_blk: int) -> int:
+    """Static length of the work list: every slot with tokens adds at
+    most one partial block to the ceil(t / q_blk) full ones."""
+    return -(-t // q_blk) + n_slots
+
+
+def ragged_work_counts(segs, t: int, page_size: int,
+                       n_ctx_pages: int, kvh: int = 1,
+                       row_width: int = 128,
+                       itemsize: int = 2) -> Tuple[int, int]:
+    """Host-side count of what the kernel does for a tick whose slots
+    hold `segs` = [(cached tokens, tokens this tick)]: (live items,
+    KV blocks those items visit, context plus in-batch). Plain ints —
+    the engine's dispatch span and the tests' hand counts share it."""
+    q_blk, ppb = ragged_block_sizes(t, page_size, n_ctx_pages, kvh,
+                                    row_width, itemsize)
+    bk = ppb * page_size
+    items = kv_blocks = 0
+    for start, n in segs:
+        n_blk = -(-n // q_blk)
+        items += n_blk
+        # item qb sweeps the slot's whole cached context, then the
+        # in-batch blocks 0..qb (the causal diagonal)
+        kv_blocks += n_blk * -(-start // bk) + n_blk * (n_blk + 1) // 2
+    return items, kv_blocks
+
+
+def ragged_work_list(slot_ids: jax.Array, valid: jax.Array,
+                     start: jax.Array, q_blk: int
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """The kernel's grid, built on the device from what a tick already
+    uploads. Returns (items, segs), both int32:
+
+    items [3, n]: per work item its slot (-1 = no item: the list is
+      padded to `ragged_item_bound`), the offset of its first token in
+      the slot's segment, and that token's row in the flat batch;
+      items run in flat order (the kernel's output writes rely on it).
+    segs [3, B]: per slot its cached tokens (`start`), its tokens this
+      tick, and the flat row of the first of them.
+
+    Loop-invariant over layers: `ragged_forward` builds it once.
+    """
+    (t,) = slot_ids.shape
+    (b,) = start.shape
+    n = ragged_item_bound(t, b, q_blk)
+    # invalid rows fall into a dummy slot b
+    sid = jnp.where(valid, slot_ids, b)
+    qlen = jnp.zeros((b + 1,), jnp.int32).at[sid].add(1)[:b]
+    first = jnp.full((b + 1,), t, jnp.int32).at[sid].min(
+        jnp.arange(t, dtype=jnp.int32))[:b]
+    n_blk = -(-qlen // q_blk)
+    order = jnp.argsort(first)             # flat order, empty slots last
+    ends = jnp.cumsum(n_blk[order])
+    i = jnp.arange(n, dtype=jnp.int32)
+    rank = jnp.minimum(jnp.searchsorted(ends, i, side="right"), b - 1)
+    slot = order[rank]
+    qoff = (i - (ends[rank] - n_blk[slot])) * q_blk
+    live = i < ends[-1]
+    items = jnp.stack([jnp.where(live, slot, -1),
+                       jnp.where(live, qoff, 0),
+                       jnp.where(live, first[slot] + qoff, 0)])
+    segs = jnp.stack([start.astype(jnp.int32), qlen, first])
+    return items.astype(jnp.int32), segs
+
+
+def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
+                         v_hbm, *rest, page_size: int, ppb: int,
+                         n_ctx_pages: int, q_blk: int, small: int,
                          scale: float, kvh: int, group: int,
                          quantized: bool = False):
-    """Grid (B, NQ, NK): slot b x query block qb x kv block i.
+    """Grid (n_items,): one step per work item (slot, query block).
 
-    kv blocks [0, n_ctx_blocks) stream the slot's CACHED context pages
-    (ppb pages manually DMA'd per step off the scalar-prefetched page
-    table, exactly the `_paged_decode_kernel_mp` pattern); blocks
-    [n_ctx_blocks, NK) are the slot's own IN-BATCH KV, block-diagonal
-    causal (new block jb only feeds query blocks qb >= jb since both
-    use the same q_blk tokens). Online-softmax state (m/l/acc) lives in
-    scratch across the NK sweep of one (b, qb) block; compute for
-    blocks past the slot's context/segment is skipped via pl.when, so
-    per-slot cost scales with the KV that EXISTS — a decode row pays
-    one q block over ceil(start/page_size) pages, never a [T, ctx]
-    score tensor.
+    A step reads its q_blk query rows from the flat batch where they
+    lie (one DMA at the item's flat row), sweeps the slot's CACHED
+    context — ceil(start / bk) blocks of ppb pages DMA'd off the
+    scalar-prefetched page table, the next block in flight while this
+    one is computed — then the slot's own IN-BATCH KV up to the causal
+    diagonal (blocks 0..qb of the slot's run in the flat new-K/V), and
+    writes its q_blk output rows back at the same flat row. The sweep
+    is one in-kernel loop whose trip count comes from the prefetched
+    scalars, so an item costs the KV that EXISTS for its slot and a
+    tick costs its items: steps past the live count do nothing at all.
+
+    Online-softmax state (m/l/acc, float32) lives in scratch for the
+    item. An item with at most `small` tokens (a decode row, a chunk's
+    tail) runs every flash step on its first `small` rows only.
+
+    A block's rows past the slot's segment belong to the next slots of
+    the flat batch (or to the padding): they are computed under the
+    key mask alone, stay finite, and are written too — the items run
+    in flat order and each write is waited for, so the next item
+    overwrites them with its own rows, and the wrapper zeroes every
+    invalid row.
 
     Per-slot causal rule, blockwise: context position c attends iff
-    c < start[b]; in-batch key offset j attends query offset i iff
-    j <= i and j < q_len[b] (the engine packs each slot's tokens
-    contiguously at positions start[b] + rank, so offset order IS
-    position order).
+    c < start[slot]; in-batch key offset j attends query offset i iff
+    j <= i and j < q_len[slot] (each slot's tokens are one contiguous
+    run in position order, so offset order IS position order).
 
     quantized=True (ISSUE 16): the pools hold int8/fp8 values and two
     extra HBM refs carry the per-(row, head) f32 scales
     ([num_pages, page, KVH], ops/kv_quant.py layout). Each context
-    step DMAs the scale rows of its ppb pages alongside the pages
-    themselves and folds the dequant — one broadcast multiply — into
-    the existing f32 upcast of the VMEM block, so the quantized
-    kernel streams ~1/4 the context bytes with no extra pass. The
-    fresh in-batch KV (kn/vn) is never quantized.
+    block DMAs the scale rows of its pages alongside the pages and
+    folds the dequant — one broadcast multiply — into the upcast of
+    the VMEM block. The fresh in-batch KV is never quantized.
     """
     if quantized:
-        (ks_hbm, vs_hbm, kn_ref, vn_ref, o_ref, k_vmem, v_vmem,
-         ks_vmem, vs_vmem, sem, m_scr, l_scr, acc_scr) = rest
+        (ks_hbm, vs_hbm, kn_hbm, vn_hbm, o_hbm, q_vmem, kn_vmem,
+         vn_vmem, o_vmem, k_vmem, v_vmem, ks_vmem, vs_vmem, kv_sem,
+         io_sem, qh_scr, kh_scr, vh_scr, m_scr, l_scr, acc_scr) = rest
     else:
-        (kn_ref, vn_ref, o_ref, k_vmem, v_vmem,
-         sem, m_scr, l_scr, acc_scr) = rest
-    b = pl.program_id(0)
-    qb = pl.program_id(1)
-    i = pl.program_id(2)
-    nk = pl.num_programs(2)
+        (kn_hbm, vn_hbm, o_hbm, q_vmem, kn_vmem, vn_vmem, o_vmem,
+         k_vmem, v_vmem, kv_sem, io_sem, qh_scr, kh_scr, vh_scr, m_scr,
+         l_scr, acc_scr) = rest
+    it = pl.program_id(0)
+    slot = items_ref[0, it]
     bk = page_size * ppb
-    r = q_blk * group                      # score rows per kv head
+    d = q_vmem.shape[-1]
+    cdt = q_vmem.dtype                     # the MXU's operand type
 
-    @pl.when(i == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, -1e30)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    @pl.when(slot >= 0)
+    def _item():
+        qoff = items_ref[1, it]
+        tok0 = items_ref[2, it]
+        ctx_len = segs_ref[0, slot]
+        qlen = segs_ref[1, slot]
+        first = segs_ref[2, slot]
+        n_ctx = (ctx_len + bk - 1) // bk if n_ctx_pages else 0
+        last_page = jnp.minimum(jnp.maximum((ctx_len - 1) // page_size, 0),
+                                max(n_ctx_pages - 1, 0))
 
-    ctx_len = start_ref[b]
-    qlen = qlen_ref[b]
-    live_q = qb * q_blk < qlen
-    d = q_ref.shape[3]
-
-    def online_update(h, s, v):
-        """One flash step for kv head h: s (r, n) masked scores,
-        v (n, D) values."""
-        rows = slice(h * r, (h + 1) * r)
-        m_prev = m_scr[rows]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[rows] = (l_scr[rows] * corr
-                       + jnp.sum(p, axis=1, keepdims=True))
-        acc_scr[rows] = acc_scr[rows] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[rows] = m_new
-
-    @pl.when(live_q & (i < n_ctx_blocks) & (i * bk < ctx_len))
-    def _ctx_step():
-        last = jnp.maximum((ctx_len - 1) // page_size, 0)
-
-        def copies():
-            out = []
-            for t in range(ppb):
-                idx = tables_ref[b, jnp.minimum(i * ppb + t, last)]
-                out.append(pltpu.make_async_copy(
-                    k_hbm.at[idx], k_vmem.at[t], sem))
-                out.append(pltpu.make_async_copy(
-                    v_hbm.at[idx], v_vmem.at[t], sem))
+        def page_dma(blk, buf, go):
+            """Start (go) or await the DMAs of context block blk's ppb
+            pages into half buf of the double buffer. A loop over the
+            pages, not ppb unrolled copies: what a program pays to
+            trace and lower the kernel grows with its ref operations."""
+            def page(t, carry):
+                idx = tables_ref[slot,
+                                 jnp.minimum(blk * ppb + t, last_page)]
+                pairs = [(k_hbm, k_vmem), (v_hbm, v_vmem)]
                 if quantized:
-                    out.append(pltpu.make_async_copy(
-                        ks_hbm.at[idx], ks_vmem.at[t], sem))
-                    out.append(pltpu.make_async_copy(
-                        vs_hbm.at[idx], vs_vmem.at[t], sem))
-            return out
+                    pairs += [(ks_hbm, ks_vmem), (vs_hbm, vs_vmem)]
+                for src, dst in pairs:
+                    c = pltpu.make_async_copy(
+                        src.at[idx], dst.at[buf, t], kv_sem.at[buf])
+                    c.start() if go else c.wait()
+                return carry
 
-        for c in copies():
-            c.start()
-        for c in copies():
-            c.wait()
+            jax.lax.fori_loop(0, ppb, page, 0)
 
-        pos = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        keep = pos < ctx_len                           # (1, bk)
-        kb = k_vmem[...].astype(jnp.float32)           # (ppb, page, kvh, D)
-        vb = v_vmem[...].astype(jnp.float32)
-        if quantized:
-            # the fused dequant: one multiply against the scale rows
-            # that rode the same DMA wave as their pages
-            kb = kb * ks_vmem[...][..., None]
-            vb = vb * vs_vmem[...][..., None]
+        q_copy = pltpu.make_async_copy(
+            q_hbm.at[pl.ds(tok0, q_blk)], q_vmem, io_sem)
+        q_copy.start()
+        if n_ctx_pages:
+            pl.when(n_ctx > 0)(lambda: page_dma(0, 0, True))
+        q_copy.wait()
+
+        m_scr[...] = jnp.full_like(m_scr, -1e30)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        # per kv head its group's queries as one (q_blk * group, D)
+        # matrix, rows in (token, group) order
         for h in range(kvh):
-            q = q_ref[0, :, h * group:(h + 1) * group, :].reshape(
-                r, d).astype(jnp.float32)
-            k = kb[:, :, h].reshape(bk, d)
-            v = vb[:, :, h].reshape(bk, d)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (r, bk)
-            online_update(h, jnp.where(keep, s, -1e30), v)
+            qh_scr[h] = q_vmem[:, h * group:(h + 1) * group, :].reshape(
+                q_blk * group, d)
 
-    jb = i - n_ctx_blocks
-    @pl.when(live_q & (i >= n_ctx_blocks) & (jb <= qb)
-             & (jb * q_blk < qlen))
-    def _new_step():
-        # query offset per score row / key offset per column, in the
-        # slot's segment (offset order == position order)
-        i_tok = (qb * q_blk + jax.lax.broadcasted_iota(
-            jnp.int32, (r, q_blk), 0) // group)
-        j_tok = jb * q_blk + jax.lax.broadcasted_iota(
-            jnp.int32, (r, q_blk), 1)
-        keep = (j_tok <= i_tok) & (j_tok < qlen)       # (r, q_blk)
-        for h in range(kvh):
-            q = q_ref[0, :, h * group:(h + 1) * group, :].reshape(
-                r, d).astype(jnp.float32)
-            k = kn_ref[0, :, h].astype(jnp.float32)    # (q_blk, D)
-            v = vn_ref[0, :, h].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (r, q_blk)
-            online_update(h, jnp.where(keep, s, -1e30), v)
+        def flash_heads(r, keep):
+            """One flash step per kv head on its first r score rows,
+            against the keys and values of kh_scr / vh_scr, masked by
+            keep(r). The heads are a loop, not unrolled: the kernel's
+            code (what every program traces, lowers and compiles) is
+            one head's."""
+            mask = keep(r)
 
-    @pl.when(i == nk - 1)
-    def _finish():
-        # all-masked rows (query padding / empty slots) have l == 0 and
-        # acc == 0: the epsilon floor makes them exact zeros, keeping
-        # every output row finite (the caller re-masks by `valid`)
-        safe_l = jnp.maximum(l_scr[:], 1e-30)
-        out = acc_scr[:] / safe_l                      # (kvh*r, D)
+            def head(h, carry):
+                s = jax.lax.dot_general(
+                    qh_scr[h, :r], kh_scr[h], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(mask, s, -1e30)
+                m_prev = m_scr[h, :r]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m_prev - m_new)
+                l_scr[h, :r] = (l_scr[h, :r] * corr
+                                + jnp.sum(p, axis=1, keepdims=True))
+                acc_scr[h, :r] = (
+                    acc_scr[h, :r] * corr + jax.lax.dot_general(
+                        p.astype(cdt), vh_scr[h],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
+                m_scr[h, :r] = m_new
+                return carry
+
+            jax.lax.fori_loop(0, kvh, head, 0)
+
+        if bk != q_blk:
+            # the narrower kind of block leaves the rest of the key
+            # rows as they were: masked, but they must be finite
+            kh_scr[...] = jnp.zeros_like(kh_scr)
+            vh_scr[...] = jnp.zeros_like(vh_scr)
+
+        def load_ctx(blk):
+            buf = blk % 2
+            pl.when(blk + 1 < n_ctx)(
+                lambda: page_dma(blk + 1, 1 - buf, True))
+            page_dma(blk, buf, False)
+            kb = k_vmem[buf].astype(jnp.float32)   # (ppb, page, kvh, D)
+            vb = v_vmem[buf].astype(jnp.float32)
+            if quantized:
+                # the fused dequant: one multiply against the scale
+                # rows that rode the same DMA wave as their pages
+                kb = kb * ks_vmem[buf][..., None]
+                vb = vb * vs_vmem[buf][..., None]
+            # pages keep a token's heads together; the MXU wants one
+            # head's keys together
+            for h in range(kvh):
+                kh_scr[h, :bk] = kb[:, :, h].reshape(bk, d).astype(cdt)
+                vh_scr[h, :bk] = vb[:, :, h].reshape(bk, d).astype(cdt)
+
+        def load_new(jb):
+            base = first + jb * q_blk
+            copies = (
+                pltpu.make_async_copy(
+                    kn_hbm.at[pl.ds(base, q_blk)], kn_vmem, io_sem),
+                pltpu.make_async_copy(
+                    vn_hbm.at[pl.ds(base, q_blk)], vn_vmem, io_sem))
+            for c in copies:
+                c.start()
+            for c in copies:
+                c.wait()
+            kn = kn_vmem[...].astype(jnp.float32)      # (q_blk, kvh, D)
+            vn = vn_vmem[...].astype(jnp.float32)
+            for h in range(kvh):
+                kh_scr[h, :q_blk] = kn[:, h].astype(cdt)
+                vh_scr[h, :q_blk] = vn[:, h].astype(cdt)
+
+        few = qlen - qoff <= small
+
+        def kv_block(blk, carry):
+            """Blocks [0, n_ctx) are the slot's cached context, the
+            rest its in-batch KV; one flash step serves both, under a
+            mask whose bounds the kind of block sets."""
+            is_ctx = blk < n_ctx
+            jb = blk - n_ctx
+            if n_ctx_pages:
+                pl.when(is_ctx)(lambda: load_ctx(blk))
+            pl.when(jnp.logical_not(is_ctx))(lambda: load_new(jb))
+            first_key = jnp.where(is_ctx, blk * bk, jb * q_blk)
+            n_keys = jnp.where(is_ctx, bk, q_blk)
+            end = jnp.where(is_ctx, ctx_len, qlen)
+            # context keys precede every query; in-batch keys are causal
+            ahead = jnp.where(is_ctx, 1 << 30, 0)
+
+            def keep(r):
+                # query offset per score row / key offset per column,
+                # in the slot's context or segment
+                shape = (r, kh_scr.shape[1])
+                i_tok = qoff + jax.lax.broadcasted_iota(
+                    jnp.int32, shape, 0) // group
+                col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                key = first_key + col
+                return ((col < n_keys) & (key < end)
+                        & (key <= i_tok + ahead))
+
+            # the rows the item holds: its first `small` tokens' or
+            # all q_blk's
+            if small < q_blk:
+                pl.when(few)(lambda: flash_heads(small * group, keep))
+                pl.when(jnp.logical_not(few))(
+                    lambda: flash_heads(q_blk * group, keep))
+            else:
+                flash_heads(q_blk * group, keep)
+            return carry
+
+        jax.lax.fori_loop(0, n_ctx + qoff // q_blk + 1, kv_block, 0)
+
+        # rows the sweep left alone (past `small`) have l == 0 and
+        # acc == 0: the epsilon floor makes them exact zeros
         for h in range(kvh):
-            rows = slice(h * r, (h + 1) * r)
-            o_ref[0, :, h * group:(h + 1) * group, :] = out[rows].reshape(
-                q_blk, group, d).astype(o_ref.dtype)
+            out = acc_scr[h] / jnp.maximum(l_scr[h], 1e-30)
+            o_vmem[:, h * group:(h + 1) * group, :] = out.reshape(
+                q_blk, group, d).astype(o_vmem.dtype)
+        o_copy = pltpu.make_async_copy(
+            o_vmem, o_hbm.at[pl.ds(tok0, q_blk)], io_sem)
+        o_copy.start()
+        o_copy.wait()
 
 
 def ragged_paged_attention_pallas(
@@ -339,9 +510,8 @@ def ragged_paged_attention_pallas(
         page_tables: jax.Array, slot_ids: jax.Array,
         positions: jax.Array, valid: jax.Array, start: jax.Array,
         k_new: jax.Array, v_new: jax.Array, *, ctx_pages: int = -1,
-        max_seg_len: int = -1, q_block: int = DEFAULT_Q_BLOCK,
-        pages_per_block: int = DEFAULT_PAGES_PER_BLOCK,
         k_scales: jax.Array = None, v_scales: jax.Array = None,
+        work: Tuple[jax.Array, jax.Array] = None,
         interpret: bool = False) -> jax.Array:
     """TPU Pallas ragged paged attention: same contract as
     `ragged_paged_prefill_decode_attention`, but each slot's KV pages
@@ -353,20 +523,23 @@ def ragged_paged_attention_pallas(
     stays in HBM); page_tables: [B, max_pages]; slot_ids/positions/
     valid: [T]; start: [B]; k_new/v_new: [T, KVH, D].
 
-    Packing contract (what the engine's `_ragged_step` produces, and
-    what the kernel's segment formulation requires): each slot's valid
-    tokens form ONE run in position order with
-    positions[t] == start[slot_ids[t]] + rank-within-slot (flat order
-    of the run is irrelevant — tokens are re-packed per slot here).
-    Invalid rows are ignored on input and zero on output.
+    Packing contract (what the engine's `_ragged_step` produces): each
+    slot's valid tokens are ONE contiguous run of the flat batch, in
+    position order, with positions[t] == start[slot_ids[t]] + rank in
+    the run. Invalid rows are ignored on input and zero on output.
 
-    Static knobs: ctx_pages bounds the context sweep (-1 = whole
-    table); max_seg_len bounds any single slot's token count
-    (-1 = T) — the engine passes its chunk cap so decode-heavy ticks
-    don't pad to T; q_block / pages_per_block are the flash block
-    sizes. The per-slot padded Q/O/new-KV staging arrays are
-    [B, ceil(max_seg_len/q_block)*q_block, ...] — O(B * C * H * D),
-    vs the gather path's O(T * ctx * KVH * D) context transient.
+    The grid follows the work: one step per (slot, query block) item
+    of `ragged_work_list` — at most ceil(T / q_blk) + B of them, the
+    live ones first — and inside a step one loop over the KV blocks:
+    the context blocks that exist for the slot, then its in-batch
+    blocks up to the causal diagonal. q, new K/V and the output are read and
+    written in place in the flat [T, ...] arrays at each item's row
+    (padded by one block so the last item's block stays in bounds):
+    nothing is staged per slot. Block sizes come from
+    `ragged_block_sizes`; ctx_pages (static) says only whether any
+    slot has a context (0 = none: no context sweep is built) — the
+    kernel reads the whole page table and stops at the context that
+    exists. `work` takes a list built once for all layers.
 
     Quantized KV (ISSUE 16): pass k_scales/v_scales
     ([num_pages, page_size, KVH] f32, ops/kv_quant.py layout) when
@@ -374,102 +547,94 @@ def ragged_paged_attention_pallas(
     beside their pages and fuses the dequant multiply into the
     streaming loop. k_new/v_new stay full-precision either way.
     """
-    t, h, head_dim = q.shape
-    _, page_size, kvh, d = k_pages.shape
-    b = page_tables.shape[0]
-    group = h // kvh
-    scale = head_dim ** -0.5
-    # a lane-padded pool (paged_attention.pool_head_dim): the kernel
-    # runs at the pool's row width; zero-padded q/new-KV lanes add
-    # exact zeros to every score and output, sliced off at the end
-    q = _fit_lanes(q, d)
-    k_new = _fit_lanes(k_new, d)
-    v_new = _fit_lanes(v_new, d)
-    tables = (page_tables if ctx_pages < 0
-              else page_tables[:, :max(ctx_pages, 1)])
-    n_ctx_pages = tables.shape[1] if ctx_pages != 0 else 0
-    ppb = max(min(pages_per_block, n_ctx_pages), 1)
-    n_ctx_blocks = -(-n_ctx_pages // ppb) if n_ctx_pages else 0
-
-    q_max = t if max_seg_len < 0 else max(min(max_seg_len, t), 1)
-    q_blk = max(min(q_block, q_max), 1)
-    nq = -(-q_max // q_blk)
-    qp = nq * q_blk
-    nk = n_ctx_blocks + nq
-
-    # per-slot repack: token -> (slot, offset-within-segment); invalid
-    # rows land in a dummy slot row b that the grid never reads
-    off = jnp.clip(positions - start[slot_ids], 0, qp - 1)
-    row = jnp.where(valid, slot_ids, b)
-    q_pad = jnp.zeros((b + 1, qp, h, d), q.dtype).at[row, off].set(q)
-    kn_pad = jnp.zeros((b + 1, qp, kvh, d),
-                       k_new.dtype).at[row, off].set(k_new)
-    vn_pad = jnp.zeros((b + 1, qp, kvh, d),
-                       v_new.dtype).at[row, off].set(v_new)
-    qlen = jnp.zeros((b,), jnp.int32).at[
-        jnp.where(valid, slot_ids, 0)].add(valid.astype(jnp.int32))
-
-    io_spec = pl.BlockSpec(
-        (1, q_blk, h, d),
-        lambda bi, qb, i, tables, start, qlen: (bi, qb, 0, 0))
-
-    def new_kv_index(bi, qb, i, tables, start, qlen):
-        # clamp to the causal diagonal: blocks past qb are fully
-        # masked, re-mapping them to qb elides the DMA entirely
-        jb = jnp.clip(i - n_ctx_blocks, 0, nq - 1)
-        return (bi, jnp.minimum(jb, qb), 0, 0)
-
-    new_spec = pl.BlockSpec((1, q_blk, kvh, d), new_kv_index)
-
     quantized = k_scales is not None
     if quantized and v_scales is None:
         raise ValueError("k_scales and v_scales must come together")
-    in_specs = [
-        io_spec,                             # padded queries
-        pl.BlockSpec(memory_space=pl.ANY),   # k pool in HBM
-        pl.BlockSpec(memory_space=pl.ANY),   # v pool in HBM
-    ]
+    items, segs = (
+        ragged_work_list(slot_ids, valid, start, ragged_q_block(q.shape[0]))
+        if work is None else work)
+    flat = _ragged_call(
+        items, segs, page_tables.astype(jnp.int32), q, k_pages, v_pages,
+        k_new, v_new, k_scales, v_scales, has_ctx=ctx_pages != 0,
+        interpret=interpret)
+    return jnp.where(valid[:, None, None], flat,
+                     jnp.zeros_like(flat)).astype(q.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("has_ctx", "interpret"))
+def _ragged_call(items, segs, tables, q, k_pages, v_pages, k_new, v_new,
+                 k_scales, v_scales, *, has_ctx: bool, interpret: bool):  # jaxlint: disable=JL002 -- the pools are read, never written: an inner jit that shares the kernel's trace, inlined into the engine's program, which donates them
+    """The pallas_call of `ragged_paged_attention_pallas`, [T, H, D]
+    out (invalid rows not yet zeroed). A jit of its own, so that its
+    trace is shared: a serving engine builds one program per (token
+    bucket, context bucket) and each would trace the kernel anew, but
+    the kernel sees the whole page table whatever the context bucket
+    (its sweep stops at the context that exists), so a token bucket's
+    programs share one trace — what a warm start pays per program is
+    mostly that trace."""
+    t, h, head_dim = q.shape
+    _, page_size, kvh, d = k_pages.shape
+    group = h // kvh
+    quantized = k_scales is not None
+    n_ctx_pages = tables.shape[1] if has_ctx else 0
+    q_blk, ppb = ragged_block_sizes(
+        t, page_size, n_ctx_pages, kvh, d, k_pages.dtype.itemsize)
+    small = min(SMALL_ROWS, q_blk)
+    # a lane-padded pool (paged_attention.pool_head_dim): the kernel
+    # runs at the pool's row width; zero-padded q/new-KV lanes add
+    # exact zeros to every score and output, sliced off at the end.
+    # One block of rows past T keeps the last item's block in bounds.
+    tail = lambda x: jnp.pad(_fit_lanes(x, d),
+                             ((0, q_blk), (0, 0), (0, 0)))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    inputs = [tail(q), k_pages, v_pages]
     scratch = [
-        pltpu.VMEM((ppb, page_size, kvh, d), k_pages.dtype),
-        pltpu.VMEM((ppb, page_size, kvh, d), v_pages.dtype),
+        pltpu.VMEM((q_blk, h, d), q.dtype),            # q block
+        pltpu.VMEM((q_blk, kvh, d), k_new.dtype),      # in-batch k / v
+        pltpu.VMEM((q_blk, kvh, d), v_new.dtype),
+        pltpu.VMEM((q_blk, h, d), q.dtype),            # output block
+        pltpu.VMEM((2, ppb, page_size, kvh, d), k_pages.dtype),
+        pltpu.VMEM((2, ppb, page_size, kvh, d), v_pages.dtype),
     ]
-    inputs = [q_pad, k_pages, v_pages]
     if quantized:
         # scale pools ride beside the page pools: HBM-resident, DMA'd
         # per context block into their own VMEM scratch rows
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
-                     pl.BlockSpec(memory_space=pl.ANY)]
-        scratch += [pltpu.VMEM((ppb, page_size, kvh), jnp.float32),
-                    pltpu.VMEM((ppb, page_size, kvh), jnp.float32)]
         inputs += [k_scales.astype(jnp.float32),
                    v_scales.astype(jnp.float32)]
-    in_specs += [new_spec, new_spec]         # padded new k / new v
-    inputs += [kn_pad, vn_pad]
+        scratch += [pltpu.VMEM((2, ppb, page_size, kvh), jnp.float32),
+                    pltpu.VMEM((2, ppb, page_size, kvh), jnp.float32)]
+    inputs += [tail(k_new), tail(v_new)]
+    r = q_blk * group
+    n_keys = max(ppb * page_size, q_blk)
+    scratch += [
+        pltpu.SemaphoreType.DMA((2,)),                 # page blocks
+        pltpu.SemaphoreType.DMA,                       # q / new kv / out
+        pltpu.VMEM((kvh, r, d), q.dtype),              # q per kv head
+        pltpu.VMEM((kvh, n_keys, d), q.dtype),         # k, v per kv head
+        pltpu.VMEM((kvh, n_keys, d), q.dtype),
+        pltpu.VMEM((kvh, r, 1), jnp.float32),          # m
+        pltpu.VMEM((kvh, r, 1), jnp.float32),          # l
+        pltpu.VMEM((kvh, r, d), jnp.float32),          # acc
+    ]
 
     out = pl.pallas_call(
         functools.partial(
             _ragged_paged_kernel, page_size=page_size, ppb=ppb,
-            n_ctx_blocks=n_ctx_blocks, q_blk=q_blk, scale=scale,
-            kvh=kvh, group=group, quantized=quantized),
+            n_ctx_pages=n_ctx_pages, q_blk=q_blk, small=small,
+            scale=head_dim ** -0.5, kvh=kvh, group=group,
+            quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, nq, nk),
-            in_specs=in_specs,
-            out_specs=io_spec,
-            scratch_shapes=scratch + [
-                pltpu.SemaphoreType.DMA,
-                pltpu.VMEM((kvh * q_blk * group, 1), jnp.float32),
-                pltpu.VMEM((kvh * q_blk * group, 1), jnp.float32),
-                pltpu.VMEM((kvh * q_blk * group, d), jnp.float32),
-            ],
+            grid=(items.shape[1],),
+            in_specs=[hbm] * len(inputs),
+            out_specs=hbm,
+            scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((b, qp, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((t + q_blk, h, d), q.dtype),
+        # the items' output writes overlap and rely on their order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="ragged_paged_attention",
-    )(tables.astype(jnp.int32), start.astype(jnp.int32), qlen,
-      *inputs)
-
-    flat = _fit_lanes(out[jnp.where(valid, slot_ids, 0), off],
-                      head_dim)                        # [T, H, D]
-    return jnp.where(valid[:, None, None], flat,
-                     jnp.zeros_like(flat)).astype(q.dtype)
+    )(items, segs, tables, *inputs)
+    return _fit_lanes(out[:t], head_dim)               # [T, H, D]

@@ -162,6 +162,75 @@ def test_dispatch_arguments_equal_what_the_tick_carried(traced):
     assert dec["prefill_tokens"] == 0 and dec["kv_tokens"] > 0
 
 
+def test_dispatch_span_counts_the_attention_kernels_work(traced):
+    """`attn_items` / `attn_kv_blocks`: the ragged kernel's live grid
+    steps and the KV blocks they sweep, on the mixed tick (T 32, so a
+    query block is 32 rows and every row of the plan is one item)."""
+    spans = traced["spans"]
+    eng = traced["eng"]
+    args = next(s[4] for s in spans if s[1] == "engine.dispatch"
+                and s[4]["tick"] == traced["mixed_tick"])
+    assert args["attn_items"] == 5
+    # a context block is 128 keys (16 pages of 8): each decode row
+    # (12-15 cached tokens) sweeps one, and every item its own
+    # in-batch block
+    assert args["attn_kv_blocks"] == 3 * (1 + 1) + 2 * 1
+    rec = next(r for r in eng._tick_times
+               if r.kind == "ragged" and r.T == 32 and r.rows == 5)
+    assert (rec.attn_items, rec.attn_kv_blocks) == (5, 8)
+    assert rec.brief()["attn_items"] == 5
+    # a decode tick runs another kernel: nothing to count
+    dec = next(s[4] for s in spans if s[1] == "engine.dispatch"
+               and s[4]["kind"] == "decode")
+    assert "attn_items" not in dec
+
+
+def test_attn_counts_equal_a_hand_count_on_a_three_slot_plan():
+    """One row decoding at 19 cached tokens, a 200-token prompt and a
+    20-token prompt in one T 256 tick: q_blk 128, so the long prompt is
+    two items whose in-batch sweeps are 1 and 2 blocks (the causal
+    diagonal), the others one item and one in-batch block each; only
+    the decode row has a context, one block of it."""
+    eng = make_engine(max_prefill_tokens=256, max_num_batched_tokens=256,
+                      num_pages=256)
+    eng.add_request(_req("d", 12, max_tokens=40))
+    for _ in range(8):
+        eng.step()
+    eng.add_request(_req("long", 200))
+    eng.add_request(_req("short", 20))
+    eng.step()
+    rec = eng._tick_times[-1]
+    assert (rec.kind, rec.T, rec.rows, rec.prefill_tokens) == (
+        "ragged", 256, 3, 220)
+    assert rec.attn_items == 1 + 2 + 1
+    assert rec.attn_kv_blocks == (1 + 1) + (1 + 2) + 1
+    assert eng._tick_carried is None
+
+
+def test_benchmarks_reduction_ignores_the_new_arguments():
+    """The benchmark's span reduction reads the dispatch span's
+    arguments by name: the chip's recorded capture reduces to the same
+    tables with `attn_items` / `attn_kv_blocks` present on every
+    ragged dispatch."""
+    import copy
+    import json
+    from benchmarks.lib import span_reduce as sr
+    from benchmarks.lib.harness import ROOT
+    with open(os.path.join(ROOT, "benchmarks", "fixtures",
+                           "chat_open_ticks_spans.json")) as f:
+        cap = json.load(f)
+    cap["enqueues"] = {int(k): v for k, v in cap["enqueues"].items()}
+    more = copy.deepcopy(cap)
+    n = 0
+    for span in more["spans"]:
+        if span[1] == "engine.dispatch" and span[4]["kind"] == "ragged":
+            span[4].update(attn_items=9, attn_kv_blocks=40)
+            n += 1
+    assert n == 3
+    assert sr.tables(more) == sr.tables(cap)
+    assert sr.ragged_cost(more) == sr.ragged_cost(cap) is not None
+
+
 def test_ring_record_matches_the_dispatch_span():
     eng = make_engine()
     rec, _, _ = _drive_mixed(eng)

@@ -27,20 +27,24 @@ from ray_tpu.models import llama
 from ray_tpu.llm._internal.engine import (EngineConfig, InferenceEngine,
                                           Request, SamplingParams)
 from ray_tpu.ops.ragged_paged_attention import (
-    ragged_attention_dense_oracle, ragged_paged_attention_pallas,
-    ragged_paged_prefill_decode_attention)
+    ragged_attention_dense_oracle, ragged_block_sizes, ragged_item_bound,
+    ragged_paged_attention_pallas, ragged_paged_prefill_decode_attention,
+    ragged_work_counts, ragged_work_list)
 
 
 # ------------------------------------------------------------ op vs oracle
 
-def _ragged_case(rng, segs, page_size=4, kvh=2, group=2, d=8, pad=0):
+def _ragged_case(rng, segs, page_size=4, kvh=2, group=2, d=8, pad=0,
+                 extra_pages=0):
     """Build a ragged batch from [(start, n_tokens)] per slot, scatter
     each slot's context into a paged pool, and return everything both
-    the op and the oracle need."""
+    the op and the oracle need. extra_pages widens the page table past
+    what any slot holds."""
     b = len(segs)
     h = kvh * group
     max_ctx = max((s for s, _ in segs), default=0)
     max_pages = max(-(-max(s + n for s, n in segs) // page_size), 1)
+    max_pages += extra_pages
     num_pages = b * max_pages + 1
     k_pages = np.zeros((num_pages, page_size, kvh, d), np.float32)
     v_pages = np.zeros((num_pages, page_size, kvh, d), np.float32)
@@ -102,8 +106,6 @@ def test_ragged_op_matches_dense_oracle(name, segs, pad):
 # ------------------------------------------------ pallas kernel vs oracle
 
 def _kernel_out(c, **kw):
-    kw.setdefault("q_block", 4)
-    kw.setdefault("pages_per_block", 2)
     return np.asarray(ragged_paged_attention_pallas(
         jnp.asarray(c["q"]), jnp.asarray(c["k_pages"]),
         jnp.asarray(c["v_pages"]), jnp.asarray(c["tables"]),
@@ -146,28 +148,91 @@ def test_pallas_ragged_kernel_matches_oracle(name, segs, pad, kvh, group):
         assert np.all(out[~c["valid"]] == 0.0)
 
 
-def test_pallas_ragged_kernel_ctx_and_seg_bounds():
-    """The static bounds (ctx_pages sweep cut, max_seg_len staging cut)
-    must not change the math when they cover the live data."""
-    rng = np.random.default_rng(11)
-    c = _ragged_case(rng, [(6, 1), (0, 3), (5, 4)])
-    full = _kernel_out(c, interpret=True)
-    bounded = _kernel_out(c, interpret=True, ctx_pages=2, max_seg_len=4)
-    np.testing.assert_allclose(full[c["valid"]], bounded[c["valid"]],
-                               rtol=1e-5, atol=1e-6)
+# every edge of the kernel's work list (one (slot, query block) item
+# per grid step, `ragged_work_list`); T > 128 makes q_blk 128, so a
+# longer chunk spans several items, and page 4 makes a context block
+# 128 keys of 32 pages
+_WORK_LIST_EDGES = [
+    # name, segs [(cached, tokens)], pad, extra table pages
+    ("slot_without_tokens", [(5, 1), (0, 0), (3, 2), (0, 0)], 0, 0),
+    ("lone_decode_row", [(9, 1)], 0, 0),
+    ("all_32_slots_live",
+     [(3 * s % 11, 1 + (s % 5 == 0) * 6) for s in range(32)], 0, 0),
+    ("chunk_not_a_multiple_of_the_block", [(6, 1), (20, 200), (2, 1)],
+     0, 0),
+    ("context_zero", [(0, 150), (0, 1)], 0, 0),
+    ("context_not_a_multiple_of_the_kv_block", [(130, 3), (257, 1)],
+     0, 0),
+    ("context_bucket_wider_than_any_context", [(5, 2), (9, 1)], 0, 40),
+    # 129 = one full block and one token over: every slot adds its
+    # partial block, which is the most a list can hold
+    ("item_list_full", [(4, 129), (0, 129), (7, 1), (2, 1)], 0, 0),
+    ("padding_between_the_bound_and_the_tokens", [(3, 140)], 9, 0),
+]
 
 
-def test_pallas_ragged_kernel_block_size_invariance():
-    """Online softmax must be exact under any blocking: q_block and
-    pages_per_block sweeps agree with each other and the oracle."""
-    rng = np.random.default_rng(12)
-    c = _ragged_case(rng, [(7, 1), (0, 5), (12, 1), (4, 6)])
+@pytest.mark.parametrize("name,segs,pad,extra", _WORK_LIST_EDGES,
+                         ids=[c[0] for c in _WORK_LIST_EDGES])
+def test_pallas_ragged_kernel_work_list_edges(name, segs, pad, extra):
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    c = _ragged_case(rng, segs, pad=pad, extra_pages=extra)
+    t = len(c["valid"])
+    q_blk, _ = ragged_block_sizes(t, 4, c["tables"].shape[1])
+    live, _ = ragged_work_counts(segs, t, 4, c["tables"].shape[1])
+    bound = ragged_item_bound(t, len(segs), q_blk)
+    assert live < bound
+    if name == "item_list_full":
+        assert live == bound - 1 and q_blk == 128
+    items, seg_rows = (np.asarray(a) for a in ragged_work_list(
+        jnp.asarray(c["slot_ids"]), jnp.asarray(c["valid"]),
+        jnp.asarray(c["start"]), q_blk))
+    assert items.shape == (3, bound)
+    assert (items[0] >= 0).sum() == live
+    assert np.all(items[0, live:] == -1)       # live items first
+    assert np.all(np.diff(items[2, :live]) > 0)    # in flat order
+    np.testing.assert_array_equal(seg_rows[1], [n for _, n in segs])
+    out = _kernel_out(c, interpret=True)
     ref = _oracle_out(c)
-    for q_blk, ppb in [(1, 1), (2, 4), (8, 3)]:
-        out = _kernel_out(c, interpret=True, q_block=q_blk,
-                          pages_per_block=ppb)
-        np.testing.assert_allclose(out[c["valid"]], ref[c["valid"]],
-                                   rtol=2e-3, atol=2e-3)
+    np.testing.assert_allclose(out[c["valid"]], ref[c["valid"]],
+                               rtol=2e-3, atol=2e-3)
+    assert np.all(np.isfinite(out))
+    if (~c["valid"]).any():
+        assert np.all(out[~c["valid"]] == 0.0)
+
+
+@pytest.mark.parametrize("page_size,pad,ctx_pages", [
+    # (q_blk, pages per context block) as ragged_block_sizes derives
+    # them from T, the page size and the table's width
+    (4, 0, -1),      # q_blk 13 = T, one block of the 4 table pages
+    (4, 150, -1),    # T 163: q_blk 128
+    (2, 0, -1),      # 7 pages of 2
+    (1, 30, 13),     # 13 pages of 1, the table cut to what exists
+    (4, 0, 4),       # the static ctx_pages bound covering the data
+])
+def test_pallas_ragged_kernel_blocking_invariance(page_size, pad,
+                                                  ctx_pages):
+    """Online softmax must be exact under any blocking: whatever block
+    sizes the kernel derives, it agrees with the oracle; and the static
+    ctx_pages bound does not change the math when it covers the live
+    data."""
+    rng = np.random.default_rng(12)
+    c = _ragged_case(rng, [(7, 1), (0, 5), (12, 1), (4, 6)],
+                     page_size=page_size, pad=pad)
+    out = _kernel_out(c, interpret=True, ctx_pages=ctx_pages)
+    np.testing.assert_allclose(out[c["valid"]], _oracle_out(c)[c["valid"]],
+                               rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("t,page_size,table,kvh,expect", [
+    (512, 16, 128, 8, (128, 8)),     # the chat-open cell
+    (64, 16, 16, 8, (64, 8)),
+    (8, 16, 4, 2, (8, 4)),           # a table narrower than a block
+    (256, 128, 64, 8, (128, 1)),     # a page as wide as a block
+    (512, 16, 128, 64, (128, 4)),    # 64 kv heads: VMEM halves the block
+])
+def test_ragged_block_sizes_follow_the_shapes(t, page_size, table, kvh,
+                                              expect):
+    assert ragged_block_sizes(t, page_size, table, kvh, 128, 2) == expect
 
 
 def test_ragged_op_ctx_bucketing_matches_full_table():

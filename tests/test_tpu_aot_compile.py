@@ -91,7 +91,7 @@ def test_serving_forwards_compile_for_v5e(v5e, name):
     i32 = lambda *shape: S(shape, jnp.int32)
     T = 64
     jax.jit(functools.partial(
-        ragged_forward, cfg, ctx_pages=16, impl="pallas", max_seg_len=T)
+        ragged_forward, cfg, ctx_pages=16, impl="pallas")
     ).lower(params, i32(T), i32(T), i32(T), S((T,), jnp.bool_),
             i32(BATCH), i32(BATCH), k, v, tables).compile()
     assert TABLE >= 16          # >= pages_per_block: the multi-page kernel
@@ -152,25 +152,56 @@ def test_pipeline_stage_flash_compiles_nested_in_pp(v5e):
 
 # ------------------------------------------------ what the engine rejects
 
-def _ragged_kernel_lowering(S, dt, kvh, quantized):
-    h, d, T = kvh * 2, 128, 64
-    pool = S((PAGES, PAGE, kvh, d), dt)
-    new = S((T, kvh, d), jnp.bfloat16)
-    scales = S((PAGES, PAGE, kvh), jnp.float32)
+def _ragged_kernel_lowering(S, dt, kvh, quantized, group=2, T=64,
+                            slots=BATCH, ctx_pages=16, table=TABLE,
+                            pages=PAGES):
+    h, d = kvh * group, 128
+    pool = S((pages, PAGE, kvh, d), dt)
+    # an unquantized pool has the model's dtype (engine: pool_dt is
+    # cfg.dtype), so the tick's fresh rows come in it too; the kernel
+    # DMAs them as [rows, kvh, d] blocks under the pages' own rule
+    act = jnp.bfloat16 if quantized else dt
+    new = S((T, kvh, d), act)
+    scales = S((pages, PAGE, kvh), jnp.float32)
     i32 = lambda *shape: S(shape, jnp.int32)
 
-    def run(q, kp, vp, tables, slots, pos, valid, start, kn, vn,
+    def run(q, kp, vp, tables, slots_, pos, valid, start, kn, vn,
             ks=None, vs=None):
         return ragged_paged_attention_pallas(
-            q, kp, vp, tables, slots, pos, valid, start, kn, vn,
-            ctx_pages=16, max_seg_len=T, k_scales=ks, v_scales=vs)
+            q, kp, vp, tables, slots_, pos, valid, start, kn, vn,
+            ctx_pages=ctx_pages, k_scales=ks, v_scales=vs)
 
-    args = [S((T, h, d), jnp.bfloat16), pool, pool,
-            i32(BATCH, TABLE), i32(T), i32(T), S((T,), jnp.bool_),
-            i32(BATCH), new, new]
+    args = [S((T, h, d), act), pool, pool,
+            i32(slots, table), i32(T), i32(T), S((T,), jnp.bool_),
+            i32(slots), new, new]
     if quantized:
         args += [scales, scales]
     return jax.jit(run).lower(*args)
+
+
+# the ragged kernel at the shapes chat-open runs it (InternLM2.5-1.8B:
+# 8 kv heads, group 2, head_dim 128, bf16 pool of 2048 pages, 32
+# slots, table 512 wide, read whole whatever the context bucket) and at what one
+# shard of Mistral-7B-v0.3 at tp=4 sees (2 kv heads, group 4)
+CELL_SHAPES = [
+    # T, context pages, kv heads, group
+    (8, 16, 8, 2), (64, 16, 8, 2), (64, 64, 8, 2), (256, 64, 8, 2),
+    (256, 128, 8, 2), (512, 16, 8, 2), (512, 128, 8, 2),
+    (64, 16, 2, 4), (512, 128, 2, 4),
+]
+
+
+@pytest.mark.parametrize("T,ctx,kvh,group", CELL_SHAPES)
+def test_ragged_kernel_compiles_at_the_cells_shapes(v5e, T, ctx, kvh,
+                                                    group):
+    """The guard against a kernel that lives in interpret mode only:
+    Mosaic takes the item-grid kernel (in-kernel loops with trip
+    counts from prefetched scalars, double-buffered page DMA, flat
+    q/new-KV/output rows DMA'd at a dynamic row) at every token bucket
+    and context bucket of the cell, within its VMEM."""
+    _ragged_kernel_lowering(
+        _on(v5e[0]), jnp.bfloat16, kvh, quantized=False, group=group,
+        T=T, slots=32, ctx_pages=ctx, table=512, pages=2048).compile()
 
 
 REJECTED = {
