@@ -46,8 +46,8 @@ import numpy as np
 
 from ...models import llama
 from ...models.llama import LlamaConfig
+from ...models.family import family_of, resolve_config
 from ...models.llama_infer import decode_step, prefill
-from ...ops.ragged_paged_attention import ragged_work_counts
 from ...util import thread_sanitizer
 from .kv_cache import PageAllocator
 from .telemetry import EngineTelemetry
@@ -288,13 +288,15 @@ class EngineConfig:
     # architecture comes from the checkpoint's config.json.
     checkpoint: Optional[str] = None
 
-    def resolve_model(self) -> LlamaConfig:
+    def resolve_model(self):
+        """The model's configuration: a `LlamaConfig`, or another
+        family's (`models/family.py`)."""
         if self.model is None:
             if not self.checkpoint:
                 raise ValueError("model=None requires checkpoint=")
             from ...models import checkpoint_io
             return checkpoint_io.load_config(self.checkpoint)
-        return llama.config(self.model)
+        return resolve_config(self.model)
 
 
 @dataclasses.dataclass
@@ -470,6 +472,17 @@ def _sample(logits, key, temps, top_ps, top_ks=None, rep_pens=None,
     return jnp.where(temps <= 0.0, greedy, sampled).astype(jnp.int32)
 
 
+def _with_rider(tokens, rider):
+    """The tick's readback: the sampled tokens and, behind them, the
+    small int32 array a model family sends back with every tick (an
+    expert layer's per-expert assignment counts) — one array, so no
+    program or transfer of its own. None: the tokens alone."""
+    if rider is None:
+        return tokens
+    return jnp.concatenate([tokens, rider.reshape(-1).astype(
+        tokens.dtype)])
+
+
 class _Stage:
     """Device placement for ONE pipeline stage: a tp Mesh (tp>1) or a
     single device, plus put() helpers. Pipeline-parallel serving splits
@@ -604,6 +617,10 @@ class InferenceEngine:
         self.model_cfg = config.resolve_model()
         self.max_seq = config.max_seq_len or self.model_cfg.max_seq
         cfg, ec = self.model_cfg, config
+        # what the engine asks of the model: parameters, the two
+        # forwards, the cache row (models/family.py)
+        self.family = family_of(cfg)
+        self._refuse_for_family()
         # explicit-tp state (EngineConfig.mesh_shape): defaults cover
         # every other placement mode so the compiled-program builders
         # can branch on it unconditionally
@@ -709,7 +726,7 @@ class InferenceEngine:
             self._repl = NamedSharding(self.mesh, PartitionSpec())
         else:
             if params is None:
-                params = llama.init_params(
+                params = self.family.init_params(
                     cfg, jax.random.PRNGKey(ec.seed))
             self.params = jax.device_put(params)
             self._kv_sharding = self._repl = None
@@ -763,21 +780,24 @@ class InferenceEngine:
         # constructs must compile, and none falls back to gather.
         from ...ops import paged_attention as _pa
         impl = self._resolve_impl()
-        pool_dt = (cfg.dtype if self._kv_kind == "f32"
-                   else kv_quant.storage_dtype(self._kv_kind))
+        # THE description of what a token writes to the cache in a
+        # layer (kv_cache.CacheRow): the pools below, the per-page
+        # bytes, stats() and the cost model all read it
+        crow = self.cache_row = self.family.cache_row(
+            cfg, impl, self._kv_kind)
+        pool_dt = crow.dtype
         # kv heads ONE shard's kernel sees, read off the sharding
         # the pools will carry (tp engines and pp stages alike)
         kv_sh = (self.stages[0].kv_sharding if self.stages
                  else self._kv_sharding)
-        local_kvh = (cfg.n_kv_heads if kv_sh is None else
+        local_kvh = (crow.heads if kv_sh is None else
                      kv_sh.shard_shape(
-                         (1, 1, 1, cfg.n_kv_heads, 1))[3])
+                         (1, 1, 1, crow.heads, 1))[3])
         # what the ragged kernel's block sizes derive from, besides a
         # tick's T and context bucket (ragged_work_counts)
         self._attn_geometry = (
-            local_kvh, _pa.pool_head_dim(cfg.head_dim, impl),
-            jnp.dtype(pool_dt).itemsize)
-        if impl == "pallas":
+            local_kvh, crow.padded_width, jnp.dtype(pool_dt).itemsize)
+        if impl == "pallas" and crow.kind == "kv":
             why = _pa.kernel_layout_error(self._kv_kind, local_kvh,
                                           pool_dt)
             if why is not None:
@@ -792,15 +812,14 @@ class InferenceEngine:
         # from this, never an assumed f32 itemsize (quantized pages
         # carry 1-byte values plus the per-(row, head) f32 scale
         # sidecar)
-        mc = self.model_cfg
-        row = _pa.pool_head_dim(mc.head_dim, impl)
-        if self._kv_kind == "f32":
-            row_bytes = int(2 * mc.n_layers * mc.n_kv_heads * row
-                            * jnp.dtype(mc.dtype).itemsize)
-        else:
-            row_bytes = 2 * mc.n_layers * kv_quant.token_row_bytes(
-                self._kv_kind, mc.n_kv_heads, row)
-        self._kv_page_bytes = row_bytes * ec.page_size
+        self._kv_page_bytes = (cfg.n_layers * crow.bytes_per_token_layer
+                               * ec.page_size)
+        # what a tick's readback carries behind the tokens (an expert
+        # family's per-layer, per-held-expert assignment counts), and
+        # the monotone totals stats()["moe"] folds it into
+        self._rider_len = int(self.family.rider_len(cfg))
+        self._moe_landed = np.zeros(self._rider_len, np.int64)
+        self._moe_tokens_routed = 0
         from .kv_offload import HostKVTier
         self.host_tier: Optional[HostKVTier] = (
             HostKVTier(ec.host_kv_pages) if ec.enable_kv_offload
@@ -848,8 +867,7 @@ class InferenceEngine:
         self._profile: Optional[Dict[str, Any]] = None
         if self.pp > 1:
             per = cfg.n_layers // self.pp
-            kv_shape = (per, ec.num_pages, ec.page_size,
-                        cfg.n_kv_heads, row)
+            kv_shape = crow.pool_shape(per, ec.num_pages, ec.page_size)
             self.k_pages = [
                 st.put(jnp.zeros(kv_shape, cfg.dtype), st.kv_sharding)
                 for st in self.stages]
@@ -861,15 +879,18 @@ class InferenceEngine:
             self._key = self.stages[-1].put(
                 jax.random.PRNGKey(ec.seed + 1))
         else:
-            kv_shape = (cfg.n_layers, ec.num_pages, ec.page_size,
-                        cfg.n_kv_heads, row)
+            kv_shape = crow.pool_shape(cfg.n_layers, ec.num_pages,
+                                       ec.page_size)
             # born sharded, like the weights: a pool zeroed on the
             # default device and then resharded passes WHOLE through
-            # chip 0 (on the chip: +1.8 GB peak there at tp=4, 8b)
+            # chip 0 (on the chip: +1.8 GB peak there at tp=4, 8b).
+            # A latent cache is ONE pool: `k_pages` holds it and
+            # `v_pages` is None through every program's signature
             self.k_pages = jnp.zeros(kv_shape, pool_dt,
                                      device=self._kv_sharding)
-            self.v_pages = jnp.zeros(kv_shape, pool_dt,
-                                     device=self._kv_sharding)
+            self.v_pages = (jnp.zeros(kv_shape, pool_dt,
+                                      device=self._kv_sharding)
+                            if crow.pools == 2 else None)
             self._key = self._dev(jax.random.PRNGKey(ec.seed + 1))
         # per-(token row, kv head) f32 scale pools beside the value
         # pools (None for f32 engines): [L, P, page, KVH], sharded on
@@ -1047,7 +1068,8 @@ class InferenceEngine:
         self.perf: Optional[PerfAccountant] = None
         if ec.enable_perf_accounting:
             self.perf = PerfAccountant(
-                CostModel(cfg, ec.page_size, kv_dtype=self._kv_kind),
+                CostModel(cfg, ec.page_size, kv_dtype=self._kv_kind,
+                          cache_row=crow),
                 detect_envelope(name=ec.perf_envelope),
                 n_chips=self.n_chips)
             if self._spec is not None:
@@ -1120,6 +1142,36 @@ class InferenceEngine:
         # fleet_stats at router cadence (fleet_counters())
         with self._step_lock:
             self._publish_counters_locked()
+
+    def _refuse_for_family(self) -> None:
+        """Refuse, with the family's reason, every engine option the
+        model's family does not compose with: an engine that constructs
+        must run, and none half-runs a pairing nobody built."""
+        ec = self.config
+        asked = {
+            "kv_dtype": ec.kv_dtype != "f32",
+            "enable_kv_offload": (ec.enable_kv_offload
+                                  or ec.kv_watermark_tokens is not None),
+            "mesh": ec.mesh is not None,
+            "mesh_shape": ec.mesh_shape is not None,
+            "speculative": bool(ec.speculative),
+            "decode_steps_per_call":
+                int(ec.decode_steps_per_call or 1) > 1,
+            "unified_step": not ec.unified_step,
+            "checkpoint": bool(ec.checkpoint),
+        }
+        for option, on in asked.items():
+            if on:
+                self._refuse_call(option)
+
+    def _refuse_call(self, what: str) -> None:
+        """Raise the family's reason if it does not compose with
+        `what`: an option above, or an entry point (LoRA registration,
+        session shipping) it has no path for."""
+        if what in self.family.refuses:
+            raise ValueError(
+                f"the {self.family.name} family does not compose with "
+                f"{what}: {self.family.refuses[what]}")
 
     @staticmethod
     def _build_placement(spec, cfg: LlamaConfig):
@@ -1226,24 +1278,34 @@ class InferenceEngine:
                   "logits_psum": self._tp_logits_psum}
                  if tp > 1 else {})
 
+        decode_step = self.family.decode_step
+        rider_len = self._rider_len
+
         def core(params, k_pages, v_pages, k_scales, v_scales, seen,
                  tokens, positions, page_tables, active, key, temps,
                  top_ps, top_ks, rep_pens, seeds, lora, lora_idx,
                  all_greedy):
+            # the fed tokens are the last tick's readback: its rider,
+            # if the family has one, rides behind the batch's tokens
+            if rider_len:
+                tokens = tokens[:active.shape[0]]
             out = decode_step(
                 cfg_fwd, params, tokens, positions, k_pages, v_pages,
                 page_tables, active, impl=impl, mesh=mesh_fwd,
                 lora=lora, lora_idx=lora_idx, kv_kind=kind,
                 k_scales=k_scales, v_scales=v_scales, **tp_kw)
-            if kind != "f32":
+            rider = None
+            if rider_len:
+                logits, k_pages, v_pages, rider = out
+            elif kind != "f32":
                 logits, k_pages, v_pages, k_scales, v_scales = out
             else:
                 logits, k_pages, v_pages = out
             if all_greedy:
                 # static fast path: no penalties/seen bookkeeping — the
                 # common greedy batch-inference case stays argmax-only
-                new_tokens = _sample(logits, key, temps, top_ps,
-                                     all_greedy=True)
+                new_tokens = _with_rider(_sample(
+                    logits, key, temps, top_ps, all_greedy=True), rider)
                 return (new_tokens, k_pages, v_pages, k_scales,
                         v_scales, seen)
             # the fed token sits at `positions`; the sampled one lands
@@ -1255,8 +1317,8 @@ class InferenceEngine:
                                  row_keys=row_keys)
             b = tokens.shape[0]
             seen = seen.at[jnp.arange(b), new_tokens].max(active)
-            return (new_tokens, k_pages, v_pages, k_scales, v_scales,
-                    seen)
+            return (_with_rider(new_tokens, rider), k_pages, v_pages,
+                    k_scales, v_scales, seen)
 
         if tp > 1:
             # ONE shard_map'd program per decode tick: outer signatures
@@ -1541,7 +1603,8 @@ class InferenceEngine:
             cfg = self.model_cfg
             impl = self._resolve_impl()
             mesh = self.mesh
-            from ...models.llama_infer import ragged_forward
+            ragged_forward = self.family.ragged_forward
+            rider_len = self._rider_len
 
             kind = self._kv_kind
             # explicit tp: the forward runs INSIDE a shard_map (shard-
@@ -1572,13 +1635,17 @@ class InferenceEngine:
                     lora_idx=lora_idx, impl=impl, mesh=mesh_fwd,
                     kv_kind=kind, k_scales=k_scales,
                     v_scales=v_scales, **tp_kw)
-                if kind != "f32":
+                rider = None
+                if rider_len:
+                    logits, k_pages, v_pages, rider = out
+                elif kind != "f32":
                     logits, k_pages, v_pages, k_scales, v_scales = out
                 else:
                     logits, k_pages, v_pages = out
                 if all_greedy:
-                    toks = _sample(logits, key, temps, top_ps,
-                                   all_greedy=True)
+                    toks = _with_rider(_sample(
+                        logits, key, temps, top_ps, all_greedy=True),
+                        rider)
                     return (toks, k_pages, v_pages, k_scales,
                             v_scales, seen)
                 # this tick's tokens count as seen BEFORE sampling
@@ -1598,7 +1665,8 @@ class InferenceEngine:
                 # samples are discarded host-side, so they must not
                 # leak into the penalty state either)
                 seen = seen.at[jnp.arange(b), toks].max(emit)
-                return toks, k_pages, v_pages, k_scales, v_scales, seen
+                return (_with_rider(toks, rider), k_pages, v_pages,
+                        k_scales, v_scales, seen)
 
             if tp > 1:
                 # ONE shard_map'd collective-bearing program per tick:
@@ -1931,7 +1999,9 @@ class InferenceEngine:
             slot_meta = np.zeros((4, B), np.int32)
             max_start = 0
             cur = 0
-            ndec = npre = kv = 0
+            # pairs: (query, key) kept by the causal rule; dec_pairs:
+            # the decode rows' part (their context + 1 each)
+            ndec = npre = kv = pairs = dec_pairs = 0
             segs = []                # (cached tokens, tokens) per row
             for s, n, is_pref in plan:
                 req = s.request
@@ -1944,7 +2014,9 @@ class InferenceEngine:
                     seg = [s.last_token]
                     pos0 = s.position
                     ndec += 1
+                    dec_pairs += pos0 + 1
                 kv += pos0 + n       # the context the row's last token reads
+                pairs += n * pos0 + n * (n + 1) // 2
                 segs.append((pos0, n))
                 tok_meta[0, cur:cur + n] = seg
                 tok_meta[1, cur:cur + n] = s.index
@@ -1969,10 +2041,10 @@ class InferenceEngine:
             # block) items of a static bound, and the KV blocks they
             # sweep (context plus in-batch); the kernel reads the whole
             # page table, whatever the context bucket
-            items, kv_blocks = ragged_work_counts(
+            items, kv_blocks = self.family.work_counts(
                 segs, T, self.config.page_size,
                 self.max_pages_per_seq if ctx else 0,
-                *self._attn_geometry)
+                self._attn_geometry)
         if self.perf is not None:
             with self._phase("account"):
                 cm = self.perf.model
@@ -1999,7 +2071,8 @@ class InferenceEngine:
             "tick": self.ticks, "kind": "ragged", "T": T, "ctx": ctx,
             "rows": len(plan),
             "decode_rows": ndec, "prefill_tokens": npre,
-            "kv_tokens": kv, "built": built,
+            "kv_tokens": kv, "attn_pairs": pairs,
+            "decode_pairs": dec_pairs, "built": built,
             "attn_items": items, "attn_kv_blocks": kv_blocks}
         with self._phase("dispatch", **carried):
             self._key, sub = jax.random.split(self._key)
@@ -2024,7 +2097,8 @@ class InferenceEngine:
         toks_host = self._read_tokens(toks, of=self.ticks)
         # fold ALL slots from the one readback before any device-state
         # refresh (same ordering contract as _multi_decode)
-        with self._phase("fold", tokens=len(plan)):
+        with self._phase("fold", tokens=len(plan),
+                         **self._fold_rider(toks_host, total, self.ticks)):
             for s, n, is_pref in plan:
                 tok = int(toks_host[s.index])
                 if is_pref:
@@ -3343,6 +3417,7 @@ class InferenceEngine:
         finish_reason="migrated", so its local stream terminates with
         a migration marker instead of an abort."""
         with self._step_lock:
+            self._refuse_call("session_shipping")
             tier = self.host_tier
             if tier is not None and request_id in tier:
                 # fast path: the pages were ALREADY spilled — export
@@ -3452,6 +3527,7 @@ class InferenceEngine:
         ValueError on an id collision or incompatible KV geometry,
         MemoryError when the tier cannot hold it — callers treat
         both as a failed ship and fall back to replay."""
+        self._refuse_call("session_shipping")
         params = dict(state.get("params") or {})
         if params.get("stop_token_ids") is not None:
             params["stop_token_ids"] = tuple(params["stop_token_ids"])
@@ -3567,6 +3643,7 @@ class InferenceEngine:
         live pools (the same sanctioned dispatch as the spill path) —
         never on the tick path."""
         with self._step_lock:
+            self._refuse_call("session_shipping")
             if not self.allocator.enable_prefix_caching:
                 return None
             pages = self.allocator.cached_prefix_pages(prompt_tokens)
@@ -3613,6 +3690,7 @@ class InferenceEngine:
         Returns the number of pages newly seeded (0 = already cached
         / no room / nothing importable)."""
         with self._step_lock:
+            self._refuse_call("session_shipping")
             if not self.allocator.enable_prefix_caching:
                 return 0
             if str(kv_dtype or "f32") != self._kv_kind:
@@ -3717,6 +3795,7 @@ class InferenceEngine:
 
     def _register_loras_locked(self, mapping: Dict[str, Dict[str, tuple]],
                                scale: float) -> None:  # jaxlint: disable=JL006 -- registration-time stack upload (one per projection), not on the tick path
+        self._refuse_call("lora")
         if self.pp > 1:
             raise NotImplementedError(
                 "multi-LoRA is not supported with pipeline-parallel "
@@ -4565,7 +4644,11 @@ class InferenceEngine:
             self._d_seen = sl.put(jnp.asarray(seen))
             self._d_lora_idx = None
         else:
-            self._d_tokens = self._dev(jnp.asarray(tokens))
+            # a family's rider rides behind the tokens in every
+            # readback, which is also the next decode tick's input: give
+            # the first one the same shape, so there is one program
+            self._d_tokens = self._dev(jnp.asarray(np.concatenate(
+                [tokens, np.zeros(self._rider_len, np.int32)])))
             self._d_positions = self._dev(jnp.asarray(positions))
             self._d_active = self._dev(jnp.asarray(active))
             self._d_temps = self._dev(jnp.asarray(temps))
@@ -4585,6 +4668,24 @@ class InferenceEngine:
                                 and np.all(rep_pens == 1.0))
         self._host_active = active
         self._seen_dirty_slots = set()   # full rebuild just happened
+
+    def _fold_rider(self, toks_host: "np.ndarray", tokens: int,
+                    of: int) -> Dict[str, int]:
+        """Fold what rode back behind tick `of`'s tokens into the totals
+        of stats()["moe"]: the assignments that landed on each held
+        expert in each expert layer, and the tokens the tick routed.
+        Returns the fold span's extra arguments (none for a family
+        with no rider): the tick, the (layer, expert) pairs that
+        received a token and the assignments landed, which is what the
+        expert layer had to read and compute for that tick."""
+        if not self._rider_len:
+            return {}
+        landed = toks_host[-self._rider_len:]
+        self._moe_landed += landed
+        self._moe_tokens_routed += tokens
+        return {"of": of,
+                "moe_experts_hit": int(np.count_nonzero(landed)),
+                "moe_assignments": int(landed.sum())}
 
     def _drain(self, touched: List[Request]) -> None:
         """Pipeline barrier: fold the in-flight tick (if any) into
@@ -4618,8 +4719,10 @@ class InferenceEngine:
             self._lagged_ticks += 1
         page = self.allocator.page_size
         finished = False
-        with self._phase("fold",
-                         tokens=int(np.count_nonzero(rec.active))):
+        n_active = int(np.count_nonzero(rec.active))
+        with self._phase("fold", tokens=n_active,
+                         **self._fold_rider(toks_host, n_active,
+                                            rec.tick)):
             for s in self.slots:
                 if not rec.active[s.index]:
                     continue
@@ -4661,7 +4764,8 @@ class InferenceEngine:
             "T": self.config.max_batch_size,
             "ctx": self.max_pages_per_seq, "rows": rows,
             "decode_rows": rows, "prefill_tokens": 0,
-            "kv_tokens": kv, "built": 0}
+            "kv_tokens": kv, "attn_pairs": kv, "decode_pairs": kv,
+            "built": 0}
         with self._phase("dispatch", **carried):
             self._key, sub = jax.random.split(self._key)
             self.dispatches += 1
@@ -4699,8 +4803,9 @@ class InferenceEngine:
                 if start is not None:
                     start()      # no-op cost; fold blocks if absent
         if not self._async:
-            self._post_decode(
-                self._read_tokens(new_tokens, of=self.ticks), touched)
+            host = self._read_tokens(new_tokens, of=self.ticks)
+            self._fold_rider(host, rows, self.ticks)
+            self._post_decode(host, touched)
             return
         prev = self._inflight
         self._inflight = _InflightTick(
@@ -5257,6 +5362,15 @@ class InferenceEngine:
             "gaps": [t.brief() for t in gaps[:k] if t.gap_ms > 0],
         }
 
+    def _moe_summary_locked(self) -> Optional[Dict[str, Any]]:
+        """Monotone totals of what rode back behind every tick's tokens
+        since the engine came up, as the model's family reads them (an
+        expert family: its routing; None for a family with no rider)."""
+        if not self._rider_len:
+            return None
+        return self.family.rider_summary(
+            self.model_cfg, self._moe_landed, self._moe_tokens_routed)
+
     def stats(self) -> Dict[str, Any]:
         # ONE _step_lock acquisition around the whole mutable-state
         # snapshot (waiting/slots/parked/preempt_counts/tick deque):
@@ -5299,10 +5413,14 @@ class InferenceEngine:
                 # report f32 bytes — per-page bytes include the quant
                 # scale sidecar)
                 "kv_dtype": self._kv_kind,
+                "cache_row": self.cache_row.describe(),
                 "kv_page_bytes": self._kv_page_bytes,
                 "kv_device_bytes_used": (self.allocator.used_pages
                                          * self._kv_page_bytes),
                 "preemptions": dict(self.preempt_counts),
+                # an expert family's routing, from what rode back with
+                # every tick's tokens (None for a dense model)
+                "moe": self._moe_summary_locked(),
                 # batch lane (ISSUE 14): preemptible bulk-work
                 # occupancy
                 "lanes": self._lane_counts_locked(),

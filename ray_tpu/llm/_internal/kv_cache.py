@@ -20,6 +20,10 @@ from __future__ import annotations
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
+# what a token writes to the cache in a layer: the model's side of the
+# seam describes it, the engine's pools are built from it
+from ...models.cache_row import CacheRow  # noqa: F401
+
 
 class PageAllocator:
     def __init__(self, num_pages: int, page_size: int,
@@ -38,6 +42,11 @@ class PageAllocator:
         # hit). The cache itself holds one reference on its pages.
         self._cache: "OrderedDict[Tuple, int]" = OrderedDict()
         self._key_by_page: Dict[int, Tuple] = {}
+        # cache entries whose page only the cache holds (rc == 1), kept
+        # as a count: `free_pages` is read a dozen times a tick, and a
+        # walk of a 16k-entry cache each time was most of a tick's host
+        # time (PERF.md section 6, PR 27)
+        self._evictable = 0
         self.cache_hit_tokens = 0
         self.cache_query_tokens = 0
 
@@ -48,9 +57,7 @@ class PageAllocator:
     @property
     def free_pages(self) -> int:
         """Pages allocatable right now (free list + evictable cache)."""
-        evictable = sum(1 for p in self._cache.values()
-                        if self._rc.get(p, 0) == 1)
-        return len(self._free) + evictable
+        return len(self._free) + self._evictable
 
     def can_allocate(self, num_tokens: int) -> bool:
         return self.pages_needed(num_tokens) <= self.free_pages
@@ -78,6 +85,8 @@ class PageAllocator:
                 self._free.append(p)
             else:
                 self._rc[p] = rc
+                if rc == 1 and p in self._key_by_page:
+                    self._evictable += 1      # only the cache holds it
 
     # ----------------------------------------------------- prefix cache
     def _chain_keys(self, tokens: Sequence[int]) -> List[Tuple]:
@@ -108,7 +117,10 @@ class PageAllocator:
             if page is None:
                 break
             self._cache.move_to_end(key)
-            self._rc[page] = self._rc.get(page, 0) + 1
+            held = self._rc.get(page, 0)
+            if held == 1:
+                self._evictable -= 1          # a sequence holds it too now
+            self._rc[page] = held + 1
             pages.append(page)
         return pages, len(pages) * self.page_size
 
@@ -152,6 +164,8 @@ class PageAllocator:
             self._cache[key] = page
             self._key_by_page[page] = key
             self._rc[page] = self._rc.get(page, 0) + 1
+            if self._rc[page] == 1:
+                self._evictable += 1
 
     def _evict_one(self) -> None:
         """Drop the least-recently-used cache entry whose page has no
@@ -162,6 +176,7 @@ class PageAllocator:
                 del self._key_by_page[page]
                 self._rc.pop(page, None)
                 self._free.append(page)
+                self._evictable -= 1
                 return
         raise MemoryError("no evictable KV cache page")
 
@@ -175,6 +190,7 @@ class PageAllocator:
                 del self._key_by_page[page]
                 self._rc.pop(page, None)
                 self._free.append(page)
+                self._evictable -= 1
 
     # ------------------------------------------------------------- stats
     @property

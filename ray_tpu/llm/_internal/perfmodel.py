@@ -49,6 +49,7 @@ import threading
 import time
 from typing import Any, Dict, Optional
 
+from ...models.family import family_of
 from ...models.llama import LlamaConfig
 
 # Rolling window of per-tick samples (matches the engine's _tick_times
@@ -136,17 +137,44 @@ def _dtype_bytes(dt: Any) -> int:
 
 
 class CostModel:
-    """Closed-form serving costs for one LlamaConfig.
+    """Closed-form serving costs for one model configuration.
 
     All per-token / per-pair constants precompute at construction so
-    the per-tick accounting is a handful of int multiplies."""
+    the per-tick accounting is a handful of int multiplies. A
+    configuration with a `serving_costs()` method (a family other than
+    the dense decoder's) gives its own FLOP and weight constants; the
+    KV bytes come from the cache-row description either way."""
 
-    def __init__(self, cfg: LlamaConfig, page_size: int,
-                 kv_dtype: str = "f32"):
+    def __init__(self, cfg, page_size: int, kv_dtype: str = "f32",
+                 cache_row=None):
         from ...ops import kv_quant
         self.cfg = cfg
         self.page_size = int(page_size)
         self.kv_dtype = kv_quant.validate_kind(kv_dtype)
+        L = cfg.n_layers
+        if hasattr(cfg, "serving_costs"):
+            own = cfg.serving_costs()
+            self.gemm_flops_per_token = float(own["gemm_flops_per_token"])
+            self.head_flops = float(own["head_flops"])
+            self.attn_flops_per_pair = float(own["attn_flops_per_pair"])
+            self.weight_bytes = float(own["weight_bytes"])
+        else:
+            self._llama_constants(cfg)
+        # one token's rows across the stack, at the POOL's row: the
+        # width the kernels stream (a head_dim of 64 is padded to 128
+        # lanes in a kernel pool; the cache, not the model, pays), the
+        # storage type, and a quantized pool's per-(row, head) scales
+        if cache_row is None:
+            # no engine behind this model (a draft's, a test's): the
+            # dense family's row as the model writes it
+            cache_row = family_of(cfg).cache_row(cfg, "gather",
+                                                 self.kv_dtype)
+        self.cache_row = cache_row
+        self.kv_bytes_per_token = float(
+            L * cache_row.bytes_per_token_layer)
+        self.page_bytes = self.kv_bytes_per_token * self.page_size
+
+    def _llama_constants(self, cfg: LlamaConfig) -> None:
         h, L = cfg.hidden, cfg.n_layers
         # -- GEMM FLOPs per token through the layer stack (no head) --
         qkvo = 2 * h * (cfg.q_dim + 2 * cfg.kv_dim) + 2 * cfg.q_dim * h
@@ -175,19 +203,6 @@ class CostModel:
             inactive = (3 * h * cfg.ffn * L
                         * max(cfg.n_experts - cfg.moe_top_k, 0))
             self.weight_bytes -= inactive * _dtype_bytes(cfg.param_dtype)
-        # one token's K+V rows across the stack. f32 pools store the
-        # activation dtype; quantized pools (ISSUE 16) store 1-byte
-        # values plus a per-(row, head) f32 scale — the scale overhead
-        # is real HBM traffic the kernel streams, so it is counted
-        if self.kv_dtype == "f32":
-            self.kv_bytes_per_token = float(
-                2 * L * cfg.n_kv_heads * cfg.head_dim
-                * _dtype_bytes(cfg.dtype))
-        else:
-            self.kv_bytes_per_token = float(
-                2 * L * kv_quant.token_row_bytes(
-                    self.kv_dtype, cfg.n_kv_heads, cfg.head_dim))
-        self.page_bytes = self.kv_bytes_per_token * self.page_size
 
     # -- primitives ----------------------------------------------------
     def _ctx_read_tokens(self, ctx: int) -> int:

@@ -282,3 +282,115 @@ def moe_ffn(x: jax.Array, router_w: jax.Array,
     out = jnp.einsum("tec,ech->th", combine,
                      expert_out.astype(jnp.float32))
     return out.reshape(b, s, h).astype(dt), aux
+
+
+# ------------------------------------------- sigmoid group-limited routing
+# DeepSeek-V3's router (`topk_method` "noaux_tc", `scoring_func`
+# "sigmoid") and an expert layer that is told which experts it holds:
+# the serving path of models/deepseek_v3.py. No auxiliary loss, no
+# capacity, no token dropped.
+
+def sigmoid_group_routing(x: jax.Array, router_w: jax.Array,
+                          bias: jax.Array, *, n_group: int,
+                          topk_group: int, top_k: int,
+                          scale: float, normalize: bool = True
+                          ) -> Tuple[jax.Array, jax.Array]:
+    """x: [T, H]; router_w: [H, E]; bias: [E] (the checkpoint's
+    `e_score_correction_bias`) -> (gate weights [T, top_k] float32,
+    expert indices [T, top_k] int32).
+
+    scores = sigmoid(x W) in float32 over ALL E experts; choice =
+    scores + bias picks, scores weigh: a group's score is the sum of
+    its two largest choices, the `topk_group` best groups stay, the
+    `top_k` largest choices inside them are the picks (ties go to the
+    lower index), and the gate weights are the picked SCORES (not
+    choices) over their sum, times `scale`."""
+    with jax.default_matmul_precision("highest"):
+        logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores + bias.astype(jnp.float32)
+    t, e = choice.shape
+    per_group = choice.reshape(t, n_group, e // n_group)
+    group_score = jnp.sum(lax.top_k(per_group, 2)[0], axis=-1)
+    _, kept = lax.top_k(group_score, topk_group)             # [T, kept]
+    group_on = jnp.zeros((t, n_group), bool).at[
+        jnp.arange(t)[:, None], kept].set(True)
+    masked = jnp.where(jnp.repeat(group_on, e // n_group, axis=1),
+                       choice, -jnp.inf)
+    _, idx = lax.top_k(masked, top_k)
+    w = jnp.take_along_axis(scores, idx, axis=1)
+    if normalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * scale, idx.astype(jnp.int32)
+
+
+def held_gates(idx: jax.Array, w: jax.Array, lo: int, hi: int,
+               valid: Optional[jax.Array] = None
+               ) -> Tuple[jax.Array, jax.Array]:
+    """The picks that fall on the experts held here, [lo, hi): (gate
+    matrix [T, hi - lo] float32, zero where a token did not pick the
+    expert; assignments landed on each held expert [hi - lo] int32).
+    Rows that are not `valid` (a tick's padding) pick nothing."""
+    hit = (idx[..., None] - lo) == jnp.arange(hi - lo)       # [T, k, E]
+    if valid is not None:
+        hit = hit & valid[:, None, None]
+    gates = jnp.sum(jnp.where(hit, w[..., None], 0.0), axis=1)
+    return gates, jnp.sum(hit, axis=(0, 1)).astype(jnp.int32)
+
+
+def _swiglu_grouped(xs, wg, wi, wd):
+    """xs: [E, C, H] rows per expert, or [T, H] rows that every expert
+    takes -> [E, C, H] float32: one batched (grouped) matrix product
+    per projection over the experts held."""
+    f32 = jnp.float32
+    into = "ech,ehf->ecf" if xs.ndim == 3 else "ch,ehf->ecf"
+    g = jnp.einsum(into, xs, wg, preferred_element_type=f32)
+    u = jnp.einsum(into, xs, wi, preferred_element_type=f32)
+    h = (jax.nn.silu(g) * u).astype(xs.dtype)
+    return jnp.einsum("ecf,efh->ech", h, wd, preferred_element_type=f32)
+
+
+# rows a held expert's tokens are gathered into: four times the 16 a
+# 512-token tick sends a held expert of a balanced router on average
+ROWS_PER_EXPERT = 64
+
+
+def held_experts_ffn(x: jax.Array, gates: jax.Array, counts: jax.Array,
+                     wg: jax.Array, wi: jax.Array,
+                     wd: jax.Array) -> jax.Array:
+    """The held experts' part of an expert layer's output: for each
+    token the gate-weighted sum of the SwiGLU of those of its picks that
+    are held here. x: [T, H]; gates: [T, E] and counts: [E] from
+    `held_gates`; wg/wi: [E, H, F], wd: [E, F, H]. Returns [T, H]
+    float32. What absent experts would add is left out.
+
+    Grouped matrix products over the experts held, at one of two static
+    sizes a tick: when no held expert received more than
+    ROWS_PER_EXPERT tokens, each expert's tokens are gathered into that
+    many rows and the product runs over E x ROWS_PER_EXPERT rows;
+    otherwise, and in a tick of at most that many tokens, over all
+    E x T rows with zero gates. Either way every assignment is computed:
+    there is no capacity and nothing is dropped. Which form a tick takes
+    follows the router's balance (PERF.md section 5 has what each costs
+    the cell)."""
+    t, _ = x.shape
+
+    def every_row(_):
+        y = _swiglu_grouped(x, wg, wi, wd)                   # [E, T, H]
+        return jnp.einsum("eth,te->th", y, gates)
+
+    if t <= ROWS_PER_EXPERT:
+        return every_row(None)
+
+    def gathered(_):
+        # per expert its assigned tokens first (a gate can be any
+        # positive number, so rank on assignment, not on the gate)
+        took, tok = lax.top_k((gates.T > 0).astype(jnp.int32),
+                              ROWS_PER_EXPERT)               # [E, C]
+        g = jnp.take_along_axis(gates.T, tok, axis=1) * took
+        y = _swiglu_grouped(x[tok], wg, wi, wd) * g[..., None]
+        return jnp.zeros((t, x.shape[1]), jnp.float32).at[
+            tok.reshape(-1)].add(y.reshape(-1, x.shape[1]))
+
+    return lax.cond(jnp.max(counts) <= ROWS_PER_EXPERT, gathered,
+                    every_row, None)

@@ -204,6 +204,35 @@ def test_ragged_kernel_compiles_at_the_cells_shapes(v5e, T, ctx, kvh,
         T=T, slots=32, ctx_pages=ctx, table=512, pages=2048).compile()
 
 
+@pytest.mark.parametrize("T,has_ctx", [(8, True), (64, True),
+                                       (512, True), (512, False)])
+def test_mla_kernel_compiles_at_the_cells_shapes(v5e, T, has_ctx):
+    """`mla_ragged_attention` at DeepSeek-V3's published widths as
+    dsv3-longchat runs it: 128 heads on one latent row of 640 lanes
+    (576 + padding), values its first 512, the WHOLE 5-layer pool of
+    16,384 pages handed over with a traced layer index (no layer's
+    slice is copied out), 64 slots, a table 512 pages wide; a decode
+    tick is T = 64. The 2-D new-row array is read at an aligned row:
+    Mosaic refuses an unaligned dynamic slice of a tiled dim."""
+    from ray_tpu.ops.mla_attention import mla_ragged_attention_pallas
+    S = _on(v5e[0])
+    i32 = lambda *shape: S(shape, jnp.int32)
+
+    def run(q, pool, layer, tables, slots, pos, valid, start, new):
+        return mla_ragged_attention_pallas(
+            q, pool, layer, tables, slots, pos, valid, start, new,
+            dv=512, scale=0.1147, ctx_pages=-1 if has_ctx else 0)
+
+    compiled = jax.jit(run).lower(
+        S((T, 128, 576), jnp.bfloat16),
+        S((5, 16384, PAGE, 1, 640), jnp.bfloat16), i32(),
+        i32(64, 512), i32(T), i32(T), S((T,), jnp.bool_), i32(64),
+        S((T, 576), jnp.bfloat16)).compile()
+    # the pool is read where it lies: no 1.68 GB copy of it, no 0.34 GB
+    # copy of a layer of it
+    assert compiled.memory_analysis().temp_size_in_bytes < 200 << 20
+
+
 REJECTED = {
     # kv_dtype, pool dtype, kv heads per shard
     "int8_kv": ("int8", jnp.int8, 8),
