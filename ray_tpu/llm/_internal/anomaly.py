@@ -9,6 +9,13 @@ roofline lower bound), keeps a robust residual baseline
 (median + MAD over the log-residual, so the CPU envelope's constant
 calibration bias cancels and a handful of outliers can't poison the
 baseline), and flags ticks whose robust z-score clears the threshold.
+The baseline is kept per tick kind (PerfSample.kind): the cost model
+misprices kinds by different factors (on a v5e a 512-token ragged tick
+runs at 4.6x its bound among decode ticks at 1.4x theirs), and under
+one pooled baseline every tick of the dearer kind read as a straggler
+and armed a capture under the step lock (PERF.md section 6, PR 28). A
+kind is judged against the pooled window until it has `warmup_ticks`
+samples of its own, so a rare kind's stall is still seen.
 
 A flagged tick is CLASSIFIED from host-side evidence the engine
 already has — in priority order:
@@ -134,8 +141,10 @@ class TickAnomalyDetector:
 
     def __init__(self, config: Optional[AnomalyConfig] = None):
         self.config = config or AnomalyConfig()
+        # log-residuals: every tick's, and each tick kind's own
         self._resid: "collections.deque[float]" = collections.deque(
             maxlen=_WINDOW)
+        self._resid_by_kind: Dict[str, "collections.deque[float]"] = {}
         self._host_share: "collections.deque[float]" = \
             collections.deque(maxlen=_WINDOW)
         self._recent: "collections.deque[int]" = collections.deque(
@@ -161,9 +170,9 @@ class TickAnomalyDetector:
         mid = n // 2
         return s[mid] if n % 2 else 0.5 * (s[mid - 1] + s[mid])
 
-    def _robust_z(self, x: float) -> float:
-        med = self._median(self._resid)
-        mad = self._median([abs(v - med) for v in self._resid])
+    def _robust_z(self, x: float, window) -> float:
+        med = self._median(window)
+        mad = self._median([abs(v - med) for v in window])
         mad = max(mad, self.config.mad_floor)
         # 0.6745 = Phi^-1(0.75): scales MAD to a sigma-equivalent
         return 0.6745 * (x - med) / mad
@@ -199,9 +208,14 @@ class TickAnomalyDetector:
         pred_ms = self.predicted_ms(sample, peak_flops, peak_bytes)
         resid = math.log(max(wall_ms, 1e-6) / max(pred_ms, 1e-6))
         host_share = (host_ms / wall_ms) if wall_ms > 0 else 0.0
-        warmed = len(self._resid) >= cfg.warmup_ticks
-        z = self._robust_z(resid) if warmed else 0.0
+        own = self._resid_by_kind.setdefault(
+            getattr(sample, "kind", ""),
+            collections.deque(maxlen=_WINDOW))
+        window = own if len(own) >= cfg.warmup_ticks else self._resid
+        warmed = len(window) >= cfg.warmup_ticks
+        z = self._robust_z(resid, window) if warmed else 0.0
         self._resid.append(resid)
+        own.append(resid)
         triggered = (warmed and z >= cfg.z_threshold
                      and wall_ms >= cfg.min_wall_ms)
         # the host-share baseline is only consumed by classification —

@@ -419,6 +419,36 @@ def _row_sample_keys(seeds, idx):
     )(seeds, idx)
 
 
+def _kept_tokens(scaled, top_ps, top_ks=None):
+    """(B, V) bool, in vocabulary order: the tokens top-k and then top-p
+    leave of each row of `scaled` (see _sample, which says why this
+    neither gathers nor scatters)."""
+    # ids along the row: a token's id in vocabulary order, a rank in
+    # sorted order
+    ids = jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)
+    neg_sorted, sort_idx = jax.lax.sort(
+        (-scaled, ids), dimension=1, num_keys=1, is_stable=True)
+    sorted_logits = -neg_sorted
+    if top_ks is not None:
+        # keep ranks < top_k (0 = off): mask in SORTED space, before
+        # top-p renormalizes over what's left
+        sorted_logits = jnp.where(
+            (top_ks[:, None] > 0) & (ids >= top_ks[:, None]),
+            -jnp.inf, sorted_logits)
+    # top-p: keep the smallest prefix of the sorted probs covering top_p
+    probs = jax.nn.softmax(sorted_logits, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep_sorted = ((cum - probs) < top_ps[:, None]) \
+        & jnp.isfinite(sorted_logits)               # always keeps rank 0
+    # the cut: the logit and the id at the last kept rank
+    n_keep = keep_sorted.sum(axis=-1)
+    last = ids == (n_keep[:, None] - 1)             # none where n_keep is 0
+    cut = jnp.min(jnp.where(last, sorted_logits, jnp.inf), axis=-1)
+    cut_id = jnp.max(jnp.where(last, sort_idx, -1), axis=-1)
+    return (scaled > cut[:, None]) | (
+        (scaled == cut[:, None]) & (ids <= cut_id[:, None]))
+
+
 @jax.named_scope("sample")
 def _sample(logits, key, temps, top_ps, top_ks=None, rep_pens=None,
             seen=None, all_greedy: bool = False, row_keys=None):
@@ -430,10 +460,28 @@ def _sample(logits, key, temps, top_ps, top_ks=None, rep_pens=None,
     penalty on raw logits (CTRL: positive seen logits divided, negative
     multiplied), then temperature, top-k, top-p, sample.
 
-    all_greedy (static) skips the sort machinery entirely — the argsort
-    over the vocab is the expensive part of sampling on TPU and pure
-    argmax decoding (the common batch-inference case) never needs it
-    (the engine only sets it when every penalty is off too).
+    all_greedy (static) skips the sort machinery entirely: pure argmax
+    decoding (the common batch-inference case) never needs it (the
+    engine only sets it when every penalty is off too).
+
+    What it costs. Top-p needs one sort of the row, with the ids as
+    payload so that the sorted logits come back with it (3.7 ms over
+    [32, 92544] on a v5e; the largest operation left here). Everything
+    else is elementwise passes and row reductions. Nothing here may
+    gather from or scatter into a [B, V] array: on the chip a gather of
+    the sorted logits was 30 ms and the scatter of the keep mask back
+    to vocabulary order 19 ms of an 87 ms decode tick whose 24 layers
+    took 17 (PERF.md section 6, PR 28). Neither is needed. The kept set
+    is DEFINED as the first n_keep ranks of the sorted order, n_keep
+    the number of ranks the top-p test passes (the test passes a prefix
+    wherever the float32 cumsum is monotone; where a device's scan is
+    not, within an ulp of top_p == 1, the prefix of that length is what
+    is kept). The stable sort orders equal logits by id, so the value
+    and the id at the last kept rank (the cut, read by masked
+    reductions) describe the set in vocabulary order: a token is kept
+    if its logit is over the cut's, or equal with an id no greater.
+    tests/test_llm_sampling.py holds the gather-and-scatter body as the
+    oracle, token for token.
 
     row_keys: optional (B,) per-row PRNG keys (_row_sample_keys) —
     the per-request deterministic path; `key` is the legacy shared
@@ -448,22 +496,7 @@ def _sample(logits, key, temps, top_ps, top_ks=None, rep_pens=None,
     if all_greedy:
         return greedy.astype(jnp.int32)
     scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    sort_idx = jnp.argsort(-scaled, axis=-1)
-    sorted_logits = jnp.take_along_axis(scaled, sort_idx, axis=-1)
-    if top_ks is not None:
-        # keep ranks < top_k (0 = off): mask in SORTED space, before
-        # top-p renormalizes over what's left
-        rank = jnp.arange(logits.shape[-1])[None, :]
-        sorted_logits = jnp.where(
-            (top_ks[:, None] > 0) & (rank >= top_ks[:, None]),
-            -jnp.inf, sorted_logits)
-    # top-p: keep the smallest prefix of the sorted probs covering top_p
-    probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep_sorted = ((cum - probs) < top_ps[:, None]) \
-        & jnp.isfinite(sorted_logits)               # always keeps rank 0
-    keep = jnp.zeros_like(keep_sorted).at[
-        jnp.arange(logits.shape[0])[:, None], sort_idx].set(keep_sorted)
+    keep = _kept_tokens(scaled, top_ps, top_ks)
     filtered = jnp.where(keep, scaled, -jnp.inf)
     if row_keys is not None:
         sampled = jax.vmap(jax.random.categorical)(row_keys, filtered)

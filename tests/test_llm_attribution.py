@@ -320,6 +320,37 @@ def test_anomaly_capture_rate_limits():
     assert not ev2["arm_profile"] and not ev2["dump"]
 
 
+def test_anomaly_baseline_is_the_tick_kinds_own():
+    """A kind the cost model prices 4.6x lower than another (a v5e's
+    512-token ragged tick among decode ticks, PR 28) is a straggler
+    against the pooled baseline only: once it has its own warm-up it is
+    judged against itself, and a real stall of it still triggers."""
+    det, S = _warm_detector(n=64)
+
+    class R(S):
+        kind = "ragged"
+
+    def tick(sample, wall):
+        return det.observe(sample(), wall, 0.2, 0.1, compiles=5,
+                           peak_flops=1e12, peak_bytes=1e12)
+
+    # one ragged tick in eight, 9.2 ms against decode's 2.0 for the
+    # same bound: pooled, each is an anomaly (z 6.9 at the MAD floor)
+    flagged = 0
+    for i in range(16 * 8):
+        if i % 8 == 7:
+            flagged += tick(R, 9.2) is not None
+        else:
+            assert tick(S, 2.0) is None
+    assert flagged == 16                   # the kind's own warm-up
+    for _ in range(32):
+        assert tick(R, 9.2) is None        # its own baseline now
+        assert tick(S, 2.0) is None
+    ev = tick(R, 46.0)                     # 5x its own kind
+    assert ev is not None and ev["composition"]["tick_kind"] == "ragged"
+    assert tick(S, 9.2) is not None        # decode at ragged's wall
+
+
 def test_anomaly_unwarmed_never_triggers():
     det = TickAnomalyDetector(AnomalyConfig(warmup_ticks=1000))
     det._gc = _GcStub()

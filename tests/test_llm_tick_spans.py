@@ -494,6 +494,49 @@ def test_sampling_carries_its_scope():
     assert _under("sample", text)
 
 
+def _equations(jaxpr, outer=""):
+    """Every equation of a jaxpr and of the jaxprs inside it, each with
+    the scope path it lowers under."""
+    for eqn in jaxpr.eqns:
+        path = f"{outer}/{eqn.source_info.name_stack}"
+        yield eqn, path
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub, path)
+
+
+@pytest.mark.parametrize("b,v", [(32, 92544), (64, 16160)],
+                         ids=["chat-open", "dsv3-longchat"])
+def test_sampling_moves_nothing_across_the_vocabulary(b, v):
+    """`_sample` as the tick programs call it, at both serving cells'
+    [B, V]: no gather and no scatter has an operand or a result with a
+    vocabulary-sized dimension (on the chip those two were 49 of the 53 ms
+    `_sample` took over [32, 92544]; PERF.md section 6, PR 28), the row is
+    sorted once, and every operation sits under `sample`."""
+    from ray_tpu.llm._internal.engine import _sample
+    S = jax.ShapeDtypeStruct
+
+    def tick_sample(logits, key, temps, top_ps, top_ks, rep_pens, seen,
+                    row_keys):
+        return _sample(logits, key, temps, top_ps, top_ks, rep_pens, seen,
+                       False, row_keys=row_keys)
+    args = (S((b, v), jnp.float32), S((2,), jnp.uint32),
+            S((b,), jnp.float32), S((b,), jnp.float32), S((b,), jnp.int32),
+            S((b,), jnp.float32), S((b, v), jnp.bool_),
+            S((b, 2), jnp.uint32))
+    for eqn, path in _equations(jax.make_jaxpr(tick_sample)(*args).jaxpr):
+        name = eqn.primitive.name
+        if name.startswith(("gather", "scatter")):
+            shapes = [getattr(x.aval, "shape", ())
+                      for x in list(eqn.invars) + list(eqn.outvars)]
+            assert not any(v in s for s in shapes), (name, shapes)
+        assert "sample" in path.split("/"), (name, path)
+    # and in what is handed to the compiler: today none at all, one sort
+    text = jax.jit(tick_sample).lower(*args).as_text()
+    assert "stablehlo.gather" not in text
+    assert "stablehlo.scatter" not in text
+    assert text.count("stablehlo.sort") == 1
+
+
 def test_train_step_carries_the_layer_scopes():
     from ray_tpu.models.training import TrainStepBundle
     from ray_tpu.parallel import MeshSpec
