@@ -285,9 +285,8 @@ async def _serve_async(args, devs) -> dict:
           f"{cfg.head_dim}, vocab {cfg.vocab_size}; pool "
           f"{eng.k_pages.shape} {eng.k_pages.dtype}", flush=True)
     want_impl = "pallas_interpret" if rehearsal else "pallas"
-    _check(ec.unified_step and ec.decode_impl == ("auto" if not rehearsal
-                                                  else want_impl),
-           "default EngineConfig dispatch (unified_step, decode_impl)")
+    _check(ec.decode_impl == ("auto" if not rehearsal else want_impl),
+           "default EngineConfig dispatch (decode_impl)")
     _check(eng._resolve_impl() == want_impl,
            f"decode_impl resolved to {eng._resolve_impl()!r}")
 

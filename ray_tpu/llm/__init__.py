@@ -265,9 +265,8 @@ meshes, slice-aware fleet placement; details: BENCH_CORE.md
                                             The data dim must be 1 (scale
                                             replicas via the fleet). Mutually
                                             exclusive with mesh= (the GSPMD
-                                            MeshSpec path); rejects pp,
-                                            speculative, multi-step decode,
-                                            MoE and LoRA. Session export/
+                                            MeshSpec path); rejects MoE and
+                                            LoRA. Session export/
                                             import and spill/restore stay on
                                             the topology-free wire format, so
                                             sessions move tp=2 <-> tp=1
@@ -385,9 +384,9 @@ def build_llm_deployment(llm_config: LLMConfig):
     dep_cfg.setdefault("max_ongoing_requests", 64)
     if llm_config.accelerator_type:
         opts = dict(dep_cfg.get("ray_actor_options") or {})
-        # chips follow the engine mesh: a tp x pp engine needs tp*pp
-        # chips on its replica (reference sizes vLLM worker placement
-        # the same way, vllm_models.py:123-139). Explicit-tp slices
+        # chips follow the engine mesh: a tp engine needs tp chips on
+        # its replica (reference sizes vLLM worker placement the same
+        # way, vllm_models.py:123-139). Explicit-tp slices
         # (engine_kwargs.mesh_shape, ISSUE 17) size the same way:
         # a (1, tp) slice reserves tp chips.
         ekw = llm_config.engine_kwargs or {}
@@ -397,20 +396,17 @@ def build_llm_deployment(llm_config: LLMConfig):
         if mesh_shape is not None:
             chips = max(1, int(mesh_shape[0]) * int(mesh_shape[1]))
         elif mesh is not None:
-            sizes = (mesh if isinstance(mesh, dict)
-                     else {"tp": getattr(mesh, "tp", 1),
-                           "pp": getattr(mesh, "pp", 1)})
-            tp = sizes.get("tp", 1)
-            pp = sizes.get("pp", 1)
-            if tp == -1 or pp == -1:
+            tp = (mesh.get("tp", 1) if isinstance(mesh, dict)
+                  else getattr(mesh, "tp", 1))
+            if tp == -1:
                 # -1 resolves against VISIBLE devices inside the
                 # replica; here we must size the reservation itself, so
-                # wildcards would silently under-provision to 1 chip
+                # a wildcard would silently under-provision to 1 chip
                 raise ValueError(
-                    "give explicit tp/pp sizes in engine_kwargs.mesh "
+                    "give an explicit tp size in engine_kwargs.mesh "
                     "when accelerator_type is set (wildcard -1 cannot "
                     "size the replica's chip reservation)")
-            chips = max(1, tp * pp)
+            chips = max(1, tp)
         opts.setdefault("num_tpus", chips)
         dep_cfg["ray_actor_options"] = opts
     return serve.deployment(**dep_cfg)(LLMServerImpl).bind(

@@ -362,8 +362,7 @@ class ReceiptLedger:
                 # finishing inside its FIRST charged tick, before any
                 # commit created a live receipt (an imported session —
                 # restarts >= 1 skips the queue-note — with a small
-                # remaining budget, or a one-tick request under
-                # multi-step decode): issue the receipt now; the
+                # remaining budget): issue the receipt now; the
                 # tick's pending charges fold in at commit through the
                 # done index. Without this, finish() would lose the
                 # receipt AND commit() would leak a zombie live one.

@@ -6,15 +6,15 @@ the engine itself is built TPU-first — SURVEY.md §7 hard part #1).
 
 Design:
 - ONE forward dispatch per tick (the key split and the position update
-  beside it are small programs of their own): a tick with prefilling
-  slots runs the unified ragged step — one jitted program consuming a flat ragged token batch
-  (each decoding slot contributes 1 token, prefilling slots contribute
-  chunks packed under a Sarathi-style token budget; Ragged Paged
-  Attention, PAPERS.md). Pure-decode ticks run the device-resident
-  decode program. Legacy mode (unified_step=False / pp>1) instead pairs
-  one single-slot prefill-chunk dispatch with a whole-batch decode.
-- Prefill compiles per padded length bucket; prompt KV scatters into the
-  page pool inside the same jit.
+  beside it are small programs of their own), and two forward programs
+  in all: a tick with a prefilling slot runs the ragged step
+  (_ragged_step) — one jitted program consuming a flat ragged token
+  batch (each decoding slot contributes 1 token, prefilling slots
+  contribute chunks packed under a Sarathi-style token budget; Ragged
+  Paged Attention, PAPERS.md), prompt KV scattering into the page pool
+  inside the same jit. Every other tick runs the device-resident decode
+  program (_decode).
+- The ragged program compiles per (token bucket, context bucket).
 - Sampling (greedy/temperature/top-p) fused into both programs.
 - Page pools are donated through every call → XLA updates KV in place
   in HBM, no copy of the cache per token.
@@ -47,7 +47,6 @@ import numpy as np
 from ...models import llama
 from ...models.llama import LlamaConfig
 from ...models.family import family_of, resolve_config
-from ...models.llama_infer import decode_step, prefill
 from ...util import thread_sanitizer
 from .kv_cache import PageAllocator
 from .telemetry import EngineTelemetry
@@ -60,7 +59,6 @@ class EngineConfig:
     page_size: int = 16
     num_pages: int = 512
     max_seq_len: Optional[int] = None    # default: model max_seq
-    prefill_buckets: tuple = (32, 64, 128, 256, 512, 1024, 2048)
     seed: int = 0
     # "auto": Pallas paged-decode kernel on TPU, dense gather elsewhere.
     # Also accepts "gather" | "pallas" | "pallas_interpret".
@@ -71,32 +69,25 @@ class EngineConfig:
     max_prefill_tokens: int = 512
     # Hash-cons full prompt pages so shared prefixes skip re-prefill.
     enable_prefix_caching: bool = True
-    # Unified ragged step (Ragged Paged Attention, PAPERS.md): any tick
-    # with a prefilling slot runs ONE jitted program consuming a flat
-    # ragged token batch — every decoding slot contributes 1 token and
-    # prefilling slots contribute chunks packed under the token budget
-    # below — instead of the legacy pair of dispatches (one chunked
-    # prefill for a single slot, then a whole-batch decode). Retires
-    # the one-chunk-per-step prefill serialization; token-exact vs the
-    # legacy path at temperature 0. pp>1 keeps the legacy stage chain.
-    unified_step: bool = True
-    # Sarathi-style global token budget for one unified tick: decoding
-    # slots take 1 token each, the remainder goes to prefilling slots
-    # round-robin (each capped at max_prefill_tokens). 0 → default
-    # max_prefill_tokens + max_batch_size: a full chunk always rides
-    # on top of the decode tokens, so a single prefilling prompt
-    # advances at least one whole chunk per tick like the legacy path
-    # (leftover budget may additionally start a second prompt's chunk
-    # in the same tick).
+    # Sarathi-style global token budget for one ragged tick (Ragged
+    # Paged Attention, PAPERS.md): decoding slots take 1 token each,
+    # the remainder goes to prefilling slots round-robin (each capped
+    # at max_prefill_tokens). 0 → default max_prefill_tokens +
+    # max_batch_size: a full chunk always rides on top of the decode
+    # tokens, so a single prefilling prompt advances at least one whole
+    # chunk per tick (leftover budget may additionally start a second
+    # prompt's chunk in the same tick).
     max_num_batched_tokens: int = 0
     # Tensor-parallel serving: a parallel.MeshSpec (tp>1) — params shard
-    # over heads/mlp/vocab, the KV page pool over kv_heads, and
-    # prefill/decode jit over the whole mesh (the reference reaches TP
+    # over heads/mlp/vocab, the KV page pool over kv_heads, and both
+    # forward programs jit over the whole mesh (the reference reaches TP
     # only by placing external vLLM workers, vllm_models.py:123-159).
+    # A MeshSpec with pp>1 is refused: there is no pipeline-parallel
+    # serving.
     mesh: Any = None
     # Explicit-tp serving on a NAMED 2D mesh (ISSUE 17 / ROADMAP 4):
     # mesh_shape=(1, tp) builds a (data, tp_axis) Mesh via
-    # ops/tp_mesh.build_serving_mesh and the whole unified tick runs as
+    # ops/tp_mesh.build_serving_mesh and the whole ragged tick runs as
     # ONE shard_map'd collective-bearing program — params in the
     # Megatron layout (llama_infer.tp_param_specs), KV/scale pools
     # sharded over kv heads, page tables and sampling state replicated,
@@ -104,7 +95,7 @@ class EngineConfig:
     # lm_head's partial logits all-reduced (through
     # ops/quantized_collectives when quantized_collectives=True).
     # Mutually exclusive with mesh= (the GSPMD auto-partitioning path);
-    # requires unified_step and rejects pp/speculative/multi-step/LoRA.
+    # rejects LoRA.
     # Donation, _read_tokens, async readback, and spill/restore keep
     # the single-dispatch discipline, so the dispatch guard holds at
     # tp>1 (tested on the virtual CPU mesh).
@@ -114,36 +105,6 @@ class EngineConfig:
     # so registering adapters never changes compiled shapes (one
     # recompile when the FIRST adapter arrives, none after).
     max_loras: int = 8
-    # Speculative decoding (vLLM-class; net-new — the reference only
-    # places vLLM): {"draft_model": preset|LlamaConfig,
-    # "num_speculative_tokens": k}. A small draft proposes k tokens in
-    # ONE compiled program; the target verifies all of them in one
-    # chunk forward, so a decode round costs 2 dispatches for up to
-    # k+1 tokens — this amortizes per-dispatch overhead, the dominant
-    # decode cost on dispatch-latency-bound links. Greedy requests
-    # only (temperature 0, no penalties). Composes with prefix caching
-    # and tp meshes (draft replicated); pp stage-split is unsupported.
-    speculative: Optional[Dict[str, Any]] = None
-    # Multi-step decode: run this many decode iterations inside ONE
-    # compiled dispatch (tokens feed back on-device; per-slot budgets
-    # mask steps past max_tokens so KV writes never pass the
-    # preallocated pages). K steps amortize one dispatch + one
-    # readback (what a dispatch costs on the chip: not measured).
-    # Greedy, penalty AND sampled outputs are step-exact vs K=1
-    # (sampling keys derive from (request seed, token index), not a
-    # per-dispatch split chain — ISSUE 9). Applied only
-    # when nothing is prefilling/waiting, so the chunked-prefill
-    # no-stall contract keeps its one-step cadence; single device or
-    # tp (pp and speculative have their own paths).
-    decode_steps_per_call: int = 1
-    # Overlapped pipeline-parallel decode: split the decode batch into
-    # this many microbatches per step. Stage i runs microbatch j while
-    # stage i+1 runs j-1 (dispatches are async and stage device groups
-    # are disjoint), so the pp bubble shrinks at the cost of more,
-    # smaller dispatches per step — worth it on real multi-chip pp,
-    # counterproductive on a dispatch-latency-bound link. Must divide
-    # max_batch_size; 1 = sequential stages (default).
-    pp_decode_microbatches: int = 1
     # Pipelined engine ticks (ISSUE 4): after dispatching decode tick
     # t, start a NON-BLOCKING device->host copy of its token buffer
     # and immediately dispatch tick t+1 from the device-resident loop
@@ -158,9 +119,7 @@ class EngineConfig:
     # fold). Any structural event — admission, retirement, prefill,
     # LoRA registration, abort — drains the in-flight tick first, so
     # those paths stay byte-identical to the synchronous engine.
-    # Greedy/penalized decode is token-exact vs sync; auto-off for
-    # pp>1 and speculative engines (their dispatch chains manage
-    # their own readbacks).
+    # Greedy/penalized decode is token-exact vs sync.
     async_readback: bool = True
     # Request-lifecycle telemetry (ISSUE 5): SLO histograms (TTFT /
     # inter-token latency / queue wait / e2e), token + finish-reason
@@ -168,9 +127,7 @@ class EngineConfig:
     # timelines and the engine flight recorder — recorded from
     # host-side admission/fold events ONLY, so instrumentation adds
     # zero device syncs and zero extra dispatches (the dispatch-guard
-    # suite runs with this on). The off switch exists for the bench
-    # overhead A/B (bench_llm --smoke), not because it costs device
-    # time.
+    # suite runs with this on and off: tests/test_dispatch_guard.py).
     enable_metrics: bool = True
     # Prometheus "model" tag on this engine's metric samples (the
     # server passes its model_id; engines sharing a tag share sample
@@ -195,8 +152,7 @@ class EngineConfig:
     # rolling decode/prefill goodput, MFU/MBU against the hardware
     # envelope, and which roof binds. Pure host arithmetic: zero
     # device syncs, zero extra dispatches (the dispatch-guard suite
-    # runs with this ON). The off switch exists for the bench
-    # overhead A/B (bench_llm --smoke), like enable_metrics.
+    # runs with this ON).
     enable_perf_accounting: bool = True
     # Hardware envelope override (a perfmodel.ENVELOPES key, e.g.
     # "tpu-v5e" | "cpu"). None autodetects from the first jax device;
@@ -243,8 +199,6 @@ class EngineConfig:
     # failover replay, so greedy AND sampled streams are byte-identical
     # to a never-preempted run). Off by default: "out of pages" stays
     # a hard signal unless the operator opts into the latency tier.
-    # Does not compose with pp>1 or speculative engines (their KV
-    # lives in stage/draft pools this tier does not migrate).
     enable_kv_offload: bool = False
     # Host-tier capacity in pages (None = unbounded). A full tier makes
     # preemption attempts fail, falling back to the exhaustion path.
@@ -257,9 +211,7 @@ class EngineConfig:
     # dequantize up front, and the Pallas kernels fuse the dequant
     # multiply into their HBM→VMEM streaming loop, so decode reads
     # ~1/4 the KV bytes. Spill/restore and session/prefix shipping
-    # move the narrow pages + scales as stored. Requires the unified
-    # ragged step; does not compose with pp>1 or speculative engines
-    # (their stage/draft pools stay f32).
+    # move the narrow pages + scales as stored.
     kv_dtype: str = "f32"
     # EQuARX-style quantized tp collectives (ops/quantized_collectives):
     # expose int8 psum/all_gather for mesh programs that opt in. On the
@@ -311,11 +263,10 @@ class SamplingParams:
     # the request id (derive_seed), so EVERY sampled request is
     # replayable: the sampling key for the token at absolute index i
     # is fold_in(PRNGKey(seed), i) — independent of tick count,
-    # batching, and which program (prefill / chunked / ragged /
-    # decode) produces it. That makes sampled mid-stream failover
-    # token-exact: a continuation re-prefilled from prompt + emitted
-    # tokens resumes the exact sample sequence. (pp>1 engines keep
-    # the legacy shared-key sampling; their greedy path is unaffected.)
+    # batching, and which program (ragged / decode) produces it. That
+    # makes sampled mid-stream failover token-exact: a continuation
+    # re-prefilled from prompt + emitted tokens resumes the exact
+    # sample sequence.
     seed: Optional[int] = None
 
 
@@ -410,7 +361,7 @@ def _row_sample_keys(seeds, idx):
     (ISSUE 9): fold the ABSOLUTE index of the token being sampled into
     a key derived from the request's seed. The key depends only on
     (seed, token index) — never on tick count, batch composition, or
-    which program (prefill / chunk / ragged / decode) produces the
+    which program (ragged / decode) produces the
     token — so a failover continuation re-prefilled from the original
     prompt + already-emitted tokens samples the same suffix the dead
     replica would have."""
@@ -484,8 +435,8 @@ def _sample(logits, key, temps, top_ps, top_ks=None, rep_pens=None,
     oracle, token for token.
 
     row_keys: optional (B,) per-row PRNG keys (_row_sample_keys) —
-    the per-request deterministic path; `key` is the legacy shared
-    key, kept for the pp stage programs and direct callers.
+    the per-request deterministic path, which both engine programs
+    take; `key` is one key shared by the batch, for direct callers.
     """
     if rep_pens is not None and seen is not None:
         pen = jnp.where(logits > 0,
@@ -514,39 +465,6 @@ def _with_rider(tokens, rider):
         return tokens
     return jnp.concatenate([tokens, rider.reshape(-1).astype(
         tokens.dtype)])
-
-
-class _Stage:
-    """Device placement for ONE pipeline stage: a tp Mesh (tp>1) or a
-    single device, plus put() helpers. Pipeline-parallel serving splits
-    the stacked layer arrays (and the KV pools) into contiguous stage
-    slices over disjoint device groups — the reference places external
-    vLLM PP workers via PACK placement groups (vllm_models.py:127-139);
-    here stages are chained jit programs in one process, activations
-    crossing device groups via device_put (ICI on real hardware)."""
-
-    def __init__(self, devices, tp: int):
-        if tp > 1:
-            from jax.sharding import NamedSharding, PartitionSpec
-            from ...parallel import MeshSpec
-            # full axis set (dp/fsdp/... sized 1) so the shared
-            # param-sharding rules resolve against a stage mesh exactly
-            # as they do against the tp-only engine mesh
-            self.mesh = MeshSpec(dp=1, fsdp=1, sp=1, tp=tp, ep=1,
-                                 pp=1).build(list(devices))
-            self.repl = NamedSharding(self.mesh, PartitionSpec())
-            self.kv_sharding = NamedSharding(
-                self.mesh, PartitionSpec(None, None, None, "tp", None))
-        else:
-            self.mesh = None
-            self.device = devices[0]
-            self.repl = self.kv_sharding = None
-
-    def put(self, x, sharding=None):
-        if self.mesh is None:
-            return jax.device_put(x, self.device)
-        return jax.device_put(x, sharding if sharding is not None
-                              else self.repl)
 
 
 # The named phases of a tick, in the order a tick meets them. Each is a
@@ -674,19 +592,6 @@ class InferenceEngine:
                                             tp_axis=ec.tp_axis)
             tp = int(named.shape[ec.tp_axis])
             if tp > 1:
-                if ec.speculative:
-                    raise ValueError(
-                        "mesh_shape does not compose with speculative "
-                        "decoding (the draft has no explicit-tp path)")
-                if int(ec.decode_steps_per_call or 1) > 1:
-                    raise ValueError(
-                        "mesh_shape does not compose with "
-                        "decode_steps_per_call > 1")
-                if not ec.unified_step:
-                    raise ValueError(
-                        "mesh_shape requires unified_step=True: the "
-                        "legacy prefill programs have no shard_map "
-                        "path")
                 self._explicit_tp = True
                 self._tp = tp
                 self._tp_axis = ec.tp_axis
@@ -696,40 +601,19 @@ class InferenceEngine:
                     cfg, ec.tp_axis)
                 self._tp_logits_psum = _tpm.logits_psum_fn(
                     "int8" if ec.quantized_collectives else "f32")
-                self.mesh, self.stages = named, None
+                self.mesh = named
             else:
                 # (1, 1): a single-chip slice is just the plain engine
-                self.mesh, self.stages = None, None
+                self.mesh = None
         else:
-            self.mesh, self.stages = self._build_placement(ec.mesh, cfg)
-        self.pp = len(self.stages) if self.stages else 1
-        if self.pp > 1:
-            import logging
-            # be loud about the ISSUE 9 caveat: the pp stage programs
-            # keep legacy shared-key sampling, so SamplingParams.seed
-            # is ignored there — sampled failover continuations on pp
-            # replicas are NOT token-exact (greedy ones are)
-            logging.getLogger(__name__).warning(
-                "pp>1 engine: per-request seeded sampling is "
-                "unavailable on the pipeline-parallel path; sampled "
-                "(temperature>0) failover replay is not token-exact "
-                "on this replica")
+            self.mesh = self._build_placement(ec.mesh, cfg)
         if params is None and ec.checkpoint:
             from ...models import checkpoint_io
             # sharded load: each device's shard is a windowed mmap read
-            # (pp stages split host-side below, so they load unsharded)
             params = checkpoint_io.load_llama_params(
                 cfg, ec.checkpoint,
-                mesh=(self.mesh if self.pp == 1
-                      and not self._explicit_tp else None))
-        if self.pp > 1:
-            if params is None:
-                params = llama.init_params(
-                    cfg, jax.random.PRNGKey(ec.seed))
-            self.params = None
-            self.stage_params = self._split_stage_params(params, cfg)
-            self._kv_sharding = self._repl = None
-        elif self.mesh is not None:
+                mesh=(None if self._explicit_tp else self.mesh))
+        if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             from ...parallel.sharding import tree_shardings
             if self._explicit_tp:
@@ -768,13 +652,6 @@ class InferenceEngine:
             enable_prefix_caching=ec.enable_prefix_caching)
         self.max_pages_per_seq = self.allocator.pages_needed(self.max_seq)
         # -- KV memory hierarchy (ISSUE 10) ----------------------------
-        if (ec.enable_kv_offload or ec.kv_watermark_tokens is not None) \
-                and (self.pp > 1 or ec.speculative):
-            raise ValueError(
-                "the KV memory hierarchy (enable_kv_offload / "
-                "kv_watermark_tokens) does not compose with pp>1 or "
-                "speculative engines: their KV lives in stage/draft "
-                "pools the host tier does not migrate")
         if ec.kv_watermark_tokens is not None \
                 and ec.kv_watermark_tokens < 1:
             raise ValueError("kv_watermark_tokens must be >= 1 or None")
@@ -790,18 +667,6 @@ class InferenceEngine:
         # -- Quantized KV pages (ISSUE 16) -----------------------------
         from ...ops import kv_quant
         self._kv_kind = kv_quant.validate_kind(ec.kv_dtype)
-        if self._kv_kind != "f32":
-            if self.pp > 1 or ec.speculative:
-                raise ValueError(
-                    "kv_dtype=int8/fp8 does not compose with pp>1 or "
-                    "speculative engines: their stage/draft pools "
-                    "have no scale plumbing")
-            if not ec.unified_step:
-                raise ValueError(
-                    "kv_dtype=int8/fp8 requires unified_step=True: "
-                    "the legacy whole-prompt prefill programs have no "
-                    "quantized write path (unified engines prefill "
-                    "through the ragged program, which does)")
         # -- KV pool layout ---------------------------------------------
         # Pools are [layers, pages, page_size, kv_heads, row] where
         # row = ops/paged_attention.pool_head_dim: the model's head_dim
@@ -820,11 +685,9 @@ class InferenceEngine:
             cfg, impl, self._kv_kind)
         pool_dt = crow.dtype
         # kv heads ONE shard's kernel sees, read off the sharding
-        # the pools will carry (tp engines and pp stages alike)
-        kv_sh = (self.stages[0].kv_sharding if self.stages
-                 else self._kv_sharding)
-        local_kvh = (crow.heads if kv_sh is None else
-                     kv_sh.shard_shape(
+        # the pools will carry
+        local_kvh = (crow.heads if self._kv_sharding is None else
+                     self._kv_sharding.shard_shape(
                          (1, 1, 1, crow.heads, 1))[3])
         # what the ragged kernel's block sizes derive from, besides a
         # tick's T and context bucket (ragged_work_counts)
@@ -898,33 +761,19 @@ class InferenceEngine:
         # on-demand profiling: {"remaining", "dir", "cm"} while armed
         # (POST /debug/profile → profile_next_ticks)
         self._profile: Optional[Dict[str, Any]] = None
-        if self.pp > 1:
-            per = cfg.n_layers // self.pp
-            kv_shape = crow.pool_shape(per, ec.num_pages, ec.page_size)
-            self.k_pages = [
-                st.put(jnp.zeros(kv_shape, cfg.dtype), st.kv_sharding)
-                for st in self.stages]
-            self.v_pages = [
-                st.put(jnp.zeros(kv_shape, cfg.dtype), st.kv_sharding)
-                for st in self.stages]
-            # sampling state (key/temps/seen/...) lives with the LAST
-            # stage, where logits are produced
-            self._key = self.stages[-1].put(
-                jax.random.PRNGKey(ec.seed + 1))
-        else:
-            kv_shape = crow.pool_shape(cfg.n_layers, ec.num_pages,
-                                       ec.page_size)
-            # born sharded, like the weights: a pool zeroed on the
-            # default device and then resharded passes WHOLE through
-            # chip 0 (on the chip: +1.8 GB peak there at tp=4, 8b).
-            # A latent cache is ONE pool: `k_pages` holds it and
-            # `v_pages` is None through every program's signature
-            self.k_pages = jnp.zeros(kv_shape, pool_dt,
-                                     device=self._kv_sharding)
-            self.v_pages = (jnp.zeros(kv_shape, pool_dt,
-                                      device=self._kv_sharding)
-                            if crow.pools == 2 else None)
-            self._key = self._dev(jax.random.PRNGKey(ec.seed + 1))
+        kv_shape = crow.pool_shape(cfg.n_layers, ec.num_pages,
+                                   ec.page_size)
+        # born sharded, like the weights: a pool zeroed on the
+        # default device and then resharded passes WHOLE through
+        # chip 0 (on the chip: +1.8 GB peak there at tp=4, 8b).
+        # A latent cache is ONE pool: `k_pages` holds it and
+        # `v_pages` is None through every program's signature
+        self.k_pages = jnp.zeros(kv_shape, pool_dt,
+                                 device=self._kv_sharding)
+        self.v_pages = (jnp.zeros(kv_shape, pool_dt,
+                                  device=self._kv_sharding)
+                        if crow.pools == 2 else None)
+        self._key = self._dev(jax.random.PRNGKey(ec.seed + 1))
         # per-(token row, kv head) f32 scale pools beside the value
         # pools (None for f32 engines): [L, P, page, KVH], sharded on
         # kv heads under tp exactly like the pools they scale
@@ -946,7 +795,7 @@ class InferenceEngine:
         # multi-LoRA: name -> adapter index (0 = the zero adapter);
         # stacks are {proj: {"a": (A, L, H, r), "b": (A, r, O)}} device
         # arrays rebuilt on registration (first registration recompiles
-        # the decode/prefill programs once)
+        # the decode and ragged programs once)
         self._lora_names: Dict[Optional[str], int] = {None: 0}
         self._lora_raw: Dict[str, dict] = {}
         self._lora_stacks = None
@@ -956,49 +805,6 @@ class InferenceEngine:
         self._page_tables = np.zeros(
             (ec.max_batch_size, self.max_pages_per_seq), np.int32)
 
-        # speculative decoding state (see EngineConfig.speculative)
-        self._spec = None
-        if ec.speculative:
-            if self.pp > 1:
-                raise ValueError(
-                    "speculative decoding does not compose with "
-                    "pipeline-parallel serving (stage-split engines "
-                    "would need per-stage draft programs)")
-            # Prefix caching composes: the draft pool mirrors the
-            # target pool's page ids, and a shared page's draft KV was
-            # written by the ORIGINAL slot's draft prefill over the
-            # same prefix tokens — value-identical for every sharer.
-            # The admission re-runs the (small) draft prefill over the
-            # full prompt, which overwrites shared pages with the same
-            # values: benign. TP composes by replicating the draft
-            # (it is small; redundant per-device draft compute is far
-            # cheaper than sharding it) while verify runs through the
-            # tp-sharded target exactly like a normal chunk forward.
-            draft_cfg = llama.config(ec.speculative["draft_model"])
-            if draft_cfg.vocab_size != cfg.vocab_size:
-                raise ValueError("draft and target must share a vocab")
-            k = int(ec.speculative.get("num_speculative_tokens", 4))
-            if k < 2:
-                raise ValueError("num_speculative_tokens must be >= 2")
-            dparams = ec.speculative.get("draft_params")
-            if dparams is None:
-                dparams = llama.init_params(
-                    draft_cfg, jax.random.PRNGKey(ec.seed + 7))
-            dkv = (draft_cfg.n_layers, ec.num_pages, ec.page_size,
-                   draft_cfg.n_kv_heads,
-                   _pa.pool_head_dim(draft_cfg.head_dim, impl))
-            # under a tp mesh the draft replicates (self._dev with no
-            # sharding = replicated placement)
-            self._spec = {
-                "cfg": draft_cfg, "k": k,
-                "params": jax.tree.map(self._dev, dparams),
-                "dk": self._dev(jnp.zeros(dkv, draft_cfg.dtype)),
-                "dv": self._dev(jnp.zeros(dkv, draft_cfg.dtype)),
-                # per-slot: canonical tokens whose KV the draft holds
-                "draft_pos": np.zeros(ec.max_batch_size, np.int64),
-                "accepted": 0, "rounds": 0, "emitted": 0,
-                "draft_fns": {}, "verify_fns": {}, "prefill_fns": {},
-            }
         # quantized engines thread the scale pools right after the
         # value pools (all donated: in-place HBM updates), shifting
         # the trailing static all_greedy arg by 2
@@ -1010,29 +816,10 @@ class InferenceEngine:
             self._decode_fn = jax.jit(
                 self._build_decode(), donate_argnums=(1, 2, 3),
                 static_argnums=(16,))
-        self._multi_decode_fn = None
-        if int(ec.decode_steps_per_call or 1) > 1:
-            if self.pp > 1:
-                raise ValueError(
-                    "decode_steps_per_call does not compose with "
-                    "pipeline-parallel serving")
-            if self._kv_kind != "f32":
-                self._multi_decode_fn = jax.jit(
-                    self._build_multi_decode(
-                        int(ec.decode_steps_per_call)),
-                    donate_argnums=(1, 2, 3, 4, 5),
-                    static_argnums=(19,))
-            else:
-                self._multi_decode_fn = jax.jit(
-                    self._build_multi_decode(
-                        int(ec.decode_steps_per_call)),
-                    donate_argnums=(1, 2, 3), static_argnums=(17,))
         self._d_tokens = None          # device-resident slot state
         self._d_seen = None
         self._d_seeds = None           # per-slot sampling seeds (B,)
         self._host_active = np.zeros(ec.max_batch_size, bool)
-        self._prefill_fns: Dict[int, Any] = {}
-        self._chunk_fns: Dict[int, Any] = {}
         self._ragged_fns: Dict[tuple, Any] = {}
         self._prefill_rr = 0           # round-robin cursor over slots
         # device-resident page tables: re-uploaded only when the host
@@ -1055,8 +842,8 @@ class InferenceEngine:
             donate_argnums=(0,))
         self._seen_scatter_buckets: set = set()
         # dispatch accounting: FORWARD-program executions vs engine
-        # ticks (the unified step's contract is one dispatch per
-        # tick). State-refresh machinery is deliberately excluded —
+        # ticks (the contract is one dispatch per tick). State-refresh
+        # machinery is deliberately excluded —
         # per-tick key splits, admit/finish-time uploads, and the
         # _refresh_seen row scatter run outside the tick's forward
         # dispatch and only on turnover events.
@@ -1070,12 +857,7 @@ class InferenceEngine:
         # (invalidated on slot admission/retirement only)
         self._samp_cache = None
         # -- pipelined async readback (EngineConfig.async_readback) --
-        # auto-off for pp>1 (stage chains pipeline their own hops) and
-        # speculative engines (rounds read host canonical state
-        # between their 2-3 dispatches — a lagged fold would feed the
-        # draft stale deltas)
-        self._async = (bool(ec.async_readback) and self.pp == 1
-                       and self._spec is None)
+        self._async = bool(ec.async_readback)
         self._inflight: Optional[_InflightTick] = None
         # tokens folded OUTSIDE a step() call (drains triggered by
         # abort/register_loras) surface through the next step's
@@ -1090,14 +872,8 @@ class InferenceEngine:
         # chips this replica occupies — the fleet's slice-accounting
         # unit (ReplicaSnapshot.chips, /fleet rows) AND the perf
         # accountant's per-chip MFU/MBU divisor
-        if self.pp > 1:
-            self.n_chips = sum(
-                (int(st.mesh.devices.size) if st.mesh is not None
-                 else 1) for st in self.stages)
-        elif self.mesh is not None:
-            self.n_chips = int(self.mesh.devices.size)
-        else:
-            self.n_chips = 1
+        self.n_chips = (int(self.mesh.devices.size)
+                        if self.mesh is not None else 1)
         self.perf: Optional[PerfAccountant] = None
         if ec.enable_perf_accounting:
             self.perf = PerfAccountant(
@@ -1105,10 +881,6 @@ class InferenceEngine:
                           cache_row=crow),
                 detect_envelope(name=ec.perf_envelope),
                 n_chips=self.n_chips)
-            if self._spec is not None:
-                # draft-model costs accounted against their own config
-                self._spec["cost_model"] = CostModel(
-                    self._spec["cfg"], ec.page_size)
         # per-request cost attribution + tick-anomaly analyzer
         # (ISSUE 13): both ride the perf accountant's numbers, so both
         # require it; both are pure host arithmetic (the dispatch-
@@ -1162,14 +934,6 @@ class InferenceEngine:
         # tests), in which case acquisition order and guarded-field
         # ownership are checked at runtime.
         self._step_lock = thread_sanitizer.make_lock("engine._step_lock")
-        self.pp_mb = max(int(ec.pp_decode_microbatches or 1), 1)
-        if self.pp_mb > 1:
-            if self.pp <= 1:
-                raise ValueError(
-                    "pp_decode_microbatches requires a pp>1 mesh")
-            if ec.max_batch_size % self.pp_mb:
-                raise ValueError(
-                    "pp_decode_microbatches must divide max_batch_size")
         # published fleet-counter snapshot: replaced WHOLESALE under
         # _step_lock by _publish_counters_locked, read lock-free by
         # fleet_stats at router cadence (fleet_counters())
@@ -1187,10 +951,6 @@ class InferenceEngine:
                                   or ec.kv_watermark_tokens is not None),
             "mesh": ec.mesh is not None,
             "mesh_shape": ec.mesh_shape is not None,
-            "speculative": bool(ec.speculative),
-            "decode_steps_per_call":
-                int(ec.decode_steps_per_call or 1) > 1,
-            "unified_step": not ec.unified_step,
             "checkpoint": bool(ec.checkpoint),
         }
         for option, on in asked.items():
@@ -1208,86 +968,46 @@ class InferenceEngine:
 
     @staticmethod
     def _build_placement(spec, cfg: LlamaConfig):
-        """EngineConfig.mesh (MeshSpec | dict | None) ->
-        (tp Mesh | None, stage list | None).
+        """EngineConfig.mesh (MeshSpec | dict | None) -> tp Mesh | None.
 
-        Serving supports the tp and pp axes (the reference's vLLM
-        TP x PP placement, vllm_models.py:123-159): tp shards
-        heads/ffn/vocab inside each stage's GSPMD program; pp>1 splits
-        the layer stack into contiguous stage slices over disjoint
-        device groups (see _Stage). dp/fsdp/sp/ep stay rejected —
-        replicated decode on dp>1 silently halves the fleet. tp=-1
-        keeps MeshSpec's "use remaining devices" meaning: all visible
-        devices divided by pp."""
+        Serving supports the tp axis alone: tp shards heads/ffn/vocab
+        inside the GSPMD programs. dp/fsdp/sp/ep are rejected —
+        replicated decode on dp>1 silently halves the fleet — and so is
+        pp. tp=-1 keeps MeshSpec's "use remaining devices" meaning."""
         if spec is None:
-            return None, None
+            return None
         from ...parallel import MeshSpec
         if isinstance(spec, dict):
             spec = MeshSpec(**spec)
         sizes = dict(spec.axis_sizes())
         devices = jax.devices()
-        pp = sizes.get("pp", 1)
-        if pp == -1 and sizes["tp"] == -1:
+        if sizes.get("pp", 1) != 1:
             raise ValueError(
-                "at most one of tp/pp may be -1 in an engine mesh")
+                f"engine mesh has pp={sizes['pp']}: pipeline-parallel "
+                f"serving was removed; the engine shards over tp only "
+                f"(a model too large for one slice's tp needs a larger "
+                f"slice)")
         if sizes["tp"] == -1:
-            sizes["tp"] = max(1, len(devices) // max(pp, 1))
-        if pp == -1:    # MeshSpec semantics: use the remaining devices
-            pp = max(1, len(devices) // sizes["tp"])
+            sizes["tp"] = len(devices)
         sizes["fsdp"] = 1 if sizes["fsdp"] == -1 else sizes["fsdp"]
         bad = {k: v for k, v in sizes.items()
                if k not in ("tp", "pp") and (v > 1 or v == -1)}
         if bad:
             raise ValueError(
-                f"engine mesh supports only tp/pp axes; got {bad}")
+                f"engine mesh supports only the tp axis; got {bad}")
         tp = sizes["tp"]
-        if tp > 1:
-            for name, dim in (("n_heads", cfg.n_heads),
-                              ("n_kv_heads", cfg.n_kv_heads),
-                              ("vocab_size", cfg.vocab_size)):
-                if dim % tp:
-                    raise ValueError(
-                        f"{name}={dim} not divisible by tp={tp}")
-        if tp * pp > len(devices):
-            raise ValueError(
-                f"engine mesh needs {tp * pp} devices, "
-                f"have {len(devices)}")
-        if pp > 1:
-            if cfg.n_layers % pp:
-                raise ValueError(
-                    f"n_layers={cfg.n_layers} not divisible by pp={pp}")
-            stages = [_Stage(devices[i * tp:(i + 1) * tp], tp)
-                      for i in range(pp)]
-            return None, stages
         if tp == 1:
-            return None, None
-        return MeshSpec(**{**sizes, "pp": 1}).build(devices[:tp]), None
-
-    def _split_stage_params(self, params: Dict[str, Any],
-                            cfg: LlamaConfig) -> List[Dict[str, Any]]:  # jaxlint: disable=JL006 -- engine-init only: one placement per pp stage, never on the tick path
-        """Slice the stacked layer arrays into per-stage params placed
-        on each stage's devices (tp-sharded inside a stage)."""
-        from ...parallel.sharding import shard_tree
-        per = cfg.n_layers // self.pp
-        axes = llama.param_logical_axes(cfg)
-        out = []
-        for i, stage in enumerate(self.stages):
-            p = {"layers": jax.tree.map(
-                lambda a: a[i * per:(i + 1) * per], params["layers"])}
-            ax = {"layers": axes["layers"]}
-            if i == 0:
-                p["embed"] = params["embed"]
-                ax["embed"] = axes["embed"]
-            if i == self.pp - 1:
-                p["final_norm"] = params["final_norm"]
-                p["lm_head"] = params["lm_head"]
-                ax["final_norm"] = axes["final_norm"]
-                ax["lm_head"] = axes["lm_head"]
-            if stage.mesh is not None:
-                out.append(shard_tree(p, ax, stage.mesh))
-            else:
-                out.append(jax.device_put(p, stage.device))
-        return out
+            return None
+        for name, dim in (("n_heads", cfg.n_heads),
+                          ("n_kv_heads", cfg.n_kv_heads),
+                          ("vocab_size", cfg.vocab_size)):
+            if dim % tp:
+                raise ValueError(
+                    f"{name}={dim} not divisible by tp={tp}")
+        if tp > len(devices):
+            raise ValueError(
+                f"engine mesh needs {tp} devices, have {len(devices)}")
+        return MeshSpec(**sizes).build(devices[:tp])
 
     def _dev(self, x, sharding=None):
         """device_put honoring the engine mesh (replicated by default)."""
@@ -1440,148 +1160,9 @@ class InferenceEngine:
 
         return step
 
-    def _build_multi_decode(self, k_steps: int):
-        """K decode iterations in one compiled program: sampled tokens
-        feed back on-device, positions advance per step, and a per-slot
-        BUDGET (remaining max_tokens) masks steps that would write past
-        the preallocated KV pages. Emits [K, B] tokens; the host
-        processes them in order (EOS/max_tokens truncate per slot)."""
-        step = self._build_decode()
-        if self._kv_kind != "f32":
-            def multi_q(params, k_pages, v_pages, k_scales, v_scales,
-                        seen, tokens, positions, page_tables, active,
-                        key, temps, top_ps, top_ks, rep_pens, seeds,
-                        lora, lora_idx, budget, all_greedy):
-                def body(carry, i):
-                    (tokens, positions, k_pages, v_pages, k_scales,
-                     v_scales, seen) = carry
-                    act_i = jnp.logical_and(active, budget > i)
-                    toks, k_pages, v_pages, k_scales, v_scales, seen \
-                        = step(params, k_pages, v_pages, k_scales,
-                               v_scales, seen, tokens, positions,
-                               page_tables, act_i, key, temps, top_ps,
-                               top_ks, rep_pens, seeds, lora, lora_idx,
-                               all_greedy)
-                    positions = positions + act_i
-                    return (toks, positions, k_pages, v_pages,
-                            k_scales, v_scales, seen), toks
-
-                (tokens, positions, k_pages, v_pages, k_scales,
-                 v_scales, seen), out = jax.lax.scan(
-                    body, (tokens, positions, k_pages, v_pages,
-                           k_scales, v_scales, seen),
-                    jnp.arange(k_steps))
-                return (out, tokens, positions, k_pages, v_pages,
-                        k_scales, v_scales, seen)
-
-            return multi_q
-
-        def multi(params, k_pages, v_pages, seen, tokens, positions,
-                  page_tables, active, key, temps, top_ps, top_ks,
-                  rep_pens, seeds, lora, lora_idx, budget, all_greedy):
-            def body(carry, i):
-                tokens, positions, k_pages, v_pages, seen = carry
-                act_i = jnp.logical_and(active, budget > i)
-                # per-request keys come from (seed, absolute position)
-                # inside step(), so sub-steps need no split chain —
-                # multi-step sampled decode is now step-exact vs K=1
-                toks, k_pages, v_pages, seen = step(
-                    params, k_pages, v_pages, seen, tokens, positions,
-                    page_tables, act_i, key, temps, top_ps, top_ks,
-                    rep_pens, seeds, lora, lora_idx, all_greedy)
-                positions = positions + act_i
-                return (toks, positions, k_pages, v_pages, seen), toks
-
-            (tokens, positions, k_pages, v_pages, seen), out = \
-                jax.lax.scan(
-                    body, (tokens, positions, k_pages, v_pages, seen),
-                    jnp.arange(k_steps))
-            return out, tokens, positions, k_pages, v_pages, seen
-
-        return multi
-
-    def _prefill_fn(self, bucket: int):
-        fn = self._prefill_fns.get(bucket)
-        if fn is None:
-            cfg = self.model_cfg
-
-            def run(params, k_pages, v_pages, tokens, true_lens,
-                    page_tables, key, temps, top_ps, top_ks, rep_pens,
-                    seeds, lora, lora_idx):
-                logits, k_pages, v_pages = prefill(
-                    cfg, params, tokens, true_lens, k_pages, v_pages,
-                    page_tables, lora=lora, lora_idx=lora_idx)
-                # prompt tokens count as "seen" for the penalty (HF
-                # semantics penalize input_ids too); padding masked
-                b, bucket_len = tokens.shape
-                valid = jnp.arange(bucket_len)[None, :] < true_lens[:, None]
-                seen = jnp.zeros((b, cfg.vocab_size), bool)
-                seen = seen.at[jnp.arange(b)[:, None], tokens].max(valid)
-                # the first generated token sits at absolute index
-                # true_lens (= prompt length): same key a decode tick
-                # would derive for it
-                first = _sample(logits, key, temps, top_ps, top_ks,
-                                rep_pens, seen,
-                                row_keys=_row_sample_keys(seeds,
-                                                          true_lens))
-                return first, k_pages, v_pages
-
-            # donation audit (JL002/JL003, vs the unified jit's
-            # donate_argnums=(1, 2, 3)): the KV pools (1, 2) are
-            # donated here too; there is no third donated arg because
-            # the whole-prompt path has no threaded `seen` — it is
-            # built in-program from the prompt itself.
-            fn = jax.jit(run, donate_argnums=(1, 2))
-            self.compiles += 1
-            self._prefill_fns[bucket] = fn
-        return fn
-
-    def _chunk_fn(self, bucket: int, ctx_pages: int):
-        """Jitted prefill_chunk + first-token sampling, cached per
-        (chunk bucket, context-pages bucket) so dense-context cost
-        scales with the context that exists, not max_seq."""
-        fn = self._chunk_fns.get((bucket, ctx_pages))
-        if fn is None:
-            cfg = self.model_cfg
-            from ...models.llama_infer import prefill_chunk
-
-            def run(params, k_pages, v_pages, tokens, start_pos,
-                    chunk_lens, page_tables, key, temps, top_ps,
-                    top_ks, rep_pens, seen, seeds, lora, lora_idx):
-                logits, k_pages, v_pages = prefill_chunk(
-                    cfg, params, tokens, start_pos, chunk_lens,
-                    k_pages, v_pages, page_tables, ctx_pages=ctx_pages,
-                    lora=lora, lora_idx=lora_idx)
-                b, bucket_len = tokens.shape
-                valid = jnp.arange(bucket_len)[None, :] < chunk_lens[:, None]
-                seen = seen.at[jnp.arange(b)[:, None], tokens].max(valid)
-                # the sample only COUNTS on the final chunk, where
-                # start_pos + chunk_lens == prompt length — the same
-                # absolute index the whole-prompt path keys on
-                first = _sample(logits, key, temps, top_ps, top_ks,
-                                rep_pens, seen,
-                                row_keys=_row_sample_keys(
-                                    seeds, start_pos + chunk_lens))
-                return first, k_pages, v_pages
-
-            # donation audit (JL002, vs the unified jit's
-            # donate_argnums=(1, 2, 3)): pools (1, 2) donated. The
-            # `seen` arg (12) intentionally is NOT: it is a fresh
-            # per-chunk upload consumed but never returned (the
-            # chunk's sample may be discarded host-side), and no
-            # output matches its (1, V) bool buffer — donating it
-            # would only emit unused-donation warnings.
-            fn = jax.jit(run, donate_argnums=(1, 2))
-            self.compiles += 1
-            self._chunk_fns[(bucket, ctx_pages)] = fn
-        return fn
-
-    # -- unified ragged step ------------------------------------------------
-
     def _device_tables(self):
         """Device-resident copy of the page tables, re-uploaded only
-        when the host mirror changed (allocation events) — the legacy
-        paths re-uploaded per spec round / per prefill chunk."""
+        when the host mirror changed (allocation events)."""
         ver, arr = self._d_tables_cache
         if ver != self._tables_version:
             # jnp.array, not asarray: the host mirror is mutated in
@@ -1595,10 +1176,9 @@ class InferenceEngine:
 
     def _read_tokens(self, dev, of: int = 0) -> "np.ndarray":
         """THE engine's device->host sync point: every compiled-
-        program readback funnels through here — lagged async folds,
-        legacy sync readbacks, pp stage outputs and speculative
-        cands/preds alike. jaxlint JL005 sanctions exactly this site;
-        a bare np.asarray on a dispatch result anywhere else is
+        program readback funnels through here — lagged async folds
+        and synchronous readbacks alike. jaxlint JL005 sanctions
+        exactly this site; a bare np.asarray on a dispatch result anywhere else is
         flagged (tools/jaxlint/README.md). Time spent blocked here is
         the tick's un-hidden device time (the `readback_wait` phase;
         `device_ms` in stats()["tick_times"]). `of`: the tick whose
@@ -1615,7 +1195,7 @@ class InferenceEngine:
 
     def _ragged_fn(self, t_bucket: int, ctx_pages: int,
                    all_greedy: bool):
-        """Jitted unified tick: ragged forward over the flat token
+        """Jitted ragged tick: the ragged forward over the flat token
         batch + per-slot sampling, cached per (token-count bucket,
         context-pages bucket, all_greedy). all_greedy is a STATIC jit
         arg — keying the cache on it too keeps the compile counter
@@ -1686,9 +1266,9 @@ class InferenceEngine:
                 # decoding slot the one token is already seen — no-op)
                 seen = seen.at[slot_ids, tokens].max(valid)
                 # each slot's sample lands one past its last packed
-                # token — the same absolute index the decode and
-                # prefill programs key on, so a request samples
-                # identically whichever program serves its tick
+                # token — the same absolute index the decode program
+                # keys on, so a request samples identically whichever
+                # program serves its tick
                 row_keys = _row_sample_keys(
                     seeds, positions[last_idx] + 1)
                 toks = _sample(logits, key, temps, top_ps, top_ks,
@@ -1804,7 +1384,7 @@ class InferenceEngine:
             ec.max_prefill_tokens + ec.max_batch_size)
 
     def _pack_ragged(self):
-        """Sarathi-style token-budget packing for one unified tick:
+        """Sarathi-style token-budget packing for one ragged tick:
         every decoding slot contributes 1 token, then prefilling slots
         claim chunks round-robin from what's left of the budget (at
         least one prefill token per tick, so a decode-saturated budget
@@ -1882,7 +1462,7 @@ class InferenceEngine:
         return seen
 
     def _refresh_seen(self) -> None:
-        """Refresh ONLY the penalty 'seen' state for a unified tick —
+        """Refresh ONLY the penalty 'seen' state for a ragged tick —
         a ragged tick needs nothing else device-resident (the decode
         loop state is rebuilt lazily by the next pure-decode tick).
 
@@ -1967,22 +1547,7 @@ class InferenceEngine:
         for k, v in c.items():
             tot[k] = tot.get(k, 0.0) + v
 
-    def _account_prefill(self, slot: _Slot, start: int,
-                         n: int) -> None:
-        """One slot's prefill chunk (full-prompt or chunked, single-
-        device or pp): fold the closed-form cost into the tick sample
-        AND the slot's request receipt (ISSUE 13)."""
-        self._prefill_tokens_dispatched += n
-        if self.perf is None:
-            return
-        c = self.perf.model.chunk_cost(start, n)
-        self.perf.add("prefill", c, prefill_tokens=n)
-        if self.attrib is not None:
-            self.attrib.charge(slot.request, c, prefill_tokens=n,
-                               pages=len(slot.pages))
-
-    def _account_decode_batch(self, kind: str = "decode"
-                              ) -> Tuple[int, int]:
+    def _account_decode_batch(self) -> Tuple[int, int]:
         """One whole-batch decode dispatch: every active slot advances
         one token at its current context. Returns (rows, the context
         tokens their attention reads) for the dispatch span."""
@@ -2010,11 +1575,11 @@ class InferenceEngine:
                     self.attrib.charge(s.request, c, decode_tokens=1,
                                        pages=len(s.pages))
             if ndec and cm is not None:
-                self.perf.add(kind, tot, decode_tokens=ndec)
+                self.perf.add("decode", tot, decode_tokens=ndec)
             return ndec, kv
 
     def _ragged_step(self, touched: List[Request]) -> None:
-        """One unified tick: pack, dispatch the single ragged program,
+        """One ragged tick: pack, dispatch the single ragged program,
         fold the one readback into slot state. Host->device traffic
         per tick: ONE (5, T) token-meta upload + ONE (4, B) slot-meta
         upload (page tables and sampling params ride their caches)."""
@@ -2129,7 +1694,7 @@ class InferenceEngine:
                     self._lora_stacks, all_greedy)
         toks_host = self._read_tokens(toks, of=self.ticks)
         # fold ALL slots from the one readback before any device-state
-        # refresh (same ordering contract as _multi_decode)
+        # refresh
         with self._phase("fold", tokens=len(plan),
                          **self._fold_rider(toks_host, total, self.ticks)):
             for s, n, is_pref in plan:
@@ -2139,7 +1704,7 @@ class InferenceEngine:
                                                     s.prefill_pos)
                     s.prefill_pos += n
                     if s.prefill_pos >= len(s.request.prompt_tokens):
-                        self._finish_prefill_host(s, tok, touched)
+                        self._finish_prefill(s, tok, touched)
                 else:
                     s.position += 1
                     s.last_token = tok
@@ -2151,684 +1716,18 @@ class InferenceEngine:
         # _mark_seen_dirty.
         self._d_tokens = None
 
-    # -- pipeline-parallel programs (pp > 1) -------------------------------
-    # Each stage runs its slice of the layer stack as its own jit
-    # program on its own device group; activations hop between groups
-    # via device_put. Sampling (and the seen/penalty state) lives with
-    # the last stage, where logits exist.
-
     def _resolve_impl(self) -> str:
         """decode_impl with "auto" resolved: an accelerator runs the
         compiled Pallas kernels; the CPU backend, which cannot compile
         them, runs the dense gather (kernel logic is covered in
         interpret mode, kernel compilation for the TPU by
         tests/test_tpu_aot_compile.py). One resolver for the pool
-        layout, the pp and the non-pp programs so they can never
-        diverge."""
+        layout and both programs so they can never diverge."""
         impl = self.config.decode_impl
         if impl == "auto":
             impl = ("gather" if jax.devices()[0].platform == "cpu"
                     else "pallas")
         return impl
-
-    def _pp_decode_fn(self, i: int):
-        fns = getattr(self, "_pp_decode_cache", None)
-        if fns is None:
-            fns = self._pp_decode_cache = {}
-        if i in fns:
-            return fns[i]
-        cfg = self.model_cfg
-        impl = self._resolve_impl()
-        stage = self.stages[i]
-        first, last = i == 0, i == self.pp - 1
-        if not last:
-            def run(params, k_pages, v_pages, xin, positions,
-                    page_tables, active):
-                tokens = (xin if first
-                          else jnp.zeros(xin.shape[0], jnp.int32))
-                h, k_pages, v_pages = decode_step(
-                    cfg, params, tokens, positions, k_pages, v_pages,
-                    page_tables, active, impl=impl, mesh=stage.mesh,
-                    hidden=None if first else xin, emit="hidden")
-                return h, k_pages, v_pages
-
-            # donation audit (JL002, vs the unified (1, 2, 3)): this
-            # stage's pool slices (1, 2) donated. `seen` lives with
-            # the LAST stage only (donated there at argnum 4); the
-            # stage-boundary activation xin stays undonated — stage 0
-            # feeds the device-resident token loop state and later
-            # stages re-put the buffer across device groups.
-            fns[i] = jax.jit(run, donate_argnums=(1, 2))
-            self.compiles += 1
-            return fns[i]
-
-        def run_last(params, k_pages, v_pages, hidden, seen, positions,
-                     page_tables, active, key, temps, top_ps, top_ks,
-                     rep_pens, all_greedy):
-            tokens = jnp.zeros(hidden.shape[0], jnp.int32)
-            logits, k_pages, v_pages = decode_step(
-                cfg, params, tokens, positions, k_pages, v_pages,
-                page_tables, active, impl=impl, mesh=stage.mesh,
-                hidden=hidden, emit="logits")
-            if all_greedy:
-                new_tokens = _sample(logits, key, temps, top_ps,
-                                     all_greedy=True)
-                return new_tokens, k_pages, v_pages, seen
-            new_tokens = _sample(logits, key, temps, top_ps, top_ks,
-                                 rep_pens, seen, False)
-            b = hidden.shape[0]
-            seen = seen.at[jnp.arange(b), new_tokens].max(active)
-            return new_tokens, k_pages, v_pages, seen
-
-        fns[i] = jax.jit(run_last, donate_argnums=(1, 2, 4),
-                         static_argnums=(13,))
-        self.compiles += 1
-        return fns[i]
-
-    def _pp_prefill_fns(self, bucket: int):
-        cache = getattr(self, "_pp_prefill_cache", None)
-        if cache is None:
-            cache = self._pp_prefill_cache = {}
-        if bucket in cache:
-            return cache[bucket]
-        cfg = self.model_cfg
-        out = []
-        for i, stage in enumerate(self.stages):
-            first, last = i == 0, i == self.pp - 1
-            if not last:
-                def run(params, k_pages, v_pages, xin, true_lens,
-                        page_tables, _first=first):
-                    tokens = (xin if _first
-                              else jnp.zeros(xin.shape[:2], jnp.int32))
-                    h, k_pages, v_pages = prefill(
-                        cfg, params, tokens, true_lens, k_pages,
-                        v_pages, page_tables,
-                        hidden=None if _first else xin, emit="hidden")
-                    return h, k_pages, v_pages
-
-                out.append(jax.jit(run, donate_argnums=(1, 2)))  # jaxlint: disable=JL008 -- bounded: one program per pp stage, memoized in cache[bucket]
-                continue
-
-            def run_last(params, k_pages, v_pages, hidden, tokens,
-                         true_lens, page_tables, key, temps, top_ps,
-                         top_ks, rep_pens):
-                logits, k_pages, v_pages = prefill(
-                    cfg, params, tokens, true_lens, k_pages, v_pages,
-                    page_tables, hidden=hidden, emit="logits")
-                b, bucket_len = tokens.shape
-                valid = (jnp.arange(bucket_len)[None, :]
-                         < true_lens[:, None])
-                seen = jnp.zeros((b, cfg.vocab_size), bool)
-                seen = seen.at[jnp.arange(b)[:, None], tokens].max(valid)
-                first_tok = _sample(logits, key, temps, top_ps, top_ks,
-                                    rep_pens, seen)
-                return first_tok, k_pages, v_pages
-
-            out.append(jax.jit(run_last, donate_argnums=(1, 2)))  # jaxlint: disable=JL008 -- bounded: one program per pp stage, memoized in cache[bucket]
-        self.compiles += len(out)
-        cache[bucket] = out
-        return out
-
-    def _pp_chunk_fns(self, bucket: int, ctx_pages: int):
-        cache = getattr(self, "_pp_chunk_cache", None)
-        if cache is None:
-            cache = self._pp_chunk_cache = {}
-        if (bucket, ctx_pages) in cache:
-            return cache[(bucket, ctx_pages)]
-        cfg = self.model_cfg
-        from ...models.llama_infer import prefill_chunk
-        out = []
-        for i, stage in enumerate(self.stages):
-            first, last = i == 0, i == self.pp - 1
-            if not last:
-                def run(params, k_pages, v_pages, xin, start_pos,
-                        chunk_lens, page_tables, _first=first):
-                    tokens = (xin if _first
-                              else jnp.zeros(xin.shape[:2], jnp.int32))
-                    h, k_pages, v_pages = prefill_chunk(
-                        cfg, params, tokens, start_pos, chunk_lens,
-                        k_pages, v_pages, page_tables,
-                        ctx_pages=ctx_pages,
-                        hidden=None if _first else xin, emit="hidden")
-                    return h, k_pages, v_pages
-
-                out.append(jax.jit(run, donate_argnums=(1, 2)))  # jaxlint: disable=JL008 -- bounded: one program per pp stage, memoized in cache[(bucket, ctx_pages)]
-                continue
-
-            def run_last(params, k_pages, v_pages, hidden, tokens,
-                         start_pos, chunk_lens, page_tables, key, temps,
-                         top_ps, top_ks, rep_pens, seen):
-                logits, k_pages, v_pages = prefill_chunk(
-                    cfg, params, tokens, start_pos, chunk_lens,
-                    k_pages, v_pages, page_tables, ctx_pages=ctx_pages,
-                    hidden=hidden, emit="logits")
-                b, bucket_len = tokens.shape
-                valid = (jnp.arange(bucket_len)[None, :]
-                         < chunk_lens[:, None])
-                seen = seen.at[jnp.arange(b)[:, None], tokens].max(valid)
-                first_tok = _sample(logits, key, temps, top_ps, top_ks,
-                                    rep_pens, seen)
-                return first_tok, k_pages, v_pages
-
-            # donation audit (JL002): `seen` (13) undonated for the
-            # same reason as _chunk_fn's — fresh per-call upload, not
-            # returned, no output aliases its buffer.
-            out.append(jax.jit(run_last, donate_argnums=(1, 2)))  # jaxlint: disable=JL008 -- bounded: one program per pp stage, memoized in cache[(bucket, ctx_pages)]
-        self.compiles += len(out)
-        cache[(bucket, ctx_pages)] = out
-        return out
-
-    def _prep_full_prompt(self, req: Request):
-        """Host-side prep for the whole-prompt fast path, shared by the
-        pp and non-pp paths (they must stay in lockstep — a bucketing
-        or padding fix applied to one would silently diverge the
-        other's tokens)."""
-        n = len(req.prompt_tokens)
-        bucket = self._bucket_for(n)
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :n] = req.prompt_tokens
-        return tokens, bucket
-
-    def _prep_chunk(self, slot: "_Slot", req: Request):
-        """Host-side prep for one prefill chunk (tokens, prior 'seen'
-        for the penalty — prompt tokens count as seen, HF semantics),
-        shared by the pp and non-pp paths."""
-        n = len(req.prompt_tokens)
-        chunk = min(self.config.max_prefill_tokens, n - slot.prefill_pos)
-        bucket = self._bucket_for(chunk)
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :chunk] = req.prompt_tokens[
-            slot.prefill_pos:slot.prefill_pos + chunk]
-        V = self.model_cfg.vocab_size
-        prior = np.zeros((1, V), bool)
-        if slot.prefill_pos:
-            prior[0, np.asarray(
-                req.prompt_tokens[:slot.prefill_pos], np.int64) % V] = True
-        return tokens, chunk, bucket, prior
-
-    def _pp_prefill_one_chunk(self, slot: "_Slot",
-                              touched: List[Request]) -> None:  # jaxlint: disable=JL006 -- legacy pp path: O(pp) one-row meta uploads per chunk (stage fan-out), not per-tick slot state
-        req = slot.request
-        n = len(req.prompt_tokens)
-        p = req.params
-        self._key, sub = jax.random.split(self._key)
-        self.dispatches += self.pp
-        tables = [st.put(jnp.array(
-            self._page_tables[slot.index:slot.index + 1]))
-            for st in self.stages]
-        sl = self.stages[-1]
-        temps = sl.put(jnp.asarray([p.temperature], jnp.float32))
-        top_ps = sl.put(jnp.asarray([p.top_p], jnp.float32))
-        top_ks = sl.put(jnp.asarray([p.top_k], jnp.int32))
-        rep_pens = sl.put(jnp.asarray(
-            [p.repetition_penalty], jnp.float32))
-
-        if slot.prefill_pos == 0 and n <= self.config.max_prefill_tokens:
-            self.telemetry.on_prefill_chunk(req, n, 0)
-            self._account_prefill(slot, 0, n)
-            tokens, bucket = self._prep_full_prompt(req)
-            fns = self._pp_prefill_fns(bucket)
-            x = self.stages[0].put(jnp.asarray(tokens))
-            lens = [st.put(jnp.asarray([n], jnp.int32))
-                    for st in self.stages]
-            for i in range(self.pp - 1):
-                x, self.k_pages[i], self.v_pages[i] = fns[i](
-                    self.stage_params[i], self.k_pages[i],
-                    self.v_pages[i],
-                    x if i == 0 else self.stages[i].put(x),
-                    lens[i], tables[i])
-            i = self.pp - 1
-            first, self.k_pages[i], self.v_pages[i] = fns[i](
-                self.stage_params[i], self.k_pages[i], self.v_pages[i],
-                sl.put(x), sl.put(jnp.asarray(tokens)), lens[i],
-                tables[i], sub, temps, top_ps, top_ks, rep_pens)
-            self._finish_prefill(slot, int(self._read_tokens(first)[0]),
-                                 touched)
-            return
-
-        tokens, chunk, bucket, prior = self._prep_chunk(slot, req)
-        self.telemetry.on_prefill_chunk(req, chunk, slot.prefill_pos)
-        self._account_prefill(slot, slot.prefill_pos, chunk)
-        fns = self._pp_chunk_fns(bucket,
-                                 self._ctx_bucket(slot.prefill_pos))
-        start = [st.put(jnp.asarray([slot.prefill_pos], jnp.int32))
-                 for st in self.stages]
-        clens = [st.put(jnp.asarray([chunk], jnp.int32))
-                 for st in self.stages]
-        x = self.stages[0].put(jnp.asarray(tokens))
-        for i in range(self.pp - 1):
-            x, self.k_pages[i], self.v_pages[i] = fns[i](
-                self.stage_params[i], self.k_pages[i], self.v_pages[i],
-                x if i == 0 else self.stages[i].put(x),
-                start[i], clens[i], tables[i])
-        i = self.pp - 1
-        first, self.k_pages[i], self.v_pages[i] = fns[i](
-            self.stage_params[i], self.k_pages[i], self.v_pages[i],
-            sl.put(x), sl.put(jnp.asarray(tokens)), start[i], clens[i],
-            tables[i], sub, temps, top_ps, top_ks, rep_pens,
-            sl.put(jnp.asarray(prior)))
-        slot.prefill_pos += chunk
-        if slot.prefill_pos >= n:
-            self._finish_prefill(slot, int(self._read_tokens(first)[0]),
-                                 touched)
-
-    def _pp_decode(self, touched: List[Request]) -> None:
-        if self._d_tokens is None:
-            self._refresh_device_state()
-        # one whole-batch decode advance regardless of stage split /
-        # microbatching: the analytic cost is the same model forward
-        self._account_decode_batch("decode")
-        if self.pp_mb > 1:
-            return self._pp_decode_overlapped(touched)
-        self._key, sub = jax.random.split(self._key)
-        self.dispatches += self.pp
-        x = self._d_tokens
-        for i in range(self.pp - 1):
-            x, self.k_pages[i], self.v_pages[i] = self._pp_decode_fn(i)(
-                self.stage_params[i], self.k_pages[i], self.v_pages[i],
-                x if i == 0 else self.stages[i].put(x),
-                self._d_positions[i], self._d_tables[i],
-                self._d_active[i])
-        i = self.pp - 1
-        sl = self.stages[i]
-        new_tokens, self.k_pages[i], self.v_pages[i], self._d_seen = \
-            self._pp_decode_fn(i)(
-                self.stage_params[i], self.k_pages[i], self.v_pages[i],
-                sl.put(x), self._d_seen, self._d_positions[i],
-                self._d_tables[i], self._d_active[i], sub,
-                self._d_temps, self._d_top_ps, self._d_top_ks,
-                self._d_rep_pens, self._all_greedy)
-        self._d_tokens = self.stages[0].put(new_tokens)
-        for j in range(self.pp):
-            self._d_positions[j] = (self._d_positions[j]
-                                    + self._d_active[j])
-        self._post_decode(self._read_tokens(new_tokens), touched)
-
-    def _pp_decode_overlapped(self, touched: List[Request]) -> None:
-        """Microbatched pp decode (VERDICT r4 weak #6): the decode batch
-        splits into pp_decode_microbatches contiguous slot slices, each
-        walked through the stage chain back-to-back. Dispatch is async
-        and the stage device groups are disjoint, so stage i executes
-        microbatch j while stage i+1 executes j-1 — the same-stage
-        ordering is enforced automatically by the donated KV pools
-        (microbatch j's stage-i call consumes the pool handle j-1's
-        call produced). The single host sync happens once at the end,
-        after every program is in flight."""
-        m = self.pp_mb
-        self._key, sub = jax.random.split(self._key)
-        self.dispatches += self.pp * m
-        subs = jax.random.split(sub, m)
-        outs = [None] * m
-        for j in range(m):
-            x = self._d_tokens[j]
-            for i in range(self.pp - 1):
-                x, self.k_pages[i], self.v_pages[i] =                     self._pp_decode_fn(i)(
-                        self.stage_params[i], self.k_pages[i],
-                        self.v_pages[i],
-                        x if i == 0 else self.stages[i].put(x),
-                        self._d_positions[i][j], self._d_tables[i][j],
-                        self._d_active[i][j])
-            i = self.pp - 1
-            sl = self.stages[i]
-            (outs[j], self.k_pages[i], self.v_pages[i],
-             self._d_seen[j]) = self._pp_decode_fn(i)(
-                self.stage_params[i], self.k_pages[i], self.v_pages[i],
-                sl.put(x), self._d_seen[j], self._d_positions[i][j],
-                self._d_tables[i][j], self._d_active[i][j], subs[j],
-                self._d_temps[j], self._d_top_ps[j],
-                self._d_top_ks[j], self._d_rep_pens[j],
-                self._all_greedy)
-            self._d_tokens[j] = self.stages[0].put(outs[j])
-        for i in range(self.pp):
-            for j in range(m):
-                self._d_positions[i][j] = (self._d_positions[i][j]
-                                           + self._d_active[i][j])
-        new_tokens = np.concatenate(
-            [self._read_tokens(o) for o in outs])
-        self._post_decode(new_tokens, touched)
-
-    # -- speculative decoding ----------------------------------------------
-    # Round invariant: canonical tokens [0..P) with target KV written
-    # for [0..P-1) and the newest token t_last = canonical[P-1] still
-    # KV-pending (exactly decode_step's input shape). One round:
-    #   1. draft program (1 dispatch): chunk-prefill the canonical
-    #      delta it hasn't seen, then scan k-2 decode steps -> proposes
-    #      d1..d_{k-1}
-    #   2. target verify (1 dispatch): chunk [t_last, d1..d_{k-1}]
-    #      with per-position logits -> greedy predictions at P..P+k-1
-    #   3. host: accept the longest matching prefix (n), emit n+1
-    #      tokens (accepted + the target's bonus), P += n+1
-    # Rejected candidates leave garbage KV at [P+n..P+k-1), but the
-    # next round's verify chunk starts at P+n and rewrites that span
-    # before attention can ever read it (context is bounded by start).
-
-    def _spec_draft_fn(self, delta_bucket: int, ctx_pages: int):
-        s = self._spec
-        fn = s["draft_fns"].get((delta_bucket, ctx_pages))
-        if fn is not None:
-            return fn
-        dcfg, k = s["cfg"], s["k"]
-        impl = self._resolve_impl()
-        from ...models.llama_infer import prefill_chunk
-
-        def run(params, dk, dv, delta_tokens, start, lens, tables,
-                active, limit):
-            logits, dk, dv = prefill_chunk(
-                dcfg, params, delta_tokens, start, lens, dk, dv,
-                tables, ctx_pages=ctx_pages)
-            d1 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            pos0 = (start + lens).astype(jnp.int32)
-
-            def body(carry, i):
-                dk, dv, tok, pos = carry
-                # never scatter past the slot's allocated pages: a
-                # zero page-table entry there is a REAL page that may
-                # belong to another request
-                lg, dk, dv = decode_step(
-                    dcfg, params, tok, pos, dk, dv, tables,
-                    active & (pos < limit), impl=impl)
-                nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-                return (dk, dv, nxt, pos + 1), nxt
-
-            (dk, dv, _, _), rest = jax.lax.scan(
-                body, (dk, dv, d1, pos0), jnp.arange(k - 2))
-            # (B, k-1) candidates d1..d_{k-1}
-            cands = jnp.concatenate(
-                [d1[:, None], jnp.transpose(rest)], axis=1)
-            return cands, dk, dv
-
-        fn = jax.jit(run, donate_argnums=(1, 2))
-        self.compiles += 1
-        s["draft_fns"][(delta_bucket, ctx_pages)] = fn
-        return fn
-
-    def _spec_sync_fn(self, bucket: int):
-        """Draft catch-up: chunk-prefill canonical tokens into the
-        draft pools with no drafting (used when regular-decode
-        fallback let the delta outgrow the round buffer)."""
-        s = self._spec
-        fn = s["draft_fns"].get(("sync", bucket))
-        if fn is not None:
-            return fn
-        dcfg = s["cfg"]
-        from ...models.llama_infer import prefill_chunk
-
-        def run(params, dk, dv, tokens, start, lens, tables):
-            _, dk, dv = prefill_chunk(
-                dcfg, params, tokens, start, lens, dk, dv, tables,
-                ctx_pages=-1, emit="hidden")
-            return dk, dv
-
-        fn = jax.jit(run, donate_argnums=(1, 2))
-        self.compiles += 1
-        s["draft_fns"][("sync", bucket)] = fn
-        return fn
-
-    def _spec_verify_fn(self, ctx_pages: int):
-        s = self._spec
-        fn = s["verify_fns"].get(ctx_pages)
-        if fn is not None:
-            return fn
-        cfg = self.model_cfg
-        from ...models.llama_infer import prefill_chunk
-
-        def run(params, k_pages, v_pages, tokens, start, lens, tables):
-            logits_all, k_pages, v_pages = prefill_chunk(
-                cfg, params, tokens, start, lens, k_pages, v_pages,
-                tables, ctx_pages=ctx_pages, emit="logits_all")
-            preds = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)
-            return preds, k_pages, v_pages
-
-        fn = jax.jit(run, donate_argnums=(1, 2))
-        self.compiles += 1
-        s["verify_fns"][ctx_pages] = fn
-        return fn
-
-    def _spec_prefill_draft(self, slot: "_Slot") -> None:
-        """Admission: give the draft the whole prompt's KV in one shot
-        (the draft is small; chunking it buys nothing)."""
-        s = self._spec
-        req = slot.request
-        n = len(req.prompt_tokens)
-        bucket = self._bucket_for(n)
-        fn = s["prefill_fns"].get(bucket)
-        if fn is None:
-            dcfg = s["cfg"]
-
-            def run(params, dk, dv, tokens, true_lens, tables):
-                h, dk, dv = prefill(
-                    dcfg, params, tokens, true_lens, dk, dv, tables,
-                    emit="hidden")
-                return dk, dv
-
-            fn = jax.jit(run, donate_argnums=(1, 2))
-            self.compiles += 1
-            s["prefill_fns"][bucket] = fn
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :n] = req.prompt_tokens
-        table = self._dev(jnp.array(
-            self._page_tables[slot.index:slot.index + 1]))
-        if self.perf is not None:
-            cm_d = s["cost_model"]
-            c = cm_d.chunk_cost(0, n)
-            self.perf.add("spec", c, weight_bytes=cm_d.weight_bytes)
-            if self.attrib is not None:
-                self.attrib.charge(req, c, pages=len(slot.pages))
-        self.dispatches += 1
-        s["dk"], s["dv"] = fn(
-            s["params"], s["dk"], s["dv"],
-            self._dev(jnp.asarray(tokens)),
-            self._dev(jnp.asarray([n], jnp.int32)), table)
-        s["draft_pos"][slot.index] = n
-
-    def _spec_ready(self) -> bool:
-        """Speculative rounds run only for an all-greedy decode batch
-        (temperature 0, no penalties — the acceptance rule is exact
-        token match). Computed from host-side slot state so the check
-        runs BEFORE any device-state refresh: back-to-back rounds must
-        not pay a re-upload."""
-        if self._spec is None:
-            return False
-        ready = [s for s in self.slots
-                 if s.request is not None and s.ready]
-        if not ready:
-            return False
-        return all(s.request.params.temperature <= 0.0
-                   and s.request.params.repetition_penalty == 1.0
-                   for s in ready)
-
-    def _spec_decode(self, touched: List[Request]) -> None:  # jaxlint: disable=JL006 -- each catch-up round uploads that round's fresh token deltas; nothing is reusable across rounds
-        s = self._spec
-        k = s["k"]
-        B = self.config.max_batch_size
-        active = [sl for sl in self.slots
-                  if sl.request is not None and sl.ready]
-        # canonical token list per slot
-        def canon(sl):
-            return sl.request.prompt_tokens + sl.request.output_tokens
-
-        tables = self._device_tables()
-        delta_bucket = k + 1
-
-        # 0. draft catch-up: regular-decode fallback steps (a mixed
-        # greedy/sampling batch) can let the canonical delta outgrow
-        # the round buffer — sync it down in bucket-sized chunks first
-        while True:
-            over = [sl for sl in active
-                    if len(canon(sl)) - int(s["draft_pos"][sl.index])
-                    > delta_bucket]
-            if not over:
-                break
-            ct = np.zeros((B, delta_bucket), np.int32)
-            cstart = np.zeros(B, np.int32)
-            clens = np.zeros(B, np.int32)
-            for sl in over:
-                seq = canon(sl)
-                dp = int(s["draft_pos"][sl.index])
-                # leave at least one delta token for the round itself
-                take = min(delta_bucket, len(seq) - dp - 1)
-                ct[sl.index, :take] = seq[dp:dp + take]
-                cstart[sl.index] = dp
-                clens[sl.index] = take
-                s["draft_pos"][sl.index] = dp + take
-            if self.perf is not None:
-                cm_d = s["cost_model"]
-                tot: Dict[str, float] = {}
-                for sl in over:
-                    c = cm_d.chunk_cost(
-                        int(cstart[sl.index]), int(clens[sl.index]))
-                    self._merge_cost(tot, c)
-                    if self.attrib is not None:
-                        self.attrib.charge(sl.request, c,
-                                           pages=len(sl.pages))
-                self.perf.add("spec", tot,
-                              weight_bytes=cm_d.weight_bytes)
-            self.dispatches += 1
-            s["dk"], s["dv"] = self._spec_sync_fn(delta_bucket)(
-                s["params"], s["dk"], s["dv"],
-                self._dev(jnp.asarray(ct)),
-                self._dev(jnp.asarray(cstart)),
-                self._dev(jnp.asarray(clens)), tables)
-
-        # 1. draft: delta-prefill + scan (one dispatch for the batch)
-        dt = np.zeros((B, delta_bucket), np.int32)
-        dstart = np.zeros(B, np.int32)
-        dlens = np.zeros(B, np.int32)
-        act = np.zeros(B, bool)
-        limit = np.zeros(B, np.int32)
-        page = self.allocator.page_size
-        for sl in active:
-            seq = canon(sl)
-            dp = int(s["draft_pos"][sl.index])
-            delta = seq[dp:]
-            assert 0 < len(delta) <= delta_bucket, (dp, len(seq))
-            dt[sl.index, :len(delta)] = delta
-            dstart[sl.index] = dp
-            dlens[sl.index] = len(delta)
-            act[sl.index] = True
-            limit[sl.index] = len(sl.pages) * page
-        ctx = self._ctx_bucket(max(len(canon(sl)) for sl in active) + k)
-        if self.perf is not None:
-            # draft round: delta chunk-prefill + k-2 scanned decode
-            # steps per slot, charged against the DRAFT model
-            cm_d = s["cost_model"]
-            tot = {}
-            for sl in active:
-                dp = int(dstart[sl.index])
-                dn = int(dlens[sl.index])
-                sc: Dict[str, float] = {}
-                self._merge_cost(sc, cm_d.chunk_cost(dp, dn))
-                for j in range(max(k - 2, 0)):
-                    self._merge_cost(sc,
-                                     cm_d.decode_cost(dp + dn + j + 1))
-                self._merge_cost(tot, sc)
-                if self.attrib is not None:
-                    self.attrib.charge(sl.request, sc,
-                                       pages=len(sl.pages))
-            # delta chunk-prefill + k-2 scanned decode steps = k-1
-            # draft forwards, each re-streaming the draft weights
-            self.perf.add("spec", tot, weight_bytes=cm_d.weight_bytes,
-                          weight_reads=max(k - 1, 1))
-        self.dispatches += 1
-        cands, s["dk"], s["dv"] = self._spec_draft_fn(
-            delta_bucket, ctx)(
-            s["params"], s["dk"], s["dv"],
-            self._dev(jnp.asarray(dt)),
-            self._dev(jnp.asarray(dstart)),
-            self._dev(jnp.asarray(dlens)), tables,
-            self._dev(jnp.asarray(act)),
-            self._dev(jnp.asarray(limit)))
-        cands = self._read_tokens(cands)     # (B, k-1)
-
-        # 2. target verify: chunk [t_last, d1..] per slot, lens clamped
-        # so no write can pass the slot's allocated pages / max_tokens
-        vt = np.zeros((B, k), np.int32)
-        vstart = np.zeros(B, np.int32)
-        vlens = np.zeros(B, np.int32)
-        for sl in active:
-            seq = canon(sl)
-            P = len(seq)
-            remaining = sl.request.params.max_tokens - len(
-                sl.request.output_tokens)
-            use = 1 + min(k - 1, max(remaining - 1, 0))
-            vt[sl.index, 0] = seq[-1]
-            vt[sl.index, 1:use] = cands[sl.index, :use - 1]
-            vstart[sl.index] = P - 1
-            vlens[sl.index] = use
-            # the max_tokens clamp above is only safe because _admit
-            # preallocates worst-case (prompt+max_tokens) pages; fail
-            # loudly if admission ever gets lazier, instead of letting
-            # verify scatter through page-table zero entries into
-            # another request's KV
-            assert P - 1 + use <= len(sl.pages) * page, (
-                "verify write past allocated pages", sl.index, P, use,
-                len(sl.pages), page)
-        if self.perf is not None:
-            # target verify: one chunk per slot with PER-POSITION
-            # logits (emit="logits_all"), so the head runs for every
-            # verified row, not just the last
-            cm = self.perf.model
-            tot = {}
-            for sl in active:
-                use = int(vlens[sl.index])
-                sc = dict(cm.chunk_cost(int(vstart[sl.index]), use))
-                sc["flops_gemm"] = (sc.get("flops_gemm", 0.0)
-                                    + (use - 1) * cm.head_flops)
-                self._merge_cost(tot, sc)
-                if self.attrib is not None:
-                    self.attrib.charge(sl.request, sc,
-                                       pages=len(sl.pages))
-            self.perf.add("spec", tot)
-        self.dispatches += 1
-        preds, self.k_pages, self.v_pages = self._spec_verify_fn(ctx)(
-            self.params, self.k_pages, self.v_pages,
-            self._dev(jnp.asarray(vt)),
-            self._dev(jnp.asarray(vstart)),
-            self._dev(jnp.asarray(vlens)), tables)
-        preds = self._read_tokens(preds)     # (B, k) greedy per position
-
-        # 3. host acceptance + bookkeeping
-        n_emit = 0
-        for sl in active:
-            i = sl.index
-            req_sl = sl.request
-            emit0 = n_emit
-            use = int(vlens[i])
-            P = int(vstart[i]) + 1
-            n_acc = 0
-            while (n_acc < use - 1
-                   and preds[i, n_acc] == vt[i, n_acc + 1]):
-                n_acc += 1
-            new_tokens = list(vt[i, 1:1 + n_acc]) + [preds[i, n_acc]]
-            s["rounds"] += 1
-            s["accepted"] += n_acc
-            # draft re-syncs from the pre-round canonical length: its
-            # in-flight drafts may be wrong past the accepted prefix
-            s["draft_pos"][i] = P
-            # position counts CACHED tokens (the pending newest token
-            # is excluded, matching the decode-loop invariant): t_last
-            # plus each accepted candidate gained KV this round
-            sl.position = P - 1
-            for tok in new_tokens:
-                s["emitted"] += 1
-                n_emit += 1
-                sl.position += 1
-                sl.last_token = int(tok)
-                self._append_token(sl, int(tok), touched)
-                if sl.request is None:       # finished mid-round
-                    break
-            if self.attrib is not None and n_emit > emit0:
-                # emitted-token attribution (the cost dicts above
-                # charged the compute; acceptance decides the tokens)
-                self.attrib.charge(req_sl,
-                                   decode_tokens=n_emit - emit0)
-        if self.perf is not None and n_emit:
-            self.perf.note_tokens(decode_tokens=n_emit)
-        # positions/actives changed: lazily invalidate so a fallback
-        # to the regular decode path refreshes, while back-to-back
-        # speculative rounds (which read host state only) skip the
-        # re-upload entirely
-        self._d_tokens = None
 
     def _ctx_bucket(self, start: int) -> int:
         """Smallest power-of-two page count covering `start` tokens."""
@@ -2837,12 +1736,6 @@ class InferenceEngine:
         while b < need:
             b *= 2
         return min(b, self.max_pages_per_seq) if need else 0
-
-    def _bucket_for(self, n: int) -> int:
-        for b in self.config.prefill_buckets:
-            if n <= b and b <= self.max_seq:
-                return b
-        return self.max_seq
 
     # -- KV memory hierarchy (ISSUE 10) -------------------------------------
     # Host-offload tier + preemption spill/restore. Every method here
@@ -3052,11 +1945,9 @@ class InferenceEngine:
         if self.config.kv_watermark_tokens is None:
             return
         page = self.allocator.page_size
-        k = max(int(self.config.decode_steps_per_call or 1), 1)
         # headroom past the host position: the next dispatch writes at
-        # s.position (min(k, rem) tokens for multi-step rounds), the
-        # pipelined successor one past that (the fold assert's +1
-        # slack), PLUS one more with async_readback on — the host
+        # s.position, the pipelined successor one past that (the fold
+        # assert's +1 slack), PLUS one more with async_readback on — the host
         # position is one tick stale at this check (the in-flight
         # tick's write is not folded yet), so growth must trigger a
         # tick early or the drain fold below trips its own assert
@@ -3074,7 +1965,7 @@ class InferenceEngine:
             rem = max(s.request.params.max_tokens
                       - len(s.request.output_tokens), 1)
             final = s.position + rem + 1
-            return min(s.position + min(k, rem) + slack, final), final
+            return min(s.position + 1 + slack, final), final
 
         def short(s):
             if s.request is None or not s.ready:
@@ -3829,15 +2720,6 @@ class InferenceEngine:
     def _register_loras_locked(self, mapping: Dict[str, Dict[str, tuple]],
                                scale: float) -> None:  # jaxlint: disable=JL006 -- registration-time stack upload (one per projection), not on the tick path
         self._refuse_call("lora")
-        if self.pp > 1:
-            raise NotImplementedError(
-                "multi-LoRA is not supported with pipeline-parallel "
-                "serving (pp>1); use tp-only meshes for LoRA")
-        if self._spec is not None:
-            raise NotImplementedError(
-                "multi-LoRA is not supported with speculative decoding "
-                "(the draft/verify programs run base weights; a greedy "
-                "adapter request would silently lose its adapter)")
         if self._explicit_tp:
             raise NotImplementedError(
                 "multi-LoRA is not supported on explicit-tp "
@@ -3961,15 +2843,12 @@ class InferenceEngine:
         return sum(1 for s in self.slots if s.request is not None)
 
     def step(self) -> List[Request]:
-        """One engine tick. Unified mode (default, pp == 1): any tick
-        with a prefilling slot runs ONE ragged dispatch that advances
-        every decoding slot by a token AND packs prefill chunks under
-        the token budget; pure-decode ticks keep the device-resident
-        decode loop (also one dispatch). Legacy mode
-        (unified_step=False, or pp > 1): at most one prefill chunk for
-        a single slot, then a separate whole-batch decode. Returns
-        requests that produced a token this step (check .finished /
-        .output_tokens). With async_readback (default), steady-state
+        """One engine tick: any tick with a prefilling slot runs ONE
+        ragged dispatch that advances every decoding slot by a token
+        AND packs prefill chunks under the token budget; pure-decode
+        ticks keep the device-resident decode loop (also one
+        dispatch). Returns requests that produced a token this step
+        (check .finished / .output_tokens). With async_readback (default), steady-state
         decode results lag ONE tick: a step may return [] while its
         tokens are still in flight — they surface on the next step's
         fold (every step still dispatches exactly once, so progress
@@ -4200,14 +3079,11 @@ class InferenceEngine:
             # (no-op unless kv_watermark_tokens is set)
             self._grow_slots(touched)
             span.set_metadata(admitted=self._admissions - admitted)
-            ragged = self.config.unified_step and self.pp == 1 and any(
-                s.request is not None and not s.ready
-                for s in self.slots)
+            ragged = any(s.request is not None and not s.ready
+                         for s in self.slots)
         if ragged:
             self._ragged_step(touched)
-            return
-        self._advance_prefill(touched)
-        if any(s.ready for s in self.slots):
+        elif any(s.ready for s in self.slots):
             self._decode(touched)
 
     def generate(self, prompts: List[List[int]],
@@ -4403,7 +3279,7 @@ class InferenceEngine:
     def _admit(self, touched: Optional[List[Request]] = None) -> None:
         """Claim slots + KV pages for waiting requests (prefix-cache
         match decides where their prefill starts); the prefill itself
-        advances chunk-by-chunk in _advance_prefill. Parked sequences
+        advances chunk-by-chunk in _ragged_step. Parked sequences
         (ISSUE 10) restore FIRST and block new admissions while any
         remain — they already hold host memory and arrived earlier, so
         a fresh request claiming the pages a parked one needs would
@@ -4487,80 +3363,8 @@ class InferenceEngine:
             self._mark_seen_dirty(slot.index)  # slot reuse: stale row
             self._samp_cache = None      # new request: stale params
 
-    def _advance_prefill(self, touched: List[Request]) -> None:
-        """Advance prefilling slots. While a decode batch is running,
-        ration to ONE chunk per step (the no-stall contract: decode
-        ticks keep flowing). With nothing decoding there is no cadence
-        to protect — drain every prefilling slot so a cold batch of
-        short prompts doesn't ramp one request per step."""
-        decoding = any(s.ready for s in self.slots)
-        B = len(self.slots)
-        for off in range(B):
-            slot = self.slots[(self._prefill_rr + off) % B]
-            if slot.request is not None and not slot.ready:
-                self._prefill_rr = (slot.index + 1) % B
-                self._prefill_one_chunk(slot, touched)
-                if decoding:
-                    return
-
-    def _prefill_one_chunk(self, slot: _Slot,
-                           touched: List[Request]) -> None:
-        if self.pp > 1:
-            return self._pp_prefill_one_chunk(slot, touched)
-        req = slot.request
-        n = len(req.prompt_tokens)
-        p = req.params
-        self._key, sub = jax.random.split(self._key)
-        table = self._dev(jnp.array(
-            self._page_tables[slot.index:slot.index + 1]))
-        temps = self._dev(jnp.asarray([p.temperature], jnp.float32))
-        top_ps = self._dev(jnp.asarray([p.top_p], jnp.float32))
-        top_ks = self._dev(jnp.asarray([p.top_k], jnp.int32))
-        rep_pens = self._dev(jnp.asarray(
-            [p.repetition_penalty], jnp.float32))
-        seeds = self._dev(jnp.asarray([slot.seed], jnp.int32))
-
-        if slot.prefill_pos == 0 and n <= self.config.max_prefill_tokens:
-            # whole prompt in one go: the dense full-causal program
-            # (no pool gather — the common short-prompt fast path)
-            self.telemetry.on_prefill_chunk(req, n, 0)
-            self._account_prefill(slot, 0, n)
-            tokens, bucket = self._prep_full_prompt(req)
-            lidx = self._dev(jnp.asarray(
-                [self._lora_names.get(req.lora, 0)], jnp.int32))
-            self.dispatches += 1
-            first, self.k_pages, self.v_pages = self._prefill_fn(bucket)(
-                self.params, self.k_pages, self.v_pages,
-                self._dev(jnp.asarray(tokens)),
-                self._dev(jnp.asarray([n], jnp.int32)),
-                table, sub, temps, top_ps, top_ks, rep_pens, seeds,
-                self._lora_stacks, lidx)
-            self._finish_prefill(slot, int(self._read_tokens(first)[0]),
-                                 touched)
-            return
-
-        tokens, chunk, bucket, prior = self._prep_chunk(slot, req)
-        self.telemetry.on_prefill_chunk(req, chunk, slot.prefill_pos)
-        self._account_prefill(slot, slot.prefill_pos, chunk)
-        lidx = self._dev(jnp.asarray(
-            [self._lora_names.get(req.lora, 0)], jnp.int32))
-        self.dispatches += 1
-        first, self.k_pages, self.v_pages = self._chunk_fn(
-            bucket, self._ctx_bucket(slot.prefill_pos))(
-            self.params, self.k_pages, self.v_pages,
-            self._dev(jnp.asarray(tokens)),
-            self._dev(jnp.asarray([slot.prefill_pos], jnp.int32)),
-            self._dev(jnp.asarray([chunk], jnp.int32)),
-            table, sub, temps, top_ps, top_ks, rep_pens,
-            self._dev(jnp.asarray(prior)), seeds,
-            self._lora_stacks, lidx)
-        slot.prefill_pos += chunk
-        if slot.prefill_pos >= n:
-            self._finish_prefill(slot, int(self._read_tokens(first)[0]),
-                                 touched)
-
-    def _finish_prefill_host(self, slot: _Slot, first_token: int,
-                             touched: List[Request]) -> None:
+    def _finish_prefill(self, slot: _Slot, first_token: int,
+                        touched: List[Request]) -> None:
         """Host-side prompt-completion bookkeeping (no device-state
         refresh — the ragged step folds a whole tick first and lets the
         next decode tick refresh lazily)."""
@@ -4573,20 +3377,13 @@ class InferenceEngine:
         slot.position = n
         slot.ready = True
         slot.last_token = first_token
-        if self._spec is not None:
-            self._spec_prefill_draft(slot)
         self._append_token(slot, first_token, touched)
-
-    def _finish_prefill(self, slot: _Slot, first_token: int,
-                        touched: List[Request]) -> None:
-        self._finish_prefill_host(slot, first_token, touched)
-        self._refresh_device_state()
 
     def _refresh_device_state(self) -> None:
         with self._phase("refresh"):
             self._rebuild_device_state()
 
-    def _rebuild_device_state(self) -> None:  # jaxlint: disable=JL006 -- admit/finish-time refresh (not per tick); the pp branches fan slot state out per stage by construction
+    def _rebuild_device_state(self) -> None:
         """Re-upload slot state after an admit/finish. Between such
         events the decode loop is device-resident: tokens feed back from
         the previous step's output and positions advance on device, so a
@@ -4615,6 +3412,7 @@ class InferenceEngine:
         top_ks = np.zeros(B, np.int32)
         rep_pens = np.ones(B, np.float32)
         seeds = np.zeros(B, np.int32)
+        lora_idx = np.zeros(B, np.int32)
         seen = self._build_seen()
         for s in self.slots:
             if s.request is None or not s.ready:
@@ -4628,75 +3426,22 @@ class InferenceEngine:
             top_ks[s.index] = p.top_k
             rep_pens[s.index] = p.repetition_penalty
             seeds[s.index] = s.seed
-        if self.pp > 1 and self.pp_mb > 1:
-            # overlapped decode: per-MICROBATCH slices of every state
-            # array (contiguous slot ranges), per stage where needed
-            m = self.pp_mb
-            bs = B // m
-
-            def cut(a):
-                return [a[j * bs:(j + 1) * bs] for j in range(m)]
-
-            sl = self.stages[-1]
-            self._d_tokens = [self.stages[0].put(jnp.asarray(t))
-                              for t in cut(tokens)]
-            self._d_positions = [[st.put(jnp.asarray(p))
-                                  for p in cut(positions)]
-                                 for st in self.stages]
-            self._d_active = [[st.put(jnp.asarray(a))
-                               for a in cut(active)]
-                              for st in self.stages]
-            self._d_tables = [[st.put(jnp.asarray(t))
-                               for t in cut(self._page_tables)]
-                              for st in self.stages]
-            self._d_temps = [sl.put(jnp.asarray(t)) for t in cut(temps)]
-            self._d_top_ps = [sl.put(jnp.asarray(t))
-                              for t in cut(top_ps)]
-            self._d_top_ks = [sl.put(jnp.asarray(t))
-                              for t in cut(top_ks)]
-            self._d_rep_pens = [sl.put(jnp.asarray(t))
-                                for t in cut(rep_pens)]
-            self._d_seen = [sl.put(jnp.asarray(t)) for t in cut(seen)]
-            self._d_lora_idx = None
-        elif self.pp > 1:
-            # per-stage copies: tokens feed stage 0; positions/active/
-            # tables drive rope+scatter in EVERY stage; sampling state
-            # lives with the last stage (where logits exist)
-            sl = self.stages[-1]
-            self._d_tokens = self.stages[0].put(jnp.asarray(tokens))
-            self._d_positions = [st.put(jnp.asarray(positions))
-                                 for st in self.stages]
-            self._d_active = [st.put(jnp.asarray(active))
-                              for st in self.stages]
-            self._d_tables = [st.put(jnp.array(self._page_tables))
-                              for st in self.stages]
-            self._d_temps = sl.put(jnp.asarray(temps))
-            self._d_top_ps = sl.put(jnp.asarray(top_ps))
-            self._d_top_ks = sl.put(jnp.asarray(top_ks))
-            self._d_rep_pens = sl.put(jnp.asarray(rep_pens))
-            self._d_seen = sl.put(jnp.asarray(seen))
-            self._d_lora_idx = None
-        else:
-            # a family's rider rides behind the tokens in every
-            # readback, which is also the next decode tick's input: give
-            # the first one the same shape, so there is one program
-            self._d_tokens = self._dev(jnp.asarray(np.concatenate(
-                [tokens, np.zeros(self._rider_len, np.int32)])))
-            self._d_positions = self._dev(jnp.asarray(positions))
-            self._d_active = self._dev(jnp.asarray(active))
-            self._d_temps = self._dev(jnp.asarray(temps))
-            self._d_top_ps = self._dev(jnp.asarray(top_ps))
-            self._d_top_ks = self._dev(jnp.asarray(top_ks))
-            self._d_rep_pens = self._dev(jnp.asarray(rep_pens))
-            self._d_seeds = self._dev(jnp.asarray(seeds))
-            lora_idx = np.zeros(B, np.int32)
-            for s2 in self.slots:
-                if s2.request is not None and s2.ready:
-                    lora_idx[s2.index] = self._lora_names.get(
-                        s2.request.lora, 0)
-            self._d_lora_idx = self._dev(jnp.asarray(lora_idx))
-            self._d_seen = self._dev(jnp.asarray(seen))
-            self._d_tables = self._device_tables()
+            lora_idx[s.index] = self._lora_names.get(s.request.lora, 0)
+        # a family's rider rides behind the tokens in every
+        # readback, which is also the next decode tick's input: give
+        # the first one the same shape, so there is one program
+        self._d_tokens = self._dev(jnp.asarray(np.concatenate(
+            [tokens, np.zeros(self._rider_len, np.int32)])))
+        self._d_positions = self._dev(jnp.asarray(positions))
+        self._d_active = self._dev(jnp.asarray(active))
+        self._d_temps = self._dev(jnp.asarray(temps))
+        self._d_top_ps = self._dev(jnp.asarray(top_ps))
+        self._d_top_ks = self._dev(jnp.asarray(top_ks))
+        self._d_rep_pens = self._dev(jnp.asarray(rep_pens))
+        self._d_seeds = self._dev(jnp.asarray(seeds))
+        self._d_lora_idx = self._dev(jnp.asarray(lora_idx))
+        self._d_seen = self._dev(jnp.asarray(seen))
+        self._d_tables = self._device_tables()
         self._all_greedy = bool(np.all(temps <= 0.0)
                                 and np.all(rep_pens == 1.0))
         self._host_active = active
@@ -4723,7 +3468,7 @@ class InferenceEngine:
     def _drain(self, touched: List[Request]) -> None:
         """Pipeline barrier: fold the in-flight tick (if any) into
         host slot state NOW. Called before any structural event —
-        slot admission, prefill advancement, multi-step rounds, LoRA
+        slot admission, prefill advancement, LoRA
         registration, abort — so those paths observe exactly the host
         state a synchronous engine would. Refreshes device state when
         the fold retired a slot."""
@@ -4780,18 +3525,9 @@ class InferenceEngine:
         return finished
 
     def _decode(self, touched: List[Request]) -> None:
-        if self.pp > 1:
-            return self._pp_decode(touched)
-        if self._spec_ready():       # before the refresh: spec rounds
-            return self._spec_decode(touched)   # read host state only
         if self._d_tokens is None:
             self._refresh_device_state()
-        if self._multi_decode_fn is not None and self._multi_ok():
-            # multi-step rounds read host output_tokens for budgets:
-            # the lagged tick must land first
-            self._drain(touched)
-            return self._multi_decode(touched)
-        rows, kv = self._account_decode_batch("decode")
+        rows, kv = self._account_decode_batch()
         carried = self._tick_carried = {
             "tick": self.ticks, "kind": "decode",
             "T": self.config.max_batch_size,
@@ -4854,102 +3590,10 @@ class InferenceEngine:
             self._fold_inflight(rec, touched, lagged=False)
             self._refresh_device_state()
 
-    def _multi_ok(self) -> bool:
-        """Multi-step rounds only while nothing is prefilling or
-        waiting: the chunked-prefill no-stall contract needs one-step
-        decode cadence whenever a prompt is advancing."""
-        if self.waiting:
-            return False
-        return not any(s.request is not None and not s.ready
-                       for s in self.slots)
-
-    def _multi_decode(self, touched: List[Request]) -> None:
-        B = self.config.max_batch_size
-        budget = np.zeros(B, np.int32)
-        for s in self.slots:
-            if s.request is not None and s.ready:
-                budget[s.index] = (s.request.params.max_tokens
-                                   - len(s.request.output_tokens))
-        if self.perf is not None:
-            # K on-device rounds; rows past a slot's budget are masked
-            # (no KV write, token discarded) so only min(budget, K)
-            # tokens count as useful work per slot
-            cm = self.perf.model
-            K = int(self.config.decode_steps_per_call or 1)
-            tot: Dict[str, float] = {}
-            ndec = 0
-            for s in self.slots:
-                if s.request is None or not self._host_active[s.index]:
-                    continue
-                rows = min(int(budget[s.index]), K)
-                sc: Dict[str, float] = {}
-                for j in range(rows):
-                    self._merge_cost(sc,
-                                     cm.decode_cost(s.position + 1 + j))
-                self._merge_cost(tot, sc)
-                if self.attrib is not None and rows:
-                    self.attrib.charge(s.request, sc,
-                                       decode_tokens=rows,
-                                       pages=len(s.pages))
-                ndec += rows
-            if ndec:
-                # the scanned program runs K full forwards even for
-                # rows masked past their budget — the weights stream
-                # from HBM once per scan iteration, not per dispatch
-                self.perf.add("multi_decode", tot, decode_tokens=ndec,
-                              weight_reads=K)
-        self._key, sub = jax.random.split(self._key)
-        self.dispatches += 1
-        if self._kv_kind != "f32":
-            (toks, last, positions, self.k_pages, self.v_pages,
-             self.k_scales, self.v_scales, self._d_seen) = \
-                self._multi_decode_fn(
-                    self.params, self.k_pages, self.v_pages,
-                    self.k_scales, self.v_scales, self._d_seen,
-                    self._d_tokens, self._d_positions, self._d_tables,
-                    self._d_active, sub, self._d_temps,
-                    self._d_top_ps, self._d_top_ks, self._d_rep_pens,
-                    self._d_seeds, self._lora_stacks,
-                    self._d_lora_idx, self._dev(jnp.asarray(budget)),
-                    self._all_greedy)
-        else:
-            (toks, last, positions, self.k_pages, self.v_pages,
-             self._d_seen) = self._multi_decode_fn(
-                self.params, self.k_pages, self.v_pages, self._d_seen,
-                self._d_tokens, self._d_positions, self._d_tables,
-                self._d_active, sub, self._d_temps, self._d_top_ps,
-                self._d_top_ks, self._d_rep_pens, self._d_seeds,
-                self._lora_stacks, self._d_lora_idx,
-                self._dev(jnp.asarray(budget)), self._all_greedy)
-        self._d_tokens = last
-        self._d_positions = positions
-        toks_host = self._read_tokens(toks)   # [K, B] — ONE readback
-        # process ALL K rows BEFORE any device-state refresh: a
-        # mid-loop refresh would roll device positions back under
-        # tokens the host already emitted, desynchronizing KV from the
-        # output stream
-        dirty = False
-        with self._phase("fold", tokens=int(
-                np.minimum(budget, toks_host.shape[0]).sum())):
-            for i in range(toks_host.shape[0]):
-                for s in self.slots:
-                    if s.request is None \
-                            or not self._host_active[s.index]:
-                        continue
-                    if budget[s.index] <= i:
-                        continue
-                    s.position += 1
-                    tok = int(toks_host[i, s.index])
-                    s.last_token = tok
-                    self._append_token(s, tok, touched)
-                    if s.request is None:   # EOS/max_tokens this step
-                        dirty = True
-        if dirty:
-            self._refresh_device_state()
-
     def _post_decode(self, host_tokens: "np.ndarray",
                      touched: List[Request]) -> None:
-        """Shared decode tail: fold the one readback into slot state."""
+        """The synchronous decode tail: fold the one readback into slot
+        state."""
         dirty = False
         with self._phase("fold", tokens=int(
                 np.count_nonzero(self._host_active))):
@@ -5419,7 +4063,7 @@ class InferenceEngine:
                 "waiting": len(self.waiting),
                 "free_pages": self.allocator.free_pages,
                 "total_pages": self.allocator.num_usable,
-                # unified-step telemetry: ticks counts step() calls,
+                # ticks counts step() calls,
                 # dispatches counts the FORWARD programs the host
                 # launched — the ragged step's contract is a 1.0 ratio
                 # on work ticks. The device runs more per tick (the key
@@ -5484,13 +4128,7 @@ class InferenceEngine:
                     "profiles_started": self._profiles_started,
                     "blackbox_dumps": dict(self._blackbox_dumps)}
             alloc_stats = self.allocator.stats()
-            spec = self._spec
-            spec_snap = (None if spec is None or not spec["rounds"]
-                         else {"rounds": spec["rounds"],
-                               "accepted": spec["accepted"],
-                               "emitted": spec["emitted"],
-                               "k": spec["k"]})
-        out = {
+        return {
             **snap,
             # per-dispatch perf accounting (ISSUE 11): rolling
             # decode/prefill goodput, MFU/MBU vs the hardware
@@ -5518,29 +4156,10 @@ class InferenceEngine:
             # `compiled_programs` flat (bucket churn = recompile storm)
             "jit_cache": {
                 "ragged_buckets": len(self._ragged_fns),
-                "prefill_buckets": len(self._prefill_fns),
-                "chunk_buckets": len(self._chunk_fns),
                 "seen_row_buckets": len(self._seen_scatter_buckets),
                 "page_migration_fns": (len(self._page_gather_fns)
                                        + len(self._page_scatter_fns)),
-                "pp_decode_fns": len(
-                    getattr(self, "_pp_decode_cache", None) or {}),
-                "pp_prefill_buckets": len(
-                    getattr(self, "_pp_prefill_cache", None) or {}),
-                "pp_chunk_buckets": len(
-                    getattr(self, "_pp_chunk_cache", None) or {}),
-                "spec_fns": (0 if self._spec is None else sum(
-                    len(self._spec[k]) for k in
-                    ("draft_fns", "verify_fns", "prefill_fns"))),
                 "compiled_programs": self.compiles,
             },
             **alloc_stats,
         }
-        if spec_snap is not None:
-            s = spec_snap
-            out["spec_rounds"] = s["rounds"]
-            out["spec_acceptance_rate"] = round(
-                s["accepted"] / (s["rounds"] * (s["k"] - 1)), 3)
-            out["spec_tokens_per_round"] = round(
-                s["emitted"] / s["rounds"], 2)
-        return out

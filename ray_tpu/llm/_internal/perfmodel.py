@@ -165,8 +165,8 @@ class CostModel:
         # lanes in a kernel pool; the cache, not the model, pays), the
         # storage type, and a quantized pool's per-(row, head) scales
         if cache_row is None:
-            # no engine behind this model (a draft's, a test's): the
-            # dense family's row as the model writes it
+            # no engine behind this model (a test's): the dense
+            # family's row as the model writes it
             cache_row = family_of(cfg).cache_row(cfg, "gather",
                                                  self.kv_dtype)
         self.cache_row = cache_row
@@ -253,8 +253,7 @@ class CostModel:
 @dataclasses.dataclass
 class PerfSample:
     """One engine tick's analytic cost, recorded beside _tick_times.
-    kinds: ragged | decode | multi_decode | prefill | spec (one tick
-    may merge several legacy dispatches, e.g. prefill+decode)."""
+    kinds: ragged | decode, the tick's one forward program."""
     kind: str = ""
     decode_tokens: int = 0
     prefill_tokens: int = 0
@@ -315,42 +314,19 @@ class PerfAccountant:
         return self._pending
 
     def add(self, kind: str, cost: Dict[str, float],
-            decode_tokens: int = 0, prefill_tokens: int = 0,
-            weight_bytes: Optional[float] = None,
-            weight_reads: int = 1) -> None:
-        """Fold one dispatch's cost into the pending tick sample.
-        Weight-read bytes are per FORWARD PASS, not per dispatch: a
-        legacy prefill+decode tick reads the weights twice (two add
-        calls), and a multi-step/speculative dispatch whose scanned
-        body runs K forwards streams them K times — callers pass
-        weight_reads=K there, or MBU understates the weight term Kx.
-        weight_bytes overrides the default full-model read — the
-        speculative path charges draft dispatches the DRAFT model's
-        weights, not the target's."""
+            decode_tokens: int = 0, prefill_tokens: int = 0) -> None:
+        """Fold the tick's forward dispatch into the pending sample:
+        its cost, and one read of the model's weights."""
         p = self._pend()
-        if not p.kind:
-            p.kind = kind
-        elif not p.kind.endswith(kind):
-            p.kind = f"{p.kind}+{kind}"
+        p.kind = kind
         p.dispatches += 1
         p.decode_tokens += decode_tokens
         p.prefill_tokens += prefill_tokens
         p.flops_gemm += cost.get("flops_gemm", 0.0)
         p.flops_attn += cost.get("flops_attn", 0.0)
-        p.bytes_weights += max(int(weight_reads), 1) * (
-            self.model.weight_bytes
-            if weight_bytes is None else weight_bytes)
+        p.bytes_weights += self.model.weight_bytes
         p.bytes_kv_read += cost.get("bytes_kv_read", 0.0)
         p.bytes_kv_write += cost.get("bytes_kv_write", 0.0)
-
-    def note_tokens(self, decode_tokens: int = 0,
-                    prefill_tokens: int = 0) -> None:
-        """Attribute emitted tokens to the pending tick without a
-        dispatch (the speculative path knows its accepted count only
-        after the host acceptance loop)."""
-        p = self._pend()
-        p.decode_tokens += decode_tokens
-        p.prefill_tokens += prefill_tokens
 
     def abort_tick(self) -> None:
         """Drop the pending sample (mid-tick crash path): a tick that

@@ -360,6 +360,14 @@ class LLMServerImpl:
             "usage": self._usage(toks, req),
         }
 
+    @staticmethod
+    def _settled(text: str) -> str:
+        """`text` less what its next token may still change: a
+        multi-byte character whose bytes have not all arrived decodes
+        to U+FFFD at the tail, and a delta once sent cannot be taken
+        back. A stream holds that tail until it completes or ends."""
+        return text.rstrip("\ufffd")
+
     async def _generate_stream(self, prompt_tokens: List[int],
                                params: SamplingParams,
                                lora: "str | None" = None,
@@ -396,7 +404,7 @@ class LLMServerImpl:
             await asyncio.get_running_loop().run_in_executor(
                 None, self.engine.add_request, req)
             self._wake.set()
-            n_sent = len(self.tokenizer.decode(ctx)) if ctx else 0
+            n_sent = len(self._settled(self.tokenizer.decode(ctx)))
             n_toks = 0
             while True:
                 _, finished, reason = await asyncio.wait_for(q.get(),
@@ -404,13 +412,15 @@ class LLMServerImpl:
                 # decode incrementally: whole-prefix decode keeps
                 # multi-byte tokenizations correct
                 text = self.tokenizer.decode(ctx + req.output_tokens)
+                if not finished:
+                    text = self._settled(text)
                 delta, n_sent = text[n_sent:], len(text)
                 new = list(req.output_tokens[n_toks:])
                 n_toks = len(req.output_tokens)
                 if not new and not delta and not finished:
-                    # multi-step decode enqueues one event per emitted
-                    # token of a dispatch; later events of the batch
-                    # carry nothing new — drop the empty events
+                    # a step that folds two ticks (a drain) touches a
+                    # request twice; the first event already read both
+                    # tokens, the second carries nothing new
                     continue
                 yield new, delta, finished, reason
                 if finished:

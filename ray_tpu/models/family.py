@@ -91,13 +91,6 @@ DEEPSEEK_REFUSES = {
             "exchange across chips",
     "mesh_shape": "the explicit-tp shard_map programs are the dense "
                   "family's (Megatron layout of wq/wk/wv/wo)",
-    "speculative": "draft-model speculation verifies through the dense "
-                   "family's chunk forward and a draft K/V pool",
-    "decode_steps_per_call": "the multi-step decode program feeds the "
-                             "dense family's decode_step",
-    "unified_step": "the legacy two-dispatch prefill programs are the "
-                    "dense family's; this family runs the unified "
-                    "ragged step only",
     "checkpoint": "no checkpoint loader for this family's tree yet",
     "session_shipping": "session and prefix export/import move K and V "
                         "pages; the latent pool is one pool",

@@ -1,11 +1,13 @@
 """Cache-aware Llama forward passes for inference.
 
 Net-new (reference inference = external vLLM; SURVEY.md §7 hard part #1).
-Two entry points, both designed to jit once and stay compiled:
+Two forwards, both designed to jit once per shape and stay compiled:
 
-- prefill: full-prompt forward that also emits every layer's K/V and
-  scatters them into the shared page pool (ops/paged_attention.py layout:
-  [n_layers, num_pages, page_size, n_kv_heads, pool_head_dim]).
+- ragged_forward: a flat ragged token batch (decode rows of one token,
+  prefill chunks of many) attending over the shared page pool
+  (ops/paged_attention.py layout: [n_layers, num_pages, page_size,
+  n_kv_heads, pool_head_dim]) plus the batch itself, every token's K/V
+  scattered into the pool.
 - decode_step: one token per active sequence, paged attention over the
   pool, new KV scattered in-place (donate the pools for true in-place
   HBM updates under jit).
@@ -21,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from ..ops.attention import attention as attention_op
 from ..ops.paged_attention import (gather_kv, gather_kv_quant,
                                    paged_attention_on_gathered,
                                    paged_decode_with_new_token, scatter_kv,
@@ -41,27 +42,13 @@ def _rope_single(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
         axis=-1).astype(x.dtype)
 
 
-def _rope_seq(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """x: (B, S, H, D); cos/sin: (S, D//2) (shared positions)."""
-    d = x.shape[-1]
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
-    cos = cos[None, :, None, :]
-    sin = sin[None, :, None, :]
-    xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    return jnp.concatenate(
-        [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin],
-        axis=-1).astype(x.dtype)
-
-
-
-
 # ---------------------------------------------------------------- layer body
 
 def _layer_body(cfg: LlamaConfig, dt, x, layer, lora_l, lora_idx,
                 lead_shape: tuple, rope_fn, attn_fn,
                 psum_axis: Optional[str] = None):
-    """ONE transformer layer, shared by every inference path (prefill,
-    chunked prefill, ragged step, decode) — the paths differ only in
+    """ONE transformer layer, shared by both forwards (ragged step,
+    decode) — they differ only in
     the leading activation shape, the rope application, and the
     attention call. Returns (x, (k, v)) with k/v rope'd, ready for the
     KV scatter.
@@ -163,155 +150,6 @@ def _tp_head_logits(last, lm_head, psum_axis, logits_psum=None):
     return logits_psum(part, psum_axis)
 
 
-# ------------------------------------------------------------------- prefill
-
-def prefill(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
-            true_lens: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-            page_tables: jax.Array, lora: Optional[dict] = None,
-            lora_idx: Optional[jax.Array] = None,
-            hidden: Optional[jax.Array] = None, emit: str = "logits"
-            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """tokens: (B, S) padded prompts; true_lens: (B); page_tables:
-    (B, max_pages). Returns (last_logits (B, V) f32, k_pages, v_pages).
-
-    Pipeline-parallel serving (engine pp>1) runs this per STAGE:
-    `params["layers"]` holds only the stage's slice of the stack (the
-    KV pools likewise), `hidden` carries the previous stage's (B, S, H)
-    activations in place of embedding (params then needs no "embed"),
-    and emit="hidden" returns the full activations for the next stage
-    instead of head logits (no "final_norm"/"lm_head" needed).
-    """
-    b, s = tokens.shape
-    dt = cfg.dtype
-    x = (params["embed"].astype(dt)[tokens] if hidden is None
-         else hidden.astype(dt))
-    cos, sin = rope_frequencies(cfg, jnp.arange(s))
-
-    impl = "xla" if cfg.attention_impl in ("auto", "ring") \
-        else cfg.attention_impl
-
-    def layer_fn(x, inp):
-        layer, lora_l = inp
-        return _layer_body(
-            cfg, dt, x, layer, lora_l, lora_idx, (b, s),
-            lambda t: _rope_seq(t, cos, sin),
-            lambda q, k, v: attention_op(q, k, v, causal=True,
-                                         impl=impl))
-
-    x, (ks, vs) = jax.lax.scan(
-        layer_fn, x, (params["layers"], lora_scan_xs(lora)))
-    # ks/vs: (L, B, S, KVH, D) -> token-major (B*S, L, KVH, D);
-    # L from the stack itself (a pp stage carries n_layers // pp)
-    n_l = ks.shape[0]
-    k_rows = jnp.transpose(ks, (1, 2, 0, 3, 4)).reshape(
-        b * s, n_l, cfg.n_kv_heads, cfg.head_dim)
-    v_rows = jnp.transpose(vs, (1, 2, 0, 3, 4)).reshape(
-        b * s, n_l, cfg.n_kv_heads, cfg.head_dim)
-    positions = jnp.tile(jnp.arange(s), b)
-    valid = positions < jnp.repeat(true_lens, s)
-    tables = jnp.repeat(page_tables, s, axis=0)
-    k_pages, v_pages = scatter_kv(k_pages, v_pages, k_rows, v_rows,
-                                  tables, positions, valid)
-
-    if emit == "hidden":
-        return x, k_pages, v_pages
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take_along_axis(
-        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = last.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
-    return logits, k_pages, v_pages
-
-
-def prefill_chunk(cfg: LlamaConfig, params: Dict[str, Any],
-                  tokens: jax.Array, start_pos: jax.Array,
-                  chunk_lens: jax.Array, k_pages: jax.Array,
-                  v_pages: jax.Array, page_tables: jax.Array,
-                  ctx_pages: int = -1, lora: Optional[dict] = None,
-                  lora_idx: Optional[jax.Array] = None,
-                  hidden: Optional[jax.Array] = None, emit: str = "logits"
-                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Prefill a CHUNK of each prompt against already-cached context.
-
-    Powers chunked prefill (long prompts advance max_prefill_tokens per
-    engine step so decode ticks never stall behind them) and prefix-
-    cache hits (the un-matched suffix prefills against the shared
-    pages). tokens: (B, C) padded chunk; start_pos: (B,) tokens already
-    in the pool; chunk_lens: (B,) valid tokens in this chunk.
-
-    Returns (last_logits (B, V) f32 — logits at the chunk's final valid
-    token, k_pages, v_pages) with the chunk's KV scattered in at
-    positions start_pos + [0, chunk_lens).
-
-    ctx_pages (static): gather/attend only the first ctx_pages table
-    entries — the caller buckets ceil(max(start_pos)/page_size) so the
-    dense context cost scales with the context that EXISTS, not
-    max_seq (-1 = the whole table). Scatter always uses the full table.
-    """
-    from ..ops.paged_attention import chunk_attention_on_gathered
-
-    b, c = tokens.shape
-    dt = cfg.dtype
-    x = (params["embed"].astype(dt)[tokens] if hidden is None
-         else hidden.astype(dt))
-    positions = start_pos[:, None] + jnp.arange(c)[None, :]      # (B, C)
-    cos, sin = rope_frequencies(cfg, positions.reshape(-1))
-    cos = cos.reshape(b, c, -1)
-    sin = sin.reshape(b, c, -1)
-
-    def rope(t):
-        d = t.shape[-1]
-        t1, t2 = t[..., : d // 2], t[..., d // 2:]
-        c_, s_ = cos[:, :, None, :], sin[:, :, None, :]
-        tf1, tf2 = t1.astype(jnp.float32), t2.astype(jnp.float32)
-        return jnp.concatenate(
-            [tf1 * c_ - tf2 * s_, tf2 * c_ + tf1 * s_],
-            axis=-1).astype(t.dtype)
-
-    # one dense gather of the cached context for all layers (layer-major)
-    ctx_tables = (page_tables if ctx_pages < 0
-                  else page_tables[:, :ctx_pages])
-    k_ctx_all, v_ctx_all = gather_kv(k_pages, v_pages, ctx_tables,
-                                     cfg.head_dim)
-
-    def layer_fn(x, inp):
-        layer, k_ctx, v_ctx, lora_l = inp
-        return _layer_body(
-            cfg, dt, x, layer, lora_l, lora_idx, (b, c), rope,
-            lambda q, k, v: chunk_attention_on_gathered(
-                q, k_ctx, v_ctx, k, v, start_pos, chunk_lens))
-
-    x, (ks, vs) = jax.lax.scan(
-        layer_fn, x,
-        (params["layers"], k_ctx_all, v_ctx_all, lora_scan_xs(lora)))
-    # ks/vs: (L, B, C, KVH, D) -> token-major (B*C, L, KVH, D);
-    # L from the stack itself (a pp stage carries n_layers // pp)
-    n_l = ks.shape[0]
-    k_rows = jnp.transpose(ks, (1, 2, 0, 3, 4)).reshape(
-        b * c, n_l, cfg.n_kv_heads, cfg.head_dim)
-    v_rows = jnp.transpose(vs, (1, 2, 0, 3, 4)).reshape(
-        b * c, n_l, cfg.n_kv_heads, cfg.head_dim)
-    flat_pos = positions.reshape(-1)
-    valid = (jnp.arange(c)[None, :] < chunk_lens[:, None]).reshape(-1)
-    tables = jnp.repeat(page_tables, c, axis=0)
-    k_pages, v_pages = scatter_kv(k_pages, v_pages, k_rows, v_rows,
-                                  tables, flat_pos, valid)
-
-    if emit == "hidden":
-        return x, k_pages, v_pages
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if emit == "logits_all":
-        # per-position logits over the whole chunk — speculative
-        # decoding's verify step greedy-checks every candidate token
-        logits_all = (x.astype(jnp.float32)
-                      @ params["lm_head"].astype(jnp.float32))
-        return logits_all, k_pages, v_pages
-    last = jnp.take_along_axis(
-        x, jnp.maximum(chunk_lens - 1, 0)[:, None, None].astype(jnp.int32),
-        axis=1)[:, 0]
-    logits = last.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
-    return logits, k_pages, v_pages
-
-
 # ---------------------------------------------------------------------- lora
 
 def lora_delta(y, stack, idx):
@@ -371,8 +209,7 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
     tick consumes a FLAT token batch where each active slot contributes
     between 1 token (decoding) and C tokens (prefilling), packed by the
     engine's token-budget scheduler. Decode is the n_tokens == 1 case
-    of chunked prefill, so this replaces the per-tick pair of
-    prefill_chunk + decode_step dispatches with one program.
+    of chunked prefill, so one program serves both.
 
     tokens: (T,) flat ragged batch (slot segments contiguous, position
     order); slot_ids: (T,) owning slot; positions: (T,) absolute
@@ -544,7 +381,6 @@ def decode_step(cfg: LlamaConfig, params: Dict[str, Any],
                 impl: str = "gather", mesh=None,
                 lora: Optional[dict] = None,
                 lora_idx: Optional[jax.Array] = None,
-                hidden: Optional[jax.Array] = None, emit: str = "logits",
                 kv_kind: str = "f32",
                 k_scales: Optional[jax.Array] = None,
                 v_scales: Optional[jax.Array] = None,
@@ -586,8 +422,7 @@ def decode_step(cfg: LlamaConfig, params: Dict[str, Any],
     dt = cfg.dtype
     quantized = kv_kind != "f32"
     with jax.named_scope("embed"):
-        x = (params["embed"].astype(dt)[tokens] if hidden is None
-             else hidden.astype(dt))                # (B, H)
+        x = params["embed"].astype(dt)[tokens]       # (B, H)
         cos, sin = rope_frequencies(cfg, positions)  # (B, D/2)
 
     use_kernel = impl in ("pallas", "pallas_interpret")
@@ -673,10 +508,6 @@ def decode_step(cfg: LlamaConfig, params: Dict[str, Any],
     else:
         k_pages, v_pages = scatter_kv(k_pages, v_pages, k_rows, v_rows,
                                       page_tables, positions, active)
-    if emit == "hidden":
-        if quantized:
-            return x, k_pages, v_pages, k_scales, v_scales
-        return x, k_pages, v_pages
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         if psum_axis is not None:

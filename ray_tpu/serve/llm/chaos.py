@@ -17,7 +17,7 @@ test fake) with a seeded, SCHEDULED fault plan:
 Faults fire at exact per-method call indices (`at_call`, 0-based over
 MATCHING calls), `count` times — the same schedule replays the same
 failure sequence every run, which is what makes the chaos e2e suite
-and the `bench_llm --smoke` chaos gate assertable. The seeded RNG is
+assertable. The seeded RNG is
 for the optional randomized mode (`random_failures`), used to fuzz
 the failover plane without fixing a script.
 

@@ -255,8 +255,8 @@ class ReplicaSnapshot:
 
 @dataclasses.dataclass
 class RouterConfig:
-    # "affinity" is the real policy; "round_robin" exists for the
-    # bench A/B (bench_llm --fleet) and as the degenerate baseline
+    # "affinity" is the real policy; "round_robin" is the degenerate
+    # baseline it is compared with
     policy: str = "affinity"
     vnodes: int = 64
     prefix_depth: int = 256

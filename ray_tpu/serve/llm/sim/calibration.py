@@ -21,8 +21,8 @@ Model shape:
   (PR 10's page-gather + scatter, measured from offload-flagged
   ticks).
 
-The sim-vs-real A/B gate (tests/test_fleet_sim.py +
-bench_llm --smoke) replays a small real workload through both and
+The sim-vs-real A/B gate (tests/test_fleet_sim.py, slow-marked)
+replays a small real workload through both and
 pins the predicted TTFT/e2e within a tolerance band — the file
 cannot silently rot.
 """

@@ -3,8 +3,8 @@
 The question the Gemma-on-TPU serving study (PAPERS.md) asks of every
 deployment — how many replicas until the p99 is bought? — answered by
 sweeping the SAME trace over fleet sizes and emitting one JSON
-artifact per sweep. `bench_llm --smoke` runs a small sweep as its sim
-gate; operators point `python -m tools.simcal` at bigger ones.
+artifact per sweep. tests/test_fleet_sim.py runs small sweeps;
+operators point `python -m tools.simcal` at bigger ones.
 """
 
 from __future__ import annotations

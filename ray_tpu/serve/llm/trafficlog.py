@@ -15,9 +15,9 @@ format any later session can replay deterministically
 Privacy by construction: records NEVER contain prompt or completion
 text. The only content-derived field is the router's prefix-chain
 fingerprint (a hash-cons key); sampling params pass through a
-numeric allowlist (`sampling_brief`). The tier-1 suite and the
-bench_llm smoke gate both assert no prompt substring survives into
-capture bytes.
+numeric allowlist (`sampling_brief`). The tier-1 suite asserts no
+prompt substring survives into capture bytes
+(tests/test_trafficlog.py).
 
 Wire discipline mirrors `kv_transport.py`, transposed to text: every
 capture line is one segment `RTTC<version> <crc32:08x> <canonical

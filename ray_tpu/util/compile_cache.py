@@ -1,7 +1,7 @@
 """Where compiled programs are kept between processes.
 
 One rule, applied first by every entry that owns a chip (chip_smoke.py's
-phases, bench.py, bench_llm.py, LLMServerImpl, TrainStepBundle): the
+phases, bench.py, LLMServerImpl, TrainStepBundle): the
 persistent XLA compile cache lives where `JAX_COMPILATION_CACHE_DIR`
 says — then no directory is set in code, jax reads the variable itself —
 and otherwise at `<checkout>/.jax_cache`. The path is part of what makes a

@@ -517,7 +517,6 @@ def test_batch_routes_through_serve_app():
             model_id="mb", model_source="debug",
             engine_kwargs=dict(max_batch_size=4, page_size=8,
                                num_pages=96, seed=7,
-                               prefill_buckets=(16, 32),
                                metrics_model_id=tag)),
         min_replicas=1, max_replicas=1,
         admission=AdmissionConfig(max_concurrent=4, max_queue=8),

@@ -590,9 +590,6 @@ def test_engine_serves_the_family_and_counts_its_experts():
     ("kv_dtype", {"kv_dtype": "int8"}),
     ("enable_kv_offload", {"enable_kv_offload": True}),
     ("mesh_shape", {"mesh_shape": (1, 1)}),
-    ("speculative", {"speculative": {"draft_model": "debug"}}),
-    ("decode_steps_per_call", {"decode_steps_per_call": 4}),
-    ("unified_step", {"unified_step": False}),
     ("checkpoint", {"checkpoint": "/nowhere"}),
 ])
 def test_engine_refuses_what_the_family_does_not_compose_with(option,

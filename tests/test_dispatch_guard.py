@@ -26,8 +26,8 @@ from ray_tpu.util.jax_guard import GuardViolation, dispatch_guard
 def _engine(tp=1, **over):
     kw = dict(model=llama.config("debug", dtype=jnp.float32),
               max_batch_size=3, page_size=8, num_pages=64,
-              prefill_buckets=(16, 32, 64), max_prefill_tokens=16,
-              seed=9, unified_step=True)
+              max_prefill_tokens=16,
+              seed=9)
     if tp > 1:
         # explicit-tp pod slice (ISSUE 17) on the conftest's emulated
         # CPU devices: the shard_map'd collective-bearing tick must

@@ -51,7 +51,6 @@ from ray_tpu.util import tracing
 def make_engine(**over):
     cfg = llama.config("debug", dtype=jnp.float32)
     kw = dict(model=cfg, max_batch_size=4, page_size=8, num_pages=64,
-              prefill_buckets=(16, 32, 64),
               metrics_model_id=f"obs{uuid.uuid4().hex[:10]}")
     kw.update(over)
     return InferenceEngine(EngineConfig(**kw))
@@ -389,7 +388,6 @@ def test_replayed_request_id_cannot_collide():
         "model_id": "m", "model_source": "debug",
         "engine_kwargs": dict(
             max_batch_size=4, page_size=8, num_pages=64,
-            prefill_buckets=(16,),
             metrics_model_id=f"rid{uuid.uuid4().hex[:8]}")})
 
     async def main():

@@ -330,7 +330,7 @@ def test_sim_vs_real_calibration_band():
     through the simulator under the committed calibration, and pin
     the predicted mean e2e within CALIBRATION_BAND of measured.
     Slow-marked: the real half builds and runs an engine (~tens of
-    seconds); bench_llm --smoke carries the tier-1 twin."""
+    seconds)."""
     import time as _t
     from tools.simcal import build_engine, check_against
     from ray_tpu.llm._internal.engine import Request, SamplingParams

@@ -36,7 +36,7 @@ from ray_tpu.llm._internal.kv_offload import (HostKVTier, ParkedSequence,
 def _engine(**over):
     kw = dict(model=llama.config("debug", dtype=jnp.float32),
               max_batch_size=4, page_size=8, num_pages=64,
-              prefill_buckets=(16, 32, 64), max_prefill_tokens=16,
+              max_prefill_tokens=16,
               seed=9)
     kw.update(over)
     return InferenceEngine(EngineConfig(**kw))
@@ -464,12 +464,11 @@ def test_watermark_requires_offload():
 def test_growth_clamped_to_final_need_at_max_seq():
     """Growth's slack headroom must clamp to the request's true
     final need: a request sized exactly to max_seq_len, landing on a
-    page boundary with multi-step decode, must not demand a page
+    page boundary, must not demand a page
     past max_pages_per_seq (unclamped, the page-table row assignment
     crashes the pump — review finding)."""
     eng = _engine(max_seq_len=16, page_size=8, num_pages=32,
-                  max_batch_size=2, prefill_buckets=(8, 16),
-                  max_prefill_tokens=8, decode_steps_per_call=4,
+                  max_batch_size=2, max_prefill_tokens=8,
                   enable_kv_offload=True, kv_watermark_tokens=4)
     req = Request("edge", list(range(2, 10)),
                   SamplingParams(max_tokens=8))

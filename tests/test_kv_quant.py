@@ -323,9 +323,6 @@ def _mk(kind, **kw):
 def test_engine_rejects_quant_composition():
     with pytest.raises(ValueError):
         InferenceEngine(EngineConfig(model="debug", kv_dtype="int4"))
-    with pytest.raises(ValueError):
-        InferenceEngine(EngineConfig(model="debug", kv_dtype="int8",
-                                     unified_step=False))
 
 
 @pytest.mark.parametrize("kind", QUANT_KINDS)

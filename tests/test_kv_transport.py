@@ -51,7 +51,7 @@ from ray_tpu.serve.llm.router import ReplicaSnapshot, prefix_fingerprint
 # ---------------------------------------------------------------- helpers
 
 _ENGINE_KW = dict(max_batch_size=4, page_size=8, num_pages=128, seed=7,
-                  max_seq_len=1024, prefill_buckets=(16, 32, 64),
+                  max_seq_len=1024,
                   max_prefill_tokens=32, enable_kv_offload=True)
 
 

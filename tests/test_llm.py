@@ -18,8 +18,7 @@ from ray_tpu.llm import (ByteTokenizer, EngineConfig, InferenceEngine,
 
 def make_engine(**over):
     cfg = llama.config("debug", dtype=jnp.float32)
-    kw = dict(model=cfg, max_batch_size=4, page_size=8, num_pages=64,
-              prefill_buckets=(16, 32, 64))
+    kw = dict(model=cfg, max_batch_size=4, page_size=8, num_pages=64)
     kw.update(over)
     return InferenceEngine(EngineConfig(**kw))
 
@@ -258,8 +257,7 @@ def test_openai_app_http(ray_start):
 
     app = build_openai_app({"llm_configs": [LLMConfig(
         model_id="m0", model_source="debug",
-        engine_kwargs=dict(max_batch_size=4, page_size=8, num_pages=128,
-                           prefill_buckets=(32, 64)))]})
+        engine_kwargs=dict(max_batch_size=4, page_size=8, num_pages=128))]})
     try:
         serve.run(app, name="llm", route_prefix="/",
                   http_options=serve.HTTPOptions(port=8126),
@@ -307,8 +305,7 @@ def test_openai_streaming_sse(ray_start):
 
     app = build_openai_app({"llm_configs": [LLMConfig(
         model_id="m0", model_source="debug",
-        engine_kwargs=dict(max_batch_size=4, page_size=8, num_pages=128,
-                           prefill_buckets=(32, 64)))]})
+        engine_kwargs=dict(max_batch_size=4, page_size=8, num_pages=128))]})
     try:
         serve.run(app, name="llm", route_prefix="/",
                   http_options=serve.HTTPOptions(port=8127),
@@ -471,44 +468,22 @@ def test_data_llm_batch_lora_column(ray_start):
 
 
 def test_deployment_chips_follow_engine_mesh():
-    """accelerator_type replicas request tp*pp chips (the reference
+    """accelerator_type replicas request tp chips (the reference
     sizes vLLM worker placement the same way, vllm_models.py:123-139)."""
     from ray_tpu.llm import LLMConfig, build_llm_deployment
 
     app = build_llm_deployment(LLMConfig(
         model_id="m", accelerator_type="TPU-V5E",
-        engine_kwargs={"mesh": {"tp": 2, "pp": 2, "fsdp": 1}}))
+        engine_kwargs={"mesh": {"tp": 4, "fsdp": 1}}))
     assert app._deployment.config.ray_actor_options["num_tpus"] == 4
+    with pytest.raises(ValueError, match="explicit tp size"):
+        build_llm_deployment(LLMConfig(
+            model_id="m3", accelerator_type="TPU-V5E",
+            engine_kwargs={"mesh": {"tp": -1}}))
 
     app1 = build_llm_deployment(LLMConfig(
         model_id="m2", accelerator_type="TPU-V5E"))
     assert app1._deployment.config.ray_actor_options["num_tpus"] == 1
-
-
-def test_multi_step_decode_matches_single_step():
-    """decode_steps_per_call=K runs K decode iterations in ONE
-    dispatch (the per-dispatch-overhead amortizer): greedy and penalty decode are token-exact vs K=1, budgets
-    clamp exactly at max_tokens, and EOS mid-scan truncates."""
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(2, 250, 6 + i).tolist() for i in range(3)]
-
-    def gen(k, **sp):
-        eng = make_engine(decode_steps_per_call=k,
-                          enable_prefix_caching=False)
-        reqs = eng.generate([list(p) for p in prompts],
-                            SamplingParams(**sp))
-        return [r.output_tokens for r in reqs]
-
-    assert gen(4, max_tokens=13) == gen(1, max_tokens=13)
-    assert gen(4, max_tokens=13, repetition_penalty=1.3) == \
-        gen(1, max_tokens=13, repetition_penalty=1.3)
-    assert all(len(o) == 5 for o in gen(8, max_tokens=5))
-    # stop tokens truncate mid-scan
-    base = gen(1, max_tokens=20)
-    stop = base[0][4]
-    stopped = gen(4, max_tokens=20, stop_token_ids=[stop])
-    ref = gen(1, max_tokens=20, stop_token_ids=[stop])
-    assert stopped == ref
 
 
 def test_async_readback_token_exact_mixed_finishes():
@@ -621,7 +596,7 @@ def test_async_stream_order_preserved():
     srv = LLMServerImpl({
         "model_id": "m0", "model_source": "debug",
         "engine_kwargs": dict(max_batch_size=4, page_size=8,
-                              num_pages=128, prefill_buckets=(16, 32))})
+                              num_pages=128)})
     assert srv.engine._async            # pipeline on by default
 
     async def consume(prompt_text, max_tokens):
@@ -653,7 +628,7 @@ def test_async_stream_order_preserved():
     # so solo sync runs are the gold text)
     ref = InferenceEngine(EngineConfig(
         model="debug", max_batch_size=4, page_size=8, num_pages=128,
-        prefill_buckets=(16, 32), async_readback=False))
+        async_readback=False))
     for deltas, (text, n) in zip(
             (d1, d2), (("hello world", 7), ("quite different", 11))):
         out = ref.generate([srv.tokenizer.encode(text)],
@@ -662,21 +637,3 @@ def test_async_stream_order_preserved():
             out[0].output_tokens)
     tt = srv.engine.stats()["tick_times"]
     assert tt["lagged_ticks"] > 0       # streams rode the pipeline
-
-
-def test_multi_step_decode_composes_with_prefix_cache():
-    rng = np.random.default_rng(7)
-    shared = rng.integers(2, 250, 24).tolist()
-    prompts = [shared + [5], shared + [9, 11]]
-
-    def gen(k, prefix):
-        eng = make_engine(decode_steps_per_call=k, page_size=8,
-                          num_pages=96, enable_prefix_caching=prefix)
-        outs = []
-        for p in prompts:
-            outs.append(eng.generate(
-                [list(p)], SamplingParams(max_tokens=10)
-            )[0].output_tokens)
-        return outs
-
-    assert gen(4, True) == gen(1, False)

@@ -31,7 +31,7 @@ from ray_tpu.models import llama
 def make_engine(**over):
     cfg = llama.config("debug", dtype=jnp.float32)
     kw = dict(model=cfg, max_batch_size=3, page_size=8, num_pages=64,
-              prefill_buckets=(16, 32, 64), max_prefill_tokens=16,
+              max_prefill_tokens=16,
               seed=11,
               metrics_model_id=f"at{uuid.uuid4().hex[:10]}")
     kw.update(over)

@@ -30,7 +30,6 @@ REPO = Path(__file__).resolve().parent.parent
 def make_engine(**over):
     cfg = llama.config("debug", dtype=jnp.float32)
     kw = dict(model=cfg, max_batch_size=4, page_size=8, num_pages=64,
-              prefill_buckets=(16, 32, 64),
               metrics_model_id=f"t{uuid.uuid4().hex[:10]}")
     kw.update(over)
     return InferenceEngine(EngineConfig(**kw))
@@ -386,7 +385,7 @@ def test_observability_http_endpoints(ray_start):
     app = build_openai_app({"llm_configs": [LLMConfig(
         model_id="m0", model_source="debug",
         engine_kwargs=dict(max_batch_size=4, page_size=8,
-                           num_pages=128, prefill_buckets=(32, 64)))]})
+                           num_pages=128))]})
     try:
         serve.run(app, name="llm", route_prefix="/",
                   http_options=serve.HTTPOptions(port=8129),

@@ -74,7 +74,7 @@ def test_tp2_decode_step_logits_close():
     """Direct logits comparison (not just sampled tokens)."""
     import jax.numpy as jnp
     from ray_tpu.models import llama
-    from ray_tpu.models.llama_infer import decode_step, prefill
+    from ray_tpu.models.llama_infer import decode_step, ragged_forward
     from ray_tpu.parallel.sharding import shard_tree
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -87,12 +87,18 @@ def test_tp2_decode_step_logits_close():
     tables = jnp.asarray(
         np.arange(B * 4, dtype=np.int32).reshape(B, 4))
     rng = np.random.default_rng(0)
-    prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, 8)), jnp.int32)
+    # one segment a sequence, 8 and 6 tokens, nothing cached before
+    prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, 14), jnp.int32)
     lens = jnp.asarray([8, 6], jnp.int32)
 
     def run(params, k_pages, v_pages):
-        _, k_pages, v_pages = prefill(
-            cfg, params, prompt, lens, k_pages, v_pages, tables)
+        _, k_pages, v_pages = ragged_forward(
+            cfg, params, prompt,
+            jnp.asarray([0] * 8 + [1] * 6, jnp.int32),
+            jnp.asarray(list(range(8)) + list(range(6)), jnp.int32),
+            jnp.ones(14, bool), jnp.zeros(B, jnp.int32),
+            jnp.asarray([7, 13], jnp.int32), k_pages, v_pages, tables,
+            ctx_pages=0)
         return decode_step(
             cfg, params, jnp.asarray([11, 12], jnp.int32), lens,
             k_pages, v_pages, tables,

@@ -28,7 +28,7 @@ PROMPTS = [[1, 2, 3, 4, 5], [9, 8, 7], [100, 101]]
 # shared engine shape for the KV-movement gates: small pages so a
 # 12-token prompt spans several, forcing real gather/scatter traffic
 _COMMON = dict(max_batch_size=3, page_size=8, num_pages=64,
-               prefill_buckets=(16, 32, 64), max_prefill_tokens=16,
+               max_prefill_tokens=16,
                seed=9)
 
 
@@ -149,7 +149,7 @@ def test_tp2_quantized_collectives_generates():
     ops.quantized_collectives.quantized_psum — tokens may differ
     from the exact-f32 reduction, but the engine must run clean."""
     eng = _mk(mesh_shape=(1, 2), quantized_collectives=True,
-              unified_step=True, async_readback=True)
+              async_readback=True)
     reqs = eng.generate([[1, 2, 3, 4, 5]], SamplingParams(max_tokens=8))
     assert len(reqs[0].output_tokens) == 8
 
@@ -162,8 +162,7 @@ def test_tp2_steady_state_one_dispatch_per_tick(kv):
     shard_map'd collective-bearing tick keeps the single-dispatch
     discipline (donation + async readback) the single-chip engine
     has, for raw and quantized KV alike."""
-    eng = _mk(mesh_shape=(1, 2), kv_dtype=kv, unified_step=True,
-              async_readback=True)
+    eng = _mk(mesh_shape=(1, 2), kv_dtype=kv, async_readback=True)
     for i in range(3):
         eng.add_request(Request(request_id=f"r{i}",
                                 prompt_tokens=list(range(1, 13)),

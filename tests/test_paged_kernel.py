@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import llama
-from ray_tpu.models.llama_infer import decode_step, prefill
+from ray_tpu.models.llama_infer import decode_step, ragged_forward
 from ray_tpu.ops import paged_attention as pa
 
 
@@ -86,10 +86,17 @@ def test_decode_step_kernel_matches_gather():
     tables = jnp.asarray(
         np.arange(B * max_pages).reshape(B, max_pages), jnp.int32)
 
-    prompts = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, 8)), jnp.int32)
+    # fill the cache: one segment a sequence, 8 and 5 tokens, nothing
+    # cached before them
     true_lens = jnp.asarray([8, 5], jnp.int32)
-    _, k_pages, v_pages = prefill(
-        cfg, params, prompts, true_lens, k_pages, v_pages, tables)
+    _, k_pages, v_pages = ragged_forward(
+        cfg, params,
+        jnp.asarray(rng.integers(0, cfg.vocab_size, 13), jnp.int32),
+        jnp.asarray([0] * 8 + [1] * 5, jnp.int32),
+        jnp.asarray(list(range(8)) + list(range(5)), jnp.int32),
+        jnp.ones(13, bool), jnp.zeros(B, jnp.int32),
+        jnp.asarray([7, 12], jnp.int32), k_pages, v_pages, tables,
+        ctx_pages=0)
 
     tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (B,)), jnp.int32)
     active = jnp.asarray([True, True])

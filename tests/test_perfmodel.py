@@ -30,7 +30,7 @@ from ray_tpu.models import llama
 def _engine(**over):
     kw = dict(model=llama.config("debug", dtype=jnp.float32),
               max_batch_size=3, page_size=8, num_pages=64,
-              prefill_buckets=(16, 32, 64), max_prefill_tokens=16,
+              max_prefill_tokens=16,
               seed=9, enable_prefix_caching=False)
     kw.update(over)
     return InferenceEngine(EngineConfig(**kw))
@@ -268,8 +268,7 @@ def test_fleet_stats_carries_perf_brief():
                          "model_source": llama.config("debug"),
                          "engine_kwargs": dict(
                              max_batch_size=2, page_size=8,
-                             num_pages=64, prefill_buckets=(16, 32),
-                             metrics_replica_id="r0")})
+                             num_pages=64, metrics_replica_id="r0")})
     rng = np.random.default_rng(5)
     srv.engine.add_request(Request(
         "f0", rng.integers(2, 250, 12).tolist(),

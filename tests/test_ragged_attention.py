@@ -8,9 +8,10 @@ Gates:
 - the Pallas ragged kernel (interpret mode — the same program compiles
   on TPU) matches the oracle across GQA group widths, partial last
   pages, decode-only rows, all-padding rows, and start=0 slots;
-- the unified engine step is token-exact vs the legacy two-dispatch
-  path at temperature 0 (with and without repetition penalty), and
-  with decode_impl=pallas_interpret vs the gather path;
+- the engine's tokens are a greedy decode by the training forward
+  (llama.forward) over the full history, under every packing the
+  scheduler's options produce (with and without repetition penalty),
+  and decode_impl=pallas_interpret is token-exact vs the gather path;
 - a mixed prefill+decode workload costs exactly ONE compiled dispatch
   per engine tick, and a steady-state decode run holds the jit-cache
   compile counter flat (no bucket-churn recompile storms).
@@ -252,13 +253,14 @@ def test_ragged_op_ctx_bucketing_matches_full_table():
                                rtol=1e-6, atol=1e-7)
 
 
-# --------------------------------------------- unified vs legacy engines
+# ------------------------------- the engine against the training forward
 
-def _engine(unified, **over):
-    kw = dict(model=llama.config("debug", dtype=jnp.float32),
-              max_batch_size=3, page_size=8, num_pages=64,
-              prefill_buckets=(16, 32, 64), max_prefill_tokens=16,
-              seed=9, unified_step=unified)
+_CFG = llama.config("debug", dtype=jnp.float32)
+
+
+def _engine(**over):
+    kw = dict(model=_CFG, max_batch_size=3, page_size=8, num_pages=64,
+              max_prefill_tokens=16, seed=9)
     kw.update(over)
     return InferenceEngine(EngineConfig(**kw))
 
@@ -286,42 +288,108 @@ def _prompts():
     return [rng.integers(2, 250, n).tolist() for n in lens]
 
 
-def test_unified_step_token_exact_vs_legacy_greedy():
-    out_u = _drive(_engine(True), _prompts(), max_tokens=12)
-    out_l = _drive(_engine(False), _prompts(), max_tokens=12)
-    assert out_u == out_l
+_ORACLE_LEN = 64        # every history here is shorter; one program
 
 
-def test_unified_step_token_exact_with_repetition_penalty():
-    """Greedy + CTRL penalty: the seen bookkeeping of the ragged step
-    (chunk tokens before sampling, emitted samples after) must
-    reproduce the legacy prior/seen handling exactly."""
-    out_u = _drive(_engine(True), _prompts(), max_tokens=10,
-                   repetition_penalty=1.3)
-    out_l = _drive(_engine(False), _prompts(), max_tokens=10,
-                   repetition_penalty=1.3)
-    assert out_u == out_l
+@jax.jit
+def _training_logits(params, tokens):
+    return llama.forward(_CFG, params, tokens)
 
 
-def test_unified_step_composes_with_prefix_cache():
+def _assert_greedy_by_training_forward(eng, prompts, outs, n,
+                                       penalty=1.0, tol=1e-4):
+    """Every request's tokens are a greedy decode by `llama.forward`,
+    the training forward, over its full history: an oracle that shares
+    nothing with the serving path (no paged cache, no `_layer_body`, no
+    `_sample`, no packing). The causal forward over prompt + output
+    gives at position len(prompt) - 1 + i the logits that output i was
+    drawn from, so one call holds a whole request; the CTRL penalty is
+    applied to them here in numpy. A token that is not the argmax may
+    only be a near tie: its logit within `tol` of the best."""
+    for prompt, out in zip(prompts, outs):
+        assert len(out) == n, (len(prompt), out)
+        hist = list(prompt) + list(out)
+        toks = np.zeros((1, _ORACLE_LEN), np.int32)
+        toks[0, :len(hist)] = hist
+        logits = np.asarray(_training_logits(eng.params,
+                                             jnp.asarray(toks)))[0]
+        for i, tok in enumerate(out):
+            row = logits[len(prompt) - 1 + i].astype(np.float64)
+            if penalty != 1.0:
+                seen = np.unique(hist[:len(prompt) + i])
+                row[seen] = np.where(row[seen] > 0, row[seen] / penalty,
+                                     row[seen] * penalty)
+            best = int(np.argmax(row))
+            assert tok == best or row[best] - row[tok] <= tol, (
+                f"prompt of {len(prompt)}: output {i} is {tok} "
+                f"(logit {row[tok]:.6f}), the training forward says "
+                f"{best} ({row[best]:.6f})")
+
+
+@pytest.mark.parametrize("penalty", [1.0, 1.3],
+                         ids=["greedy", "penalty"])
+@pytest.mark.parametrize("async_readback", [True, False],
+                         ids=["async", "sync"])
+@pytest.mark.parametrize("budget", [0, 24])
+@pytest.mark.parametrize("chunk", [8, 16, 64])
+def test_engine_tokens_are_the_training_forwards(chunk, budget,
+                                                 async_readback, penalty):
+    """The staggered workload under every packing the scheduler's
+    options produce (chunk cap x token budget: prompts cut into 8s and
+    16s or taken whole, a budget that splits a chunk across ticks or
+    never binds) with the fold a tick late or not, greedy and with the
+    seen bookkeeping of the repetition penalty (chunk tokens before
+    sampling, emitted samples after)."""
+    eng = _engine(max_prefill_tokens=chunk,
+                  max_num_batched_tokens=budget,
+                  async_readback=async_readback)
+    prompts = _prompts()
+    outs = _drive(eng, prompts, max_tokens=12,
+                  repetition_penalty=penalty)
+    _assert_greedy_by_training_forward(eng, prompts, outs, 12, penalty)
+    assert eng.stats()["dispatches_per_step"] == 1.0
+
+
+@pytest.mark.parametrize("penalty", [1.0, 1.3],
+                         ids=["greedy", "penalty"])
+def test_cold_batch_tokens_are_the_training_forwards(penalty):
+    """All six prompts admitted at once on six slots: a tick packs
+    several prompts' chunks, and the batch turns to pure decode
+    together."""
+    eng = _engine(max_batch_size=6)
+    prompts = _prompts()
+    outs = [r.output_tokens for r in eng.generate(
+        [list(p) for p in prompts],
+        SamplingParams(max_tokens=10, repetition_penalty=penalty))]
+    _assert_greedy_by_training_forward(eng, prompts, outs, 10, penalty)
+
+
+def test_prefix_cache_tokens_are_the_training_forwards():
+    """A prompt that starts on another's cached pages prefills only its
+    tail, and decodes what the training forward does over the whole."""
     rng = np.random.default_rng(5)
     shared = rng.integers(2, 250, 24).tolist()
     prompts = [shared + [5], shared + [9, 11]]
-    eng = _engine(True, enable_prefix_caching=True)
+    eng = _engine(enable_prefix_caching=True)
     outs = [eng.generate([list(p)], SamplingParams(max_tokens=8)
                          )[0].output_tokens for p in prompts]
     assert eng.allocator.cache_hit_tokens >= 16
-    cold = _engine(False, enable_prefix_caching=False)
-    ref = [cold.generate([list(p)], SamplingParams(max_tokens=8)
-                         )[0].output_tokens for p in prompts]
-    assert outs == ref
+    _assert_greedy_by_training_forward(eng, prompts, outs, 8)
+
+
+def test_pipeline_parallel_mesh_is_refused():
+    from ray_tpu.parallel import MeshSpec
+    with pytest.raises(ValueError, match="pipeline-parallel serving "
+                                         "was removed"):
+        _engine(mesh=MeshSpec(dp=1, fsdp=1, sp=1, tp=1, pp=2))
+    with pytest.raises(TypeError):
+        EngineConfig(unified_step=True)
 
 
 def test_unified_step_one_dispatch_per_tick():
     """The tentpole contract: a mixed prefill+decode workload costs
-    exactly ONE compiled dispatch per engine tick (the legacy path
-    pays two on every mixed tick, more when draining a cold batch)."""
-    eng = _engine(True)
+    exactly ONE compiled dispatch per engine tick."""
+    eng = _engine()
     for i, p in enumerate(_prompts()):
         eng.add_request(Request(f"d{i}", list(p),
                                 SamplingParams(max_tokens=8)))
@@ -334,17 +402,6 @@ def test_unified_step_one_dispatch_per_tick():
     assert eng.dispatches - d0 == steps
     assert eng.stats()["dispatches_per_step"] == 1.0
 
-    legacy = _engine(False)
-    for i, p in enumerate(_prompts()):
-        legacy.add_request(Request(f"l{i}", list(p),
-                                   SamplingParams(max_tokens=8)))
-    l_steps = 0
-    l0 = legacy.dispatches
-    while legacy.has_work():
-        legacy.step()
-        l_steps += 1
-    assert legacy.dispatches - l0 > l_steps   # the two-dispatch tick
-
 
 def test_unified_step_pallas_interpret_token_exact():
     """decode_impl=pallas_interpret routes the ragged tick through the
@@ -353,9 +410,9 @@ def test_unified_step_pallas_interpret_token_exact():
     vs the dense gather engine on a mixed staggered workload."""
     rng = np.random.default_rng(3)
     prompts = [rng.integers(2, 250, n).tolist() for n in (40, 23, 1, 19)]
-    out_g = _drive(_engine(True, decode_impl="gather"),
+    out_g = _drive(_engine(decode_impl="gather"),
                    [list(p) for p in prompts], max_tokens=6)
-    out_p = _drive(_engine(True, decode_impl="pallas_interpret"),
+    out_p = _drive(_engine(decode_impl="pallas_interpret"),
                    [list(p) for p in prompts], max_tokens=6)
     assert out_g == out_p
 
@@ -365,7 +422,7 @@ def test_jit_cache_counter_stable_in_steady_state():
     cumulative compile counter; once a decode batch reaches steady
     state, further ticks must not build new programs (bucket churn
     would show up as a recompile storm here)."""
-    eng = _engine(True)
+    eng = _engine()
     rng = np.random.default_rng(7)
     for i in range(3):
         eng.add_request(Request(
@@ -389,9 +446,9 @@ def test_jit_cache_counter_stable_in_steady_state():
 def test_unified_step_multi_lora_mixed_batch():
     """Per-token adapter indices: a batch mixing base and a strong
     adapter through the ragged step reproduces each request's solo
-    output (same gate as the legacy multi-LoRA test)."""
+    output."""
     cfg = llama.config("debug", dtype=jnp.float32)
-    eng = _engine(True, model=cfg, max_batch_size=4)
+    eng = _engine(model=cfg, max_batch_size=4)
     L, h, q_dim, r = cfg.n_layers, cfg.hidden, cfg.q_dim, 4
     rng = np.random.default_rng(1)
     eng.register_lora("strong", {
@@ -417,29 +474,3 @@ def test_unified_step_multi_lora_mixed_batch():
         eng.step()
     assert r1.output_tokens == base
     assert r2.output_tokens == strong
-
-
-def test_bench_llm_smoke_mode():
-    """CI gate for the scheduler: bench_llm.py --smoke must finish
-    fast on CPU and report one dispatch per step for the mixed
-    workload."""
-    import json
-    import subprocess
-    import sys
-    import os
-    out = subprocess.run(
-        [sys.executable, "bench_llm.py", "--smoke"],
-        cwd=os.path.join(os.path.dirname(__file__), ".."),
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        capture_output=True, text=True, timeout=300)
-    assert out.returncode == 0, out.stderr[-2000:]
-    row = json.loads(out.stdout.strip().splitlines()[-1])
-    assert row["metric"] == "llm_mixed_smoke"
-    assert row["detail"]["unified"]["dispatches_per_step"] == 1.0
-    # ISSUE 2 gate: a unified tick through the Pallas ragged kernel
-    # (interpret mode) is token-exact vs the gather path at temp 0
-    assert row["detail"]["kernel_tick"]["token_exact"] is True
-    # greedy agreement across the two engines (1.0 in practice; the
-    # bound tolerates near-tie argmax flips, which are FP noise, not
-    # scheduler bugs — see bench_mixed's docstring)
-    assert row["detail"]["token_match"] >= 0.9

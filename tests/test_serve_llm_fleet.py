@@ -793,7 +793,7 @@ def _make_server(rid, tag):
         "model_id": "m", "model_source": "debug",
         "engine_kwargs": dict(
             max_batch_size=4, page_size=8, num_pages=128, seed=7,
-            prefill_buckets=(16, 32, 64), max_prefill_tokens=32,
+            max_prefill_tokens=32,
             metrics_model_id=tag, metrics_replica_id=rid),
     })
 
@@ -1286,7 +1286,6 @@ def test_e2e_guard_violation_bundle_fetchable_via_fleet(tmp_path):
         "model_id": "bbm", "model_source": "debug",
         "engine_kwargs": dict(
             max_batch_size=2, page_size=8, num_pages=64,
-            prefill_buckets=(16,),
             metrics_model_id=f"bb{uuid.uuid4().hex[:8]}",
             blackbox_dir=str(tmp_path / "bb"))})
     with pytest.raises(GuardViolation):
@@ -1339,7 +1338,6 @@ def test_fleet_app_local_testing_mode(fleet_servers):
             model_id="mf", model_source="debug",
             engine_kwargs=dict(max_batch_size=4, page_size=8,
                                num_pages=96, seed=7,
-                               prefill_buckets=(16, 32),
                                metrics_model_id=tag)),
         min_replicas=2, max_replicas=2,
         admission=AdmissionConfig(max_concurrent=4, max_queue=8)))
@@ -1615,7 +1613,7 @@ def test_e2e_anomaly_capture_fetchable_via_fleet():
                 # phase, so the injected long prompt admits (and its
                 # cold-bucket recompile fires) immediately
                 max_batch_size=4, page_size=8, num_pages=128, seed=7,
-                prefill_buckets=(16, 32, 64), max_prefill_tokens=16,
+                max_prefill_tokens=16,
                 metrics_model_id=tag, metrics_replica_id=rid,
                 # fast warmup + no capture rate limits: the test
                 # injects exactly one stall and wants its evidence
@@ -2268,7 +2266,7 @@ def test_serve_status_replica_details_llm(ray_start):
     app = build_llm_deployment(LLMConfig(
         model_id="m0", model_source="debug",
         engine_kwargs=dict(max_batch_size=4, page_size=8,
-                           num_pages=96, prefill_buckets=(16, 32)),
+                           num_pages=96),
         deployment_config=dict(health_check_period_s=0.5)))
     try:
         serve.run(app, name="llm-status", _start_http=False,

@@ -193,7 +193,7 @@ def test_sanitized_scope_resets_and_disarms():
 def _engine(**over):
     kw = dict(model=llama.config("debug", dtype=jnp.float32),
               max_batch_size=4, page_size=8, num_pages=160,
-              prefill_buckets=(16, 32, 64), seed=7, unified_step=True)
+              seed=7)
     kw.update(over)
     return InferenceEngine(EngineConfig(**kw))
 

@@ -714,8 +714,8 @@ def test_dispatch_guard_steady_state_with_recorder_armed():
     eng = InferenceEngine(EngineConfig(
         model=llama.config("debug", dtype=jnp.float32),
         max_batch_size=3, page_size=8, num_pages=64,
-        prefill_buckets=(16, 32, 64), max_prefill_tokens=16,
-        seed=9, unified_step=True))
+        max_prefill_tokens=16,
+        seed=9))
     rng = np.random.default_rng(5)
     for i in range(3):
         eng.add_request(Request(f"g{i}",

@@ -3,7 +3,7 @@
 Turns BENCH_CORE.md's prose perf trajectory into an ASSERTED one: a
 perf fingerprint — the analytic cost model's exact per-token numbers,
 the workload's dispatch mix and token totals, plus the (machine-
-dependent) achieved rates — is recorded by `bench_llm --smoke` and by
+dependent) achieved rates — is recorded by
 `run_canonical_workload()` here, and `compare()` checks a fresh run
 against the committed baseline (PERF_BASELINE.json at the repo root):
 
@@ -63,7 +63,7 @@ def run_canonical_workload() -> Dict[str, Any]:
     cfg = llama.config("debug")
     eng = InferenceEngine(EngineConfig(
         model=cfg, max_batch_size=4, page_size=8, num_pages=128,
-        prefill_buckets=(16, 32, 64), max_prefill_tokens=16, seed=7,
+        max_prefill_tokens=16, seed=7,
         enable_prefix_caching=False, perf_envelope="cpu"))
     rng = np.random.default_rng(11)
     reqs = [Request(f"pf{i}",
@@ -91,8 +91,7 @@ def run_canonical_workload() -> Dict[str, Any]:
 
 def make_fingerprint(stats: Dict[str, Any], model_cfg,
                      elapsed_s: float = 0.0) -> Dict[str, Any]:
-    """Build a fingerprint from engine stats() + the model config.
-    Shared by run_canonical_workload and the bench_llm perf gate."""
+    """Build a fingerprint from engine stats() + the model config."""
     from ray_tpu.llm._internal.perfmodel import CostModel
 
     perf = stats.get("perf") or {}

@@ -45,11 +45,6 @@ def main() -> int:
     if args.current:
         with open(args.current) as f:
             current = json.load(f)
-        # accept either a bare fingerprint or a full bench_llm --smoke
-        # JSON line (the fingerprint rides detail.perf.fingerprint)
-        if "exact" not in current:
-            current = (current.get("detail", {}).get("perf", {})
-                       .get("fingerprint", {}))
     else:
         current = perfdiff.run_canonical_workload()
 
