@@ -46,7 +46,7 @@ import numpy as np
 
 from ...models import llama
 from ...models.llama import LlamaConfig
-from ...models.family import family_of, resolve_config
+from ...models.family import family_of, resolve_config, store_params
 from ...util import thread_sanitizer
 from .kv_cache import PageAllocator
 from .telemetry import EngineTelemetry
@@ -607,12 +607,23 @@ class InferenceEngine:
                 self.mesh = None
         else:
             self.mesh = self._build_placement(ec.mesh, cfg)
+        # Weights are STORED as the tick's programs use them
+        # (family.storage_dtypes: the dense family's matrices and
+        # embedding in cfg.dtype, head and norms in float32), cast once
+        # where the tree enters the engine, on every branch below; a
+        # program that converts a weight reads and writes the stack
+        # again in every tick (15 of chat-open's 37 ms, PERF.md section
+        # 6, PR 30). self.params is the only copy the engine holds.
+        store = functools.partial(store_params, self.family, cfg)
         if params is None and ec.checkpoint:
             from ...models import checkpoint_io
-            # sharded load: each device's shard is a windowed mmap read
+            # sharded load: each device's shard is a windowed mmap read,
+            # cast on the host straight to the leaf's storage type
             params = checkpoint_io.load_llama_params(
                 cfg, ec.checkpoint,
-                mesh=(None if self._explicit_tp else self.mesh))
+                mesh=(None if self._explicit_tp else self.mesh),
+                dtype=self.family.storage_dtypes(cfg))
+        shardings = None
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             from ...parallel.sharding import tree_shardings
@@ -626,27 +637,35 @@ class InferenceEngine:
             else:
                 shardings = tree_shardings(
                     llama.param_logical_axes(cfg), self.mesh)
-            if params is None:
-                # born sharded: each chip draws only its own shard, so
-                # a model larger than one chip's HBM (8b in f32 is
-                # 29.9 GiB) never passes through a single device
-                self.params = jax.jit(
-                    functools.partial(llama.init_params, cfg),
-                    out_shardings=shardings)(
-                        jax.random.PRNGKey(ec.seed))
-            else:
-                self.params = jax.tree.map(jax.device_put, params,
-                                           shardings)
             self._kv_sharding = NamedSharding(
                 self.mesh,
                 PartitionSpec(None, None, None, self._tp_axis, None))
             self._repl = NamedSharding(self.mesh, PartitionSpec())
         else:
-            if params is None:
-                params = self.family.init_params(
-                    cfg, jax.random.PRNGKey(ec.seed))
-            self.params = jax.device_put(params)
             self._kv_sharding = self._repl = None
+        if params is None and shardings is not None:
+            # born sharded and in the storage types: each chip draws
+            # and casts only its own shard, so a model larger than one
+            # chip's HBM (8b is 15 GiB as stored, 29.9 in float32)
+            # never passes through a single device
+            self.params = jax.jit(
+                lambda key: store(llama.init_params(cfg, key)),
+                out_shardings=shardings)(jax.random.PRNGKey(ec.seed))
+        elif params is None:
+            # the seed's draw is eager and float32 (the parent's bits),
+            # rounded leaf by leaf and let go as it is
+            self.params = store(
+                self.family.init_params(cfg, jax.random.PRNGKey(ec.seed)),
+                release=True)
+        else:
+            self.params = store(params, shardings)
+        # what the arrays are, not what the configuration says: bytes
+        # in all (a tp engine's over its chips) and by type
+        by_dtype: Dict[str, int] = collections.Counter()
+        for leaf in jax.tree.leaves(self.params):
+            by_dtype[str(leaf.dtype)] += int(leaf.nbytes)
+        self._weights_held = {"bytes": sum(by_dtype.values()),
+                              "by_dtype": dict(by_dtype)}
         self.allocator = PageAllocator(
             ec.num_pages, ec.page_size,
             enable_prefix_caching=ec.enable_prefix_caching)
@@ -758,8 +777,9 @@ class InferenceEngine:
         # router's liveness input (fleet_stats last_tick_age_s) — a
         # replica whose pump wedged stops advancing this
         self.last_step_at: Optional[float] = None
-        # on-demand profiling: {"remaining", "dir", "cm"} while armed
-        # (POST /debug/profile → profile_next_ticks)
+        # on-demand profiling: {"remaining", "dir", "cm", "writer"}
+        # while armed, running or being written (POST /debug/profile →
+        # profile_next_ticks)
         self._profile: Optional[Dict[str, Any]] = None
         kv_shape = crow.pool_shape(cfg.n_layers, ec.num_pages,
                                    ec.page_size)
@@ -878,7 +898,8 @@ class InferenceEngine:
         if ec.enable_perf_accounting:
             self.perf = PerfAccountant(
                 CostModel(cfg, ec.page_size, kv_dtype=self._kv_kind,
-                          cache_row=crow),
+                          cache_row=crow,
+                          weight_bytes=self._weights_held["bytes"]),
                 detect_envelope(name=ec.perf_envelope),
                 n_chips=self.n_chips)
         # per-request cost attribution + tick-anomaly analyzer
@@ -3729,7 +3750,7 @@ class InferenceEngine:
                 import tempfile
                 log_dir = tempfile.mkdtemp(prefix="ray_tpu_llm_prof_")
             self._profile = {"remaining": int(ticks), "dir": log_dir,
-                             "cm": None}
+                             "cm": None, "writer": None}
         self._count_capture(self._profiles_armed, "manual")
         self.telemetry.recorder.record(
             "profile_armed", ticks=int(ticks), log_dir=log_dir)
@@ -3745,7 +3766,7 @@ class InferenceEngine:
         """Start the armed jax.profiler trace (called under the step
         lock at tick entry; no-op unless freshly armed)."""
         ps = self._profile
-        if ps is None or ps["cm"] is not None:
+        if ps is None or ps["cm"] is not None or ps["writer"] is not None:
             return
         from ...util import profiling
         cm = profiling.trace(ps["dir"])
@@ -3760,38 +3781,57 @@ class InferenceEngine:
         self._profiles_started += 1
 
     def _profile_tick_end(self) -> None:
+        """Count the captured tick; after the last, hand the capture to
+        a writer thread. stop_trace collects from the runtime and
+        writes the trace: seconds, and 14 s behind another export
+        (PERF.md section 6, PR 30), which under the step lock every
+        live stream waited out to have one slow tick explained. The
+        capture stays the engine's one capture until it is written."""
         ps = self._profile
-        if ps is None or ps["cm"] is None:
+        if ps is None or ps["cm"] is None or ps["writer"] is not None:
             return
         ps["remaining"] -= 1
         if ps["remaining"] > 0:
             return
-        self._profile = None
+        ps["writer"] = threading.Thread(
+            target=self._profile_stop, args=(ps, "profile_done"),
+            name="engine-profile-writer", daemon=True)
+        ps["writer"].start()
+
+    def _profile_stop(self, ps: Dict[str, Any], event: str) -> None:
         try:
             ps["cm"].__exit__(None, None, None)
         except Exception as e:
             self.telemetry.recorder.record("profile_error",
                                            error=repr(e))
-            return
-        self.telemetry.recorder.record("profile_done",
-                                       log_dir=ps["dir"])
+        else:
+            self.telemetry.recorder.record(event, log_dir=ps["dir"])
+        finally:
+            if self._profile is ps:
+                self._profile = None
+
+    def wait_for_profile(self, timeout: Optional[float] = None) -> bool:
+        """Block until a finished capture's trace is written (tests,
+        and a caller about to read the log dir). True when no capture
+        is being written any more."""
+        ps = self._profile
+        writer = ps["writer"] if ps is not None else None
+        if writer is not None:
+            writer.join(timeout)
+            return not writer.is_alive()
+        return True
 
     def _profile_abort(self) -> None:
         """Stop an in-flight capture after a mid-tick exception: flush
         whatever was recorded so far and disarm, so the next
-        profile_next_ticks() isn't wedged behind a phantom capture."""
+        profile_next_ticks() isn't wedged behind a phantom capture.
+        In line: the tick has failed already, nothing waits on it."""
         ps = self._profile
+        if ps is None or ps["writer"] is not None:
+            return
         self._profile = None
-        if ps is None or ps["cm"] is None:
-            return
-        try:
-            ps["cm"].__exit__(None, None, None)
-        except Exception as e:
-            self.telemetry.recorder.record("profile_error",
-                                           error=repr(e))
-            return
-        self.telemetry.recorder.record("profile_aborted",
-                                       log_dir=ps["dir"])
+        if ps["cm"] is not None:
+            self._profile_stop(ps, "profile_aborted")
 
     def _arm_profile_locked(self, ticks: int,
                             trigger: str = "tick_anomaly"
@@ -3806,7 +3846,7 @@ class InferenceEngine:
         import tempfile
         log_dir = tempfile.mkdtemp(prefix="ray_tpu_llm_prof_")
         self._profile = {"remaining": int(ticks), "dir": log_dir,
-                         "cm": None}
+                         "cm": None, "writer": None}
         self._count_capture(self._profiles_armed, trigger)
         self.telemetry.recorder.record(
             "profile_armed", ticks=int(ticks), log_dir=log_dir,
@@ -4091,6 +4131,8 @@ class InferenceEngine:
                 # scale sidecar)
                 "kv_dtype": self._kv_kind,
                 "cache_row": self.cache_row.describe(),
+                # the weights as stored, read off the arrays at load
+                "weights": self._weights_held,
                 "kv_page_bytes": self._kv_page_bytes,
                 "kv_device_bytes_used": (self.allocator.used_pages
                                          * self._kv_page_bytes),

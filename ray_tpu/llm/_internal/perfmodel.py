@@ -146,7 +146,7 @@ class CostModel:
     KV bytes come from the cache-row description either way."""
 
     def __init__(self, cfg, page_size: int, kv_dtype: str = "f32",
-                 cache_row=None):
+                 cache_row=None, weight_bytes: Optional[float] = None):
         from ...ops import kv_quant
         self.cfg = cfg
         self.page_size = int(page_size)
@@ -160,6 +160,11 @@ class CostModel:
             self.weight_bytes = float(own["weight_bytes"])
         else:
             self._llama_constants(cfg)
+        if weight_bytes is not None:
+            # an engine's: the bytes of the leaves it stores (serving
+            # keeps the dense family's matrices in cfg.dtype, not in
+            # cfg.param_dtype as the configuration alone would say)
+            self.weight_bytes = float(weight_bytes)
         # one token's rows across the stack, at the POOL's row: the
         # width the kernels stream (a head_dim of 64 is padded to 128
         # lanes in a kernel pool; the cache, not the model, pays), the

@@ -281,24 +281,30 @@ def load_llama_params(cfg: llama.LlamaConfig, ckpt_dir: str,
     (``param_logical_axes`` + the repo's sharding rules): each
     addressable device's shard is one windowed mmap read + dtype cast —
     a host never materializes more than its own shards.
+
+    ``dtype`` is one type for every leaf (default ``cfg.param_dtype``,
+    the trainer's masters) or a tree of types shaped like the params
+    (a serving engine's ``llama_infer.storage_dtypes``): each window is
+    cast on the host straight to its leaf's type, so a bfloat16
+    checkpoint read for serving is never widened on the way.
     """
-    dtype = dtype or cfg.param_dtype
     files = _FileSet(ckpt_dir)
     specs = _llama_leaf_specs(cfg, files)
     axes = llama.param_logical_axes(cfg)
+    is_leaf = lambda x: isinstance(x, _Leaf)
+    if not isinstance(dtype, dict):
+        one = np.dtype(dtype or cfg.param_dtype)
+        dtype = jax.tree.map(lambda _: one, specs, is_leaf=is_leaf)
 
-    def build(leaf: _Leaf, leaf_axes):
+    def build(leaf: _Leaf, leaf_axes, dt):
         if mesh is None:
-            return jnp.asarray(
-                np.asarray(leaf.read(None), dtype=np.dtype(dtype)))
+            return jnp.asarray(np.asarray(leaf.read(None), dtype=dt))
         sharding = named_sharding(mesh, *leaf_axes, rules=rules)
         return jax.make_array_from_callback(
             leaf.shape, sharding,
-            lambda idx: np.asarray(leaf.read(idx), dtype=np.dtype(dtype)))
+            lambda idx: np.asarray(leaf.read(idx), dtype=dt))
 
-    return jax.tree.map(
-        build, specs, axes,
-        is_leaf=lambda x: isinstance(x, _Leaf))
+    return jax.tree.map(build, specs, axes, dtype, is_leaf=is_leaf)
 
 
 def save_llama_checkpoint(cfg: llama.LlamaConfig, params: Dict[str, Any],

@@ -1,7 +1,8 @@
 """The seam between the serving engine and a model family.
 
 The engine asks a family, not a model file, for what it needs: the
-parameter tree and its initialiser from a seed, the ragged forward and
+parameter tree, its initialiser from a seed and the type each leaf is
+stored in (`store_params`), the ragged forward and
 the decode forward its two programs call, the cache row a token writes
 (`cache_row.CacheRow`), the attention kernel's host-side work count for
 the dispatch span, what a tick's readback carries besides tokens and
@@ -42,6 +43,10 @@ class ModelFamily:
     # (cfg, the riders summed since start-up, tokens the ticks carried)
     # -> what stats() shows of them; None for a family with no rider
     rider_summary: Optional[Callable[..., Dict[str, Any]]] = None
+    # (cfg) -> the type each leaf is STORED in for serving, a tree of
+    # dtypes shaped like init_params': the type the tick's programs use
+    # the leaf in, so that they convert no weight. None: kept as given
+    storage_dtypes: Optional[Callable[[Any], Dict[str, Any]]] = None
     # engine options this family does not compose with: name -> reason
     refuses: Dict[str, str] = dataclasses.field(default_factory=dict)
 
@@ -107,7 +112,8 @@ def _families() -> Dict[type, ModelFamily]:
             name="llama", init_params=llama.init_params,
             ragged_forward=llama_infer.ragged_forward,
             decode_step=llama_infer.decode_step,
-            cache_row=_llama_cache_row, work_counts=_llama_work_counts),
+            cache_row=_llama_cache_row, work_counts=_llama_work_counts,
+            storage_dtypes=llama_infer.storage_dtypes),
         deepseek_v3.DeepseekV3Config: ModelFamily(
             name="deepseek_v3", init_params=deepseek_v3.init_params,
             ragged_forward=deepseek_v3.ragged_forward,
@@ -118,6 +124,38 @@ def _families() -> Dict[type, ModelFamily]:
             rider_summary=deepseek_v3.routing_summary,
             refuses=DEEPSEEK_REFUSES),
     }
+
+
+def store_params(family: ModelFamily, cfg, params, shardings=None,
+                 release: bool = False):
+    """`params` as the engine keeps them: each leaf placed (under its
+    entry of `shardings`, else on the default device) and in the
+    family's storage type, cast ONCE here and not in every tick. Leaves
+    go one at a time, smallest first, so what is held while loading is
+    the tree that came in plus one leaf, never a second whole tree.
+    release: the caller hands its buffers over (the engine's own draw
+    from the seed): each wide leaf is deleted as its narrow copy exists.
+    A leaf already placed and in its type is kept as it is. Also runs
+    under jit (the born-sharded init), where it is the casts alone."""
+    import jax
+    leaves, treedef = jax.tree.flatten(params)
+    dtypes = (treedef.flatten_up_to(family.storage_dtypes(cfg))
+              if family.storage_dtypes is not None
+              else [leaf.dtype for leaf in leaves])
+    places = (treedef.flatten_up_to(shardings) if shardings is not None
+              else [None] * len(leaves))
+    for i in sorted(range(len(leaves)), key=lambda i: leaves[i].size):
+        wide = jax.device_put(leaves[i], places[i])  # jaxlint: disable=JL006 -- load time, once: a leaf is placed, cast and let go before the next so that no second whole tree is ever held
+        if wide.dtype == dtypes[i]:
+            leaves[i] = wide
+            continue
+        leaves[i] = wide.astype(dtypes[i])
+        if release:
+            leaves[i].block_until_ready()
+            wide.delete()
+        # a placed copy made here goes before the next leaf's is made
+        del wide
+    return treedef.unflatten(leaves)
 
 
 def family_of(cfg) -> ModelFamily:

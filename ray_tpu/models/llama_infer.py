@@ -27,7 +27,8 @@ from ..ops.paged_attention import (gather_kv, gather_kv_quant,
                                    paged_attention_on_gathered,
                                    paged_decode_with_new_token, scatter_kv,
                                    scatter_kv_quant)
-from .llama import LlamaConfig, rms_norm, rope_frequencies
+from .llama import (LlamaConfig, param_logical_axes, rms_norm,
+                    rope_frequencies)
 
 
 def _rope_single(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -40,6 +41,29 @@ def _rope_single(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return jnp.concatenate(
         [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin],
         axis=-1).astype(x.dtype)
+
+
+# ------------------------------------------------------------------ storage
+
+# the leaves both forwards multiply in cfg.dtype (`_proj`, the MLP, the
+# embedding's rows); lm_head and the norms are used in float32
+_COMPUTE_LEAVES = frozenset(
+    ("embed", "wq", "wk", "wv", "wo", "wg", "wi", "wd"))
+
+
+def storage_dtypes(cfg: LlamaConfig) -> Dict[str, Any]:
+    """The type a serving engine stores each leaf of init_params' tree
+    in: the type `ragged_forward` and `decode_step` use it in, so that
+    a tick's program converts no weight (models/family.store_params
+    casts once, checkpoint_io reads straight into these). The forwards
+    still take a tree in any type: a cast to the type a leaf has is
+    free. Training keeps cfg.param_dtype masters and shares none of
+    this."""
+    compute, f32 = jnp.dtype(cfg.dtype), jnp.dtype(jnp.float32)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: compute if path[-1].key in _COMPUTE_LEAVES
+        else f32,
+        param_logical_axes(cfg), is_leaf=lambda x: isinstance(x, tuple))
 
 
 # ---------------------------------------------------------------- layer body

@@ -448,6 +448,7 @@ def test_anomaly_profile_arm_does_not_wedge_manual_arming():
     assert eng._arm_profile_locked(2) is None      # already armed
     for _ in range(3):
         eng.step()
+    assert eng.wait_for_profile(60)                # written off-tick
     assert eng._profile is None                    # capture completed
     assert eng.profile_next_ticks(1)               # manual re-arm ok
     for _ in range(2):

@@ -314,6 +314,7 @@ def test_profile_next_ticks_writes_trace():
         eng.profile_next_ticks(1)        # one capture at a time
     eng.generate([rng.integers(2, 200, 8).tolist()],
                  SamplingParams(max_tokens=4))
+    assert eng.wait_for_profile(60)      # the trace is written off-tick
     kinds = [e["event"] for e in eng.telemetry.recorder.events()]
     if "profile_error" in kinds:
         pytest.skip("jax.profiler unavailable on this backend")
@@ -325,6 +326,49 @@ def test_profile_next_ticks_writes_trace():
     eng.profile_next_ticks(1, log_dir=d)
     eng.generate([rng.integers(2, 200, 8).tolist()],
                  SamplingParams(max_tokens=2))
+
+
+def test_tick_does_not_wait_for_the_capture_to_be_written(monkeypatch):
+    """A finished capture is stopped and written by a writer thread,
+    not under the step lock (ROADMAP A13; on the chip one stop_trace
+    held every live stream for 14 s, PERF.md section 6, PR 30): the
+    tick that ends the capture returns while the writer still works,
+    later ticks run beside it, the capture stays the engine's one
+    capture until it is written, and then arming works again."""
+    import contextlib
+    import threading
+    import time
+    from ray_tpu.util import profiling
+    release, stopping = threading.Event(), threading.Event()
+
+    @contextlib.contextmanager
+    def slow_trace(log_dir):
+        yield
+        stopping.set()
+        assert release.wait(60)
+
+    monkeypatch.setattr(profiling, "trace", slow_trace)
+    eng = make_engine()
+    eng.profile_next_ticks(1)
+    eng.add_request(Request("p", list(range(2, 10)),
+                            SamplingParams(max_tokens=6)))
+    t0 = time.monotonic()
+    eng.step()                           # the captured tick
+    assert stopping.wait(60)             # the writer is in stop_trace
+    while eng.has_work():                # ... and ticks go on beside it
+        eng.step()
+    assert time.monotonic() - t0 < 30
+    assert not eng.wait_for_profile(0.01)
+    with pytest.raises(RuntimeError, match="already"):
+        eng.profile_next_ticks(1)
+    assert eng._arm_profile_locked(1) is None
+    kinds = [e["event"] for e in eng.telemetry.recorder.events()]
+    assert "profile_done" not in kinds
+    release.set()
+    assert eng.wait_for_profile(60)
+    kinds = [e["event"] for e in eng.telemetry.recorder.events()]
+    assert "profile_done" in kinds and eng._profile is None
+    eng.profile_next_ticks(1)            # re-arming works again
 
 
 def test_profile_disarms_on_mid_tick_exception(monkeypatch):

@@ -361,6 +361,7 @@ def test_self_captures_count_arming_starting_and_dumping(tmp_path):
     assert eng._arm_profile_locked(2) is None     # armed already: no count
     while eng.has_work():
         eng.step()
+    assert eng.wait_for_profile(60)               # written off-tick
     assert eng._arm_profile_locked(1, trigger="tick_anomaly")
     eng.dump_blackbox("manual")
     eng.dump_blackbox("manual")

@@ -30,7 +30,8 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm._internal.engine import EngineConfig, InferenceEngine
 from ray_tpu.models import llama
-from ray_tpu.models.llama_infer import decode_step, ragged_forward
+from ray_tpu.models.llama_infer import (decode_step, ragged_forward,
+                                        storage_dtypes)
 from ray_tpu.models.training import TrainStepBundle, default_optimizer
 from ray_tpu.ops import paged_attention as pa
 from ray_tpu.ops.attention import flash_attention
@@ -54,9 +55,12 @@ def _on(dev):
 
 
 def _param_structs(cfg, S):
+    """The tree as a serving engine stores it (PR 30): matrices and
+    embedding in cfg.dtype, head and norms in float32."""
     shapes = jax.eval_shape(
         lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
-    return jax.tree.map(lambda a: S(a.shape, a.dtype), shapes)
+    return jax.tree.map(lambda a, dt: S(a.shape, dt), shapes,
+                        storage_dtypes(cfg))
 
 
 def _pools(cfg, S):
