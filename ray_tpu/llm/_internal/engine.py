@@ -48,7 +48,7 @@ from ...models import llama
 from ...models.llama import LlamaConfig
 from ...models.family import family_of, resolve_config, store_params
 from ...util import thread_sanitizer
-from .kv_cache import PageAllocator
+from .kv_cache import CacheManager
 from .telemetry import EngineTelemetry
 
 
@@ -58,6 +58,11 @@ class EngineConfig:
     max_batch_size: int = 8
     page_size: int = 16
     num_pages: int = 512
+    # pages of each cache group by its name, for a model whose family
+    # describes more than one (models/cache_row.CacheGroup: a window
+    # group wants fewer pages than a full one); a group not named here,
+    # and every group when this is None, gets `num_pages`
+    num_pages_by_group: Optional[Dict[str, int]] = None
     max_seq_len: Optional[int] = None    # default: model max_seq
     seed: int = 0
     # "auto": Pallas paged-decode kernel on TPU, dense gather elsewhere.
@@ -666,10 +671,7 @@ class InferenceEngine:
             by_dtype[str(leaf.dtype)] += int(leaf.nbytes)
         self._weights_held = {"bytes": sum(by_dtype.values()),
                               "by_dtype": dict(by_dtype)}
-        self.allocator = PageAllocator(
-            ec.num_pages, ec.page_size,
-            enable_prefix_caching=ec.enable_prefix_caching)
-        self.max_pages_per_seq = self.allocator.pages_needed(self.max_seq)
+        self.max_pages_per_seq = -(-self.max_seq // ec.page_size)
         # -- KV memory hierarchy (ISSUE 10) ----------------------------
         if ec.kv_watermark_tokens is not None \
                 and ec.kv_watermark_tokens < 1:
@@ -697,12 +699,30 @@ class InferenceEngine:
         # constructs must compile, and none falls back to gather.
         from ...ops import paged_attention as _pa
         impl = self._resolve_impl()
-        # THE description of what a token writes to the cache in a
-        # layer (kv_cache.CacheRow): the pools below, the per-page
-        # bytes, stats() and the cost model all read it
-        crow = self.cache_row = self.family.cache_row(
+        # THE description of what the model's layers write to the
+        # cache: its groups (models/cache_row.CacheGroup), each a row,
+        # the layers that write it and an optional window. The manager
+        # below (an allocator and a page table a group), the pools, the
+        # per-page bytes, stats() and the cost model all read them.
+        # `cache_row` stays the FIRST group's row for its readers.
+        groups = self.family.cache_groups(
             cfg, impl, self._kv_kind)
+        crow = self.cache_row = groups[0].row
         pool_dt = crow.dtype
+        by_group = ec.num_pages_by_group or {}
+        unknown = set(by_group) - {g.name for g in groups}
+        if unknown:
+            raise ValueError(
+                f"num_pages_by_group names {sorted(unknown)}; the "
+                f"{self.family.name} family's cache groups are "
+                f"{[g.name for g in groups]}")
+        self.cache = CacheManager(
+            groups, [by_group.get(g.name, ec.num_pages) for g in groups],
+            ec.page_size, ec.max_batch_size, self.max_pages_per_seq,
+            tick_tokens=self._tick_token_budget(),
+            enable_prefix_caching=ec.enable_prefix_caching)
+        # the first group's allocator: a one-group family's only one
+        self.allocator = self.cache.first
         # kv heads ONE shard's kernel sees, read off the sharding
         # the pools will carry
         local_kvh = (crow.heads if self._kv_sharding is None else
@@ -727,8 +747,7 @@ class InferenceEngine:
         # from this, never an assumed f32 itemsize (quantized pages
         # carry 1-byte values plus the per-(row, head) f32 scale
         # sidecar)
-        self._kv_page_bytes = (cfg.n_layers * crow.bytes_per_token_layer
-                               * ec.page_size)
+        self._kv_page_bytes = self.cache.page_bytes()
         # what a tick's readback carries behind the tokens (an expert
         # family's per-layer, per-held-expert assignment counts), and
         # the monotone totals stats()["moe"] folds it into
@@ -781,18 +800,30 @@ class InferenceEngine:
         # while armed, running or being written (POST /debug/profile →
         # profile_next_ticks)
         self._profile: Optional[Dict[str, Any]] = None
-        kv_shape = crow.pool_shape(cfg.n_layers, ec.num_pages,
+        kv_shape = crow.pool_shape(len(groups[0].layers),
+                                   self.cache.groups[0].num_pages,
                                    ec.page_size)
         # born sharded, like the weights: a pool zeroed on the
         # default device and then resharded passes WHOLE through
         # chip 0 (on the chip: +1.8 GB peak there at tp=4, 8b).
         # A latent cache is ONE pool: `k_pages` holds it and
-        # `v_pages` is None through every program's signature
-        self.k_pages = jnp.zeros(kv_shape, pool_dt,
-                                 device=self._kv_sharding)
-        self.v_pages = (jnp.zeros(kv_shape, pool_dt,
-                                  device=self._kv_sharding)
-                        if crow.pools == 2 else None)
+        # `v_pages` is None through every program's signature.
+        # A family with several cache groups gets its pools (and its
+        # page tables) as TUPLES, one entry a group; a one-group
+        # family's programs see the arrays they always saw
+        def pools(g):
+            shape = g.spec.row.pool_shape(len(g.spec.layers),
+                                          g.num_pages, ec.page_size)
+            return tuple(jnp.zeros(shape, g.spec.row.dtype,
+                                   device=self._kv_sharding)
+                         for _ in range(g.spec.row.pools))
+        made = [pools(g) for g in self.cache.groups]
+        if len(made) == 1:
+            self.k_pages = made[0][0]
+            self.v_pages = made[0][1] if crow.pools == 2 else None
+        else:
+            self.k_pages = tuple(m[0] for m in made)
+            self.v_pages = tuple(m[1] for m in made)
         self._key = self._dev(jax.random.PRNGKey(ec.seed + 1))
         # per-(token row, kv head) f32 scale pools beside the value
         # pools (None for f32 engines): [L, P, page, KVH], sharded on
@@ -821,9 +852,9 @@ class InferenceEngine:
         self._lora_stacks = None
         self.slots = [_Slot(i) for i in range(ec.max_batch_size)]
         self.waiting: List[Request] = []
-        # host-side mirrors of the device-side slot state
-        self._page_tables = np.zeros(
-            (ec.max_batch_size, self.max_pages_per_seq), np.int32)
+        # host-side mirrors of the device-side slot state: the cache
+        # manager's tables, the first group's under its old name
+        self._page_tables = self.cache.groups[0].tables
 
         # quantized engines thread the scale pools right after the
         # value pools (all donated: in-place HBM updates), shifting
@@ -898,7 +929,7 @@ class InferenceEngine:
         if ec.enable_perf_accounting:
             self.perf = PerfAccountant(
                 CostModel(cfg, ec.page_size, kv_dtype=self._kv_kind,
-                          cache_row=crow,
+                          cache_groups=groups,
                           weight_bytes=self._weights_held["bytes"]),
                 detect_envelope(name=ec.perf_envelope),
                 n_chips=self.n_chips)
@@ -1191,7 +1222,12 @@ class InferenceEngine:
             # asarray/device_put ALIAS a large enough numpy buffer
             # (jax 0.9) — the "device" tables then change under an
             # in-flight tick and the engine stops being deterministic
-            arr = self._dev(jnp.array(self._page_tables))
+            # (a family with several cache groups gets its groups'
+            # tables stacked [groups, B, pages]: ONE upload, and
+            # `tables[g]` reads a group's as a tuple's entry would)
+            tabs = self.cache.tables
+            arr = self._dev(jnp.array(
+                tabs[0] if len(tabs) == 1 else np.stack(tabs)))
             self._d_tables_cache = (self._tables_version, arr)
         return arr
 
@@ -1568,14 +1604,18 @@ class InferenceEngine:
         for k, v in c.items():
             tot[k] = tot.get(k, 0.0) + v
 
-    def _account_decode_batch(self) -> Tuple[int, int]:
+    def _account_decode_batch(self) -> Tuple[int, int, Dict[str, int]]:
         """One whole-batch decode dispatch: every active slot advances
         one token at its current context. Returns (rows, the context
-        tokens their attention reads) for the dispatch span."""
+        tokens their attention reads, what a family with window layers
+        adds) for the dispatch span."""
         with self._phase("account"):
             cm = self.perf.model if self.perf is not None else None
             tot: Dict[str, float] = {}
             ndec = kv = 0
+            # (cached tokens, 1) per row, for a family with window layers
+            counts = self.family.span_counts
+            segs = []
             # the host's positions lag the device's by the tick in
             # flight, if one is
             ahead = 1 if self._inflight is not None else 0
@@ -1585,6 +1625,8 @@ class InferenceEngine:
                     continue
                 ndec += 1
                 kv += s.position + 1 + ahead
+                if counts is not None:
+                    segs.append((s.position + ahead, 1))
                 if cm is None:
                     continue
                 c = cm.decode_cost(s.position + 1)
@@ -1597,7 +1639,9 @@ class InferenceEngine:
                                        pages=len(s.pages))
             if ndec and cm is not None:
                 self.perf.add("decode", tot, decode_tokens=ndec)
-            return ndec, kv
+            extra = (counts(self.model_cfg, segs, [True] * ndec)
+                     if counts is not None else {})
+            return ndec, kv, extra
 
     def _ragged_step(self, touched: List[Request]) -> None:
         """One ragged tick: pack, dispatch the single ragged program,
@@ -1664,6 +1708,10 @@ class InferenceEngine:
                 segs, T, self.config.page_size,
                 self.max_pages_per_seq if ctx else 0,
                 self._attn_geometry)
+            # what a family with window layers adds to the span
+            extra = (self.family.span_counts(
+                self.model_cfg, segs, [not p for _, _, p in plan])
+                if self.family.span_counts is not None else {})
         if self.perf is not None:
             with self._phase("account"):
                 cm = self.perf.model
@@ -1692,7 +1740,7 @@ class InferenceEngine:
             "decode_rows": ndec, "prefill_tokens": npre,
             "kv_tokens": kv, "attn_pairs": pairs,
             "decode_pairs": dec_pairs, "built": built,
-            "attn_items": items, "attn_kv_blocks": kv_blocks}
+            "attn_items": items, "attn_kv_blocks": kv_blocks, **extra}
         with self._phase("dispatch", **carried):
             self._key, sub = jax.random.split(self._key)
             self.dispatches += 1
@@ -2838,14 +2886,11 @@ class InferenceEngine:
         if worst_case > self.max_seq:
             raise ValueError(
                 f"prompt+max_tokens exceeds max_seq_len {self.max_seq}")
-        if self.allocator.pages_needed(worst_case) \
-                > self.allocator.num_usable:
+        why = self.cache.fits(worst_case)
+        if why is not None:
             # would never be admittable — reject now instead of stalling
             # the head of the queue forever
-            raise ValueError(
-                f"prompt+max_tokens needs "
-                f"{self.allocator.pages_needed(worst_case)} KV pages but "
-                f"the pool only has {self.allocator.num_usable}")
+            raise ValueError(f"prompt+max_tokens {why}")
         self.telemetry.on_queued(request)
         self.waiting.append(request)
 
@@ -2909,9 +2954,12 @@ class InferenceEngine:
             # ring's record, closed below, also counts this accounting
             # (the counters' publication walks the prefix cache)
             wall = time.perf_counter() - t0
-            with self._phase("account"):
+            with self._phase("account") as span:
                 if self.perf is not None:
                     self._commit_tick_costs(wall)
+                if self.cache.windowed:
+                    span.set_metadata(
+                        pages_returned=self._advance_windows())
                 self._publish_counters_locked()
             self._close_tick(t0, carry_s, compiles0)
             self.last_step_at = time.monotonic()
@@ -3010,6 +3058,21 @@ class InferenceEngine:
         self._tick_carried = None
         for k in ph:
             ph[k] = 0.0
+
+    def _advance_windows(self) -> int:
+        """The tick boundary of a family with a window group: every
+        live sequence hands back the pages behind its window and claims
+        those its next tick may write (`CacheManager.advance`), on the
+        host; the tables go up again with the next dispatch. Returns
+        the pages handed back. The earliest query still to come sits at
+        a prefilling slot's next chunk, or at a decoding slot's host
+        position (the tick in flight, if one is, computes that one)."""
+        returned, claimed = self.cache.advance(
+            (s.index, s.position if s.ready else s.prefill_pos)
+            for s in self.slots if s.request is not None)
+        if returned or claimed:
+            self._tables_version += 1
+        return returned
 
     def _admit_possible(self) -> bool:
         """Could _admit place the head-of-line request this tick?
@@ -3288,14 +3351,12 @@ class InferenceEngine:
         assuming best-case prefix sharing? (The same arithmetic as
         _admit_possible's head-of-line check.)"""
         req = self.waiting[0]
-        need = self.allocator.pages_needed(self._reserve_tokens(
-            len(req.prompt_tokens), req.params.max_tokens))
-        if self.allocator.enable_prefix_caching:
-            # best case: every full page of prompt[:-1] is cached
-            # (match_prefix caps one token short of the prompt)
-            need -= ((len(req.prompt_tokens) - 1)
-                     // self.allocator.page_size)
-        return need <= self.allocator.free_pages
+        # best case: every full page of prompt[:-1] is cached
+        # (match_prefix caps one token short of the prompt)
+        shared = ((len(req.prompt_tokens) - 1) // self.allocator.page_size
+                  if self.allocator.enable_prefix_caching else 0)
+        return self.cache.can_admit(self._reserve_tokens(
+            len(req.prompt_tokens), req.params.max_tokens), shared)
 
     def _admit(self, touched: Optional[List[Request]] = None) -> None:
         """Claim slots + KV pages for waiting requests (prefix-cache
@@ -3342,8 +3403,7 @@ class InferenceEngine:
                                            req.params.max_tokens)
             shared, matched = self.allocator.match_prefix(
                 req.prompt_tokens)
-            need = self.allocator.pages_needed(reserve) - len(shared)
-            if need > self.allocator.free_pages:
+            if not self.cache.can_admit(reserve, len(shared)):
                 self.allocator.free(shared)   # undo the match refs
                 break            # head-of-line admission control
             self.waiting.pop(0)
@@ -3369,17 +3429,15 @@ class InferenceEngine:
             slot.request = req
             self._alloc_ctx = slot.index
             try:
-                slot.pages = shared + self.allocator.allocate_pages(
-                    need)
+                # every group's reservation; the tables are the manager's
+                slot.pages = self.cache.admit(slot.index, reserve, shared,
+                                              matched)
             finally:
                 self._alloc_ctx = None
             slot.prefill_pos = matched
             slot.ready = False
             slot.position = 0
             slot.seed = self._request_seed(req)
-            table = np.zeros(self.max_pages_per_seq, np.int32)
-            table[:len(slot.pages)] = slot.pages
-            self._page_tables[slot.index] = table
             self._tables_version += 1
             self._mark_seen_dirty(slot.index)  # slot reuse: stale row
             self._samp_cache = None      # new request: stale params
@@ -3462,7 +3520,6 @@ class InferenceEngine:
         self._d_seeds = self._dev(jnp.asarray(seeds))
         self._d_lora_idx = self._dev(jnp.asarray(lora_idx))
         self._d_seen = self._dev(jnp.asarray(seen))
-        self._d_tables = self._device_tables()
         self._all_greedy = bool(np.all(temps <= 0.0)
                                 and np.all(rep_pens == 1.0))
         self._host_active = active
@@ -3548,14 +3605,14 @@ class InferenceEngine:
     def _decode(self, touched: List[Request]) -> None:
         if self._d_tokens is None:
             self._refresh_device_state()
-        rows, kv = self._account_decode_batch()
+        rows, kv, extra = self._account_decode_batch()
         carried = self._tick_carried = {
             "tick": self.ticks, "kind": "decode",
             "T": self.config.max_batch_size,
             "ctx": self.max_pages_per_seq, "rows": rows,
             "decode_rows": rows, "prefill_tokens": 0,
             "kv_tokens": kv, "attn_pairs": kv, "decode_pairs": kv,
-            "built": 0}
+            "built": 0, **extra}
         with self._phase("dispatch", **carried):
             self._key, sub = jax.random.split(self._key)
             self.dispatches += 1
@@ -3564,7 +3621,8 @@ class InferenceEngine:
                  self.v_scales, self._d_seen) = self._decode_fn(
                     self.params, self.k_pages, self.v_pages,
                     self.k_scales, self.v_scales, self._d_seen,
-                    self._d_tokens, self._d_positions, self._d_tables,
+                    self._d_tokens, self._d_positions,
+                    self._device_tables(),
                     self._d_active, sub, self._d_temps, self._d_top_ps,
                     self._d_top_ks, self._d_rep_pens, self._d_seeds,
                     self._lora_stacks, self._d_lora_idx,
@@ -3574,7 +3632,7 @@ class InferenceEngine:
                     self._decode_fn(
                         self.params, self.k_pages, self.v_pages,
                         self._d_seen, self._d_tokens,
-                        self._d_positions, self._d_tables,
+                        self._d_positions, self._device_tables(),
                         self._d_active, sub, self._d_temps,
                         self._d_top_ps, self._d_top_ks,
                         self._d_rep_pens, self._d_seeds,
@@ -3670,7 +3728,8 @@ class InferenceEngine:
         slot.position = 0
         slot.prefill_pos = 0
         slot.ready = False
-        self._page_tables[slot.index] = 0
+        # the table rows, and what the slot holds beyond the first group
+        self.cache.vacate(slot.index)
         self._tables_version += 1
         self._mark_seen_dirty(slot.index)
         self._samp_cache = None
@@ -4101,8 +4160,6 @@ class InferenceEngine:
             snap = {
                 "active": self.num_active(),
                 "waiting": len(self.waiting),
-                "free_pages": self.allocator.free_pages,
-                "total_pages": self.allocator.num_usable,
                 # ticks counts step() calls,
                 # dispatches counts the FORWARD programs the host
                 # launched — the ragged step's contract is a 1.0 ratio
@@ -4130,12 +4187,14 @@ class InferenceEngine:
                 # report f32 bytes — per-page bytes include the quant
                 # scale sidecar)
                 "kv_dtype": self._kv_kind,
+                # the FIRST cache group's row, kept for its readers:
+                # `cache_groups` below (the manager's stats) is the
+                # description since PR 31
                 "cache_row": self.cache_row.describe(),
                 # the weights as stored, read off the arrays at load
                 "weights": self._weights_held,
                 "kv_page_bytes": self._kv_page_bytes,
-                "kv_device_bytes_used": (self.allocator.used_pages
-                                         * self._kv_page_bytes),
+                "kv_device_bytes_used": self.cache.bytes_used(),
                 "preemptions": dict(self.preempt_counts),
                 # an expert family's routing, from what rode back with
                 # every tick's tokens (None for a dense model)
@@ -4169,7 +4228,10 @@ class InferenceEngine:
                     "profiles_armed": dict(self._profiles_armed),
                     "profiles_started": self._profiles_started,
                     "blackbox_dumps": dict(self._blackbox_dumps)}
-            alloc_stats = self.allocator.stats()
+            # free_pages, total_pages and occupancy of the FULLEST
+            # cache group (the one that gates admission), and
+            # `cache_groups`: each group's row, layers, window, pages
+            alloc_stats = self.cache.stats()
         return {
             **snap,
             # per-dispatch perf accounting (ISSUE 11): rolling

@@ -18,11 +18,14 @@ and are evicted LRU only under allocation pressure.
 from __future__ import annotations
 
 from collections import OrderedDict
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
-# what a token writes to the cache in a layer: the model's side of the
-# seam describes it, the engine's pools are built from it
-from ...models.cache_row import CacheRow  # noqa: F401
+import numpy as np
+
+# what a token writes to the cache in a layer, and which layers write
+# alike: the model's side of the seam describes it, the engine's pools
+# are built from it
+from ...models.cache_row import CacheGroup, CacheRow  # noqa: F401
 
 
 class PageAllocator:
@@ -226,4 +229,281 @@ class PageAllocator:
         }
         if self.host_tier is not None:
             out.update(self.host_tier.stats())
+        return out
+
+
+
+PREFIX_CACHE_OFF_FOR_WINDOWS = (
+    "off: a window group hands pages back as a sequence moves past "
+    "them, so a cached chain of the full group has no window pages to "
+    "resume on; a family with a window group matches nothing")
+
+
+class _GroupState:
+    """One cache group at run time: its allocator, its page table a
+    slot and, for a window group, what each slot holds of it."""
+
+    def __init__(self, spec: CacheGroup, num_pages: int, page_size: int,
+                 n_slots: int, table_width: int, prefix_caching: bool):
+        self.spec = spec
+        self.num_pages = num_pages
+        self.allocator = PageAllocator(
+            num_pages, page_size, enable_prefix_caching=prefix_caching)
+        self.tables = np.zeros((n_slots, table_width), np.int32)
+        # a window group's slots hold table columns [lo, hi); `reserve`
+        # pages were set aside at admission, `final` columns is all the
+        # request will ever write
+        self.lo = [0] * n_slots
+        self.hi = [0] * n_slots
+        self.reserve = [0] * n_slots
+        self.final = [0] * n_slots
+        self.returned = 0            # pages handed back behind windows
+        self.peak_used = 0
+        # pages in use when the whole cache's bytes in use last peaked
+        self.used_at_peak = 0
+
+    @property
+    def outstanding(self) -> int:
+        """Pages reserved at admission that their slots do not hold
+        right now: free, but spoken for."""
+        return sum(min(r - (h - l), f - h) for l, h, r, f in zip(
+            self.lo, self.hi, self.reserve, self.final) if r)
+
+    @property
+    def admittable(self) -> int:
+        """Pages a new admission may reserve."""
+        return self.allocator.free_pages - (
+            self.outstanding if self.spec.window else 0)
+
+    def note_peak(self) -> None:
+        self.peak_used = max(self.peak_used, self.allocator.used_pages)
+
+
+class CacheManager:
+    """The engine's cache: one `PageAllocator`, one page table a slot
+    and (the engine's) one set of pools for each `CacheGroup` of the
+    model's family. A family with one group (every layer, no window)
+    gets today's allocator and table and nothing else; `first` is that
+    allocator, and the engine's `slot.pages` is its page list.
+
+    A window group (`CacheGroup.window` = w tokens) holds, for a live
+    sequence whose next query sits at position p, the pages that cover
+    (p - w, p + what the next tick may write] and no others: `advance`
+    hands the pages wholly behind every query still to come back to the
+    group's allocator (their table entries point at the scratch page;
+    the kernel's sweep starts at the window's first block and never
+    reads them) and claims the pages ahead. Hand-back, not a ring of
+    pages a slot: position -> table column stays one rule for every
+    group, and a page behind one sequence's window serves another's
+    front. Admission reserves each group's worst case, prompt +
+    max_new_tokens in a full group and min(that, w + a tick's tokens +
+    2 pages) in a window group, and counts what is reserved but not
+    held against later admissions, so a running sequence cannot meet an
+    empty pool mid-decode.
+
+    Prefix cache: a resume at token m needs the window group's pages
+    over (m - w, m] too, and those are gone; with a window group the
+    cache is off in every group (`PREFIX_CACHE_OFF_FOR_WINDOWS`)."""
+
+    def __init__(self, groups: Sequence[CacheGroup],
+                 num_pages: Sequence[int], page_size: int, n_slots: int,
+                 table_width: int, tick_tokens: int,
+                 enable_prefix_caching: bool = True):
+        if groups[0].window is not None or any(
+                g.window is None for g in groups[1:]):
+            raise ValueError(
+                "the first cache group holds whole contexts (the "
+                "engine's slot.pages is its page list) and the groups "
+                "after it are window groups: no family has asked for "
+                "another layout")
+        self.page_size = page_size
+        self.tick_tokens = int(tick_tokens)
+        self.windowed = len(groups) > 1
+        self.prefix_cache = (
+            PREFIX_CACHE_OFF_FOR_WINDOWS if self.windowed
+            else "on" if enable_prefix_caching else "off")
+        self.groups = [
+            _GroupState(g, n, page_size, n_slots, table_width,
+                        enable_prefix_caching and not self.windowed)
+            for g, n in zip(groups, num_pages)]
+        self.first = self.groups[0].allocator
+        self._rest = self.groups[1:]
+        self._peak_bytes = 0
+
+    # ------------------------------------------------------- admission
+    def reserve_pages(self, g: _GroupState, tokens: int) -> int:
+        """Pages group `g` sets aside for a request of `tokens` in all
+        (prompt + max_new_tokens, or what optimistic admission
+        reserves of it)."""
+        w = g.spec.window
+        if w is not None:
+            tokens = min(tokens, w + self.tick_tokens + 2 * self.page_size)
+        return g.allocator.pages_needed(tokens)
+
+    def fits(self, tokens: int) -> Optional[str]:
+        """None if a request of `tokens` could ever be admitted, else
+        which group cannot hold it."""
+        for g in self.groups:
+            need = self.reserve_pages(g, tokens)
+            if need > g.allocator.num_usable:
+                return (f"needs {need} KV pages of group "
+                        f"{g.spec.name!r} but the pool only has "
+                        f"{g.allocator.num_usable}")
+        return None
+
+    def can_admit(self, tokens: int, shared: int = 0) -> bool:
+        """Does EVERY group have the pages a request of `tokens` in all
+        reserves? `shared`: pages of the first group a prefix match
+        already holds."""
+        if self.reserve_pages(self.groups[0], tokens) - shared \
+                > self.first.free_pages:
+            return False
+        return all(self.reserve_pages(g, tokens) <= g.admittable
+                   for g in self._rest)
+
+    def admit(self, slot: int, tokens: int, shared: Sequence[int] = (),
+              pos: int = 0) -> List[int]:
+        """Claim every group's reservation for `slot`. Returns the first
+        group's pages (`shared` first), which the engine keeps as
+        `slot.pages`; the other groups' pages live here. `pos`: where
+        the sequence's prefill starts."""
+        head = self.groups[0]
+        pages = list(shared) + self.first.allocate_pages(
+            self.reserve_pages(head, tokens) - len(shared))
+        head.tables[slot] = 0
+        head.tables[slot, :len(pages)] = pages
+        head.note_peak()
+        for g in self._rest:
+            g.reserve[slot] = self.reserve_pages(g, tokens)
+            g.final[slot] = g.allocator.pages_needed(tokens)
+            g.tables[slot] = g.num_pages - 1
+            g.lo[slot] = g.hi[slot] = max(
+                (pos - g.spec.window + 1) // self.page_size, 0)
+        self.advance([(slot, pos)])
+        return pages
+
+    def reset_peaks(self) -> None:
+        """Forget the peaks so far: what `pages_peak` and
+        `pages_at_peak` say from here on is of what comes after (a
+        benchmark's window, after its checks and warm-up)."""
+        self._peak_bytes = 0
+        for g in self.groups:
+            g.peak_used = g.used_at_peak = g.allocator.used_pages
+
+    def _note_bytes_peak(self) -> None:
+        """Remember what every group held when the bytes in use, all
+        groups together, were at their most: what a family with a window
+        group saves is read there (`pages_at_peak`)."""
+        now = self.bytes_used()
+        if now > self._peak_bytes:
+            self._peak_bytes = now
+            for g in self.groups:
+                g.used_at_peak = g.allocator.used_pages
+
+    def vacate(self, slot: int) -> None:
+        """`slot` is empty: whatever it holds beyond the first group
+        goes back, and its table rows are cleared. (The first group's
+        pages are the engine's `slot.pages`, freed by whoever clears
+        the slot.)"""
+        self.groups[0].tables[slot] = 0
+        for g in self._rest:
+            lo, hi = g.lo[slot], g.hi[slot]
+            if hi > lo:
+                g.allocator.free(g.tables[slot, lo:hi].tolist())
+            g.lo[slot] = g.hi[slot] = g.reserve[slot] = g.final[slot] = 0
+            g.tables[slot] = 0
+
+    # ---------------------------------------------------- window groups
+    def advance(self, live: Iterable[Tuple[int, int]]) -> Tuple[int, int]:
+        """A tick boundary. `live`: (slot, position of the earliest
+        query still to come) for each sequence: a prefilling slot's next
+        chunk starts there, a decoding slot's in-flight or next token
+        sits there. Window groups first hand back every page wholly
+        behind that query's window, then claim the pages the next tick
+        may write (a tick's tokens, and one more for the token a
+        pipelined decode tick writes before the host has folded its
+        predecessor). Returns (pages handed back, pages claimed).
+        Nothing to do, and nothing done, for a family without a window
+        group."""
+        if not self.windowed:
+            return 0, 0
+        live = list(live)
+        page, handed, claimed = self.page_size, 0, 0
+        for g in self._rest:
+            w = g.spec.window
+            scratch, returned = g.num_pages - 1, 0
+            for slot, pos in live:
+                lo = min(max((pos - w + 1) // page, g.lo[slot]),
+                         g.hi[slot])
+                if lo > g.lo[slot]:
+                    row = g.tables[slot]
+                    g.allocator.free(row[g.lo[slot]:lo].tolist())
+                    row[g.lo[slot]:lo] = scratch
+                    returned += lo - g.lo[slot]
+                    g.lo[slot] = lo
+            for slot, pos in live:
+                hi = min(g.final[slot], g.allocator.pages_needed(
+                    pos + self.tick_tokens + 2))
+                if hi > g.hi[slot]:
+                    g.tables[slot, g.hi[slot]:hi] = (
+                        g.allocator.allocate_pages(hi - g.hi[slot]))
+                    claimed += hi - g.hi[slot]
+                    g.hi[slot] = hi
+            g.returned += returned
+            handed += returned
+            g.note_peak()
+        self._note_bytes_peak()
+        return handed, claimed
+
+    # ------------------------------------------------------------ stats
+    @property
+    def tables(self) -> List[np.ndarray]:
+        return [g.tables for g in self.groups]
+
+    def bytes_used(self) -> int:
+        """Device bytes the pages in use hold, every group."""
+        return sum(g.allocator.used_pages * self.page_size
+                   * g.spec.bytes_per_token for g in self.groups)
+
+    def page_bytes(self) -> int:
+        """Device bytes of one page in every group (one group: a page
+        across the whole stack)."""
+        return sum(self.page_size * g.spec.bytes_per_token
+                   for g in self.groups)
+
+    def stats(self) -> Dict[str, Any]:
+        """The allocator's stats of the FULLEST group, the one that
+        gates admission (a window group's free pages are those a new
+        admission may reserve), `total_pages`, and `cache_groups`: each
+        group's row, layers, window and pages total / used / peak /
+        reserved / at the peak of the whole cache's bytes in use, and
+        for a window group the pages handed back behind the window
+        since start-up."""
+        def occupancy(g):
+            total = g.allocator.num_usable
+            return 1.0 - g.admittable / total if total else 0.0
+
+        full = max(self.groups, key=occupancy)
+        out = full.allocator.stats()
+        if full.spec.window is not None:
+            out["free_pages"] = full.admittable
+            out["used_pages"] = full.allocator.num_usable - full.admittable
+            out["occupancy"] = occupancy(full)
+        out["total_pages"] = full.allocator.num_usable
+        out["prefix_cache"] = self.prefix_cache
+        groups = []
+        for g in self.groups:
+            used = g.allocator.used_pages
+            g.note_peak()
+            d = {**g.spec.describe(),
+                 "pages_total": g.allocator.num_usable,
+                 "pages_used": used, "pages_peak": g.peak_used,
+                 "pages_reserved": used + (
+                     g.outstanding if g.spec.window else 0),
+                 "pages_at_peak": (g.used_at_peak if self.windowed
+                                   else g.peak_used)}
+            if g.spec.window is not None:
+                d["pages_returned"] = g.returned
+            groups.append(d)
+        out["cache_groups"] = groups
         return out

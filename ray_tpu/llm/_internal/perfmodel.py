@@ -146,7 +146,8 @@ class CostModel:
     KV bytes come from the cache-row description either way."""
 
     def __init__(self, cfg, page_size: int, kv_dtype: str = "f32",
-                 cache_row=None, weight_bytes: Optional[float] = None):
+                 cache_row=None, weight_bytes: Optional[float] = None,
+                 cache_groups=None):
         from ...ops import kv_quant
         self.cfg = cfg
         self.page_size = int(page_size)
@@ -169,15 +170,25 @@ class CostModel:
         # width the kernels stream (a head_dim of 64 is padded to 128
         # lanes in a kernel pool; the cache, not the model, pays), the
         # storage type, and a quantized pool's per-(row, head) scales
-        if cache_row is None:
-            # no engine behind this model (a test's): the dense
-            # family's row as the model writes it
-            cache_row = family_of(cfg).cache_row(cfg, "gather",
-                                                 self.kv_dtype)
-        self.cache_row = cache_row
+        # The cache is priced a GROUP (models/cache_row.CacheGroup):
+        # its layers' rows, read over the keys a query still sees (a
+        # window layer reads min(context, window) keys a row)
+        if cache_groups is None and cache_row is None:
+            # no engine behind this model (a test's): the family's
+            # groups as the model writes them
+            cache_groups = family_of(cfg).cache_groups(
+                cfg, "gather", self.kv_dtype)
+        if cache_groups is None:
+            # one row for every layer (a caller with a row in hand)
+            from ...models.family import one_group
+            cache_groups = one_group(cache_row, L)
+        self.cache_groups = tuple(cache_groups)
+        self.cache_row = self.cache_groups[0].row
         self.kv_bytes_per_token = float(
-            L * cache_row.bytes_per_token_layer)
+            sum(g.bytes_per_token for g in self.cache_groups))
         self.page_bytes = self.kv_bytes_per_token * self.page_size
+        self._windowed = any(g.window is not None
+                             for g in self.cache_groups)
 
     def _llama_constants(self, cfg: LlamaConfig) -> None:
         h, L = cfg.hidden, cfg.n_layers
@@ -219,14 +230,42 @@ class CostModel:
         pages = -(-ctx // self.page_size)
         return pages * self.page_size
 
+    def _kv_read_bytes(self, ctx: int, n: int = 1) -> float:
+        """Bytes of cached context that `n` queries whose last sits
+        `ctx` keys into its sequence read, every group: a window group
+        reads at most its window's keys and the n - 1 before them."""
+        if not self._windowed:
+            return self.kv_bytes_per_token * self._ctx_read_tokens(ctx)
+        return float(sum(
+            g.bytes_per_token * self._ctx_read_tokens(
+                ctx if g.window is None else min(ctx, g.window + n - 1))
+            for g in self.cache_groups))
+
+    def _windowed_pairs(self, start: int, n: int) -> float:
+        """(query, key) pairs `n` queries after `start` cached tokens
+        keep, summed over layers as a share of `attn_flops_per_pair`'s
+        every-layer count: a window layer's query keeps at most its
+        window."""
+        total = sum(len(g.layers) for g in self.cache_groups)
+        pairs = 0.0
+        for g in self.cache_groups:
+            kept = n * start + n * (n + 1) // 2
+            if g.window is not None:
+                full = max(min(g.window - start, n), 0)
+                kept = (full * start + full * (full + 1) // 2
+                        + (n - full) * g.window)
+            pairs += kept * len(g.layers) / total
+        return pairs
+
     def decode_cost(self, ctx: int) -> Dict[str, float]:
         """One decode token whose attention context is `ctx` tokens
         (cached + itself)."""
         return {
             "flops_gemm": self.gemm_flops_per_token + self.head_flops,
-            "flops_attn": self.attn_flops_per_pair * ctx,
-            "bytes_kv_read": (self.kv_bytes_per_token
-                              * self._ctx_read_tokens(ctx - 1)),
+            "flops_attn": self.attn_flops_per_pair * (
+                self._windowed_pairs(ctx - 1, 1) if self._windowed
+                else ctx),
+            "bytes_kv_read": self._kv_read_bytes(ctx - 1),
             "bytes_kv_write": self.kv_bytes_per_token,
         }
 
@@ -235,13 +274,13 @@ class CostModel:
         tokens (causal: token i attends to start + i + 1 keys). The
         chunk's own K/V stay on-chip; only the cached context is read
         from the pool."""
-        pairs = n * start + n * (n + 1) // 2
+        pairs = (self._windowed_pairs(start, n) if self._windowed
+                 else n * start + n * (n + 1) // 2)
         return {
             "flops_gemm": n * self.gemm_flops_per_token
             + self.head_flops,
             "flops_attn": self.attn_flops_per_pair * pairs,
-            "bytes_kv_read": (self.kv_bytes_per_token
-                              * self._ctx_read_tokens(start)),
+            "bytes_kv_read": self._kv_read_bytes(start, n),
             "bytes_kv_write": n * self.kv_bytes_per_token,
         }
 

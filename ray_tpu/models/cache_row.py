@@ -1,6 +1,7 @@
-"""What one token writes to the cache in one layer: the description a
-model family hands the serving engine (`models/family.py`). It lives on
-the model's side of the seam and imports nothing of the engine."""
+"""What one token writes to the cache in one layer, and which layers
+write alike: the description a model family hands the serving engine
+(`models/family.py`). It lives on the model's side of the seam and
+imports nothing of the engine."""
 
 from __future__ import annotations
 
@@ -13,10 +14,12 @@ import numpy as np
 @dataclasses.dataclass(frozen=True)
 class CacheRow:
     """What ONE token writes to the cache in ONE layer, and how the pool
-    lays it out: the one description that the engine's pool
-    construction, its per-page byte reckoning, `stats()` and
-    `perfmodel.CostModel` read. A model family gives it
-    (`models/family.py`); nothing else knows a row's shape.
+    lays it out. A model's cache is one or more `CacheGroup`s of layers,
+    each with a row: since PR 31 the groups, not one row for every
+    layer, are the description that the engine's pool construction, its
+    per-page byte reckoning, `stats()` and `perfmodel.CostModel` read.
+    A model family gives them (`models/family.py`); nothing else knows a
+    row's shape.
 
     kind "kv": a K pool and a V pool, `heads` kv heads of `width`
     values each (a dense GQA decoder). kind "latent": ONE pool of one
@@ -55,3 +58,29 @@ class CacheRow:
                 "padded_width": self.padded_width,
                 "dtype": np.dtype(self.dtype).name,
                 "bytes_per_token_layer": self.bytes_per_token_layer}
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheGroup:
+    """The layers of a model that write the same row under the same
+    rule of what a query may still see. Each group has pools of its own
+    `[its layers, its pages, page, heads, width]`, an allocator and a
+    page table a slot (`kv_cache.CacheManager`).
+
+    window None: a query sees its whole context and a sequence holds
+    every page it wrote. window w: query i sees keys j with
+    i - w < j <= i, and pages wholly behind the window of every query
+    still to come go back to the group's allocator."""
+    name: str
+    row: CacheRow
+    layers: Tuple[int, ...]
+    window: Optional[int] = None
+
+    @property
+    def bytes_per_token(self) -> int:
+        """Device bytes one token holds in this group's layers."""
+        return len(self.layers) * self.row.bytes_per_token_layer
+
+    def describe(self) -> Dict[str, Any]:
+        return {"name": self.name, "row": self.row.describe(),
+                "layers": list(self.layers), "window": self.window}

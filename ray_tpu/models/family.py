@@ -10,9 +10,11 @@ how its totals read in `stats()`, and the engine options the family
 does not compose with (refused at construction with the reason, never
 half-run). Nothing here imports the engine: the engine imports this.
 
-Two families: `llama` (dense RoPE/GQA/SwiGLU decoders, `LlamaConfig`,
-programs unchanged) and `deepseek_v3` (latent attention over a latent
-cache, expert layers with the experts held here, `DeepseekV3Config`).
+Three families: `llama` (dense RoPE/GQA/SwiGLU decoders, `LlamaConfig`,
+programs unchanged), `deepseek_v3` (latent attention over a latent
+cache, expert layers with the experts held here, `DeepseekV3Config`)
+and `trinity` (sliding-window and full-attention layers over two page
+groups, gated QK-normed GQA attention, held experts, `TrinityConfig`).
 """
 
 from __future__ import annotations
@@ -21,7 +23,7 @@ import dataclasses
 import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
-from .cache_row import CacheRow
+from .cache_row import CacheGroup, CacheRow
 
 
 @dataclasses.dataclass(frozen=True)
@@ -30,13 +32,20 @@ class ModelFamily:
     init_params: Callable[..., Dict[str, Any]]
     ragged_forward: Callable[..., tuple]
     decode_step: Callable[..., tuple]
-    # (cfg, impl, kv_kind) -> CacheRow
-    cache_row: Callable[..., CacheRow]
+    # (cfg, impl, kv_kind) -> the cache groups, in the order the
+    # forwards take their pools and page tables. One group with every
+    # layer and no window: the forwards take one pool (or a K and a V
+    # pool) and one table, as arrays; more: tuples of them, a group each
+    cache_groups: Callable[..., Tuple[CacheGroup, ...]]
     # (segs, T, page_size, n_ctx_pages, geometry) -> (live items, KV
     # blocks they visit): the attention kernel's grid for a tick whose
     # rows hold `segs` = [(cached tokens, tokens this tick)]; geometry
     # is the engine's (local kv heads, pool row width, itemsize)
     work_counts: Callable[..., Tuple[int, int]]
+    # (cfg, segs) -> ints the dispatch span carries besides the usual
+    # ones, counted on the host from the plan (a family with window
+    # layers: the keys and pairs inside their windows)
+    span_counts: Optional[Callable[..., Dict[str, int]]] = None
     # (cfg) -> ints a tick's program appends to its token readback
     # (0: the readback is the sampled tokens alone)
     rider_len: Callable[[Any], int] = lambda cfg: 0
@@ -49,6 +58,25 @@ class ModelFamily:
     storage_dtypes: Optional[Callable[[Any], Dict[str, Any]]] = None
     # engine options this family does not compose with: name -> reason
     refuses: Dict[str, str] = dataclasses.field(default_factory=dict)
+
+    def cache_row(self, cfg, impl: str, kv_kind: str = "f32") -> CacheRow:
+        """The FIRST group's row, kept for its readers; `cache_groups`
+        is the description since PR 31."""
+        return self.cache_groups(cfg, impl, kv_kind)[0].row
+
+
+def one_group(row: CacheRow, n_layers: int) -> Tuple[CacheGroup, ...]:
+    """Every layer writes `row` and sees its whole context."""
+    return (CacheGroup("all", row, tuple(range(n_layers))),)
+
+
+def _llama_cache_groups(cfg, impl: str, kv_kind: str = "f32"):
+    return one_group(_llama_cache_row(cfg, impl, kv_kind), cfg.n_layers)
+
+
+def _deepseek_cache_groups(cfg, impl: str, kv_kind: str = "f32"):
+    return one_group(_deepseek_cache_row(cfg, impl, kv_kind),
+                     cfg.n_layers)
 
 
 def _llama_cache_row(cfg, impl: str, kv_kind: str = "f32") -> CacheRow:
@@ -106,23 +134,36 @@ DEEPSEEK_REFUSES = {
 def _families() -> Dict[type, ModelFamily]:
     """Configuration type -> its family (built on first use: the model
     modules import jax)."""
-    from . import deepseek_v3, llama, llama_infer
+    from . import deepseek_v3, llama, llama_infer, trinity
     return {
         llama.LlamaConfig: ModelFamily(
             name="llama", init_params=llama.init_params,
             ragged_forward=llama_infer.ragged_forward,
             decode_step=llama_infer.decode_step,
-            cache_row=_llama_cache_row, work_counts=_llama_work_counts,
+            cache_groups=_llama_cache_groups,
+            work_counts=_llama_work_counts,
             storage_dtypes=llama_infer.storage_dtypes),
         deepseek_v3.DeepseekV3Config: ModelFamily(
             name="deepseek_v3", init_params=deepseek_v3.init_params,
             ragged_forward=deepseek_v3.ragged_forward,
             decode_step=deepseek_v3.decode_step,
-            cache_row=_deepseek_cache_row,
+            cache_groups=_deepseek_cache_groups,
             work_counts=_deepseek_work_counts,
             rider_len=lambda c: c.n_moe_layers * c.n_held,
             rider_summary=deepseek_v3.routing_summary,
             refuses=DEEPSEEK_REFUSES),
+        trinity.TrinityConfig: ModelFamily(
+            name="trinity", init_params=trinity.init_params,
+            ragged_forward=trinity.ragged_forward,
+            decode_step=trinity.decode_step,
+            cache_groups=trinity.cache_groups,
+            # the full layers' kernel: the dense family's count
+            work_counts=_llama_work_counts,
+            span_counts=trinity.span_counts,
+            rider_len=lambda c: c.n_moe_layers * c.n_held,
+            # the same counts of the same held-expert layer
+            rider_summary=deepseek_v3.routing_summary,
+            refuses=trinity.TRINITY_REFUSES),
     }
 
 
@@ -159,8 +200,8 @@ def store_params(family: ModelFamily, cfg, params, shardings=None,
 
 
 def family_of(cfg) -> ModelFamily:
-    """The family that serves `cfg` (a LlamaConfig or a
-    DeepseekV3Config)."""
+    """The family that serves `cfg` (a LlamaConfig, a DeepseekV3Config
+    or a TrinityConfig)."""
     for kind, family in _families().items():
         if isinstance(cfg, kind):
             return family
@@ -169,10 +210,15 @@ def family_of(cfg) -> ModelFamily:
 
 def resolve_config(model):
     """A preset name or a family's configuration -> the configuration.
-    Names are the dense family's presets, or `deepseek_v3:<preset>`."""
-    from . import deepseek_v3, llama
-    if isinstance(model, deepseek_v3.DeepseekV3Config):
+    Names are the dense family's presets, `deepseek_v3:<preset>` or
+    `trinity:<preset>`."""
+    from . import deepseek_v3, llama, trinity
+    if isinstance(model, (deepseek_v3.DeepseekV3Config,
+                          trinity.TrinityConfig)):
         return model
-    if isinstance(model, str) and model.startswith("deepseek_v3:"):
-        return deepseek_v3.config(model.split(":", 1)[1])
+    if isinstance(model, str) and ":" in model:
+        family, preset = model.split(":", 1)
+        named = {"deepseek_v3": deepseek_v3, "trinity": trinity}
+        if family in named:
+            return named[family].config(preset)
     return llama.config(model)

@@ -42,7 +42,7 @@ Two implementations:
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,8 +56,8 @@ from .paged_attention import _fit_lanes
 def ragged_prefill_decode_attention(
         q: jax.Array, k_ctx: jax.Array, v_ctx: jax.Array,
         k_new: jax.Array, v_new: jax.Array, slot_ids: jax.Array,
-        positions: jax.Array, valid: jax.Array, start: jax.Array
-) -> jax.Array:
+        positions: jax.Array, valid: jax.Array, start: jax.Array,
+        window: Optional[int] = None) -> jax.Array:
     """Core ragged attention over gathered context + the batch's own KV.
 
     q: [T, H, D] queries of the flat ragged token batch; k_ctx/v_ctx:
@@ -70,7 +70,9 @@ def ragged_prefill_decode_attention(
 
     Token t attends its slot's context positions c < start[slot] plus
     batch tokens u of the same slot with positions[u] <= positions[t].
-    GQA (H // KVH query heads per kv head), softmax in float32.
+    With `window` w (a sliding-window layer) a key at position j is
+    kept only if j > positions[t] - w: w keys, the token's own among
+    them. GQA (H // KVH query heads per kv head), softmax in float32.
     Returns [T, H, D].
 
     Every token also attends ITSELF unconditionally — a no-op for
@@ -100,7 +102,12 @@ def ragged_prefill_decode_attention(
                 < start[slot_ids][:, None])            # [T, ctx]
     new_mask = ((slot_ids[:, None] == slot_ids[None, :])
                 & (positions[None, :] <= positions[:, None])
-                & valid[None, :]) | jnp.eye(t, dtype=bool)  # [T, T]
+                & valid[None, :])
+    if window is not None:
+        floor = positions[:, None] - window                # keys above it
+        ctx_mask = ctx_mask & (jnp.arange(ctx)[None, :] > floor)
+        new_mask = new_mask & (positions[None, :] > floor)
+    new_mask = new_mask | jnp.eye(t, dtype=bool)           # [T, T]
     s_ctx = jnp.where(ctx_mask[:, None, None, :], s_ctx * scale,
                       -jnp.inf)
     s_new = jnp.where(new_mask[:, None, None, :], s_new * scale,
@@ -119,7 +126,7 @@ def ragged_paged_prefill_decode_attention(
         page_tables: jax.Array, slot_ids: jax.Array,
         positions: jax.Array, valid: jax.Array, start: jax.Array,
         k_new: jax.Array, v_new: jax.Array,
-        ctx_pages: int = -1) -> jax.Array:
+        ctx_pages: int = -1, window: Optional[int] = None) -> jax.Array:
     """Single-layer convenience: gather each slot's pages then run the
     ragged attention (what the model forward does once for all layers).
 
@@ -136,7 +143,87 @@ def ragged_paged_prefill_decode_attention(
     return ragged_prefill_decode_attention(
         q, _fit_lanes(g_k.reshape(b, p * s, kvh, d), hd),
         _fit_lanes(g_v.reshape(b, p * s, kvh, d), hd),
-        k_new, v_new, slot_ids, positions, valid, start)
+        k_new, v_new, slot_ids, positions, valid, start, window)
+
+
+# gathered context rows (one token's slot's cached keys, every kv head)
+# that a block of tokens may hold at once: x 8 heads x 128 lanes x 2 B
+# x (K and V) = 0.5 GB at 2**17; a 512-token tick over 16k-token tables
+# runs in 64 blocks of 8 tokens, a tick of the CPU tests in one
+GATHER_ROWS = 1 << 17
+
+
+def ragged_gather_paged_blocked(
+        q: jax.Array, k_pool: jax.Array, v_pool: jax.Array, layer,
+        page_tables: jax.Array, slot_ids: jax.Array,
+        positions: jax.Array, valid: jax.Array, start: jax.Array,
+        k_new: jax.Array, v_new: jax.Array, *,
+        window: Optional[int] = None,
+        gather_rows: int = GATHER_ROWS) -> jax.Array:
+    """The ragged attention rule of `ragged_prefill_decode_attention`
+    (window included) straight off a WHOLE pool, in blocks of tokens
+    that each gather their own slots' pages of `layer` (a traced index
+    is fine), at most `gather_rows` context rows at a time: the same
+    sums whatever the block, so a 512-token tick over 16k-token tables
+    fits beside the weights. Operands as stored, scores, softmax and
+    accumulation in float32. The oracle the window kernel is held to:
+    it reads every table entry, also those of pages a window group has
+    handed back, and masks them.
+
+    q: [T, H, D]; k_pool/v_pool: [L, P, page, KVH, Dp]; page_tables:
+    [B, n]; k_new/v_new: [T, KVH, D]. Returns [T, H, D] in q's type."""
+    t, h, d = q.shape
+    n, page, kvh = page_tables.shape[1], k_pool.shape[2], k_pool.shape[3]
+    ctx, group = n * page, h // k_pool.shape[3]
+    f32 = jnp.float32
+    scale = d ** -0.5
+    kn, vn = k_new.astype(q.dtype), v_new.astype(q.dtype)
+
+    def block(q_b, slot_b, pos_b, idx_b):
+        b = q_b.shape[0]
+        own = page_tables[slot_b]                        # [b, n]
+        rows = (b, ctx, kvh, k_pool.shape[-1])
+        kc = _fit_lanes(k_pool[layer, own].reshape(rows), d)
+        vc = _fit_lanes(v_pool[layer, own].reshape(rows), d)
+        qg = q_b.reshape(b, kvh, group, d)
+        s_ctx = jnp.einsum("tkgd,tckd->tkgc", qg, kc.astype(q.dtype),
+                           preferred_element_type=f32) * scale
+        s_new = jnp.einsum("tkgd,ukd->tkgu", qg, kn,
+                           preferred_element_type=f32) * scale
+        col = jnp.arange(ctx)[None, :]
+        ctx_mask = col < start[slot_b][:, None]
+        new_mask = ((slot_b[:, None] == slot_ids[None, :])
+                    & (positions[None, :] <= pos_b[:, None])
+                    & valid[None, :])
+        if window is not None:
+            floor = pos_b[:, None] - window
+            ctx_mask = ctx_mask & (col > floor)
+            new_mask = new_mask & (positions[None, :] > floor)
+        # every token attends itself: padding rows stay finite
+        new_mask = new_mask | (idx_b[:, None] == jnp.arange(t)[None, :])
+        s_all = jnp.concatenate(
+            [jnp.where(ctx_mask[:, None, None, :], s_ctx, -jnp.inf),
+             jnp.where(new_mask[:, None, None, :], s_new, -jnp.inf)],
+            axis=-1)
+        p = jax.nn.softmax(s_all, axis=-1).astype(q.dtype)
+        out = (jnp.einsum("tkgc,tckd->tkgd", p[..., :ctx],
+                          vc.astype(q.dtype), preferred_element_type=f32)
+               + jnp.einsum("tkgu,ukd->tkgd", p[..., ctx:], vn,
+                            preferred_element_type=f32))
+        return out.reshape(b, h, d).astype(q.dtype)
+
+    idx = jnp.arange(t)
+    blk = max(gather_rows // max(ctx, 1), 1)
+    if blk >= t:
+        return block(q, slot_ids, positions, idx)
+    blk = 1 << (blk.bit_length() - 1)
+    pad = -t % blk
+    cut = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)
+                            ).reshape((-1, blk) + a.shape[1:])
+    # padding tokens repeat token 0's slot and index: finite, cut off
+    out = jax.lax.map(lambda a: block(*a),
+                      (cut(q), cut(slot_ids), cut(positions), cut(idx)))
+    return out.reshape((-1,) + out.shape[2:])[:t]
 
 
 def ragged_attention_dense_oracle(
@@ -225,11 +312,14 @@ def ragged_item_bound(t: int, n_slots: int, q_blk: int) -> int:
 def ragged_work_counts(segs, t: int, page_size: int,
                        n_ctx_pages: int, kvh: int = 1,
                        row_width: int = 128,
-                       itemsize: int = 2) -> Tuple[int, int]:
+                       itemsize: int = 2,
+                       window: Optional[int] = None) -> Tuple[int, int]:
     """Host-side count of what the kernel does for a tick whose slots
     hold `segs` = [(cached tokens, tokens this tick)]: (live items,
     KV blocks those items visit, context plus in-batch). Plain ints —
-    the engine's dispatch span and the tests' hand counts share it."""
+    the engine's dispatch span and the tests' hand counts share it.
+    With `window` an item's sweep starts at the first context block its
+    first query's window still covers."""
     q_blk, ppb = ragged_block_sizes(t, page_size, n_ctx_pages, kvh,
                                     row_width, itemsize)
     bk = ppb * page_size
@@ -239,7 +329,13 @@ def ragged_work_counts(segs, t: int, page_size: int,
         items += n_blk
         # item qb sweeps the slot's whole cached context, then the
         # in-batch blocks 0..qb (the causal diagonal)
-        kv_blocks += n_blk * -(-start // bk) + n_blk * (n_blk + 1) // 2
+        n_ctx = -(-start // bk) if n_ctx_pages else 0
+        kv_blocks += n_blk * n_ctx + n_blk * (n_blk + 1) // 2
+        if window is not None:
+            # ... less the context blocks wholly behind its first query
+            kv_blocks -= sum(
+                min(max(start + qb * q_blk - (window - 1), 0) // bk, n_ctx)
+                for qb in range(n_blk))
     return items, kv_blocks
 
 
@@ -285,7 +381,8 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
                          v_hbm, *rest, page_size: int, ppb: int,
                          n_ctx_pages: int, q_blk: int, small: int,
                          scale: float, kvh: int, group: int,
-                         quantized: bool = False):
+                         quantized: bool = False,
+                         window: Optional[int] = None):
     """Grid (n_items,): one step per work item (slot, query block).
 
     A step reads its q_blk query rows from the flat batch where they
@@ -314,6 +411,14 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
     c < start[slot]; in-batch key offset j attends query offset i iff
     j <= i and j < q_len[slot] (each slot's tokens are one contiguous
     run in position order, so offset order IS position order).
+
+    window=w (static; a sliding-window layer): a key at position j is
+    kept only if j > the query's position - w. The context sweep starts
+    at the block that holds position (the item's first query - (w - 1)):
+    earlier blocks lie behind every query of the item (their pages may
+    have gone back to the allocator) and are never read; the lower edge
+    is masked inside the boundary block, and the in-batch blocks obey
+    the same rule by the mask alone.
 
     quantized=True (ISSUE 16): the pools hold int8/fp8 values and two
     extra HBM refs carry the per-(row, head) f32 scales
@@ -344,6 +449,9 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
         qlen = segs_ref[1, slot]
         first = segs_ref[2, slot]
         n_ctx = (ctx_len + bk - 1) // bk if n_ctx_pages else 0
+        # the first context block the item's first query still sees
+        lo_blk = 0 if window is None else jnp.minimum(
+            jnp.maximum(ctx_len + qoff - (window - 1), 0) // bk, n_ctx)
         last_page = jnp.minimum(jnp.maximum((ctx_len - 1) // page_size, 0),
                                 max(n_ctx_pages - 1, 0))
 
@@ -370,7 +478,8 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
             q_hbm.at[pl.ds(tok0, q_blk)], q_vmem, io_sem)
         q_copy.start()
         if n_ctx_pages:
-            pl.when(n_ctx > 0)(lambda: page_dma(0, 0, True))
+            pl.when(n_ctx > lo_blk)(
+                lambda: page_dma(lo_blk, lo_blk % 2, True))
         q_copy.wait()
 
         m_scr[...] = jnp.full_like(m_scr, -1e30)
@@ -469,6 +578,9 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
             end = jnp.where(is_ctx, ctx_len, qlen)
             # context keys precede every query; in-batch keys are causal
             ahead = jnp.where(is_ctx, 1 << 30, 0)
+            # a query at segment offset i sits ctx_len + i into the
+            # sequence; a context key's offset is its position
+            behind = jnp.where(is_ctx, ctx_len, 0)
 
             def keep(r):
                 # query offset per score row / key offset per column,
@@ -478,8 +590,11 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
                     jnp.int32, shape, 0) // group
                 col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
                 key = first_key + col
-                return ((col < n_keys) & (key < end)
+                kept = ((col < n_keys) & (key < end)
                         & (key <= i_tok + ahead))
+                if window is not None:
+                    kept = kept & (key > i_tok + behind - window)
+                return kept
 
             # the rows the item holds: its first `small` tokens' or
             # all q_blk's
@@ -491,7 +606,7 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
                 flash_heads(q_blk * group, keep)
             return carry
 
-        jax.lax.fori_loop(0, n_ctx + qoff // q_blk + 1, kv_block, 0)
+        jax.lax.fori_loop(lo_blk, n_ctx + qoff // q_blk + 1, kv_block, 0)
 
         # rows the sweep left alone (past `small`) have l == 0 and
         # acc == 0: the epsilon floor makes them exact zeros
@@ -512,6 +627,7 @@ def ragged_paged_attention_pallas(
         k_new: jax.Array, v_new: jax.Array, *, ctx_pages: int = -1,
         k_scales: jax.Array = None, v_scales: jax.Array = None,
         work: Tuple[jax.Array, jax.Array] = None,
+        window: Optional[int] = None,
         interpret: bool = False) -> jax.Array:
     """TPU Pallas ragged paged attention: same contract as
     `ragged_paged_prefill_decode_attention`, but each slot's KV pages
@@ -546,6 +662,12 @@ def ragged_paged_attention_pallas(
     the pools hold int8/fp8 values; the kernel DMAs the scale rows
     beside their pages and fuses the dequant multiply into the
     streaming loop. k_new/v_new stay full-precision either way.
+
+    window (static): None is a full-attention layer, the kernel named
+    `ragged_paged_attention`; w tokens is a sliding-window layer, the
+    same kernel with its sweep started at the window's first block and
+    its lower edge masked, named `ragged_window_attention` so that a
+    device trace tells the two kinds of layer apart.
     """
     quantized = k_scales is not None
     if quantized and v_scales is None:
@@ -556,14 +678,40 @@ def ragged_paged_attention_pallas(
     flat = _ragged_call(
         items, segs, page_tables.astype(jnp.int32), q, k_pages, v_pages,
         k_new, v_new, k_scales, v_scales, has_ctx=ctx_pages != 0,
-        interpret=interpret)
+        window=window, interpret=interpret)
     return jnp.where(valid[:, None, None], flat,
                      jnp.zeros_like(flat)).astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("has_ctx", "interpret"))
+# Mosaic's default scoped VMEM, and what a kernel may ask of the 128
+# MiB a v5e core has
+_VMEM_DEFAULT, _VMEM_MOST = 16 << 20, 96 << 20
+
+
+def _vmem_limit(q_blk: int, h: int, kvh: int, group: int, d: int,
+                n_keys: int, itemsize: int) -> dict:
+    """`vmem_limit_bytes` for a geometry whose scratch outgrows the
+    default: the q and output blocks, a kv head's queries, the
+    accumulator and the two statistics (one lane used of 128) grow with
+    q_blk x heads, so 48 query heads over 8 kv heads (a group of 6)
+    want ~15 MiB where a group of 2 wants ~6. Nothing for a geometry
+    inside the default: its compiler parameters are what they were."""
+    r = q_blk * group
+    need = (2 * q_blk * h * d * itemsize            # q, output blocks
+            + kvh * r * d * itemsize                # q per kv head
+            + kvh * r * d * 4 + 2 * kvh * r * 128 * 4   # acc, m, l
+            + 2 * kvh * n_keys * d * itemsize       # k, v per kv head
+            + _KV_VMEM_BYTES + 2 * q_blk * kvh * d * itemsize)
+    if need <= _VMEM_DEFAULT * 3 // 4:
+        return {}
+    return {"vmem_limit_bytes": min(2 * need + (8 << 20), _VMEM_MOST)}
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("has_ctx", "window", "interpret"))
 def _ragged_call(items, segs, tables, q, k_pages, v_pages, k_new, v_new,
-                 k_scales, v_scales, *, has_ctx: bool, interpret: bool):  # jaxlint: disable=JL002 -- the pools are read, never written: an inner jit that shares the kernel's trace, inlined into the engine's program, which donates them
+                 k_scales, v_scales, *, has_ctx: bool,
+                 window: Optional[int] = None, interpret: bool):  # jaxlint: disable=JL002 -- the pools are read, never written: an inner jit that shares the kernel's trace, inlined into the engine's program, which donates them
     """The pallas_call of `ragged_paged_attention_pallas`, [T, H, D]
     out (invalid rows not yet zeroed). A jit of its own, so that its
     trace is shared: a serving engine builds one program per (token
@@ -622,7 +770,7 @@ def _ragged_call(items, segs, tables, q, k_pages, v_pages, k_new, v_new,
             _ragged_paged_kernel, page_size=page_size, ppb=ppb,
             n_ctx_pages=n_ctx_pages, q_blk=q_blk, small=small,
             scale=head_dim ** -0.5, kvh=kvh, group=group,
-            quantized=quantized),
+            quantized=quantized, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(items.shape[1],),
@@ -633,8 +781,11 @@ def _ragged_call(items, segs, tables, q, k_pages, v_pages, k_new, v_new,
         out_shape=jax.ShapeDtypeStruct((t + q_blk, h, d), q.dtype),
         # the items' output writes overlap and rely on their order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            **_vmem_limit(q_blk, h, kvh, group, d, n_keys,
+                          q.dtype.itemsize)),
         interpret=interpret,
-        name="ragged_paged_attention",
+        name=("ragged_paged_attention" if window is None
+              else "ragged_window_attention"),
     )(items, segs, tables, *inputs)
     return _fit_lanes(out[:t], head_dim)               # [T, H, D]

@@ -557,6 +557,8 @@ def test_train_step_carries_the_layer_scopes():
 
 KERNEL_NAMES = {
     "ragged_paged_attention": "ray_tpu/ops/ragged_paged_attention.py",
+    # the same call site on a sliding-window layer (`window=`, PR 31)
+    "ragged_window_attention": "ray_tpu/ops/ragged_paged_attention.py",
     "paged_decode": "ray_tpu/ops/paged_attention.py",
     "paged_decode_mp": "ray_tpu/ops/paged_attention.py",
     "flash_fwd": "ray_tpu/ops/attention.py",
@@ -572,9 +574,13 @@ def test_every_pallas_call_site_passes_a_stable_name():
     for path in sorted(set(KERNEL_NAMES.values())):
         src = open(os.path.join(root, path)).read()
         calls = src.count("pl.pallas_call(")
-        names = re.findall(r'\n\s+name="(\w+)",\n', src)
-        assert calls == len(names), f"{path}: a pallas_call without name="
-        found.update({n: path for n in names})
+        # a literal, or a choice between literals by a static argument
+        named = re.findall(r'\n\s+name=((?:.|\n)*?),\n', src)
+        assert calls == len(named), f"{path}: a pallas_call without name="
+        for arg in named:
+            names = re.findall(r'"(\w+)"', arg)
+            assert names, f"{path}: name={arg} is no literal"
+            found.update({n: path for n in names})
     assert found == KERNEL_NAMES
 
 

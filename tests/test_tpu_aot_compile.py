@@ -208,6 +208,57 @@ def test_ragged_kernel_compiles_at_the_cells_shapes(v5e, T, ctx, kvh,
         T=T, slots=32, ctx_pages=ctx, table=512, pages=2048).compile()
 
 
+def _trinity_kernel_lowering(S, T, window, has_ctx):
+    """The work-list kernel as `trinity-mixed` runs it: 48 query heads
+    over 8 kv heads (a group of 6, where chat-open's is 2), head_dim
+    128, a cache group's bf16 pools WHOLE and flattened over its layers
+    (the layer's index rides in the page table), 32 slots, a table
+    1,024 pages wide; window 4,096 names it `ragged_window_attention`."""
+    kvh, group, d = 8, 6, 128
+    pages = 7 * 6144 if window else 2 * 12288
+    pool = S((pages, PAGE, kvh, d), jnp.bfloat16)
+    new = S((T, kvh, d), jnp.bfloat16)
+    i32 = lambda *shape: S(shape, jnp.int32)
+
+    def run(q, kp, vp, tables, slots, pos, valid, start, kn, vn):
+        return ragged_paged_attention_pallas(
+            q, kp, vp, tables, slots, pos, valid, start, kn, vn,
+            ctx_pages=-1 if has_ctx else 0, window=window)
+
+    return jax.jit(run).lower(
+        S((T, kvh * group, d), jnp.bfloat16), pool, pool, i32(32, 1024),
+        i32(T), i32(T), S((T,), jnp.bool_), i32(32), new, new)
+
+
+@pytest.mark.parametrize("T,window,has_ctx", [
+    (8, 4096, True), (32, 4096, True), (32, None, True),
+    (128, 4096, True), (512, 4096, True), (512, 4096, False),
+    (512, None, True), (512, None, False)])
+def test_window_kernel_compiles_at_the_cells_shapes(v5e, T, window,
+                                                    has_ctx):
+    """A group of 6 makes a 128-row query block 768 score rows a kv
+    head: its scratch outgrows Mosaic's default scoped VMEM (refused:
+    'Ran out of memory in memory space vmem') and the kernel asks for
+    what it needs (`_vmem_limit`); T = 32 is the decode tick. The
+    pools are read where they lie."""
+    compiled = _trinity_kernel_lowering(_on(v5e[0]), T, window,
+                                        has_ctx).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    name = ("ragged_window_attention" if window
+            else "ragged_paged_attention")
+    assert f"{name}" in compiled.as_text()
+
+
+def test_a_group_of_two_keeps_its_compiler_parameters():
+    """chat-open's geometry asks for no VMEM limit: its kernel's
+    compiler parameters, and so its programs, are what they were."""
+    from ray_tpu.ops.ragged_paged_attention import _vmem_limit
+    assert _vmem_limit(128, 16, 8, 2, 128, 128, 2) == {}
+    assert _vmem_limit(128, 8, 2, 4, 128, 128, 2) == {}
+    big = _vmem_limit(128, 48, 8, 6, 128, 128, 2)
+    assert 32 << 20 < big["vmem_limit_bytes"] <= 96 << 20
+
+
 @pytest.mark.parametrize("T,has_ctx", [(8, True), (64, True),
                                        (512, True), (512, False)])
 def test_mla_kernel_compiles_at_the_cells_shapes(v5e, T, has_ctx):
