@@ -3823,13 +3823,19 @@ class InferenceEngine:
 
     def _profile_tick_begin(self) -> None:
         """Start the armed jax.profiler trace (called under the step
-        lock at tick entry; no-op unless freshly armed)."""
+        lock at tick entry; no-op unless freshly armed). While another
+        session is open (an operator's, the benchmark's) the capture
+        stays armed and starts at the first tick after it: starting
+        under the step lock behind that session's export held every
+        stream 23 and 40 s (PERF.md section 6, PR 32)."""
         ps = self._profile
         if ps is None or ps["cm"] is not None or ps["writer"] is not None:
             return
         from ...util import profiling
-        cm = profiling.trace(ps["dir"])
         try:
+            if profiling.session_open():
+                return
+            cm = profiling.trace(ps["dir"])
             cm.__enter__()
         except Exception as e:   # profiler unavailable on this backend
             self._profile = None

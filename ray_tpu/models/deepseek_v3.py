@@ -20,8 +20,11 @@ modelling code (https://huggingface.co/deepseek-ai/DeepSeek-V3):
   over ALL published experts, the shared expert's SwiGLU, and
   ops/moe.held_experts_ffn over `experts_held`, a contiguous range of
   the routed experts: the chip's share of an expert-parallel
-  deployment. What absent experts would add is left out and the partial
-  sum goes on; nothing stands in for the absent chips.
+  deployment, as one grouped product a projection over the tick's
+  assignments sorted by held expert (what the router sent here is what
+  is computed and read: an expert without a token is not touched). What
+  absent experts would add is left out and the partial sum goes on;
+  nothing stands in for the absent chips.
 
 Departures from the published code: rotate-half rope pairing (the
 published pairing is interleaved; a checkpoint's rope columns of W_qb
@@ -51,7 +54,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import mla_attention as mla_ops
-from ..ops.moe import held_experts_ffn, held_gates, sigmoid_group_routing
+from ..ops.moe import (held_experts_ffn, held_gates, platform_impl,
+                       sigmoid_group_routing)
 from .llama import rms_norm
 
 
@@ -347,11 +351,14 @@ def swiglu(w, y):
     return (jax.nn.silu(y @ w["wg"]) * (y @ w["wi"])) @ w["wd"]
 
 
-def moe_block(cfg: DeepseekV3Config, layer, y, valid=None):
+def moe_block(cfg: DeepseekV3Config, layer, y, valid=None,
+              impl: Optional[str] = None):
     """y: [T, H] normalised -> (the expert layer's output [T, H]: the
     shared expert plus the held experts' part of the routed sum; the
     assignments of `valid` rows landed on each held expert [n_held]
-    int32)."""
+    int32). `impl` is the forward's (`_stack` passes
+    the engine's); a caller with no engine (a check of one block)
+    leaves it out and gets `ops/moe.platform_impl()`."""
     lo, hi = cfg.held
     # inside the `mlp` scope of `_stack`: the span tables' split of a
     # layer is attn / mlp, these names split the expert layer further
@@ -361,13 +368,14 @@ def moe_block(cfg: DeepseekV3Config, layer, y, valid=None):
             n_group=cfg.n_group, topk_group=cfg.topk_group,
             top_k=cfg.moe_top_k, scale=cfg.routed_scaling_factor,
             normalize=cfg.norm_topk_prob)
-        gates, counts = held_gates(idx, w, lo, hi, valid)
+        gates, took, counts = held_gates(idx, w, lo, hi, valid)
     with jax.named_scope("moe_shared"):
         out = swiglu(layer["shared"], y)
     with jax.named_scope("moe_experts"):
         ex = layer["experts"]
-        routed = held_experts_ffn(y, gates, counts, ex["wg"], ex["wi"],
-                                  ex["wd"])
+        routed = held_experts_ffn(y, gates, took, ex["wg"], ex["wi"],
+                                  ex["wd"], picks=cfg.moe_top_k,
+                                  impl=impl or platform_impl())
     return out + routed.astype(out.dtype), counts
 
 
@@ -396,8 +404,10 @@ def routing_summary(cfg: DeepseekV3Config, landed, tokens_routed: int
 
 # ------------------------------------------------------------------- forwards
 
-def _stack(cfg: DeepseekV3Config, params, x, positions, valid, attend):
-    """Every layer in turn. attend(q, new_rows, layer_index) -> o_lat.
+def _stack(cfg: DeepseekV3Config, params, x, positions, valid, attend,
+           impl: str):
+    """Every layer in turn. attend(q, new_rows, layer_index) -> o_lat;
+    `impl` is the held experts' as it is the attention's.
     Returns (x, cache rows [L, T, latent_width], expert counts
     [n_moe_layers, n_held])."""
     cos, sin = rope_cos_sin(cfg, positions)
@@ -412,7 +422,7 @@ def _stack(cfg: DeepseekV3Config, params, x, positions, valid, attend):
         with jax.named_scope("mlp"):
             y = rms_norm(x, layer["ln2"], cfg.norm_eps)
             if "router" in layer:
-                out, landed = moe_block(cfg, layer, y, valid)
+                out, landed = moe_block(cfg, layer, y, valid, impl)
                 counts.append(landed)
             else:
                 out = swiglu(layer, y)
@@ -479,7 +489,8 @@ def ragged_forward(cfg: DeepseekV3Config, params: Dict[str, Any],
         x = params["embed"][tokens].astype(cfg.dtype)
     attend = cache_attention(cfg, impl, pool, page_tables, slot_ids,
                              positions, valid, start, ctx_pages)
-    x, rows, counts = _stack(cfg, params, x, positions, valid, attend)
+    x, rows, counts = _stack(cfg, params, x, positions, valid, attend,
+                             impl)
     pool = mla_ops.scatter_latent(pool, rows, page_tables[slot_ids],
                                   positions, valid)
     with jax.named_scope("lm_head"):

@@ -20,7 +20,9 @@ for layer l of kind `layer_types[l]`:
   ops/moe.sigmoid_group_routing over ALL published experts (one group),
   the shared expert's SwiGLU, and ops/moe.held_experts_ffn over
   `experts_held`, a contiguous range of the routed experts: the chip's
-  share of an expert-parallel deployment. What absent experts would
+  share of an expert-parallel deployment, as one grouped product a
+  projection over the tick's assignments sorted by held expert (an
+  expert without a token is not touched). What absent experts would
   add is left out and the partial sum goes on; nothing stands in for
   the absent chips.
 - Final RMSNorm, then the head over the vocabulary rows held.
@@ -41,12 +43,13 @@ pair and a page table a group, as tuples in that order.
 The stack is a list of one tree a layer (`params["layers"]`, as the
 latent family's is) and the forward a loop over it, not a `lax.scan`
 over whole periods of stacked trees: scanned, every tick copies each
-layer's held experts out of the stack before the expert layer's
-`lax.cond` may take them (24 x 302 MB by the TPU compiler's analysis of
-the 9-layer cut's 512-token program, 1.1 GB of temporaries against
-0.2), while the unrolled program compiles in 15 to 19 s against 13 to
-14 (XLA:TPU and Mosaic, for a v5e without the chip): depth costs the
-compile little here. The pools go whole
+layer's held experts out of the stack before the expert layer may take
+them (24 x 302 MB by the TPU compiler's analysis of PR 31's 9-layer
+cut's 512-token program, 1.1 GB of temporaries against 0.2; a kernel
+wants its operand whole as much as that tree's `lax.cond` did), while
+the unrolled program compiles in 15 to 19 s against 13 to 14 (XLA:TPU
+and Mosaic, for a v5e without the chip): depth costs the compile little
+here. The pools go whole
 to the attention kernel with the layer's index folded into the page
 table (a pool sliced by layer is copied first), and a tick's rows are
 scattered once, after the stack.
@@ -63,7 +66,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import ragged_paged_attention as rpa
-from ..ops.moe import held_experts_ffn, held_gates, sigmoid_group_routing
+from ..ops.moe import (held_experts_ffn, held_gates, platform_impl,
+                       sigmoid_group_routing)
 from ..ops.paged_attention import _fit_lanes
 from .cache_row import CacheGroup, CacheRow
 from .deepseek_v3 import _rope, swiglu
@@ -346,29 +350,33 @@ def attn_output(cfg: TrinityConfig, layer, o, g):
     return o @ layer["wo"]
 
 
-def moe_block(cfg: TrinityConfig, layer, y, valid=None):
+def moe_block(cfg: TrinityConfig, layer, y, valid=None,
+              impl: Optional[str] = None):
     """y: [T, H] normalised -> (the expert layer's output [T, H]: the
     shared expert plus the held experts' part of the routed sum; the
     assignments of `valid` rows landed on each held expert [n_held]
-    int32). One routing group of all the experts."""
+    int32). One routing group of all the experts. `impl` is the forward's (`_stack` passes
+    the engine's); a caller with no engine (a check of one block)
+    leaves it out and gets `ops/moe.platform_impl()`."""
     lo, hi = cfg.held
     with jax.named_scope("moe_router"):
         w, idx = sigmoid_group_routing(
             y, layer["router"], layer["router_bias"], n_group=1,
             topk_group=1, top_k=cfg.moe_top_k, scale=cfg.route_scale,
             normalize=cfg.route_norm)
-        gates, counts = held_gates(idx, w, lo, hi, valid)
+        gates, took, counts = held_gates(idx, w, lo, hi, valid)
     with jax.named_scope("moe_shared"):
         out = swiglu(layer["shared"], y)
     with jax.named_scope("moe_experts"):
         ex = layer["experts"]
-        routed = held_experts_ffn(y, gates, counts, ex["wg"], ex["wi"],
-                                  ex["wd"])
+        routed = held_experts_ffn(y, gates, took, ex["wg"], ex["wi"],
+                                  ex["wd"], picks=cfg.moe_top_k,
+                                  impl=impl or platform_impl())
     return out + routed.astype(out.dtype), counts
 
 
 def _layer(cfg: TrinityConfig, layer, x, kind: str, cos, sin, valid,
-           attend, gi):
+           attend, gi, impl: str):
     """One layer. attend(q, k, v, kind, index in the kind's group) -> o.
     Returns (x, the tick's k rows, v rows, expert counts or None)."""
     with jax.named_scope("attn"), jax.named_scope(
@@ -380,21 +388,22 @@ def _layer(cfg: TrinityConfig, layer, x, kind: str, cos, sin, valid,
     with jax.named_scope("mlp"):
         y = rms_norm(x, layer["ln_pre_mlp"], cfg.norm_eps)
         if "router" in layer:
-            f, counts = moe_block(cfg, layer, y, valid)
+            f, counts = moe_block(cfg, layer, y, valid, impl)
         else:
             f = swiglu(layer, y)
         x = x + rms_norm(f, layer["ln_post_mlp"], cfg.norm_eps)
     return x, k, v, counts
 
 
-def _stack(cfg: TrinityConfig, params, x, positions, valid, attend):
+def _stack(cfg: TrinityConfig, params, x, positions, valid, attend,
+           impl: str):
     """Every layer in turn. Returns (x, k rows [L, T, kv heads, d], v
     rows, expert counts [n_moe_layers, n_held])."""
     cos, sin = rope_cos_sin(cfg, positions)
     ks, vs, counts = [], [], []
     for li, (layer, kind) in enumerate(zip(params["layers"], cfg.kinds)):
         x, k, v, landed = _layer(cfg, layer, x, kind, cos, sin, valid,
-                                 attend, cfg.group_index(li))
+                                 attend, cfg.group_index(li), impl)
         ks.append(k)
         vs.append(v)
         if landed is not None:
@@ -493,7 +502,8 @@ def ragged_forward(cfg: TrinityConfig, params: Dict[str, Any],
              * cfg.embed_scale).astype(cfg.dtype)
     attend = cache_attention(cfg, impl, k_pages, v_pages, page_tables,
                              slot_ids, positions, valid, start, ctx_pages)
-    x, ks, vs, counts = _stack(cfg, params, x, positions, valid, attend)
+    x, ks, vs, counts = _stack(cfg, params, x, positions, valid, attend,
+                               impl)
     new_k, new_v = [], []
     for g, kind in enumerate((FULL, SLIDING)[:len(k_pages)]):
         of = np.asarray(cfg.layers_of(kind))         # static

@@ -19,6 +19,10 @@ Two dispatch modes:
   ``ragged_all_to_all`` ICI collective; CPU tests: semantics-exact
   emulation). SURVEY §2.4's EP target (`ragged_all_to_all`-style,
   VERDICT r4 weak #7).
+
+Serving (below the two training paths): sigmoid group-limited routing
+and `held_experts_ffn`, the expert layer of a chip that holds a range
+of the routed experts; its kernels are in ``ops/grouped_experts.py``.
 """
 
 from __future__ import annotations
@@ -31,6 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.sharding import with_logical_constraint as wlc
+from . import grouped_experts as ge
 from .ragged_exchange import exchange_offsets, ragged_all_to_all
 
 
@@ -326,71 +331,84 @@ def sigmoid_group_routing(x: jax.Array, router_w: jax.Array,
 
 def held_gates(idx: jax.Array, w: jax.Array, lo: int, hi: int,
                valid: Optional[jax.Array] = None
-               ) -> Tuple[jax.Array, jax.Array]:
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The picks that fall on the experts held here, [lo, hi): (gate
     matrix [T, hi - lo] float32, zero where a token did not pick the
-    expert; assignments landed on each held expert [hi - lo] int32).
-    Rows that are not `valid` (a tick's padding) pick nothing."""
+    expert; that mask itself [T, hi - lo] bool, the tick's assignments;
+    assignments landed on each held expert [hi - lo] int32). Rows that
+    are not `valid` (a tick's padding) pick nothing."""
     hit = (idx[..., None] - lo) == jnp.arange(hi - lo)       # [T, k, E]
     if valid is not None:
         hit = hit & valid[:, None, None]
     gates = jnp.sum(jnp.where(hit, w[..., None], 0.0), axis=1)
-    return gates, jnp.sum(hit, axis=(0, 1)).astype(jnp.int32)
+    took = jnp.any(hit, axis=1)
+    return gates, took, jnp.sum(took, axis=0).astype(jnp.int32)
 
 
-def _swiglu_grouped(xs, wg, wi, wd):
-    """xs: [E, C, H] rows per expert, or [T, H] rows that every expert
-    takes -> [E, C, H] float32: one batched (grouped) matrix product
-    per projection over the experts held."""
-    f32 = jnp.float32
-    into = "ech,ehf->ecf" if xs.ndim == 3 else "ch,ehf->ecf"
-    g = jnp.einsum(into, xs, wg, preferred_element_type=f32)
-    u = jnp.einsum(into, xs, wi, preferred_element_type=f32)
-    h = (jax.nn.silu(g) * u).astype(xs.dtype)
-    return jnp.einsum("ecf,efh->ech", h, wd, preferred_element_type=f32)
+HELD_IMPLS = ("pallas", "pallas_interpret", "gather")
 
 
-# rows a held expert's tokens are gathered into: four times the 16 a
-# 512-token tick sends a held expert of a balanced router on average
-ROWS_PER_EXPERT = 64
+def platform_impl() -> str:
+    """The `impl` of a caller that has no engine to ask (a check or a
+    test of one `moe_block`): what the engine's `_resolve_impl` makes
+    of "auto", by the same question, so the two agree on every
+    platform."""
+    return "gather" if jax.devices()[0].platform == "cpu" else "pallas"
 
 
-def held_experts_ffn(x: jax.Array, gates: jax.Array, counts: jax.Array,
-                     wg: jax.Array, wi: jax.Array,
-                     wd: jax.Array) -> jax.Array:
+def held_experts_ffn(x: jax.Array, gates: jax.Array, took: jax.Array,
+                     wg: jax.Array, wi: jax.Array, wd: jax.Array, *,
+                     picks: int, impl: str) -> jax.Array:
     """The held experts' part of an expert layer's output: for each
     token the gate-weighted sum of the SwiGLU of those of its picks that
-    are held here. x: [T, H]; gates: [T, E] and counts: [E] from
-    `held_gates`; wg/wi: [E, H, F], wd: [E, F, H]. Returns [T, H]
-    float32. What absent experts would add is left out.
+    are held here. x: [T, H]; gates and took: [T, E] from `held_gates`;
+    wg/wi: [E, H, F], wd: [E, F, H]; `picks`: the router's picks a
+    token. Returns [T, H] float32. What absent experts would add is left
+    out.
 
-    Grouped matrix products over the experts held, at one of two static
-    sizes a tick: when no held expert received more than
-    ROWS_PER_EXPERT tokens, each expert's tokens are gathered into that
-    many rows and the product runs over E x ROWS_PER_EXPERT rows;
-    otherwise, and in a tick of at most that many tokens, over all
-    E x T rows with zero gates. Either way every assignment is computed:
-    there is no capacity and nothing is dropped. Which form a tick takes
-    follows the router's balance (PERF.md section 5 has what each costs
-    the cell)."""
+    One grouped matrix product a projection over the tick's assignments
+    sorted by held expert (`grouped_experts.assignment_rows`): the rows
+    of x that picked expert e form group e of at most T * min(picks, E)
+    rows, through gate and up, the SwiGLU cast to the operands' type,
+    and down in float32, each row times its gate added to its token. Its
+    size is what the tick's router sent here: rows past the last
+    assignment are not computed and an expert that received nothing is
+    not read; every assignment is computed, there is no capacity and
+    nothing is dropped.
+
+    `impl` is the caller's, resolved (the engine's `_resolve_impl`, or
+    `platform_impl` for a caller with no engine), and splits chip from
+    host as the attention kernels' does: "pallas" is
+    `grouped_experts.grouped_swiglu` (its grid visits the row tiles
+    that hold assignments), "pallas_interpret" the same kernels
+    interpreted, "gather" `lax.ragged_dot` over the same sorted rows:
+    what a CPU runs and what the checks hold the kernels to, on the
+    chip 2-3 x the kernels' time at 512 tokens and not a serving
+    path. Any other value is refused."""
+    if impl not in HELD_IMPLS:
+        raise ValueError(f"held_experts_ffn: impl {impl!r} is none of "
+                         f"{HELD_IMPLS}")
     t, _ = x.shape
-
-    def every_row(_):
-        y = _swiglu_grouped(x, wg, wi, wd)                   # [E, T, H]
-        return jnp.einsum("eth,te->th", y, gates)
-
-    if t <= ROWS_PER_EXPERT:
-        return every_row(None)
-
-    def gathered(_):
-        # per expert its assigned tokens first (a gate can be any
-        # positive number, so rank on assignment, not on the gate)
-        took, tok = lax.top_k((gates.T > 0).astype(jnp.int32),
-                              ROWS_PER_EXPERT)               # [E, C]
-        g = jnp.take_along_axis(gates.T, tok, axis=1) * took
-        y = _swiglu_grouped(x[tok], wg, wi, wd) * g[..., None]
-        return jnp.zeros((t, x.shape[1]), jnp.float32).at[
-            tok.reshape(-1)].add(y.reshape(-1, x.shape[1]))
-
-    return lax.cond(jnp.max(counts) <= ROWS_PER_EXPERT, gathered,
-                    every_row, None)
+    e = took.shape[1]
+    rows = t * min(picks, e)
+    place, offsets = ge.assignment_rows(took)
+    if impl in ("pallas", "pallas_interpret"):
+        return ge.grouped_swiglu(x, gates, place, offsets, wg, wi, wd,
+                                 rows=rows,
+                                 interpret=(impl == "pallas_interpret"))
+    f32 = jnp.float32
+    # the same rows, the other way round: each row's token and gate (a
+    # row past the last assignment is token T, dropped on the way back)
+    at = jnp.where(took, place, rows).reshape(-1)
+    tok = jnp.full((rows,), t, jnp.int32).at[at].set(
+        jnp.repeat(jnp.arange(t, dtype=jnp.int32), e), mode="drop")
+    gate = jnp.zeros((rows,), f32).at[at].set(gates.reshape(-1),
+                                              mode="drop")
+    sizes = offsets[1:] - offsets[:-1]
+    xs = jnp.take(x, tok, axis=0, mode="clip")
+    g = lax.ragged_dot(xs, wg, sizes, preferred_element_type=f32)
+    u = lax.ragged_dot(xs, wi, sizes, preferred_element_type=f32)
+    y = lax.ragged_dot((jax.nn.silu(g) * u).astype(x.dtype), wd, sizes,
+                       preferred_element_type=f32)
+    return jnp.zeros((t, x.shape[1]), f32).at[tok].add(
+        y * gate[:, None], mode="drop")

@@ -50,6 +50,24 @@ def memory_summary() -> dict:
     return summary
 
 
+def session_open() -> bool:
+    """Is a jax.profiler session open in this process, someone else's
+    or one still being written? `start_trace` then either raises or,
+    while `stop_trace` exports (it holds the profiler's lock for the
+    whole export: 14 to 40 s after a 4 s capture of a serving engine,
+    PERF.md section 6), waits that long. jax has no public question for
+    this; the session object is what `start_trace` itself tests. Where
+    a jax keeps it elsewhere the answer is "not known", False: the
+    caller goes on to `start_trace`, which refuses a second session
+    itself. A session that opens between this answer and the caller's
+    `start_trace` meets the same refusal."""
+    try:
+        from jax._src import profiler
+        return profiler._profile_state.profile_session is not None
+    except (ImportError, AttributeError):
+        return False
+
+
 @contextlib.contextmanager
 def trace(log_dir: str) -> Iterator[None]:
     """jax.profiler trace scope: the device's timeline and the host's

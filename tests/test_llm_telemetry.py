@@ -326,6 +326,9 @@ def test_profile_next_ticks_writes_trace():
     eng.profile_next_ticks(1, log_dir=d)
     eng.generate([rng.integers(2, 200, 8).tolist()],
                  SamplingParams(max_tokens=2))
+    # leave no export running: while one is, the next engine's armed
+    # capture waits for it (`profiling.session_open`, PR 32)
+    assert eng.wait_for_profile(60)
 
 
 def test_tick_does_not_wait_for_the_capture_to_be_written(monkeypatch):

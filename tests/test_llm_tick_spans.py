@@ -374,6 +374,71 @@ def test_self_captures_count_arming_starting_and_dumping(tmp_path):
     assert {"engine.step", "engine.dispatch"} <= names
 
 
+def test_an_armed_capture_waits_out_another_profiler_session(
+        tmp_path, monkeypatch):
+    """While someone else's jax.profiler session is open (or being
+    exported: the session object stays until the export ends), an armed
+    capture neither starts nor fails: `start_trace` under the step lock
+    would wait out that export. It starts at the first tick after."""
+    from jax._src import profiler as jax_profiler
+    eng = make_engine()
+    eng.add_request(_req("w", 12, max_tokens=8))
+    eng.step()
+    monkeypatch.setattr(jax_profiler._profile_state, "profile_session",
+                        object())
+    eng.profile_next_ticks(2, str(tmp_path / "prof"))
+    eng.step()
+    eng.step()
+    assert eng._profile is not None and eng._profile["cm"] is None
+    assert eng.stats()["self_captures"]["profiles_started"] == 0
+    monkeypatch.setattr(jax_profiler._profile_state, "profile_session",
+                        None)
+    while eng.has_work():
+        eng.step()
+    assert eng.wait_for_profile(60)
+    assert eng.stats()["self_captures"]["profiles_started"] == 1
+
+
+def test_session_open_is_not_known_where_jax_keeps_it_elsewhere(
+        tmp_path, monkeypatch):
+    """The session object is jax's own (`jax._src.profiler`). Where a
+    jax has moved it `session_open` answers False, not an error, and
+    the armed capture goes on to `start_trace` as it did before."""
+    from jax._src import profiler as jax_profiler
+    assert profiling.session_open() is False
+    monkeypatch.delattr(jax_profiler, "_profile_state")
+    assert profiling.session_open() is False
+    eng = make_engine()
+    eng.add_request(_req("w", 12, max_tokens=6))
+    eng.step()
+    eng.profile_next_ticks(2, str(tmp_path / "prof"))
+    monkeypatch.undo()
+    while eng.has_work():
+        eng.step()
+    assert eng.wait_for_profile(60)
+    assert eng.stats()["self_captures"]["profiles_started"] == 1
+
+
+def test_a_fault_in_the_question_is_a_profile_error_not_a_failed_tick(
+        tmp_path, monkeypatch):
+    """Whatever `session_open` raises is recorded as the capture's
+    error, as `start_trace`'s own failure is: `step()` goes on."""
+    eng = make_engine()
+    eng.add_request(_req("w", 12, max_tokens=6))
+    eng.step()
+
+    def broken():
+        raise RuntimeError("no such state")
+    monkeypatch.setattr(profiling, "session_open", broken)
+    eng.profile_next_ticks(2, str(tmp_path / "prof"))
+    eng.step()
+    assert eng._profile is None
+    assert eng.stats()["self_captures"]["profiles_started"] == 0
+    errors = [e for e in eng.telemetry.recorder.events()
+              if e["event"] == "profile_error"]
+    assert errors and "no such state" in errors[-1]["error"]
+
+
 def test_request_timeline_records_engine_ticks():
     eng = make_engine()
     eng.add_request(_req("first", 12, max_tokens=3))

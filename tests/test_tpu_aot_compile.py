@@ -288,6 +288,35 @@ def test_mla_kernel_compiles_at_the_cells_shapes(v5e, T, has_ctx):
     assert compiled.memory_analysis().temp_size_in_bytes < 200 << 20
 
 
+@pytest.mark.parametrize("T,picks,hidden,ffn", [
+    (64, 8, 7168, 2048), (512, 8, 7168, 2048),     # dsv3-longchat
+    (32, 4, 3072, 3072), (512, 4, 3072, 3072)])    # trinity-mixed
+def test_grouped_experts_compile_at_the_cells_shapes(v5e, T, picks,
+                                                     hidden, ffn):
+    """`held_experts_ffn` by the kernels (`moe_grouped_up`,
+    `moe_grouped_down`) over 16 held experts at both expert-layer cells'
+    widths: a decode tick's row bound (64 x 8 = 512, 32 x 4 = 128) and a
+    512-token tick's (4,096 and 2,048). The grid's middle bound is read
+    on the device; the experts are read where they lie."""
+    from ray_tpu.ops.moe import held_experts_ffn
+    S = _on(v5e[0])
+    bf16 = jnp.bfloat16
+
+    def run(x, gates, took, wg, wi, wd):
+        return held_experts_ffn(x, gates, took, wg, wi, wd, picks=picks,
+                                impl="pallas")
+
+    compiled = jax.jit(run).lower(
+        S((T, hidden), bf16), S((T, 16), jnp.float32),
+        S((T, 16), jnp.bool_), S((16, hidden, ffn), bf16),
+        S((16, hidden, ffn), bf16), S((16, ffn, hidden), bf16)).compile()
+    text = compiled.as_text()
+    assert "moe_grouped_up" in text and "moe_grouped_down" in text
+    # the sorted rows and their SwiGLU (T * picks rows of hidden and of
+    # ffn), never a copy of an expert's 88 or 57 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 96 << 20
+
+
 REJECTED = {
     # kv_dtype, pool dtype, kv heads per shard
     "int8_kv": ("int8", jnp.int8, 8),
