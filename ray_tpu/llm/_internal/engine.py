@@ -732,7 +732,12 @@ class InferenceEngine:
         # tick's T and context bucket (ragged_work_counts)
         self._attn_geometry = (
             local_kvh, crow.padded_width, jnp.dtype(pool_dt).itemsize)
-        if impl == "pallas" and crow.kind == "kv":
+        # (a "rows" pool merges a page's tokens and heads in one axis
+        # because its head count is off the tile: the rule below is the
+        # [page, heads, width] page's, and tests/test_tpu_aot_compile.py
+        # holds the merged one against the compiler)
+        if impl == "pallas" and crow.kind == "kv" \
+                and crow.layout == "token":
             why = _pa.kernel_layout_error(self._kv_kind, local_kvh,
                                           pool_dt)
             if why is not None:
@@ -811,13 +816,20 @@ class InferenceEngine:
         # A family with several cache groups gets its pools (and its
         # page tables) as TUPLES, one entry a group; a one-group
         # family's programs see the arrays they always saw
-        def pools(g):
-            shape = g.spec.row.pool_shape(len(g.spec.layers),
-                                          g.num_pages, ec.page_size)
-            return tuple(jnp.zeros(shape, g.spec.row.dtype,
-                                   device=self._kv_sharding)
-                         for _ in range(g.spec.row.pools))
-        made = [pools(g) for g in self.cache.groups]
+        # A STATE group's arrays (`[its layers, slots, ...]`, a
+        # recurrent layer's state a slot) ride the same tuples behind
+        # the page groups' pools, in `cache_groups`' order: its first
+        # part in `k_pages`' entry, its second in `v_pages`'. They are
+        # donated through `jit_run` and `jit_step` with the pools, so a
+        # tick's state is the tick before's output, however deep the
+        # pipeline; a family with no state group is handed none
+        def pools(spec, num_pages=0):
+            return tuple(
+                jnp.zeros(shape, dt, device=self._kv_sharding)
+                for shape, dt in spec.array_shapes(
+                    num_pages, ec.page_size, ec.max_batch_size))
+        made = ([pools(g.spec, g.num_pages) for g in self.cache.groups]
+                + [pools(st.spec) for st in self.cache.states])
         if len(made) == 1:
             self.k_pages = made[0][0]
             self.v_pages = made[0][1] if crow.pools == 2 else None
@@ -1799,8 +1811,13 @@ class InferenceEngine:
         return impl
 
     def _ctx_bucket(self, start: int) -> int:
-        """Smallest power-of-two page count covering `start` tokens."""
+        """Smallest power-of-two page count covering `start` tokens;
+        the whole table for a family whose kernels read it whole
+        (`ModelFamily.whole_table_kernels`), off the gather path."""
         need = self.allocator.pages_needed(start)
+        if (need and self.family.whole_table_kernels
+                and self._resolve_impl() != "gather"):
+            return self.max_pages_per_seq
         b = 1
         while b < need:
             b *= 2

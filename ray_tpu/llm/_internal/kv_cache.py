@@ -237,6 +237,33 @@ PREFIX_CACHE_OFF_FOR_WINDOWS = (
     "off: a window group hands pages back as a sequence moves past "
     "them, so a cached chain of the full group has no window pages to "
     "resume on; a family with a window group matches nothing")
+PREFIX_CACHE_OFF_FOR_STATE = (
+    "off: a resume at token m needs the recurrent state as it stood at "
+    "m, and a state group keeps one state a slot, the newest; no "
+    "snapshot is taken at page boundaries, so a family with a state "
+    "group matches nothing")
+
+
+class _SlotState:
+    """A state group at run time: which slots hold it. It has arrays
+    `[layers, slots, ...]` on the device (the engine's), no allocator
+    and no table: a slot's state is its own from admission to vacate,
+    and a sequence that starts at token 0 starts from zeros INSIDE the
+    tick's program, so nothing is cleared here or on the device."""
+
+    def __init__(self, spec: CacheGroup, n_slots: int):
+        self.spec = spec
+        self.n_slots = n_slots
+        self.held = [False] * n_slots
+        self.peak_held = 0
+        self.held_at_peak = 0
+
+    @property
+    def n_held(self) -> int:
+        return sum(self.held)
+
+    def note_peak(self) -> None:
+        self.peak_held = max(self.peak_held, self.n_held)
 
 
 class _GroupState:
@@ -303,12 +330,29 @@ class CacheManager:
 
     Prefix cache: a resume at token m needs the window group's pages
     over (m - w, m] too, and those are gone; with a window group the
-    cache is off in every group (`PREFIX_CACHE_OFF_FOR_WINDOWS`)."""
+    cache is off in every group (`PREFIX_CACHE_OFF_FOR_WINDOWS`).
+
+    A STATE group (`CacheGroup.state`: a recurrent layer's fixed bytes a
+    slot) comes after the page groups; `states` holds them. It is held
+    from `admit` to `vacate`, counts in `can_admit`, `bytes_used`, the
+    peaks and `stats()["cache_groups"]`, and has no pages: `groups`,
+    `tables` and the top-level page counts are the PAGE groups' alone.
+    With one, the prefix cache is off too
+    (`PREFIX_CACHE_OFF_FOR_STATE`)."""
 
     def __init__(self, groups: Sequence[CacheGroup],
                  num_pages: Sequence[int], page_size: int, n_slots: int,
                  table_width: int, tick_tokens: int,
                  enable_prefix_caching: bool = True):
+        states = [g for g in groups if g.state is not None]
+        n_paged = len(groups) - len(states)
+        if any(g.state is not None for g in groups[:n_paged]) \
+                or not n_paged:
+            raise ValueError(
+                "state groups come after the page groups, and a family "
+                "has at least one page group")
+        # (a state group's entry of `num_pages` counts nothing)
+        groups, num_pages = groups[:n_paged], list(num_pages)[:n_paged]
         if groups[0].window is not None or any(
                 g.window is None for g in groups[1:]):
             raise ValueError(
@@ -319,12 +363,15 @@ class CacheManager:
         self.page_size = page_size
         self.tick_tokens = int(tick_tokens)
         self.windowed = len(groups) > 1
+        self.states = [_SlotState(g, n_slots) for g in states]
         self.prefix_cache = (
-            PREFIX_CACHE_OFF_FOR_WINDOWS if self.windowed
+            PREFIX_CACHE_OFF_FOR_STATE if self.states
+            else PREFIX_CACHE_OFF_FOR_WINDOWS if self.windowed
             else "on" if enable_prefix_caching else "off")
+        matching = (enable_prefix_caching and not self.windowed
+                    and not self.states)
         self.groups = [
-            _GroupState(g, n, page_size, n_slots, table_width,
-                        enable_prefix_caching and not self.windowed)
+            _GroupState(g, n, page_size, n_slots, table_width, matching)
             for g, n in zip(groups, num_pages)]
         self.first = self.groups[0].allocator
         self._rest = self.groups[1:]
@@ -358,6 +405,8 @@ class CacheManager:
         if self.reserve_pages(self.groups[0], tokens) - shared \
                 > self.first.free_pages:
             return False
+        if any(st.n_held >= st.n_slots for st in self.states):
+            return False
         return all(self.reserve_pages(g, tokens) <= g.admittable
                    for g in self._rest)
 
@@ -379,6 +428,9 @@ class CacheManager:
             g.tables[slot] = g.num_pages - 1
             g.lo[slot] = g.hi[slot] = max(
                 (pos - g.spec.window + 1) // self.page_size, 0)
+        for st in self.states:
+            st.held[slot] = True
+            st.note_peak()
         self.advance([(slot, pos)])
         return pages
 
@@ -389,6 +441,8 @@ class CacheManager:
         self._peak_bytes = 0
         for g in self.groups:
             g.peak_used = g.used_at_peak = g.allocator.used_pages
+        for st in self.states:
+            st.peak_held = st.held_at_peak = st.n_held
 
     def _note_bytes_peak(self) -> None:
         """Remember what every group held when the bytes in use, all
@@ -399,6 +453,8 @@ class CacheManager:
             self._peak_bytes = now
             for g in self.groups:
                 g.used_at_peak = g.allocator.used_pages
+            for st in self.states:
+                st.held_at_peak = st.n_held
 
     def vacate(self, slot: int) -> None:
         """`slot` is empty: whatever it holds beyond the first group
@@ -412,6 +468,8 @@ class CacheManager:
                 g.allocator.free(g.tables[slot, lo:hi].tolist())
             g.lo[slot] = g.hi[slot] = g.reserve[slot] = g.final[slot] = 0
             g.tables[slot] = 0
+        for st in self.states:
+            st.held[slot] = False
 
     # ---------------------------------------------------- window groups
     def advance(self, live: Iterable[Tuple[int, int]]) -> Tuple[int, int]:
@@ -461,9 +519,12 @@ class CacheManager:
         return [g.tables for g in self.groups]
 
     def bytes_used(self) -> int:
-        """Device bytes the pages in use hold, every group."""
-        return sum(g.allocator.used_pages * self.page_size
-                   * g.spec.bytes_per_token for g in self.groups)
+        """Device bytes the pages in use hold, every group, and what
+        the slots that hold a state group's state hold of it."""
+        return (sum(g.allocator.used_pages * self.page_size
+                    * g.spec.bytes_per_token for g in self.groups)
+                + sum(st.n_held * st.spec.bytes_per_slot
+                      for st in self.states))
 
     def page_bytes(self) -> int:
         """Device bytes of one page in every group (one group: a page
@@ -505,5 +566,12 @@ class CacheManager:
             if g.spec.window is not None:
                 d["pages_returned"] = g.returned
             groups.append(d)
+        for st in self.states:
+            st.note_peak()
+            groups.append({**st.spec.describe(),
+                           "slots_total": st.n_slots,
+                           "slots_held": st.n_held,
+                           "slots_peak": st.peak_held,
+                           "slots_at_peak": st.held_at_peak})
         out["cache_groups"] = groups
         return out

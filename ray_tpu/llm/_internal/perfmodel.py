@@ -159,7 +159,12 @@ class CostModel:
             self.head_flops = float(own["head_flops"])
             self.attn_flops_per_pair = float(own["attn_flops_per_pair"])
             self.weight_bytes = float(own["weight_bytes"])
+            # layers that only a row that samples runs (a cross-decoder
+            # behind one layer's cache): a chunk pays them once
+            self.row_flops = float(
+                own.get("gemm_flops_per_sampled_row", 0.0))
         else:
+            self.row_flops = 0.0
             self._llama_constants(cfg)
         if weight_bytes is not None:
             # an engine's: the bytes of the leaves it stores (serving
@@ -172,7 +177,10 @@ class CostModel:
         # storage type, and a quantized pool's per-(row, head) scales
         # The cache is priced a GROUP (models/cache_row.CacheGroup):
         # its layers' rows, read over the keys a query still sees (a
-        # window layer reads min(context, window) keys a row)
+        # window layer reads min(context, window) keys a row; a group
+        # that other layers READ costs its row again for each reader; a
+        # STATE group costs its bytes a slot once read and once written
+        # a row a tick, whatever the row's tokens)
         if cache_groups is None and cache_row is None:
             # no engine behind this model (a test's): the family's
             # groups as the model writes them
@@ -189,6 +197,10 @@ class CostModel:
         self.page_bytes = self.kv_bytes_per_token * self.page_size
         self._windowed = any(g.window is not None
                              for g in self.cache_groups)
+        self.state_bytes_per_row = float(
+            sum(g.bytes_per_slot for g in self.cache_groups))
+        self._read_bytes_per_token = float(
+            sum(g.read_bytes_per_token for g in self.cache_groups))
 
     def _llama_constants(self, cfg: LlamaConfig) -> None:
         h, L = cfg.hidden, cfg.n_layers
@@ -235,9 +247,10 @@ class CostModel:
         `ctx` keys into its sequence read, every group: a window group
         reads at most its window's keys and the n - 1 before them."""
         if not self._windowed:
-            return self.kv_bytes_per_token * self._ctx_read_tokens(ctx)
-        return float(sum(
-            g.bytes_per_token * self._ctx_read_tokens(
+            return (self._read_bytes_per_token
+                    * self._ctx_read_tokens(ctx))
+        return self.state_bytes_per_row + float(sum(
+            g.read_bytes_per_token * self._ctx_read_tokens(
                 ctx if g.window is None else min(ctx, g.window + n - 1))
             for g in self.cache_groups))
 
@@ -246,27 +259,32 @@ class CostModel:
         keep, summed over layers as a share of `attn_flops_per_pair`'s
         every-layer count: a window layer's query keeps at most its
         window."""
-        total = sum(len(g.layers) for g in self.cache_groups)
+        # the layers that attend: a page group's writers and readers
+        attending = [(g, len(g.layers) + len(g.readers))
+                     for g in self.cache_groups if g.state is None]
+        total = sum(k for _, k in attending)
         pairs = 0.0
-        for g in self.cache_groups:
+        for g, k in attending:
             kept = n * start + n * (n + 1) // 2
             if g.window is not None:
                 full = max(min(g.window - start, n), 0)
                 kept = (full * start + full * (full + 1) // 2
                         + (n - full) * g.window)
-            pairs += kept * len(g.layers) / total
+            pairs += kept * k / total
         return pairs
 
     def decode_cost(self, ctx: int) -> Dict[str, float]:
         """One decode token whose attention context is `ctx` tokens
         (cached + itself)."""
         return {
-            "flops_gemm": self.gemm_flops_per_token + self.head_flops,
+            "flops_gemm": (self.gemm_flops_per_token + self.row_flops
+                           + self.head_flops),
             "flops_attn": self.attn_flops_per_pair * (
                 self._windowed_pairs(ctx - 1, 1) if self._windowed
                 else ctx),
             "bytes_kv_read": self._kv_read_bytes(ctx - 1),
-            "bytes_kv_write": self.kv_bytes_per_token,
+            "bytes_kv_write": (self.kv_bytes_per_token
+                               + self.state_bytes_per_row),
         }
 
     def chunk_cost(self, start: int, n: int) -> Dict[str, float]:
@@ -278,10 +296,11 @@ class CostModel:
                  else n * start + n * (n + 1) // 2)
         return {
             "flops_gemm": n * self.gemm_flops_per_token
-            + self.head_flops,
+            + self.row_flops + self.head_flops,
             "flops_attn": self.attn_flops_per_pair * pairs,
             "bytes_kv_read": self._kv_read_bytes(start, n),
-            "bytes_kv_write": n * self.kv_bytes_per_token,
+            "bytes_kv_write": (n * self.kv_bytes_per_token
+                               + self.state_bytes_per_row),
         }
 
     def forward_flops(self, batch: int, seq: int) -> float:

@@ -10,11 +10,20 @@ how its totals read in `stats()`, and the engine options the family
 does not compose with (refused at construction with the reason, never
 half-run). Nothing here imports the engine: the engine imports this.
 
-Three families: `llama` (dense RoPE/GQA/SwiGLU decoders, `LlamaConfig`,
+Four families: `llama` (dense RoPE/GQA/SwiGLU decoders, `LlamaConfig`,
 programs unchanged), `deepseek_v3` (latent attention over a latent
-cache, expert layers with the experts held here, `DeepseekV3Config`)
-and `trinity` (sliding-window and full-attention layers over two page
-groups, gated QK-normed GQA attention, held experts, `TrinityConfig`).
+cache, expert layers with the experts held here, `DeepseekV3Config`),
+`trinity` (sliding-window and full-attention layers over two page
+groups, gated QK-normed GQA attention, held experts, `TrinityConfig`)
+and `phi4flash` (Mamba layers whose state is kept a slot beside a
+window page group and ONE layer's pages that eight layers read,
+differential attention, a tied head, a cross-decoder that only sampling
+rows run, `Phi4FlashConfig`).
+
+A family with a STATE group (`cache_row.CacheGroup.state`) gets that
+group's arrays in `k_pages` / `v_pages` behind its page groups' pools
+(its first part / its second) and returns them in the same places; the
+forwards of a family without one take and return what they always did.
 """
 
 from __future__ import annotations
@@ -58,6 +67,12 @@ class ModelFamily:
     storage_dtypes: Optional[Callable[[Any], Dict[str, Any]]] = None
     # engine options this family does not compose with: name -> reason
     refuses: Dict[str, str] = dataclasses.field(default_factory=dict)
+    # True: on the kernel path the forwards read `ctx_pages` only as
+    # zero or not (the kernels walk a row's own pages off the whole
+    # table), so the engine keeps ONE ragged program a token bucket
+    # there, not one a context bucket: those are one compile, but each
+    # was traced and lowered on its own at its first call
+    whole_table_kernels: bool = False
 
     def cache_row(self, cfg, impl: str, kv_kind: str = "f32") -> CacheRow:
         """The FIRST group's row, kept for its readers; `cache_groups`
@@ -134,7 +149,7 @@ DEEPSEEK_REFUSES = {
 def _families() -> Dict[type, ModelFamily]:
     """Configuration type -> its family (built on first use: the model
     modules import jax)."""
-    from . import deepseek_v3, llama, llama_infer, trinity
+    from . import deepseek_v3, llama, llama_infer, phi4flash, trinity
     return {
         llama.LlamaConfig: ModelFamily(
             name="llama", init_params=llama.init_params,
@@ -164,6 +179,17 @@ def _families() -> Dict[type, ModelFamily]:
             # the same counts of the same held-expert layer
             rider_summary=deepseek_v3.routing_summary,
             refuses=trinity.TRINITY_REFUSES),
+        phi4flash.Phi4FlashConfig: ModelFamily(
+            name="phi4flash", init_params=phi4flash.init_stacked,
+            ragged_forward=phi4flash.ragged_forward,
+            decode_step=phi4flash.decode_step,
+            cache_groups=phi4flash.cache_groups,
+            # the full layer's kernel: the dense family's count
+            work_counts=_llama_work_counts,
+            span_counts=phi4flash.span_counts,
+            storage_dtypes=phi4flash.storage_dtypes,
+            refuses=phi4flash.PHI4FLASH_REFUSES,
+            whole_table_kernels=True),
     }
 
 
@@ -200,8 +226,8 @@ def store_params(family: ModelFamily, cfg, params, shardings=None,
 
 
 def family_of(cfg) -> ModelFamily:
-    """The family that serves `cfg` (a LlamaConfig, a DeepseekV3Config
-    or a TrinityConfig)."""
+    """The family that serves `cfg` (a LlamaConfig, a DeepseekV3Config,
+    a TrinityConfig or a Phi4FlashConfig)."""
     for kind, family in _families().items():
         if isinstance(cfg, kind):
             return family
@@ -210,15 +236,17 @@ def family_of(cfg) -> ModelFamily:
 
 def resolve_config(model):
     """A preset name or a family's configuration -> the configuration.
-    Names are the dense family's presets, `deepseek_v3:<preset>` or
-    `trinity:<preset>`."""
-    from . import deepseek_v3, llama, trinity
+    Names are the dense family's presets, `deepseek_v3:<preset>`,
+    `trinity:<preset>` or `phi4flash:<preset>`."""
+    from . import deepseek_v3, llama, phi4flash, trinity
     if isinstance(model, (deepseek_v3.DeepseekV3Config,
-                          trinity.TrinityConfig)):
+                          trinity.TrinityConfig,
+                          phi4flash.Phi4FlashConfig)):
         return model
     if isinstance(model, str) and ":" in model:
         family, preset = model.split(":", 1)
-        named = {"deepseek_v3": deepseek_v3, "trinity": trinity}
+        named = {"deepseek_v3": deepseek_v3, "trinity": trinity,
+                 "phi4flash": phi4flash}
         if family in named:
             return named[family].config(preset)
     return llama.config(model)

@@ -159,7 +159,8 @@ def ragged_gather_paged_blocked(
         positions: jax.Array, valid: jax.Array, start: jax.Array,
         k_new: jax.Array, v_new: jax.Array, *,
         window: Optional[int] = None,
-        gather_rows: int = GATHER_ROWS) -> jax.Array:
+        gather_rows: int = GATHER_ROWS,
+        merged_rows: bool = False) -> jax.Array:
     """The ragged attention rule of `ragged_prefill_decode_attention`
     (window included) straight off a WHOLE pool, in blocks of tokens
     that each gather their own slots' pages of `layer` (a traced index
@@ -170,11 +171,13 @@ def ragged_gather_paged_blocked(
     it reads every table entry, also those of pages a window group has
     handed back, and masks them.
 
-    q: [T, H, D]; k_pool/v_pool: [L, P, page, KVH, Dp]; page_tables:
+    q: [T, H, D]; k_pool/v_pool: [L, P, page, KVH, Dp] (merged_rows:
+    [L, P, page * KVH, Dp], `CacheRow.layout` "rows"); page_tables:
     [B, n]; k_new/v_new: [T, KVH, D]. Returns [T, H, D] in q's type."""
     t, h, d = q.shape
-    n, page, kvh = page_tables.shape[1], k_pool.shape[2], k_pool.shape[3]
-    ctx, group = n * page, h // k_pool.shape[3]
+    n, kvh = page_tables.shape[1], k_new.shape[1]
+    page = k_pool.shape[2] // kvh if merged_rows else k_pool.shape[2]
+    ctx, group = n * page, h // kvh
     f32 = jnp.float32
     scale = d ** -0.5
     kn, vn = k_new.astype(q.dtype), v_new.astype(q.dtype)
@@ -382,7 +385,8 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
                          n_ctx_pages: int, q_blk: int, small: int,
                          scale: float, kvh: int, group: int,
                          quantized: bool = False,
-                         window: Optional[int] = None):
+                         window: Optional[int] = None,
+                         merged_rows: bool = False):
     """Grid (n_items,): one step per work item (slot, query block).
 
     A step reads its q_blk query rows from the flat batch where they
@@ -426,6 +430,17 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
     block DMAs the scale rows of its pages alongside the pages and
     folds the dequant — one broadcast multiply — into the upcast of
     the VMEM block. The fresh in-batch KV is never quantized.
+
+    merged_rows=True: the pools are [num_pages, page * KVH, D], a
+    page's (token, head) rows in one axis, token-major as ever: for a
+    number of kv heads that is no multiple of the 8-row tile (10),
+    which a [page, KVH, D] page pads to the next one in HBM (1.6 x the
+    bytes) and which a page DMA cannot slice. A token's write is still
+    KVH adjacent rows; a head's keys of a context block are every
+    KVH-th row of it, read with a strided load from a float32 copy of
+    the block (two more scratch buffers, last in `rest`). The in-batch
+    K and V stay [T, KVH, D], their heads padded to the tile by the
+    wrapper.
     """
     if quantized:
         (ks_hbm, vs_hbm, kn_hbm, vn_hbm, o_hbm, q_vmem, kn_vmem,
@@ -434,7 +449,8 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
     else:
         (kn_hbm, vn_hbm, o_hbm, q_vmem, kn_vmem, vn_vmem, o_vmem,
          k_vmem, v_vmem, kv_sem, io_sem, qh_scr, kh_scr, vh_scr, m_scr,
-         l_scr, acc_scr) = rest
+         l_scr, acc_scr) = rest[:17]
+        kf_scr, vf_scr = rest[17:] if merged_rows else (None, None)
     it = pl.program_id(0)
     slot = items_ref[0, it]
     bk = page_size * ppb
@@ -532,6 +548,18 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
             pl.when(blk + 1 < n_ctx)(
                 lambda: page_dma(blk + 1, 1 - buf, True))
             page_dma(blk, buf, False)
+            if merged_rows:
+                # (ppb, page * kvh, D): head h's keys are rows h, h +
+                # kvh, ... of the block
+                kf_scr[...] = k_vmem[buf].reshape(bk * kvh, d).astype(
+                    jnp.float32)
+                vf_scr[...] = v_vmem[buf].reshape(bk * kvh, d).astype(
+                    jnp.float32)
+                for h in range(kvh):
+                    every = pl.ds(h, bk, stride=kvh)
+                    kh_scr[h, :bk] = kf_scr[every, :].astype(cdt)
+                    vh_scr[h, :bk] = vf_scr[every, :].astype(cdt)
+                return
             kb = k_vmem[buf].astype(jnp.float32)   # (ppb, page, kvh, D)
             vb = v_vmem[buf].astype(jnp.float32)
             if quantized:
@@ -628,7 +656,7 @@ def ragged_paged_attention_pallas(
         k_scales: jax.Array = None, v_scales: jax.Array = None,
         work: Tuple[jax.Array, jax.Array] = None,
         window: Optional[int] = None,
-        interpret: bool = False) -> jax.Array:
+        interpret: bool = False, merged_rows: bool = False) -> jax.Array:
     """TPU Pallas ragged paged attention: same contract as
     `ragged_paged_prefill_decode_attention`, but each slot's KV pages
     are STREAMED through VMEM with online softmax — no [T, ctx] score
@@ -678,7 +706,7 @@ def ragged_paged_attention_pallas(
     flat = _ragged_call(
         items, segs, page_tables.astype(jnp.int32), q, k_pages, v_pages,
         k_new, v_new, k_scales, v_scales, has_ctx=ctx_pages != 0,
-        window=window, interpret=interpret)
+        window=window, interpret=interpret, merged_rows=merged_rows)
     return jnp.where(valid[:, None, None], flat,
                      jnp.zeros_like(flat)).astype(q.dtype)
 
@@ -708,10 +736,12 @@ def _vmem_limit(q_blk: int, h: int, kvh: int, group: int, d: int,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("has_ctx", "window", "interpret"))
+                   static_argnames=("has_ctx", "window", "interpret",
+                                    "merged_rows"))
 def _ragged_call(items, segs, tables, q, k_pages, v_pages, k_new, v_new,
                  k_scales, v_scales, *, has_ctx: bool,
-                 window: Optional[int] = None, interpret: bool):  # jaxlint: disable=JL002 -- the pools are read, never written: an inner jit that shares the kernel's trace, inlined into the engine's program, which donates them
+                 window: Optional[int] = None, interpret: bool,
+                 merged_rows: bool = False):  # jaxlint: disable=JL002 -- the pools are read, never written: an inner jit that shares the kernel's trace, inlined into the engine's program, which donates them
     """The pallas_call of `ragged_paged_attention_pallas`, [T, H, D]
     out (invalid rows not yet zeroed). A jit of its own, so that its
     trace is shared: a serving engine builds one program per (token
@@ -721,9 +751,20 @@ def _ragged_call(items, segs, tables, q, k_pages, v_pages, k_new, v_new,
     programs share one trace — what a warm start pays per program is
     mostly that trace."""
     t, h, head_dim = q.shape
-    _, page_size, kvh, d = k_pages.shape
-    group = h // kvh
     quantized = k_scales is not None
+    if merged_rows:
+        if quantized:
+            raise ValueError("a merged-rows pool has no quantized read "
+                             "path")
+        kvh, d = k_new.shape[1], k_pages.shape[-1]
+        page_size = k_pages.shape[1] // kvh
+        # the in-batch K and V of a head count off the 8-row tile ride
+        # with their heads padded to it (the kernel reads the first kvh)
+        kvh_new = -(-kvh // 8) * 8
+    else:
+        _, page_size, kvh, d = k_pages.shape
+        kvh_new = kvh
+    group = h // kvh
     n_ctx_pages = tables.shape[1] if has_ctx else 0
     q_blk, ppb = ragged_block_sizes(
         t, page_size, n_ctx_pages, kvh, d, k_pages.dtype.itemsize)
@@ -736,13 +777,15 @@ def _ragged_call(items, segs, tables, q, k_pages, v_pages, k_new, v_new,
                              ((0, q_blk), (0, 0), (0, 0)))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     inputs = [tail(q), k_pages, v_pages]
+    page_block = ((2, ppb, page_size * kvh, d) if merged_rows
+                  else (2, ppb, page_size, kvh, d))
     scratch = [
         pltpu.VMEM((q_blk, h, d), q.dtype),            # q block
-        pltpu.VMEM((q_blk, kvh, d), k_new.dtype),      # in-batch k / v
-        pltpu.VMEM((q_blk, kvh, d), v_new.dtype),
+        pltpu.VMEM((q_blk, kvh_new, d), k_new.dtype),  # in-batch k / v
+        pltpu.VMEM((q_blk, kvh_new, d), v_new.dtype),
         pltpu.VMEM((q_blk, h, d), q.dtype),            # output block
-        pltpu.VMEM((2, ppb, page_size, kvh, d), k_pages.dtype),
-        pltpu.VMEM((2, ppb, page_size, kvh, d), v_pages.dtype),
+        pltpu.VMEM(page_block, k_pages.dtype),
+        pltpu.VMEM(page_block, v_pages.dtype),
     ]
     if quantized:
         # scale pools ride beside the page pools: HBM-resident, DMA'd
@@ -751,7 +794,9 @@ def _ragged_call(items, segs, tables, q, k_pages, v_pages, k_new, v_new,
                    v_scales.astype(jnp.float32)]
         scratch += [pltpu.VMEM((2, ppb, page_size, kvh), jnp.float32),
                     pltpu.VMEM((2, ppb, page_size, kvh), jnp.float32)]
-    inputs += [tail(k_new), tail(v_new)]
+    heads = lambda x: (x if kvh_new == kvh else jnp.pad(
+        x, ((0, 0), (0, kvh_new - kvh), (0, 0))))
+    inputs += [tail(heads(k_new)), tail(heads(v_new))]
     r = q_blk * group
     n_keys = max(ppb * page_size, q_blk)
     scratch += [
@@ -764,13 +809,17 @@ def _ragged_call(items, segs, tables, q, k_pages, v_pages, k_new, v_new,
         pltpu.VMEM((kvh, r, 1), jnp.float32),          # l
         pltpu.VMEM((kvh, r, d), jnp.float32),          # acc
     ]
+    if merged_rows:
+        # a context block's rows in float32, for the strided loads
+        scratch += [pltpu.VMEM((ppb * page_size * kvh, d), jnp.float32)] * 2
 
     out = pl.pallas_call(
         functools.partial(
             _ragged_paged_kernel, page_size=page_size, ppb=ppb,
             n_ctx_pages=n_ctx_pages, q_blk=q_blk, small=small,
             scale=head_dim ** -0.5, kvh=kvh, group=group,
-            quantized=quantized, window=window),
+            quantized=quantized, window=window,
+            **({"merged_rows": True} if merged_rows else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(items.shape[1],),
