@@ -375,34 +375,96 @@ def _row_sample_keys(seeds, idx):
     )(seeds, idx)
 
 
+def _order_keys(x):
+    """uint32 keys that order as the float32 `x` does, -0.0 and +0.0
+    one key (as lax.sort's comparator has them)."""
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(x == 0, 0.0, x), jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def _prefix(keys, weights, member, limit):
+    """(B, V) bool, in vocabulary order: the tokens whose predecessors,
+    in order of falling key and then rising id, weigh under `limit`.
+    It is what a stable sort's prefix test (cumsum - weight < limit)
+    keeps. `member`: the tokens in the running, whose weights count;
+    equal keys carry equal weights.
+
+    The cut's key is the smallest c whose heavier keys weigh under the
+    limit, sum(weights[keys > c]) < limit. That sum falls as c rises, so
+    c is settled from its top bit down: 32 passes, each one compare and
+    one row sum, whatever the row holds; a loop's trips, so the program
+    stays small. One bit a pass: 3 or 15 thresholds over one read were no
+    faster on a v5e (1.83 / 1.92 / 3.26 ms at 1 / 2 / 4 bits over
+    [64, 200064]: the compares bind, XLA keeps the rows in VMEM across
+    the loop) and a larger program, which every tick program's first
+    call pays for (PERF.md section 6, PR 37)."""
+    def settle(step, c):
+        bit = jnp.uint32(1) << (31 - step).astype(jnp.uint32)
+        cand = c | (bit - jnp.uint32(1))    # the largest c with this bit 0
+        heavier = jnp.sum(
+            jnp.where(keys > cand[:, None], weights, 0.0), axis=-1)
+        return jnp.where(heavier < limit, c, c | bit)
+    c = jax.lax.fori_loop(
+        0, 32, settle, jnp.zeros(keys.shape[:1], jnp.uint32))
+    over = keys > c[:, None]
+    tied = member & (keys == c[:, None])
+    before = jnp.sum(jnp.where(over, weights, 0.0), axis=-1)
+    each = jnp.max(jnp.where(tied, weights, 0.0), axis=-1)
+    # of the tokens tied on the cut, the i-th by id is kept while
+    # before + i * each is under the limit (all of them where their
+    # weight underflowed to 0)
+    n = jnp.where(each > 0, jnp.ceil((limit - before) / each), jnp.inf)
+    rank = jnp.cumsum(tied, axis=-1, dtype=jnp.int32)
+    return over | (tied & (rank <= jnp.maximum(n, 1.0)[:, None]))
+
+
 def _kept_tokens(scaled, top_ps, top_ks=None):
     """(B, V) bool, in vocabulary order: the tokens top-k and then top-p
     leave of each row of `scaled` (see _sample, which says why this
-    neither gathers nor scatters)."""
-    # ids along the row: a token's id in vocabulary order, a rank in
-    # sorted order
-    ids = jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)
-    neg_sorted, sort_idx = jax.lax.sort(
-        (-scaled, ids), dimension=1, num_keys=1, is_stable=True)
-    sorted_logits = -neg_sorted
-    if top_ks is not None:
-        # keep ranks < top_k (0 = off): mask in SORTED space, before
-        # top-p renormalizes over what's left
-        sorted_logits = jnp.where(
-            (top_ks[:, None] > 0) & (ids >= top_ks[:, None]),
-            -jnp.inf, sorted_logits)
-    # top-p: keep the smallest prefix of the sorted probs covering top_p
-    probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep_sorted = ((cum - probs) < top_ps[:, None]) \
-        & jnp.isfinite(sorted_logits)               # always keeps rank 0
-    # the cut: the logit and the id at the last kept rank
-    n_keep = keep_sorted.sum(axis=-1)
-    last = ids == (n_keep[:, None] - 1)             # none where n_keep is 0
-    cut = jnp.min(jnp.where(last, sorted_logits, jnp.inf), axis=-1)
-    cut_id = jnp.max(jnp.where(last, sort_idx, -1), axis=-1)
-    return (scaled > cut[:, None]) | (
-        (scaled == cut[:, None]) & (ids <= cut_id[:, None]))
+    neither sorts, gathers nor scatters).
+
+    A selection, not a sort: of the sorted order only the cut is wanted,
+    and a cut is found by bisection on row sums (`_prefix`). The logits
+    become uint32 keys of the same order; the cut's key is settled bit
+    by bit (the count of heavier keys against top_k, then their
+    probability against top_p); the tokens that share the cut's key
+    share its weight, so how many of them are kept is arithmetic, and
+    which is their rank by id, one cumulative sum. The same cost for a
+    flat row and a peaked one: no prefix to fall back from, no cap on
+    what top-p may keep. The top-k cut is searched only when some row
+    of the batch sets top_k (a trip of the loop taken or not by the
+    array's values, not a static argument: the tick programs always
+    pass it and stay one program a bucket)."""
+    v = scaled.shape[1]
+    if v >= 1 << 24:
+        raise ValueError(f"vocabulary {v}: counts are float32 row sums")
+    keys = _order_keys(scaled)
+    # top_p 1 keeps all that top-k left, though a float32 sum of the
+    # row's probabilities reaches 1.0 before its last token
+    by_mass = jnp.where(top_ps < 1.0, top_ps, jnp.inf)
+    if top_ks is None:
+        top_ks = jnp.zeros(top_ps.shape, jnp.int32)
+    on = (top_ks > 0) & (top_ks < v)        # 0 or >= V: off
+    ranks = jnp.where(on, top_ks, v).astype(jnp.float32)
+
+    def cut(by_count, live):
+        """One cut of what is live: by count against top_k (every token
+        weighs 1), or by probability, renormalised over what is live,
+        against top_p. One body for both, so a tick program holds the
+        bisection once."""
+        probs = jnp.where(
+            live, jax.nn.softmax(jnp.where(live, scaled, -jnp.inf),
+                                 axis=-1), 0.0)
+        return live & _prefix(
+            keys, jnp.where(by_count, 1.0, probs), live | by_count,
+            jnp.where(by_count, ranks, by_mass))
+
+    # ranks < top_k first; that cut only where a row of the batch asks
+    # for it: the loop starts at top-p otherwise
+    return jax.lax.fori_loop(
+        jnp.where(jnp.any(on), 0, 1), 2,
+        lambda phase, live: cut(phase == 0, live), jnp.isfinite(scaled))
 
 
 @jax.named_scope("sample")
@@ -416,28 +478,34 @@ def _sample(logits, key, temps, top_ps, top_ks=None, rep_pens=None,
     penalty on raw logits (CTRL: positive seen logits divided, negative
     multiplied), then temperature, top-k, top-p, sample.
 
-    all_greedy (static) skips the sort machinery entirely: pure argmax
+    all_greedy (static) skips the selection entirely: pure argmax
     decoding (the common batch-inference case) never needs it (the
     engine only sets it when every penalty is off too).
 
-    What it costs. Top-p needs one sort of the row, with the ids as
-    payload so that the sorted logits come back with it (3.7 ms over
-    [32, 92544] on a v5e; the largest operation left here). Everything
-    else is elementwise passes and row reductions. Nothing here may
-    gather from or scatter into a [B, V] array: on the chip a gather of
-    the sorted logits was 30 ms and the scatter of the keep mask back
-    to vocabulary order 19 ms of an 87 ms decode tick whose 24 layers
-    took 17 (PERF.md section 6, PR 28). Neither is needed. The kept set
-    is DEFINED as the first n_keep ranks of the sorted order, n_keep
-    the number of ranks the top-p test passes (the test passes a prefix
-    wherever the float32 cumsum is monotone; where a device's scan is
-    not, within an ulp of top_p == 1, the prefix of that length is what
-    is kept). The stable sort orders equal logits by id, so the value
-    and the id at the last kept rank (the cut, read by masked
-    reductions) describe the set in vocabulary order: a token is kept
-    if its logit is over the cut's, or equal with an id no greater.
-    tests/test_llm_sampling.py holds the gather-and-scatter body as the
-    oracle, token for token.
+    What it costs. Elementwise passes, row reductions and one
+    cumulative sum: the selection of `_kept_tokens` and the draw's
+    vocabulary-wide noise. On a v5e (`_sample` whole, top_p 0.9 at
+    temperature 0.7, no row with top_k; PERF.md section 6, PR 37)
+    2.35 ms over [64, 200064], 0.66 over [32, 92544] and under 0.5
+    over [64, 16160] and [32, 25024], flat rows or peaked; with top_k
+    set 4.19, 1.16 and under 0.5. The stable sort it replaces (the row
+    with its ids as payload) took 18.8, 4.0, 1.05 and 1.08 ms there and
+    12 to 24 s to compile, where this takes 1 to 5. Nothing here may
+    sort a row, nor gather from or scatter into a [B, V] array: a
+    gather of the sorted logits was 30 ms and the scatter of the keep
+    mask back to vocabulary order 19 ms of an 87 ms decode tick whose
+    24 layers took 17 (PERF.md section 6, PR 28). None is needed. The
+    kept set is DEFINED in the order of a stable sort by falling logit
+    (equal logits by rising id, -0.0 and +0.0 equal): a token is kept while
+    the tokens before it weigh under the limit, first by count against
+    top_k, then, over what that left, by float32 probability against
+    top_p (every probability of a larger logit summed, plus the tied
+    tokens before it at the one probability they share). That is a
+    prefix of the order, so its last token's logit (the cut) and the
+    rank by id among the tokens tied on it describe it in vocabulary
+    order. top_p 0 keeps nothing, top_p 1 all that top-k left, a -inf
+    logit is never kept. tests/test_llm_sampling.py holds the
+    gather-and-scatter body as the oracle, token for token.
 
     row_keys: optional (B,) per-row PRNG keys (_row_sample_keys) —
     the per-request deterministic path, which both engine programs

@@ -13,8 +13,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.llm._internal.engine import (_kept_tokens, _row_sample_keys,
-                                          _sample)
+from ray_tpu.llm._internal.engine import (EngineConfig,
+                                          InferenceEngine, Request,
+                                          SamplingParams, _kept_tokens,
+                                          _row_sample_keys, _sample)
+from ray_tpu.models import llama
 
 
 def _oracle(logits, key, temps, top_ps, top_ks=None, rep_pens=None,
@@ -89,6 +92,22 @@ def _inputs(b, v, seed):
         seeds=jnp.asarray(rng.integers(0, 2**31 - 1, b), jnp.int32))
 
 
+def _tokens_equal_the_oracle(x, top_p, top_k, temp, mode):
+    new, oracle = _programs(mode)
+    b, v = x["logits"].shape
+    args = dict(
+        x, temps=jnp.full((b,), temp, jnp.float32),
+        top_ps=jnp.full((b,), top_p, jnp.float32),
+        top_ks=jnp.full((b,), top_k, jnp.int32))
+    for draw in range(4):
+        key = jax.random.PRNGKey(1000 * draw + int(np.max(top_k)))
+        idx = jnp.arange(b, dtype=jnp.int32) + 17 * draw
+        got = np.asarray(new(key=key, idx=idx, **args))
+        want = np.asarray(oracle(key=key, idx=idx, **args))
+        np.testing.assert_array_equal(got, want)
+        assert got.dtype == np.int32 and (0 <= got).all() and (got < v).all()
+
+
 @pytest.mark.parametrize("mode", sorted(MODES))
 @pytest.mark.parametrize("temp", [0.0, 0.7, 5.0])
 @pytest.mark.parametrize("top_k", [0, 1, 5, 50])
@@ -96,19 +115,69 @@ def _inputs(b, v, seed):
 @pytest.mark.parametrize("b,v", [(4, 1000), (6, 2048), (8, 4096)])
 def test_tokens_equal_the_gather_and_scatter_oracle(b, v, top_p, top_k,
                                                     temp, mode):
-    new, oracle = _programs(mode)
-    x = _inputs(b, v, seed=v + b)
-    args = dict(
-        x, temps=jnp.full((b,), temp, jnp.float32),
-        top_ps=jnp.full((b,), top_p, jnp.float32),
-        top_ks=jnp.full((b,), top_k, jnp.int32))
-    for draw in range(4):
-        key = jax.random.PRNGKey(1000 * draw + top_k)
-        idx = jnp.arange(b, dtype=jnp.int32) + 17 * draw
-        got = np.asarray(new(key=key, idx=idx, **args))
-        want = np.asarray(oracle(key=key, idx=idx, **args))
-        np.testing.assert_array_equal(got, want)
-        assert got.dtype == np.int32 and (0 <= got).all() and (got < v).all()
+    _tokens_equal_the_oracle(_inputs(b, v, seed=v + b), top_p, top_k,
+                             temp, mode)
+
+
+def _cell_rows(b, v, seed):
+    """Row 0 on the grid of 0.25 (thousands of equal logits on the cut),
+    the others the benchmark's own: near N(0, 1) off any grid, about two
+    fifths of a row kept at temperature 0.7 and top_p 0.9."""
+    x = _inputs(b, v, seed)
+    rng = np.random.default_rng(seed + 1)
+    logits = np.array(x["logits"])
+    logits[1:] = rng.normal(0.0, 1.0, (b - 1, v))
+    return dict(x, logits=jnp.asarray(logits, jnp.float32))
+
+
+def _signed_zeros_on_the_cut(b, v, seed):
+    """Most of each row is one logit, 0, written +0.0 and -0.0 by turns
+    (one key, as lax.sort has them), under a few larger ones: every cut
+    of top-k and of top-p falls among the zeros and is settled by id."""
+    x = _inputs(b, v, seed)
+    rng = np.random.default_rng(seed + 1)
+    logits = np.where(rng.random((b, v)) < 0.5, 0.0, -0.0)
+    logits[:, ::97] = np.round(rng.normal(2.0, 1.0, (b, len(
+        range(0, v, 97)))) * 4) / 4
+    logits[:, 5::89] = -1.5
+    return dict(x, logits=jnp.asarray(logits, jnp.float32),
+                rep_pens=jnp.ones((b,), jnp.float32))
+
+
+def _masked_vocabulary(b, v, seed):
+    """All but one id in 23 at -inf, as a grammar or an adapter's
+    vocabulary masks a row; one row keeps three ids, one a single id."""
+    x = _inputs(b, v, seed)
+    rng = np.random.default_rng(seed + 1)
+    logits = np.array(x["logits"])
+    logits[rng.random((b, v)) < 22 / 23] = -np.inf
+    logits[0] = -np.inf
+    logits[0, [7, v // 2, v - 1]] = [1.0, 1.0, 0.25]
+    logits[1] = -np.inf
+    logits[1, v - 3] = -2.0
+    return dict(x, logits=jnp.asarray(logits, jnp.float32))
+
+
+# the four serving cells' row widths (16,160 and 25,024 are no multiple
+# of 128) and the rows no random draw holds
+ROWS = {
+    "dsv3-longchat": (2, 16160, _cell_rows),
+    "trinity-mixed": (2, 25024, _cell_rows),
+    "chat-open": (2, 92544, _cell_rows),
+    "phi4flash-reason": (2, 200064, _cell_rows),
+    "signed-zeros-on-the-cut": (4, 3000, _signed_zeros_on_the_cut),
+    "masked-vocabulary": (4, 5003, _masked_vocabulary),
+}
+
+
+@pytest.mark.parametrize("mode", ["rows+topk", "rows+topk+rep", "shared"])
+@pytest.mark.parametrize("top_k", [0, 50, "some-rows"])
+@pytest.mark.parametrize("rows", sorted(ROWS))
+def test_tokens_equal_the_oracle_at_the_cells_rows(rows, top_k, mode):
+    b, v, make = ROWS[rows]
+    if top_k == "some-rows":        # one batch: rows that set it, rows
+        top_k = jnp.asarray([50, 0, 3, 0][:b], jnp.int32)   # that do not
+    _tokens_equal_the_oracle(make(b, v, seed=v), 0.9, top_k, 0.7, mode)
 
 
 def test_rows_of_one_batch_keep_their_own_parameters():
@@ -251,3 +320,62 @@ def test_all_greedy_never_sorts():
         l, k, jnp.ones(2), jnp.ones(2), all_greedy=True)).lower(
             jnp.zeros((2, 64)), jax.random.PRNGKey(0)).as_text()
     assert "stablehlo.sort" not in text
+
+
+@pytest.mark.parametrize("top_ks", [False, True])
+@pytest.mark.parametrize("rows", [False, True])
+def test_sampled_never_sorts_gathers_or_scatters(rows, top_ks):
+    """The mechanism, where no ledger line can see it: a sampled program
+    holds a loop of compares and row sums, and of a sort, a gather or a
+    scatter over the row nothing."""
+    b, v = 4, 1000
+
+    def sampled(logits, key, top_k, seeds):
+        return _sample(
+            logits, key, jnp.full((b,), 0.7), jnp.full((b,), 0.9),
+            top_k if top_ks else None,
+            row_keys=_row_sample_keys(seeds, seeds) if rows else None)
+    text = jax.jit(sampled).lower(
+        jnp.zeros((b, v)), jax.random.PRNGKey(0),
+        jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32)).as_text()
+    for op in ("stablehlo.sort", "stablehlo.gather", "stablehlo.scatter",
+               "chlo.top_k", "stablehlo.custom_call"):
+        assert op not in text, op
+    # the passes are a loop's trips, not copies of its body, and the
+    # top-k cut is a trip of the loop that makes the top-p cut (it starts
+    # there where no row sets top_k), not a second copy of the bisection
+    assert "stablehlo.while" in text
+    assert text.count("stablehlo.reduce") <= 8
+    assert "stablehlo.case" not in text
+
+
+def test_top_k_is_no_static_argument_of_the_tick_programs():
+    """An engine builds the programs for a token bucket that it built:
+    one sampled ragged program a (tokens, context) bucket and one decode
+    program, whether a request sets top_k or none does."""
+    eng = InferenceEngine(EngineConfig(
+        model=llama.config("debug", dtype=jnp.float32), max_batch_size=3,
+        page_size=8, num_pages=64, max_prefill_tokens=16, seed=9,
+        enable_prefix_caching=False))
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(2, 250, n).tolist() for n in (12, 12, 7)]
+
+    def serve(tag, **sp):
+        reqs = [Request(f"{tag}{i}", list(p), SamplingParams(
+            max_tokens=6, temperature=0.7, top_p=0.9, **sp))
+            for i, p in enumerate(prompts)]
+        for r in reqs:
+            eng.add_request(r)
+        while eng.has_work():
+            eng.step()
+        return [r.output_tokens for r in reqs]
+
+    plain = serve("a")
+    built = (eng.compiles, sorted(eng._ragged_fns))
+    assert all(not greedy for _, _, greedy in eng._ragged_fns)
+    limited = serve("b", top_k=2)
+    mixed = serve("c", top_k=0)
+    assert (eng.compiles, sorted(eng._ragged_fns)) == built
+    for fn in [eng._decode_fn, *eng._ragged_fns.values()]:
+        assert fn._cache_size() == 1
+    assert all(len(t) == 6 for t in plain + limited + mixed)
