@@ -3,19 +3,41 @@
 ISSUE 13: the tick_times telemetry (PR 4/11) shows that a p99 tail
 exists, but not WHY a specific tick went slow — and by the time an
 operator asks, the evidence is gone. This module watches every
-committed tick's measured wall time against the analytic prediction
+committed tick's measured cost against the analytic prediction
 PR 11's cost model already produces (flops/peak vs bytes/peak — the
 roofline lower bound), keeps a robust residual baseline
 (median + MAD over the log-residual, so the CPU envelope's constant
 calibration bias cancels and a handful of outliers can't poison the
 baseline), and flags ticks whose robust z-score clears the threshold.
-The baseline is kept per tick kind (PerfSample.kind): the cost model
-misprices kinds by different factors (on a v5e a 512-token ragged tick
-runs at 4.6x its bound among decode ticks at 1.4x theirs), and under
-one pooled baseline every tick of the dearer kind read as a straggler
-and armed a capture under the step lock (PERF.md section 6, PR 28). A
-kind is judged against the pooled window until it has `warmup_ticks`
-samples of its own, so a rare kind's stall is still seen.
+
+What a tick is judged on is what ITS OWN program cost (PR 39): the
+host time of the step() call that dispatched it plus the time its
+program kept the device to itself (`own_program_ms`: from the later of
+its dispatch's end and the end of the readback before its own, to the
+end of its own readback), handed over with the tick's own PerfSample
+when the tick is FOLDED — a call after its dispatch under lagged
+folds. The wall of a step() call is not that: with the pipeline two
+deep a call dispatches tick n and waits for tick n-1, and a call that
+retires a slot waits for both programs, so a call's wall priced another
+tick's program, or two, against the dispatched tick's kind (the 41-55
+ms "decode ticks" among 17-24 ms ones, PERF.md section 6, PR 39).
+Waiting for another tick's program is that tick's cost. A synchronous
+readback degenerates to the call's wall.
+
+The baseline is kept per tick kind (PerfSample.kind) AND size (the
+tick's tokens, to the next power of two): the cost model misprices
+kinds by different factors (on a v5e a 512-token ragged tick runs at
+4.6x its bound among decode ticks at 1.4x theirs), and under one pooled
+baseline every tick of the dearer kind read as a straggler and armed a
+capture under the step lock (PERF.md section 6, PR 28); within a kind
+it misprices by size just as much (a `trinity-mixed` decode tick is
+priced at one read of all the weights, 11.8-13.9 ms, whether it runs
+one row in 4.5 ms or thirteen long-context rows in 24), so that a
+swell of load read as a hundred stragglers in a row against the quiet
+stretch's median (PERF.md section 6, PR 39). A stall is a tick that
+costs more than ticks LIKE it. A kind and size is judged against the
+pooled window until it has `warmup_ticks` samples of its own, so a rare
+one's stall is still seen.
 
 A flagged tick is CLASSIFIED from host-side evidence the engine
 already has — in priority order:
@@ -25,9 +47,10 @@ already has — in priority order:
     h2d_transfer      the tick moved restore/import h2d page bytes
     gc_pause          the gc.callbacks monitor saw a collector pause
                       overlapping the tick
-    host_fold_stall   the host-fold share of the tick wall is far
+    host_fold_stall   the host-fold share of the tick's cost is far
                       above its own baseline
-    device_straggler  the blocked-readback (device) share dominates
+    device_straggler  the tick's own program's share dominates: THIS
+                      program ran long, not "this call waited"
     unknown           slow with no fingerprint — the profile capture
                       below is exactly for these
 
@@ -43,7 +66,11 @@ Zero-sync discipline: pure host arithmetic over numbers the engine
 already holds — no jax import, no device values, nothing on the tick
 path beyond a few float ops (the dispatch-guard suite runs with the
 detector enabled). The capture actions run only when a tick has
-ALREADY gone anomalous.
+ALREADY gone anomalous, and what they cost in milliseconds
+(`start_trace`, `stop_trace`, the bundle's dump) runs on the engine's
+writer thread: under the step lock stay the flight event, the counters
+and the dict that arms the capture
+(`stats()["self_captures"]["lock_hold_s"]` says what that held).
 """
 
 from __future__ import annotations
@@ -93,6 +120,17 @@ class AnomalyConfig:
     rate_window: int = 256
 
 
+def own_program_ms(dispatched: float, read_before: float,
+                   read: float) -> float:
+    """Milliseconds a tick's program kept the device to itself, from
+    three host stamps of one clock (seconds): the end of its dispatch,
+    the end of the readback before its own (the tick before: until then
+    the device, which runs programs in order, was that tick's) and the
+    end of its own readback. An upper bound where the host came late to
+    read. Waiting for another tick's program is that tick's cost."""
+    return max(read - max(dispatched, read_before), 0.0) * 1e3
+
+
 class GcMonitor:
     """Process-wide gc.callbacks pause accountant. Installed once,
     lazily, by the first detector; every detector reads the cumulative
@@ -133,7 +171,8 @@ class GcMonitor:
 
 
 class TickAnomalyDetector:
-    """Feed `observe()` once per committed tick (under the engine step
+    """Feed `observe()` once per committed tick, when it is folded
+    and with its own cost (module docstring; under the engine step
     lock — mutation needs no lock of its own); read `stats()` from
     scrape threads (its own lock). Returns the anomaly event dict on
     trigger, with `arm_profile` / `dump` booleans pre-resolved against
@@ -141,7 +180,7 @@ class TickAnomalyDetector:
 
     def __init__(self, config: Optional[AnomalyConfig] = None):
         self.config = config or AnomalyConfig()
-        # log-residuals: every tick's, and each tick kind's own
+        # log-residuals: every tick's, and each kind and size's own
         self._resid: "collections.deque[float]" = collections.deque(
             maxlen=_WINDOW)
         self._resid_by_kind: Dict[str, "collections.deque[float]"] = {}
@@ -178,6 +217,15 @@ class TickAnomalyDetector:
         return 0.6745 * (x - med) / mad
 
     @staticmethod
+    def _like(sample: Any) -> str:
+        """The baseline a tick is judged against: its kind and its
+        tokens to the next power of two."""
+        n = (int(getattr(sample, "decode_tokens", 0))
+             + int(getattr(sample, "prefill_tokens", 0)))
+        return (f"{getattr(sample, 'kind', '')}/"
+                f"{1 << max(n - 1, 0).bit_length()}")
+
+    @staticmethod
     def predicted_ms(sample: Any, peak_flops: float,
                      peak_bytes: float) -> float:
         """Roofline lower bound for the tick: whichever roof binds.
@@ -209,8 +257,7 @@ class TickAnomalyDetector:
         resid = math.log(max(wall_ms, 1e-6) / max(pred_ms, 1e-6))
         host_share = (host_ms / wall_ms) if wall_ms > 0 else 0.0
         own = self._resid_by_kind.setdefault(
-            getattr(sample, "kind", ""),
-            collections.deque(maxlen=_WINDOW))
+            self._like(sample), collections.deque(maxlen=_WINDOW))
         window = own if len(own) >= cfg.warmup_ticks else self._resid
         warmed = len(window) >= cfg.warmup_ticks
         z = self._robust_z(resid, window) if warmed else 0.0
@@ -313,4 +360,5 @@ class TickAnomalyDetector:
             }
 
 
-__all__ = ["AnomalyConfig", "TickAnomalyDetector", "GcMonitor"]
+__all__ = ["AnomalyConfig", "TickAnomalyDetector", "GcMonitor",
+           "own_program_ms"]

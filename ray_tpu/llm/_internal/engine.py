@@ -48,6 +48,7 @@ from ...models import llama
 from ...models.llama import LlamaConfig
 from ...models.family import family_of, resolve_config, store_params
 from ...util import thread_sanitizer
+from .anomaly import own_program_ms
 from .kv_cache import CacheManager
 from .telemetry import EngineTelemetry
 
@@ -591,6 +592,59 @@ class _Phase:
         self.span.__exit__(*exc)
 
 
+# how long an armed capture waits for another profiler session to close
+_START_WAIT_S = 60.0
+
+
+class _Writer:
+    """The engine's one writer thread, `engine-profile-writer`: what the
+    analyzer's reactions cost in milliseconds and seconds (`start_trace`,
+    `stop_trace` with its export, a black-box bundle's dump) runs here,
+    one job at a time in the order submitted, off the step lock. Started
+    by the first job and parked between jobs (a daemon: starting a thread
+    is the dearest thing left under the lock, 0.7 ms on the chip's host,
+    so it is paid once an engine, not once a flag)."""
+
+    def __init__(self):
+        self._cv = threading.Condition()
+        self._jobs: "collections.deque" = collections.deque()
+        self._pending = 0               # queued or running
+        self._thread: Optional[threading.Thread] = None
+
+    def submit(self, fn, *args) -> None:
+        with self._cv:
+            self._jobs.append((fn, args))
+            self._pending += 1
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="engine-profile-writer",
+                    daemon=True)
+                self._thread.start()
+            self._cv.notify_all()
+
+    def _run(self) -> None:
+        while True:
+            with self._cv:
+                while not self._jobs:
+                    self._cv.wait()
+                fn, args = self._jobs.popleft()
+            try:
+                fn(*args)
+            except Exception:
+                # a capture or a bundle lost; the engine keeps serving
+                import logging
+                logging.getLogger(__name__).exception(
+                    "engine-profile-writer: %s failed", fn.__name__)
+            finally:
+                with self._cv:
+                    self._pending -= 1
+                    self._cv.notify_all()
+
+    def wait_idle(self, timeout: Optional[float] = None) -> bool:
+        with self._cv:
+            return self._cv.wait_for(lambda: not self._pending, timeout)
+
+
 class _TickRecord(NamedTuple):
     """One entry of the tick ring. The first three fields are the ring's
     old (wall, host, device) triple; `host_ms` is the `fold` phase and
@@ -869,7 +923,7 @@ class InferenceEngine:
         # router's liveness input (fleet_stats last_tick_age_s) — a
         # replica whose pump wedged stops advancing this
         self.last_step_at: Optional[float] = None
-        # on-demand profiling: {"remaining", "dir", "cm", "writer"}
+        # on-demand profiling: the one capture (`_arm_profile_locked`)
         # while armed, running or being written (POST /debug/profile →
         # profile_next_ticks)
         self._profile: Optional[Dict[str, Any]] = None
@@ -1055,6 +1109,17 @@ class InferenceEngine:
         self._profiles_armed: Dict[str, int] = {}
         self._profiles_started = 0
         self._blackbox_dumps: Dict[str, int] = {}
+        # seconds for which the analyzer's reactions held the step lock
+        # (`_held`), and the thread that does the rest of them
+        self._capture_hold_s = 0.0
+        self._writer = _Writer()
+        # what the detector is told of a tick, gathered until it is
+        # whole (tick -> record): the end of its dispatch; at the end of
+        # the dispatching call its PerfSample and that call's host time;
+        # at its own readback, a call later under lagged folds, the time
+        # its program kept the device to itself (`_program_read`)
+        self._judged: Dict[int, Dict[str, Any]] = {}
+        self._read_end = 0.0     # perf_counter at the latest readback's end
         # serializes the mutating entry points (step/abort/LoRA
         # registration): the server runs step() on an executor thread
         # while abort() fires from the event loop on client
@@ -1323,7 +1388,54 @@ class InferenceEngine:
         tick before), so that a trace can hold each program's end
         against the end of its own wait."""
         with self._phase("readback_wait", of=of):
-            return np.asarray(dev)  # jaxlint: disable=JL005 -- the one sanctioned readback: the async pipeline folds land here, a tick behind dispatch
+            host = np.asarray(dev)  # jaxlint: disable=JL005 -- the one sanctioned readback: the async pipeline folds land here, a tick behind dispatch
+        if self.anomaly is not None:
+            self._program_read(of)
+        return host
+
+    def _program_dispatched(self) -> None:
+        """The current tick's program is on its way: open the record the
+        detector will judge it on (`_judged`)."""
+        if self.anomaly is not None:
+            self._judged[self.ticks] = {"dispatched": time.perf_counter()}
+
+    def _program_read(self, of: int) -> None:
+        """A readback has ended: tick `of`'s program (if it was one)
+        kept the device to itself since the later of its dispatch's end
+        and the readback before this one. Waiting for another tick's
+        program is that tick's cost and is booked to it here."""
+        now = time.perf_counter()
+        rec = self._judged.get(of)
+        if rec is not None:
+            rec["device_ms"] = own_program_ms(rec["dispatched"],
+                                              self._read_end, now)
+            if "sample" in rec:
+                self._judge(of)
+        self._read_end = now
+
+    def _judge(self, tick: int) -> None:
+        """Hand the detector tick `tick`, whole: its own PerfSample, the
+        host time of the call that dispatched it plus its own program's
+        time, that call's fold (the classifier's host share) and the
+        compile counter as that call left it."""
+        rec = self._judged.pop(tick)
+        ev = self.anomaly.observe(
+            rec["sample"], rec["host_ms"] + rec["device_ms"],
+            rec["fold_ms"], rec["device_ms"], rec["compiles"],
+            self.perf.envelope.peak_flops * self.perf.n_chips,
+            self.perf.envelope.peak_bytes_per_s * self.perf.n_chips)
+        if ev is not None:
+            self._held(self._on_tick_anomaly, ev)
+
+    def _held(self, fn, *args):
+        """Run one of the analyzer's reactions under the step lock (the
+        caller holds it) and book its time to
+        stats()["self_captures"]["lock_hold_s"]."""
+        t0 = time.perf_counter()
+        try:
+            return fn(*args)
+        finally:
+            self._capture_hold_s += time.perf_counter() - t0
 
     def _phase(self, name: str, **args) -> _Phase:
         """Open phase `name` (one of TICK_PHASES) of the current tick;
@@ -1841,6 +1953,7 @@ class InferenceEngine:
                     self._dev(jnp.asarray(slot_meta)),
                     samp, self._device_tables(), sub,
                     self._lora_stacks, all_greedy)
+        self._program_dispatched()
         toks_host = self._read_tokens(toks, of=self.ticks)
         # fold ALL slots from the one readback before any device-state
         # refresh
@@ -3005,16 +3118,20 @@ class InferenceEngine:
         fold (every step still dispatches exactly once, so progress
         and termination are unchanged)."""
         with self._step_lock:
-            # an armed capture starts before the tick's span opens and
-            # stops after it closes, so that it holds the span whole
-            self._profile_tick_begin()
+            # an armed capture counts the ticks whose span opens after
+            # its trace has started, so that it holds their spans whole
+            # (one that waits for its start costs a tick nothing)
+            ps = self._profile
+            if ps is not None and ps["state"] in ("armed", "running"):
+                self._held(self._profile_tick_begin)
             with jax.profiler.TraceAnnotation(
                     "engine.step", tick=self.ticks + 1) as span:
                 touched = self._step_locked()
                 # work=0: the device idles after this tick for want of
                 # requests, not for the host
                 span.set_metadata(work=int(self._step_end is not None))
-            self._profile_tick_end()
+            if ps is not None and ps["live"]:
+                self._held(self._profile_tick_end)
             return touched
 
     def _step_locked(self) -> List[Request]:
@@ -3055,7 +3172,8 @@ class InferenceEngine:
             # uncovered allocator path — record the alert-hooked
             # kv_exhausted event (it black-boxes a bundle), retire
             # a victim with finish_reason="error", keep pumping
-            self._profile_abort()
+            self._held(self._profile_abort)
+            self._judged.pop(self.ticks, None)
             if self.perf is not None:
                 self.perf.abort_tick()
             if self.attrib is not None:
@@ -3069,7 +3187,8 @@ class InferenceEngine:
             # GuardViolation, allocator OOM, ...) must not leave an
             # armed jax.profiler capture running forever — stop the
             # trace and disarm so /debug/profile can be re-armed
-            self._profile_abort()
+            self._held(self._profile_abort)
+            self._judged.pop(self.ticks, None)
             if self.perf is not None:
                 self.perf.abort_tick()
             if self.attrib is not None:
@@ -3085,25 +3204,31 @@ class InferenceEngine:
     def _commit_tick_costs(self, wall: float) -> None:
         """Fold the tick's pending PerfSample (cost hooks ran beside
         each dispatch) into the rolling MFU/MBU window, stamped with
-        the tick wall, split it across the per-request receipts, and
-        let the anomaly detector judge the tick."""
-        host_ms = self._phase_s["fold"] * 1e3
-        device_ms = self._phase_s["readback_wait"] * 1e3
+        the tick wall, and split it across the per-request receipts (a
+        call's wall over its requests, whichever tick's program it
+        waited for). The anomaly detector gets the sample and this
+        call's host time, the wall less every readback wait, and judges
+        the tick when its own program has been read back too: here if
+        that was in this call, else at its fold (`_program_read`)."""
+        fold_ms = self._phase_s["fold"] * 1e3
+        wait_ms = self._phase_s["readback_wait"] * 1e3
         sample = self.perf.commit(wall * 1e3)
         if sample is None:
             return
         if self.attrib is not None:
             # split the tick's shared costs + times across its
             # per-request charges (ISSUE 13)
-            self.attrib.commit(sample, host_ms=host_ms,
-                               device_ms=device_ms)
+            self.attrib.commit(sample, host_ms=fold_ms,
+                               device_ms=wait_ms)
         if self.anomaly is not None:
-            ev = self.anomaly.observe(
-                sample, wall * 1e3, host_ms, device_ms, self.compiles,
-                self.perf.envelope.peak_flops * self.perf.n_chips,
-                self.perf.envelope.peak_bytes_per_s * self.perf.n_chips)
-            if ev is not None:
-                self._on_tick_anomaly(ev)
+            # a sample with no forward program (page migration alone)
+            # is judged on the call, as it always was
+            rec = self._judged.setdefault(self.ticks,
+                                          {"device_ms": wait_ms})
+            rec.update(sample=sample, host_ms=wall * 1e3 - wait_ms,
+                       fold_ms=fold_ms, compiles=self.compiles)
+            if "device_ms" in rec:
+                self._judge(self.ticks)
 
     def _close_tick(self, t0: float, carry_s: float,
                     compiles0: int) -> None:
@@ -3126,7 +3251,7 @@ class InferenceEngine:
         if self._step_end is not None:
             # time between two ticks' walls while work remained: the
             # pump's delivery, the event loop, this method's own
-            # epilogue and an armed profile capture's start and stop
+            # epilogue and an armed profile capture's bookkeeping
             gap = max(t0 - self._step_end, 0.0)
             self._gap_total_s += gap
         self._step_end = end if self.has_work() else None
@@ -3735,6 +3860,7 @@ class InferenceEngine:
                 start = getattr(new_tokens, "copy_to_host_async", None)
                 if start is not None:
                     start()      # no-op cost; fold blocks if absent
+        self._program_dispatched()
         if not self._async:
             host = self._read_tokens(new_tokens, of=self.ticks)
             self._fold_rider(host, rows, self.ticks)
@@ -3876,12 +4002,14 @@ class InferenceEngine:
     # -- observability (ISSUE 5) -------------------------------------------
     def profile_next_ticks(self, ticks: int = 8,
                            log_dir: Optional[str] = None) -> str:
-        """Arm on-demand profiling (POST /debug/profile): the next
-        `ticks` engine ticks run under util/profiling.trace
-        (jax.profiler — XLA timeline + HLO ops for TensorBoard /
-        xprof). Returns the log dir; the profiler starts at the NEXT
-        step() and stops after `ticks` ticks. Re-arming while a
-        capture is pending raises (one capture at a time)."""
+        """Arm on-demand profiling (POST /debug/profile): `ticks` engine
+        ticks run under util/profiling.trace (jax.profiler — XLA
+        timeline + HLO ops for TensorBoard / xprof). Returns the log
+        dir. The trace's start is the writer thread's, asked for here;
+        `ticks` counts the ticks that begin after it has started, and
+        the thread stops and writes it after the last.
+        Re-arming while a capture is pending raises (one capture at a
+        time)."""
         if int(ticks) < 1:
             raise ValueError("ticks must be >= 1")
         with self._step_lock:
@@ -3893,11 +4021,10 @@ class InferenceEngine:
             if log_dir is None:
                 import tempfile
                 log_dir = tempfile.mkdtemp(prefix="ray_tpu_llm_prof_")
-            self._profile = {"remaining": int(ticks), "dir": log_dir,
-                             "cm": None, "writer": None}
-        self._count_capture(self._profiles_armed, "manual")
-        self.telemetry.recorder.record(
-            "profile_armed", ticks=int(ticks), log_dir=log_dir)
+            self._arm_profile_locked(ticks, "manual", log_dir)
+            # an operator's capture is asked for at once, not at the
+            # next tick's entry: it is wanted whatever the engine does
+            self._profile_tick_begin()
         return log_dir
 
     def _count_capture(self, table: Dict[str, int], key: str) -> None:
@@ -3907,46 +4034,71 @@ class InferenceEngine:
             table[key] = table.get(key, 0) + 1
 
     def _profile_tick_begin(self) -> None:
-        """Start the armed jax.profiler trace (called under the step
-        lock at tick entry; no-op unless freshly armed). While another
-        session is open (an operator's, the benchmark's) the capture
-        stays armed and starts at the first tick after it: starting
-        under the step lock behind that session's export held every
-        stream 23 and 40 s (PERF.md section 6, PR 32)."""
+        """Tick entry with a capture armed or running, under the step
+        lock: the first tick after the arming asks the writer thread for
+        the start; a tick that begins after the trace has started is one
+        of the capture's. Starting is the thread's (`_profile_start`):
+        `start_trace` took 39-48 ms of every stream's time here
+        (PERF.md section 6, PR 32), and behind another session's export
+        23 and 40 s."""
         ps = self._profile
-        if ps is None or ps["cm"] is not None or ps["writer"] is not None:
+        if ps is None:
             return
+        if ps["state"] == "running":
+            ps["live"] = True
+        elif ps["state"] == "armed":
+            ps["state"] = "starting"
+            self._writer.submit(self._profile_start, ps)
+
+    def _profile_start(self, ps: Dict[str, Any]) -> None:
+        """Start the armed jax.profiler trace (writer thread). While
+        another session is open (an operator's, the benchmark's, or one
+        still being exported) the capture stays armed and the thread
+        asks again every 20 ms, a minute at most."""
         from ...util import profiling
         try:
-            if profiling.session_open():
-                return
+            give_up = time.monotonic() + _START_WAIT_S
+            while profiling.session_open():
+                if ps["aborted"]:
+                    return
+                if time.monotonic() > give_up:
+                    raise TimeoutError(
+                        "another profiler session stayed open for "
+                        f"{_START_WAIT_S:g} s")
+                time.sleep(0.02)
             cm = profiling.trace(ps["dir"])
             cm.__enter__()
         except Exception as e:   # profiler unavailable on this backend
-            self._profile = None
+            if self._profile is ps:
+                self._profile = None
             self.telemetry.recorder.record("profile_error",
                                            error=repr(e))
             return
-        ps["cm"] = cm
-        self._profiles_started += 1
+        with self._captures_lock:
+            ps["cm"] = cm
+            aborted = ps["aborted"]
+            if not aborted:
+                ps["state"] = "running"
+                self._profiles_started += 1
+        if aborted:              # the tick raised while this started
+            self._profile_stop(ps, "profile_aborted")
 
     def _profile_tick_end(self) -> None:
         """Count the captured tick; after the last, hand the capture to
-        a writer thread. stop_trace collects from the runtime and
+        the writer thread. stop_trace collects from the runtime and
         writes the trace: seconds, and 14 s behind another export
         (PERF.md section 6, PR 30), which under the step lock every
         live stream waited out to have one slow tick explained. The
         capture stays the engine's one capture until it is written."""
         ps = self._profile
-        if ps is None or ps["cm"] is None or ps["writer"] is not None:
+        if ps is None or not ps["live"]:
             return
+        ps["live"] = False
         ps["remaining"] -= 1
         if ps["remaining"] > 0:
             return
-        ps["writer"] = threading.Thread(
-            target=self._profile_stop, args=(ps, "profile_done"),
-            name="engine-profile-writer", daemon=True)
-        ps["writer"].start()
+        ps["state"] = "writing"
+        self._writer.submit(self._profile_stop, ps, "profile_done")
 
     def _profile_stop(self, ps: Dict[str, Any], event: str) -> None:
         try:
@@ -3961,42 +4113,52 @@ class InferenceEngine:
                 self._profile = None
 
     def wait_for_profile(self, timeout: Optional[float] = None) -> bool:
-        """Block until a finished capture's trace is written (tests,
-        and a caller about to read the log dir). True when no capture
-        is being written any more."""
-        ps = self._profile
-        writer = ps["writer"] if ps is not None else None
-        if writer is not None:
-            writer.join(timeout)
-            return not writer.is_alive()
-        return True
+        """Block until the writer thread has nothing left to do: an
+        asked-for start has been made, a finished capture's trace is
+        written, a bundle is in the spool (tests, and a caller about to
+        read the log dir or the spool). True when it is idle."""
+        return self._writer.wait_idle(timeout)
 
     def _profile_abort(self) -> None:
-        """Stop an in-flight capture after a mid-tick exception: flush
-        whatever was recorded so far and disarm, so the next
-        profile_next_ticks() isn't wedged behind a phantom capture.
-        In line: the tick has failed already, nothing waits on it."""
+        """Disarm after a mid-tick exception, so the next
+        profile_next_ticks() isn't wedged behind a phantom capture, and
+        stop a capture in flight with whatever was recorded so far. In
+        line: the tick has failed already, nothing waits on it. A start
+        the writer thread is in the middle of is stopped by the thread
+        as soon as it is made."""
         ps = self._profile
-        if ps is None or ps["writer"] is not None:
+        if ps is None or ps["state"] == "writing":
             return
         self._profile = None
-        if ps["cm"] is not None:
+        with self._captures_lock:
+            ps["aborted"] = True
+            started = ps["cm"] is not None
+        if started:
             self._profile_stop(ps, "profile_aborted")
 
     def _arm_profile_locked(self, ticks: int,
-                            trigger: str = "tick_anomaly"
+                            trigger: str = "tick_anomaly",
+                            log_dir: Optional[str] = None
                             ) -> Optional[str]:
-        """profile_next_ticks' body WITHOUT taking the step lock — the
-        anomaly detector fires inside step() with the lock held, so
-        the auto-arm path must not re-enter it. No-op (None) when a
+        """Arm a capture of `ticks` ticks, step lock held (the anomaly
+        detector fires inside step(); profile_next_ticks takes the lock
+        and comes here): a dict, a counter and a flight event, nothing
+        that takes time. The next tick's entry asks the writer thread
+        for the start, so that an engine with no tick left after a flag
+        opens no session it could not close. No-op (None) when a
         capture is already armed instead of raising: an anomaly storm
         must never crash the tick it is trying to explain."""
         if self._profile is not None:
             return None
-        import tempfile
-        log_dir = tempfile.mkdtemp(prefix="ray_tpu_llm_prof_")
-        self._profile = {"remaining": int(ticks), "dir": log_dir,
-                         "cm": None, "writer": None}
+        if log_dir is None:
+            import tempfile
+            log_dir = tempfile.mkdtemp(prefix="ray_tpu_llm_prof_")
+        # state: armed -> starting (the writer thread has been asked) ->
+        # running (cm is the trace) -> writing (handed over to be stopped
+        # and written); live: the current tick began under the trace
+        self._profile = {
+            "remaining": int(ticks), "dir": log_dir, "state": "armed",
+            "cm": None, "live": False, "aborted": False}
         self._count_capture(self._profiles_armed, trigger)
         self.telemetry.recorder.record(
             "profile_armed", ticks=int(ticks), log_dir=log_dir,
@@ -4005,12 +4167,14 @@ class InferenceEngine:
 
     def _on_tick_anomaly(self, ev: Dict[str, Any]) -> None:
         """React to a classified tick anomaly (ISSUE 13): record the
-        flight event with the offending batch composition, auto-arm a
-        profile capture of the next ticks, and drop a rate-limited
-        black-box bundle (all decisions — including the rate limits —
+        flight event with the offending batch composition, arm a profile
+        capture of the next ticks, and have a rate-limited black-box
+        bundle dropped (all decisions — including the rate limits —
         were made by the detector; this just acts on them). Runs under
-        the step lock on an ALREADY-slow tick, so the capture cost
-        never taxes a healthy one."""
+        the step lock, at the flagged tick's fold, and only arms: the
+        trace is started and the bundle gathered and written by the
+        writer thread, so that a flag costs the streams nothing
+        (`lock_hold_s` is the time spent here)."""
         # "kind" would collide with the recorder's positional event
         # kind — the classification rides as "anomaly_kind"
         fields = {("anomaly_kind" if k == "kind" else k): v
@@ -4025,8 +4189,8 @@ class InferenceEngine:
             # "anomaly_event" — the bundle already carries the
             # detector's stats under "anomaly", and extra is applied
             # last (it would silently replace them)
-            self.dump_blackbox("tick_anomaly",
-                               extra={"anomaly_event": ev})
+            self._writer.submit(self.dump_blackbox, "tick_anomaly", None,
+                                {"anomaly_event": ev})
 
     def _on_alert_event(self, kind: str, event: Dict[str, Any]) -> None:
         """FlightRecorder alert hook: a guard violation landing in the
@@ -4318,7 +4482,9 @@ class InferenceEngine:
                 snap["self_captures"] = {
                     "profiles_armed": dict(self._profiles_armed),
                     "profiles_started": self._profiles_started,
-                    "blackbox_dumps": dict(self._blackbox_dumps)}
+                    "blackbox_dumps": dict(self._blackbox_dumps),
+                    # seconds the step lock was held by all of that
+                    "lock_hold_s": round(self._capture_hold_s, 6)}
             # free_pages, total_pages and occupancy of the FULLEST
             # cache group (the one that gates admission), and
             # `cache_groups`: each group's row, layers, window, pages

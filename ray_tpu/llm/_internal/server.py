@@ -800,7 +800,11 @@ class LLMServerImpl:
 
     async def debug_bundles(self) -> List[Dict[str, Any]]:
         """Black-box spool listing (id, cause, ts, bytes) — oldest
-        first; served merged at GET /fleet/debug/bundles."""
+        first; served merged at GET /fleet/debug/bundles. A bundle the
+        engine's writer thread is still gathering (a tick anomaly's) is
+        waited for, briefly and off the event loop."""
+        await asyncio.get_running_loop().run_in_executor(
+            None, self.engine.wait_for_profile, 5.0)
         return self.engine.blackbox.list()
 
     async def debug_bundle(self, bundle_id: str

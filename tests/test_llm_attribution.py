@@ -18,8 +18,11 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
+import time
+
 from ray_tpu.llm._internal.anomaly import (AnomalyConfig,
-                                           TickAnomalyDetector)
+                                           TickAnomalyDetector,
+                                           own_program_ms)
 from ray_tpu.llm._internal.attribution import (CONSERVED_FIELDS,
                                                ReceiptLedger,
                                                _largest_remainder_split)
@@ -407,15 +410,16 @@ def test_forced_recompile_produces_classified_capture():
     comp0 = eng.compiles
     for _ in range(30):
         eng.step()
-        if eng.anomaly.anomalies_total > base_anoms:
+        # (a loaded host can have another tick flagged first)
+        if eng.anomaly.by_kind.get("recompile"):
             break
     assert eng.compiles > comp0           # the recompile really ran
     assert eng.anomaly.anomalies_total > base_anoms
     events = eng.telemetry.recorder.events()
-    anoms = [e for e in events if e["event"] == "tick_anomaly"]
-    assert anoms, "no tick_anomaly flight event"
+    anoms = [e for e in events if e["event"] == "tick_anomaly"
+             and e["anomaly_kind"] == "recompile"]
+    assert anoms, "no classified tick_anomaly flight event"
     ev = anoms[0]
-    assert ev["anomaly_kind"] == "recompile"
     assert ev["compile_delta"] >= 1
     assert ev["wall_ms"] > ev["predicted_ms"]
     assert "composition" in ev and ev["composition"]["dispatches"] >= 1
@@ -423,20 +427,26 @@ def test_forced_recompile_produces_classified_capture():
     armed = [e for e in events if e["event"] == "profile_armed"
              and e.get("trigger") == "tick_anomaly"]
     assert armed, "profile capture was not auto-armed"
-    # black-box bundle dropped and fetchable from the spool
+    # black-box bundle dropped (by the writer thread, off the step
+    # lock) and fetchable from the spool
+    assert eng.wait_for_profile(60)
     bundles = eng.blackbox.list()
     causes = {b["cause"] for b in bundles}
     assert "tick_anomaly" in causes
-    bid = next(b["id"] for b in bundles
-               if b["cause"] == "tick_anomaly")
-    bundle = eng.blackbox.read(bid)
-    assert bundle["anomaly_event"]["kind"] == "recompile"
+    bundle = next(
+        b for b in (eng.blackbox.read(b["id"]) for b in bundles
+                    if b["cause"] == "tick_anomaly")
+        if b["anomaly_event"]["kind"] == "recompile")
     # the triggering event must not displace the detector's stats
     assert bundle["anomaly"]["anomalies_total"] >= 1
     assert bundle["attribution"] is not None
     # anomaly state rides stats() and the fleet snapshot brief
     assert eng.stats()["anomaly"]["anomalies_total"] >= 1
     assert eng.stats()["anomaly"]["by_kind"].get("recompile", 0) >= 1
+    # leave no profiler session open for the tests after this one
+    assert eng.wait_for_profile(60)
+    eng._profile_abort()
+    assert eng.wait_for_profile(60)
 
 
 def test_anomaly_profile_arm_does_not_wedge_manual_arming():
@@ -446,13 +456,263 @@ def test_anomaly_profile_arm_does_not_wedge_manual_arming():
     eng = _steady_engine()
     eng.profile_next_ticks(2)
     assert eng._arm_profile_locked(2) is None      # already armed
-    for _ in range(3):
-        eng.step()
-    assert eng.wait_for_profile(60)                # written off-tick
+    _run_capture(eng)
     assert eng._profile is None                    # capture completed
     assert eng.profile_next_ticks(1)               # manual re-arm ok
-    for _ in range(2):
+    _run_capture(eng)
+
+
+def _run_capture(eng, max_ticks=16):
+    """Step an armed capture to its end: the writer thread starts the
+    trace, the ticks that begin after that count, the thread stops and
+    writes it."""
+    assert eng.wait_for_profile(60)                # started off-tick
+    for _ in range(max_ticks):
+        if eng._profile is None or eng._profile["state"] == "writing":
+            break
         eng.step()
+    assert eng.wait_for_profile(60)                # written off-tick
+    assert eng._profile is None
+
+
+# ------------------------ a tick is judged on its own program (PR 39)
+
+def _pipeline(programs):
+    """A two-deep pipeline's timeline. `programs`: (kind, device ms) in
+    dispatch order; every call dispatches its tick in 1 ms of host time
+    and then waits for the tick BEFORE (lagged folds, the ragged tick's
+    too: the wait for a 40 ms program falls in the next call). Returns
+    for each tick but the last (kind, the wall of the call that
+    dispatched it, its own program's ms by `own_program_ms`)."""
+    t = dev_free = read_before = 0.0
+    rows = []
+    for i, (kind, ms) in enumerate(programs):
+        t0 = t
+        t += 1e-3                                   # host: dispatch
+        dispatched = t
+        done = max(dev_free, dispatched) + ms * 1e-3
+        dev_free = done
+        if i:
+            t = max(t, rows[-1]["done"])            # wait for tick i-1
+            rows[-1]["read"] = t
+            rows[-1]["own_ms"] = own_program_ms(
+                rows[-1]["dispatched"], read_before, t)
+            read_before = t
+        rows.append({"kind": kind, "dispatched": dispatched,
+                     "done": done, "wall_ms": (t - t0) * 1e3})
+    return rows[:-1]
+
+
+def test_a_tick_behind_a_long_program_is_judged_on_its_own():
+    """Decode programs of 5 ms, every tenth tick a ragged program of
+    40 ms whose wait falls in the NEXT call. Fed the call's wall (what
+    the engine handed over until PR 39) every decode tick behind a
+    ragged one reads as a device straggler; fed the tick's own program
+    nothing does, and a decode program that itself takes 40 ms still
+    does."""
+    programs = [("ragged" if i % 10 == 9 else "decode",
+                 40.0 if i % 10 == 9 else 5.0) for i in range(400)]
+    rows = _pipeline(programs)
+    behind = [i for i in range(1, len(rows))
+              if rows[i]["kind"] == "decode"
+              and rows[i - 1]["kind"] == "ragged"]
+    assert len(behind) >= 38
+
+    def feed(walls):
+        det = TickAnomalyDetector(AnomalyConfig(
+            warmup_ticks=16, min_wall_ms=0.1))
+        det._gc = _GcStub()
+        det._gc_prev = 0.0
+
+        class S:
+            flops, hbm_bytes, bytes_h2d, bytes_d2h = 2e9, 1e9, 0.0, 0.0
+            dispatches, decode_tokens, prefill_tokens = 1, 3, 0
+
+        flagged = []
+        for i, (row, (wall, device)) in enumerate(zip(rows, walls)):
+            s = S()
+            s.kind = row["kind"]
+            ev = det.observe(s, wall, 0.1, device, compiles=0,
+                             peak_flops=1e12, peak_bytes=1e12)
+            if ev is not None:
+                flagged.append((i, ev["kind"]))
+        return det, flagged
+
+    # the parent's rule: the call's wall, its readback wait as device
+    _, flagged = feed([(r["wall_ms"], r["wall_ms"] - 1.0) for r in rows])
+    warmed = [i for i in behind if i > 200]
+    assert {i for i, _ in flagged} >= set(warmed)
+    assert all(k == "device_straggler" for _, k in flagged)
+    # the tick's own program, plus its call's host millisecond: no
+    # decode tick is flagged; the ragged ticks are (40 ms among 5), as
+    # long as their kind is judged against the pooled window
+    det, flagged = feed([(1.0 + r["own_ms"], r["own_ms"]) for r in rows])
+    assert [i for i, _ in flagged if rows[i]["kind"] == "decode"] == []
+    assert len(flagged) <= 16 and flagged[-1][0] < 170
+
+    class Long:
+        flops, hbm_bytes, bytes_h2d, bytes_d2h = 2e9, 1e9, 0.0, 0.0
+        dispatches, decode_tokens, prefill_tokens = 1, 3, 0
+        kind = "decode"
+
+    ev = det.observe(Long(), 41.0, 0.1, 40.0, compiles=0,
+                     peak_flops=1e12, peak_bytes=1e12)
+    assert ev is not None and ev["kind"] == "device_straggler"
+
+
+def test_a_swell_of_load_is_not_a_run_of_stragglers():
+    """The prediction holds flat (the weights' one read) while a decode
+    tick's program grows with its rows: 4.5 ms for one row, 18 ms for
+    twelve. Twelve-row ticks are judged against twelve-row ticks, so a
+    swell after a quiet stretch flags at most its size's own warm-up
+    against the pooled window, not every tick until the median moves;
+    a twelve-row tick that stalls is still seen."""
+    det, S = _warm_detector(n=0)
+
+    def tick(rows, wall):
+        s = S()
+        s.decode_tokens = rows
+        return det.observe(s, wall, 0.1, wall - 1.0, compiles=5,
+                           peak_flops=1e12, peak_bytes=1e12)
+
+    for _ in range(300):
+        assert tick(1, 4.5) is None
+    flagged = [tick(12, 18.0) is not None for _ in range(300)]
+    assert sum(flagged) <= 16 and not any(flagged[16:])
+    assert tick(1, 4.5) is None and tick(2, 5.0) is None
+    ev = tick(12, 90.0)
+    assert ev is not None and ev["kind"] == "device_straggler"
+    assert TickAnomalyDetector._like(S()) == "decode/4"      # 3 tokens
+
+
+def test_own_program_ms_books_a_wait_to_the_tick_waited_for():
+    # dispatched at 1.0, the tick before read at 1.5, its own at 1.6
+    assert own_program_ms(1.0, 1.5, 1.6) == pytest.approx(100.0)
+    # synchronous: nothing read since its dispatch
+    assert own_program_ms(1.0, 0.7, 1.25) == pytest.approx(250.0)
+    assert own_program_ms(2.0, 1.0, 1.9) == 0.0
+
+
+def test_a_ragged_tick_between_decode_ticks_flags_nothing():
+    """Lagged folds on: the calls around an admission wait for other
+    ticks' programs (the drain before the ragged tick, the retirement's
+    double fold). Each tick is judged on its own, so a prompt of a shape
+    the engine has run before leaves the detector silent."""
+    eng = make_engine(
+        max_batch_size=4, num_pages=128,
+        anomaly={"warmup_ticks": 16, "min_wall_ms": 0.0})
+    assert eng._async
+    rng = np.random.default_rng(5)
+
+    def prompt(rid, n, max_tokens):
+        eng.add_request(Request(rid, rng.integers(2, 250, n).tolist(),
+                                SamplingParams(max_tokens=max_tokens)))
+
+    for i in range(3):
+        prompt(f"s{i}", 12, 230)
+    for round_ in range(6):
+        prompt(f"w{round_}", 12, 2)
+        for _ in range(12):
+            eng.step()
+    assert eng.anomaly.stats()["warmed"]
+    assert eng.stats()["tick_times"]["lagged_ticks"] > 40
+    compiles, base = eng.compiles, eng.anomaly.anomalies_total
+    judged = eng.anomaly.ticks
+    for round_ in range(4):
+        prompt(f"p{round_}", 12, 2)
+        for _ in range(12):
+            eng.step()
+    assert eng.compiles == compiles
+    assert eng.anomaly.ticks >= judged + 40     # every tick is judged
+    assert eng.anomaly.anomalies_total == base, eng.anomaly.stats()
+    assert not eng._judged or max(eng._judged) == eng.ticks
+
+
+def test_reactions_to_a_flag_run_off_the_step_lock(monkeypatch):
+    """`start_trace` and the bundle's dump each sleep 0.2 s here: the
+    tick after a flag still returns at once, and the step lock was held
+    for the arming alone."""
+    import contextlib
+    from ray_tpu.util import profiling
+
+    @contextlib.contextmanager
+    def slow_trace(log_dir):
+        time.sleep(0.2)
+        yield
+
+    # a detector that never judges: the flag below is the only one
+    eng = make_engine(max_batch_size=4, num_pages=128,
+                      anomaly={"warmup_ticks": 10 ** 6})
+    for i in range(3):
+        eng.add_request(Request(f"s{i}", list(range(2, 14)),
+                                SamplingParams(max_tokens=200)))
+    for _ in range(8):
+        eng.step()
+    monkeypatch.setattr(profiling, "trace", slow_trace)
+    dump = eng.blackbox.dump
+    monkeypatch.setattr(eng.blackbox, "dump", lambda cause, bundle: (
+        time.sleep(0.2), dump(cause, bundle))[1])
+    eng._held(eng._on_tick_anomaly, {
+        "kind": "unknown", "arm_profile": True, "dump": True})
+    longest = 0.0
+    for _ in range(3):
+        t0 = time.perf_counter()
+        eng.step()
+        longest = max(longest, time.perf_counter() - t0)
+    assert longest < 0.1
+    assert eng.wait_for_profile(60)
+    _run_capture(eng)
+    sc = eng.stats()["self_captures"]
+    assert sc["profiles_armed"] == {"tick_anomaly": 1}
+    assert sc["profiles_started"] == 1
+    assert sc["blackbox_dumps"] == {"tick_anomaly": 1}
+    # the arming's microseconds (10 ms on an idle host; a loaded one
+    # has read 12), of the 0.4 s that the two stubs slept
+    assert 0.0 < sc["lock_hold_s"] < 0.05
+
+
+@pytest.mark.parametrize("started", [True, False],
+                         ids=["trace running", "start in flight"])
+def test_a_mid_tick_raise_leaves_no_profiler_session(monkeypatch,
+                                                     started):
+    """`_profile_abort` after a raise inside a tick: a running capture
+    is stopped in line, a start the writer thread is in the middle of
+    is stopped by the thread as soon as it is made; either way no
+    session stays open and the next capture can be armed."""
+    import contextlib
+    from ray_tpu.util import profiling
+    eng = _steady_engine()
+    if not started:
+        real = profiling.trace
+
+        @contextlib.contextmanager
+        def slow_trace(log_dir):
+            time.sleep(0.3)
+            with real(log_dir):
+                yield
+
+        monkeypatch.setattr(profiling, "trace", slow_trace)
+    eng.profile_next_ticks(4)
+    if started:
+        assert eng.wait_for_profile(60)
+        assert eng._profile["cm"] is not None
+
+    def boom(touched):
+        raise RuntimeError("mid-tick")
+
+    monkeypatch.setattr(eng, "_step_tick", boom)
+    with pytest.raises(RuntimeError, match="mid-tick"):
+        eng.step()
+    monkeypatch.undo()
+    assert eng._profile is None
+    assert eng.wait_for_profile(60)
+    assert not profiling.session_open()
+    events = [e["event"] for e in eng.telemetry.recorder.events()]
+    assert "profile_aborted" in events
+    assert eng.stats()["self_captures"]["profiles_started"] == int(started)
+    assert eng.profile_next_ticks(1)
+    _run_capture(eng)
+    assert not profiling.session_open()
 
 
 # --------------------------------------------------- ledger edge cases

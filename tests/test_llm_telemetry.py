@@ -312,6 +312,7 @@ def test_profile_next_ticks_writes_trace():
     d = eng.profile_next_ticks(2)
     with pytest.raises(RuntimeError, match="already"):
         eng.profile_next_ticks(1)        # one capture at a time
+    assert eng.wait_for_profile(60)      # the trace is started off-tick
     eng.generate([rng.integers(2, 200, 8).tolist()],
                  SamplingParams(max_tokens=4))
     assert eng.wait_for_profile(60)      # the trace is written off-tick
@@ -324,6 +325,7 @@ def test_profile_next_ticks_writes_trace():
         eng.profile_next_ticks(0)
     # capture finished: re-arming is allowed again
     eng.profile_next_ticks(1, log_dir=d)
+    assert eng.wait_for_profile(60)
     eng.generate([rng.integers(2, 200, 8).tolist()],
                  SamplingParams(max_tokens=2))
     # leave no export running: while one is, the next engine's armed
@@ -353,6 +355,7 @@ def test_tick_does_not_wait_for_the_capture_to_be_written(monkeypatch):
     monkeypatch.setattr(profiling, "trace", slow_trace)
     eng = make_engine()
     eng.profile_next_ticks(1)
+    assert eng.wait_for_profile(60)      # started, by the same thread
     eng.add_request(Request("p", list(range(2, 10)),
                             SamplingParams(max_tokens=6)))
     t0 = time.monotonic()
@@ -372,6 +375,7 @@ def test_tick_does_not_wait_for_the_capture_to_be_written(monkeypatch):
     kinds = [e["event"] for e in eng.telemetry.recorder.events()]
     assert "profile_done" in kinds and eng._profile is None
     eng.profile_next_ticks(1)            # re-arming works again
+    assert eng.wait_for_profile(60)
 
 
 def test_profile_disarms_on_mid_tick_exception(monkeypatch):
@@ -382,6 +386,7 @@ def test_profile_disarms_on_mid_tick_exception(monkeypatch):
     eng = make_engine()
     rng = np.random.default_rng(3)
     eng.profile_next_ticks(4)
+    assert eng.wait_for_profile(60)       # the trace is running
 
     def boom(touched):
         raise RuntimeError("mid-tick failure")
@@ -395,8 +400,10 @@ def test_profile_disarms_on_mid_tick_exception(monkeypatch):
     if "profile_error" not in kinds:      # backend supports profiling
         assert "profile_aborted" in kinds
     eng.profile_next_ticks(1)             # re-arming works again
+    assert eng.wait_for_profile(60)
     eng.generate([rng.integers(2, 200, 8).tolist()],
                  SamplingParams(max_tokens=2))
+    assert eng.wait_for_profile(60)
 
 
 # ------------------------------------------------- instrumentation lint

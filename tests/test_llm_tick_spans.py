@@ -9,6 +9,7 @@ are checked where a reader finds them, not where the engine puts them.
 """
 
 import asyncio
+import contextlib
 import glob
 import inspect
 import os
@@ -356,9 +357,10 @@ def test_self_captures_count_arming_starting_and_dumping(tmp_path):
     eng.step()
     assert eng.stats()["self_captures"] == {
         "profiles_armed": {}, "profiles_started": 0,
-        "blackbox_dumps": {}}
+        "blackbox_dumps": {}, "lock_hold_s": 0.0}
     eng.profile_next_ticks(2, str(tmp_path / "prof"))
     assert eng._arm_profile_locked(2) is None     # armed already: no count
+    assert eng.wait_for_profile(60)               # started off-tick
     while eng.has_work():
         eng.step()
     assert eng.wait_for_profile(60)               # written off-tick
@@ -369,6 +371,7 @@ def test_self_captures_count_arming_starting_and_dumping(tmp_path):
     assert sc["profiles_armed"] == {"manual": 1, "tick_anomaly": 1}
     assert sc["profiles_started"] == 1
     assert sc["blackbox_dumps"] == {"manual": 2}
+    assert 0.0 < sc["lock_hold_s"] < 0.05         # the ticks' counting
     # the operator's capture is the light one and holds the spans
     names = {s[1] for s in _host_spans(str(tmp_path / "prof"))}
     assert {"engine.step", "engine.dispatch"} <= names
@@ -391,12 +394,15 @@ def test_an_armed_capture_waits_out_another_profiler_session(
     eng.step()
     assert eng._profile is not None and eng._profile["cm"] is None
     assert eng.stats()["self_captures"]["profiles_started"] == 0
+    assert not eng.wait_for_profile(0.1)          # the thread asks on
     monkeypatch.setattr(jax_profiler._profile_state, "profile_session",
                         None)
+    assert eng.wait_for_profile(60)               # started off-tick
     while eng.has_work():
         eng.step()
     assert eng.wait_for_profile(60)
     assert eng.stats()["self_captures"]["profiles_started"] == 1
+    assert eng._profile is None
 
 
 def test_session_open_is_not_known_where_jax_keeps_it_elsewhere(
@@ -411,12 +417,22 @@ def test_session_open_is_not_known_where_jax_keeps_it_elsewhere(
     eng = make_engine()
     eng.add_request(_req("w", 12, max_tokens=6))
     eng.step()
+    started = []
+
+    @contextlib.contextmanager
+    def trace(log_dir):
+        started.append(log_dir)
+        yield
+
+    monkeypatch.setattr(profiling, "trace", trace)
     eng.profile_next_ticks(2, str(tmp_path / "prof"))
+    assert eng.wait_for_profile(60)               # started off-tick
     monkeypatch.undo()
     while eng.has_work():
         eng.step()
     assert eng.wait_for_profile(60)
     assert eng.stats()["self_captures"]["profiles_started"] == 1
+    assert started == [str(tmp_path / "prof")] and eng._profile is None
 
 
 def test_a_fault_in_the_question_is_a_profile_error_not_a_failed_tick(
