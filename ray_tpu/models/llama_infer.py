@@ -213,6 +213,21 @@ def lora_scan_xs(lora: Optional[dict]):
     return lora if lora else None
 
 
+def _whole_pools(*pools):
+    """What the kernel path of both forwards gives the layer scan in
+    place of the pools: each pool ([L, pages, page, KVH(, D)]; the
+    scale pools of quantized values too) viewed as [L * pages, ...], a
+    reshape of the two leading axes that moves no data, and every
+    layer's first page in that view ([L], an xs of the scan). A layer's
+    call gets the pools WHOLE and `page_tables + first page`: the
+    kernels keep their pools in HBM and reach pages only through the
+    table, whereas a pool sliced by layer in the scan is copied out
+    before a Pallas call — twice the pool's bytes a tick."""
+    n_layers, pages = pools[0].shape[:2]
+    return (tuple(p.reshape((-1,) + p.shape[2:]) for p in pools),
+            jnp.arange(n_layers, dtype=jnp.int32) * pages)
+
+
 # -------------------------------------------------------------- ragged step
 
 def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
@@ -295,9 +310,10 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
     use_kernel = impl in ("pallas", "pallas_interpret")
     kernel_quant = use_kernel and quantized
     if use_kernel:
-        # pool stays layer-major in HBM: the scan slices one layer's
-        # [pages, page, KVH, D] and the kernel streams pages from it
-        k_by_layer, v_by_layer = k_pages, v_pages
+        # the scan carries each layer's first page, not its pages
+        pools, first_pages = _whole_pools(
+            k_pages, v_pages, *((k_scales, v_scales) if quantized else ()))
+        kv_xs = (first_pages,)
         # the kernel's grid: the same list for every layer
         work = ragged_work_list(slot_ids, valid, start,
                                 ragged_q_block(t))
@@ -305,25 +321,22 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
         ctx_tables = (page_tables if ctx_pages < 0
                       else page_tables[:, :ctx_pages])
         if quantized:
-            k_by_layer, v_by_layer = gather_kv_quant(
+            kv_xs = gather_kv_quant(
                 k_pages, v_pages, k_scales, v_scales, ctx_tables,
                 cfg.head_dim)
         else:
-            k_by_layer, v_by_layer = gather_kv(k_pages, v_pages,
-                                               ctx_tables, cfg.head_dim)
+            kv_xs = gather_kv(k_pages, v_pages, ctx_tables, cfg.head_dim)
 
     def layer_fn(x, inp):
-        if kernel_quant:
-            layer, k_l, v_l, ks_l, vs_l, lora_l = inp
-        else:
-            layer, k_l, v_l, lora_l = inp
-            ks_l = vs_l = None
+        layer, *kv_l, lora_l = inp
 
         def attn_fn(q, k, v):
             if not use_kernel:
+                k_l, v_l = kv_l
                 return ragged_prefill_decode_attention(
                     q, k_l, v_l, k, v, slot_ids, positions, valid,
                     start)
+            (first_page,) = kv_l
             # positional wrapper so shard_map's in_specs line up
             def kernel(q_, kp, vp, tb, si, po, va, st, kn, vn, items,
                        segs, ksl=None, vsl=None):
@@ -355,22 +368,17 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
                 kernel = jax.shard_map(
                     kernel, mesh=mesh, in_specs=tuple(in_specs),
                     out_specs=P(None, "tp", None), check_vma=False)
-            args = (q, k_l, v_l, page_tables, slot_ids,
-                    positions, valid, start, k, v, *work)
-            if kernel_quant:
-                args += (ks_l, vs_l)
-            return kernel(*args)
+            return kernel(q, *pools[:2], page_tables + first_page,
+                          slot_ids, positions, valid, start, k, v, *work,
+                          *pools[2:])
 
         return _layer_body(
             cfg, dt, x, layer, lora_l, lora_idx, (t,),
             lambda a: _rope_single(a, cos, sin), attn_fn,
             psum_axis=psum_axis)
 
-    scan_xs = (params["layers"], k_by_layer, v_by_layer)
-    if kernel_quant:
-        scan_xs += (k_scales, v_scales)
-    scan_xs += (lora_scan_xs(lora),)
-    x, (ks, vs) = jax.lax.scan(layer_fn, x, scan_xs)
+    x, (ks, vs) = jax.lax.scan(
+        layer_fn, x, (params["layers"], *kv_xs, lora_scan_xs(lora)))
     # ks/vs: (L, T, KVH, D) -> token-major (T, L, KVH, D)
     k_rows = jnp.swapaxes(ks, 0, 1)
     v_rows = jnp.swapaxes(vs, 0, 1)
@@ -452,24 +460,21 @@ def decode_step(cfg: LlamaConfig, params: Dict[str, Any],
     use_kernel = impl in ("pallas", "pallas_interpret")
     kernel_quant = use_kernel and quantized
     if use_kernel:
-        # Pool is layer-major already: scan slices (pages, page, KVH, D).
-        k_by_layer, v_by_layer = k_pages, v_pages
+        # the scan carries each layer's first page, not its pages
+        pools, first_pages = _whole_pools(
+            k_pages, v_pages, *((k_scales, v_scales) if quantized else ()))
+        kv_xs = (first_pages,)
     else:
         # One gather of the whole context for all layers, layer-major.
         if quantized:
-            k_by_layer, v_by_layer = gather_kv_quant(
+            kv_xs = gather_kv_quant(
                 k_pages, v_pages, k_scales, v_scales, page_tables,
                 cfg.head_dim)
         else:
-            k_by_layer, v_by_layer = gather_kv(k_pages, v_pages,
-                                               page_tables, cfg.head_dim)
+            kv_xs = gather_kv(k_pages, v_pages, page_tables, cfg.head_dim)
 
     def layer_fn(x, inp):
-        if kernel_quant:
-            layer, k_l, v_l, ks_l, vs_l, lora_l = inp
-        else:
-            layer, k_l, v_l, lora_l = inp
-            ks_l = vs_l = None
+        layer, *kv_l, lora_l = inp
 
         def attn_fn(q, k, v):
             # The just-computed token's KV is not yet in the pages: the
@@ -477,10 +482,12 @@ def decode_step(cfg: LlamaConfig, params: Dict[str, Any],
             # the gather path appends it to the dense context
             # (append_len=1).
             if not use_kernel:
+                k_l, v_l = kv_l
                 k_full = jnp.concatenate([k_l, k[:, None]], axis=1)
                 v_full = jnp.concatenate([v_l, v[:, None]], axis=1)
                 return paged_attention_on_gathered(
                     q, k_full, v_full, positions, append_len=1)
+            (first_page,) = kv_l
             base = functools.partial(
                 paged_decode_with_new_token,
                 interpret=(impl == "pallas_interpret"))
@@ -509,20 +516,15 @@ def decode_step(cfg: LlamaConfig, params: Dict[str, Any],
                 kernel = jax.shard_map(
                     kernel, mesh=mesh, in_specs=tuple(in_specs),
                     out_specs=P(None, "tp", None), check_vma=False)
-            args = (q, k_l, v_l, page_tables, positions, k, v)
-            if kernel_quant:
-                args += (ks_l, vs_l)
-            return kernel(*args)
+            return kernel(q, *pools[:2], page_tables + first_page,
+                          positions, k, v, *pools[2:])
 
         return _layer_body(cfg, dt, x, layer, lora_l, lora_idx, (b,),
                            lambda a: _rope_single(a, cos, sin),
                            attn_fn, psum_axis=psum_axis)
 
-    scan_xs = (params["layers"], k_by_layer, v_by_layer)
-    if kernel_quant:
-        scan_xs += (k_scales, v_scales)
-    scan_xs += (lora_scan_xs(lora),)
-    x, (ks, vs) = jax.lax.scan(layer_fn, x, scan_xs)
+    x, (ks, vs) = jax.lax.scan(
+        layer_fn, x, (params["layers"], *kv_xs, lora_scan_xs(lora)))
     k_rows = jnp.transpose(ks, (1, 0, 2, 3))        # (B, L, KVH, D)
     v_rows = jnp.transpose(vs, (1, 0, 2, 3))
     if quantized:

@@ -6,8 +6,11 @@ external vLLM CUDA kernels; SURVEY.md §7 step 10). Layout decision
 
     k_pages, v_pages: [n_layers, num_pages, page_size, n_kv_heads, head_dim]
 
-chosen for the hot paths at once: the decode scan over layers slices
-dim 0 (no per-step transpose of the pool); (page, token) are adjacent so
+chosen for the hot paths at once: a layer's pages are contiguous, so
+the scan over layers hands the kernels the pool whole, viewed as
+[n_layers * num_pages, ...], and a page table shifted by
+layer * num_pages (no slice, which XLA would copy out before a Pallas
+call, and no transpose of the pool); (page, token) are adjacent so
 KV writes flatten the pool to [L, P*page_size, KVH, D] and scatter on a
 SINGLE index dim (row = page*page_size + offset) — the
 two-index-dim form (.at[:, page_idx, :, offset]) lowers to a
@@ -451,7 +454,9 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     """Pallas paged decode attention for one layer.
 
     q: [B, H, D]; k_pages/v_pages: [num_pages, page_size, KVH, D]
-    (already sliced to the layer); page_tables: [B, max_pages] int32;
+    (one layer's pages, or the pool of all layers flattened over them
+    with the table shifted to the layer's pages);
+    page_tables: [B, max_pages] int32;
     seq_lens: [B] int32. Returns [B, H, D], or with return_stats=True
     (out, m, l) where m/l are the [B, H] online-softmax row max /
     denominator — callers merge extra not-yet-paged KV (the token being

@@ -663,9 +663,12 @@ def ragged_paged_attention_pallas(
     and no gathered [T, ctx, KVH, D] context is ever materialized.
 
     q: [T, H, D] flat ragged batch (kv-major head order);
-    k_pages/v_pages: [num_pages, page_size, KVH, D] (layer slice,
-    stays in HBM); page_tables: [B, max_pages]; slot_ids/positions/
-    valid: [T]; start: [B]; k_new/v_new: [T, KVH, D].
+    k_pages/v_pages: [num_pages, page_size, KVH, D] (stays in HBM and
+    is reached only through the table: the forwards pass the pool of
+    ALL layers, flattened over them, and a table shifted to the
+    layer's pages, since a slice of the pool would be copied first);
+    page_tables: [B, max_pages]; slot_ids/positions/valid: [T];
+    start: [B]; k_new/v_new: [T, KVH, D].
 
     Packing contract (what the engine's `_ragged_step` produces): each
     slot's valid tokens are ONE contiguous run of the flat batch, in

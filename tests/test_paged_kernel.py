@@ -6,12 +6,16 @@ Pool layout: [n_layers, num_pages, page_size, KVH, D]; single-layer
 slices passed to the kernel are [num_pages, page_size, KVH, D].
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ray_tpu.models import llama
 from ray_tpu.models.llama_infer import decode_step, ragged_forward
+from ray_tpu.ops import kv_quant
 from ray_tpu.ops import paged_attention as pa
 
 
@@ -110,6 +114,58 @@ def test_decode_step_kernel_matches_gather():
                                np.asarray(out_logits), atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(rk), np.asarray(ok),
                                atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("kind", ["f32", "int8"])
+@pytest.mark.parametrize("forward", ["decode_step", "ragged_forward"])
+def test_kernel_forwards_read_each_layers_own_pages(forward, kind):
+    """The kernel path hands the kernels the pools of all layers whole
+    and a table shifted to the layer's pages. Three layers whose pools
+    hold DIFFERENT rows and tables over the pool's LAST pages: a layer
+    that read another's pages, or pages past its own, would leave the
+    gather path's logits."""
+    cfg = dataclasses.replace(llama.config("debug", dtype=jnp.float32),
+                              n_layers=3)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(4)
+    B, page_size, num_pages, max_pages = 2, 16, 12, 4
+    shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads,
+             cfg.head_dim)
+    k_pages = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    v_pages = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    kw = {}
+    if kind != "f32":
+        k_pages, k_scales = kv_quant.quantize_rows(k_pages, kind)
+        v_pages, v_scales = kv_quant.quantize_rows(v_pages, kind)
+        kw = dict(kv_kind=kind, k_scales=k_scales, v_scales=v_scales)
+    # pages 3..10 in a random order; 11 is the scatter's scratch page
+    tables = jnp.asarray(
+        num_pages - 2 - rng.permutation(B * max_pages).reshape(
+            B, max_pages), jnp.int32)
+    cached = jnp.asarray([2 * page_size + 3, 5], jnp.int32)
+    if forward == "decode_step":
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, B), jnp.int32)
+        run = lambda impl: decode_step(
+            cfg, params, tokens, cached, k_pages, v_pages, tables,
+            jnp.ones(B, bool), impl=impl, **kw)
+    else:
+        # a decode row of slot 0 and a 6-token chunk of slot 1
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, 7), jnp.int32)
+        run = lambda impl: ragged_forward(
+            cfg, params, tokens, jnp.asarray([0] + [1] * 6, jnp.int32),
+            jnp.asarray([2 * page_size + 3] + list(range(5, 11)),
+                        jnp.int32),
+            jnp.ones(7, bool), cached, jnp.asarray([0, 6], jnp.int32),
+            k_pages, v_pages, tables, ctx_pages=3, impl=impl, **kw)
+    ref, out = run("gather"), run("pallas_interpret")
+    assert len(ref) == len(out) == (3 if kind == "f32" else 5)
+    np.testing.assert_allclose(np.asarray(ref[0]), np.asarray(out[0]),
+                               atol=1e-4, rtol=1e-4)
+    for r, o in zip(ref[1:], out[1:]):          # pools (and scales)
+        assert r.shape == o.shape and r.dtype == o.dtype
+        np.testing.assert_allclose(np.asarray(r, np.float32),
+                                   np.asarray(o, np.float32),
+                                   atol=1e-4, rtol=1e-4)
 
 
 def test_multipage_kernel_matches_dense_gather():
