@@ -245,9 +245,10 @@ class CostModel:
     def _kv_read_bytes(self, ctx: int, n: int = 1) -> float:
         """Bytes of cached context that `n` queries whose last sits
         `ctx` keys into its sequence read, every group: a window group
-        reads at most its window's keys and the n - 1 before them."""
+        reads at most its window's keys and the n - 1 before them; a
+        state group's bytes a row once (0 without one)."""
         if not self._windowed:
-            return (self._read_bytes_per_token
+            return (self.state_bytes_per_row + self._read_bytes_per_token
                     * self._ctx_read_tokens(ctx))
         return self.state_bytes_per_row + float(sum(
             g.read_bytes_per_token * self._ctx_read_tokens(

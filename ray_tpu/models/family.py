@@ -10,15 +10,19 @@ how its totals read in `stats()`, and the engine options the family
 does not compose with (refused at construction with the reason, never
 half-run). Nothing here imports the engine: the engine imports this.
 
-Four families: `llama` (dense RoPE/GQA/SwiGLU decoders, `LlamaConfig`,
+Five families: `llama` (dense RoPE/GQA/SwiGLU decoders, `LlamaConfig`,
 programs unchanged), `deepseek_v3` (latent attention over a latent
 cache, expert layers with the experts held here, `DeepseekV3Config`),
 `trinity` (sliding-window and full-attention layers over two page
-groups, gated QK-normed GQA attention, held experts, `TrinityConfig`)
-and `phi4flash` (Mamba layers whose state is kept a slot beside a
+groups, gated QK-normed GQA attention, held experts, `TrinityConfig`),
+`phi4flash` (Mamba layers whose state is kept a slot beside a
 window page group and ONE layer's pages that eight layers read,
 differential attention, a tied head, a cross-decoder that only sampling
-rows run, `Phi4FlashConfig`).
+rows run, `Phi4FlashConfig`) and `nemotron_h` (Mamba-2 layers whose
+2 MB state a slot a layer lies beside ONE small page group, ungated
+relu^2 held experts, layers that are a mixer or a feed-forward part
+alone, `NemotronHConfig`: the first family with BOTH a rider and a
+state group).
 
 A family with a STATE group (`cache_row.CacheGroup.state`) gets that
 group's arrays in `k_pages` / `v_pages` behind its page groups' pools
@@ -149,7 +153,8 @@ DEEPSEEK_REFUSES = {
 def _families() -> Dict[type, ModelFamily]:
     """Configuration type -> its family (built on first use: the model
     modules import jax)."""
-    from . import deepseek_v3, llama, llama_infer, phi4flash, trinity
+    from . import (deepseek_v3, llama, llama_infer, nemotron_h, phi4flash,
+                   trinity)
     return {
         llama.LlamaConfig: ModelFamily(
             name="llama", init_params=llama.init_params,
@@ -190,6 +195,20 @@ def _families() -> Dict[type, ModelFamily]:
             storage_dtypes=phi4flash.storage_dtypes,
             refuses=phi4flash.PHI4FLASH_REFUSES,
             whole_table_kernels=True),
+        nemotron_h.NemotronHConfig: ModelFamily(
+            name="nemotron_h", init_params=nemotron_h.init_params,
+            ragged_forward=nemotron_h.ragged_forward,
+            decode_step=nemotron_h.decode_step,
+            cache_groups=nemotron_h.cache_groups,
+            # the attention layers' kernel: the dense family's count
+            work_counts=_llama_work_counts,
+            span_counts=nemotron_h.span_counts,
+            rider_len=lambda c: c.n_moe_layers * c.n_held,
+            # the same counts of the same held-expert layer
+            rider_summary=deepseek_v3.routing_summary,
+            storage_dtypes=nemotron_h.storage_dtypes,
+            refuses=nemotron_h.NEMOTRON_H_REFUSES,
+            whole_table_kernels=True),
     }
 
 
@@ -227,7 +246,7 @@ def store_params(family: ModelFamily, cfg, params, shardings=None,
 
 def family_of(cfg) -> ModelFamily:
     """The family that serves `cfg` (a LlamaConfig, a DeepseekV3Config,
-    a TrinityConfig or a Phi4FlashConfig)."""
+    a TrinityConfig, a Phi4FlashConfig or a NemotronHConfig)."""
     for kind, family in _families().items():
         if isinstance(cfg, kind):
             return family
@@ -237,16 +256,17 @@ def family_of(cfg) -> ModelFamily:
 def resolve_config(model):
     """A preset name or a family's configuration -> the configuration.
     Names are the dense family's presets, `deepseek_v3:<preset>`,
-    `trinity:<preset>` or `phi4flash:<preset>`."""
-    from . import deepseek_v3, llama, phi4flash, trinity
+    `trinity:<preset>`, `phi4flash:<preset>` or `nemotron_h:<preset>`."""
+    from . import deepseek_v3, llama, nemotron_h, phi4flash, trinity
     if isinstance(model, (deepseek_v3.DeepseekV3Config,
                           trinity.TrinityConfig,
-                          phi4flash.Phi4FlashConfig)):
+                          phi4flash.Phi4FlashConfig,
+                          nemotron_h.NemotronHConfig)):
         return model
     if isinstance(model, str) and ":" in model:
         family, preset = model.split(":", 1)
         named = {"deepseek_v3": deepseek_v3, "trinity": trinity,
-                 "phi4flash": phi4flash}
+                 "phi4flash": phi4flash, "nemotron_h": nemotron_h}
         if family in named:
             return named[family].config(preset)
     return llama.config(model)

@@ -256,3 +256,120 @@ def grouped_swiglu(x: jax.Array, gates: jax.Array, place: jax.Array,
     )(vis_g, vis_t, offsets, h, wd, place, gates)
     # no visit, no store: a tick that sent nothing here reads as zeros
     return jnp.where(n_visits > 0, out, 0.0)
+
+
+# ------------------------------------------------------ ungated experts
+# An expert of the form act(x W_up) W_down with act = relu(.)^2 and NO
+# gate matrix (`mlp_hidden_act` "relu2": the Nemotron-H family), through
+# the same layout: `assignment_rows`, `tile_visits`, only the experts hit
+# are read, the way back by `_down_kernel` as it is. The experts may lie
+# in a STACK of several layers' ([layers * E, H, F]): `base`, the first
+# of this layer's, rides with the prefetched scalars and shifts the
+# weights' block index alone, so a stack that scans its layers never
+# slices (copies) a layer's experts out. Kept below the SwiGLU kernels,
+# whose lines (a Mosaic module keeps them) stay where they were.
+
+def _up_relu2_kernel(vis_g, vis_t, off, base, x_ref, row_ref, wu_ref,
+                     h_ref, acc, *, tm: int, exact):
+    """`_up_kernel` with one matrix: h = relu(xs W_up[e])^2."""
+    del base                       # the index maps' alone
+    v, k = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+
+    t = x_ref.shape[0]
+    here = (vis_t[v] * tm + lax.broadcasted_iota(jnp.int32, (tm, t), 0)
+            == row_ref[...]).astype(x_ref.dtype)
+    xs = jnp.dot(here, x_ref[...], precision=exact,
+                 preferred_element_type=jnp.float32).astype(x_ref.dtype)
+    acc[...] += lax.dot_general(xs, wu_ref[...], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(k == pl.num_programs(2) - 1)
+    def _():
+        h = jnp.square(jnp.maximum(acc[...], 0.0)).astype(h_ref.dtype)
+        first = (v == 0) | (vis_t[jnp.maximum(v - 1, 0)] != vis_t[v])
+        kept = jnp.where(first, jnp.zeros_like(h), h_ref[...])
+        h_ref[...] = jnp.where(
+            _own_rows(v, vis_g, vis_t, off, tm, h.shape), h, kept)
+
+
+def _down_based_kernel(vis_g, vis_t, off, base, *refs, tm: int):
+    del base
+    _down_kernel(vis_g, vis_t, off, *refs, tm=tm)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
+def grouped_relu2(x: jax.Array, gates: jax.Array, place: jax.Array,
+                  offsets: jax.Array, wu: jax.Array, wd: jax.Array,
+                  base: jax.Array, *, rows: int, interpret: bool = False
+                  ) -> jax.Array:
+    """`grouped_swiglu` for ungated relu^2 experts. x: [T, H]; gates,
+    place: [T, E]; offsets: [E + 1]; wu: [S, F, H] (out by in, as
+    `nn.Linear` keeps it: an expert width that is no whole number of
+    128-lane vectors, 1856, is then no array's minor dim, which XLA
+    would pad in a COPY of the stack before the kernel), wd: [S, F, H],
+    a stack of S >= E experts of which [base, base + E) are this
+    layer's (base: an int32 scalar, traced or not) -> [T, H] float32."""
+    t, hid = x.shape
+    e = offsets.shape[0] - 1
+    ffn = wu.shape[1]
+    tm = row_tile(rows)
+    rows = -(-rows // tm) * tm
+    vis_g, vis_t, n_visits = tile_visits(offsets, rows, tm)
+    base = jnp.asarray(base, jnp.int32).reshape(1)
+    item = jnp.dtype(wu.dtype).itemsize
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT)
+    of_expert = lambda n, v, k, g, tl, off, b: (b[0] + g[v], k, n)
+    of_expert_t = lambda n, v, k, g, tl, off, b: (b[0] + g[v], n, k)
+    of_tile = lambda n, v, k, g, tl, off, b: (tl[v], n)
+    whole = lambda n, v, k, g, tl, off, b: (0, 0)
+
+    tn = _divisor(ffn, 2048)
+    tk = _divisor(hid, _WEIGHT_TILE_BYTES // (tn * item))
+    h = pl.pallas_call(
+        functools.partial(
+            _up_relu2_kernel, tm=tm,
+            exact=lax.Precision.HIGHEST if x.dtype == jnp.float32 else None),
+        out_shape=jax.ShapeDtypeStruct((rows, ffn), x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(ffn // tn, n_visits, hid // tk),
+            in_specs=[
+                pl.BlockSpec((t, tk),
+                             lambda n, v, k, g, tl, off, b: (0, k)),
+                pl.BlockSpec((None, 1, t),
+                             lambda n, v, k, g, tl, off, b: (g[v], 0, 0)),
+                pl.BlockSpec((None, tn, tk), of_expert_t)],
+            out_specs=pl.BlockSpec((tm, tn), of_tile),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+        compiler_params=params, interpret=interpret,
+        name="moe_grouped_up_relu2",
+    )(vis_g, vis_t, offsets, base, x, place.T[:, None, :], wu)
+
+    tk = _divisor(ffn, 4096)
+    tn = _divisor(hid, min(_WEIGHT_TILE_BYTES // (tk * item),
+                           _OUT_TILE_BYTES // (t * 4)))
+    out = pl.pallas_call(
+        functools.partial(_down_based_kernel, tm=tm),
+        out_shape=jax.ShapeDtypeStruct((t, hid), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(hid // tn, n_visits, ffn // tk),
+            in_specs=[
+                pl.BlockSpec((tm, tk),
+                             lambda n, v, k, g, tl, off, b: (tl[v], k)),
+                pl.BlockSpec((None, tk, tn), of_expert),
+                pl.BlockSpec((t, e), whole),
+                pl.BlockSpec((t, e), whole)],
+            out_specs=pl.BlockSpec(
+                (t, tn), lambda n, v, k, g, tl, off, b: (0, n)),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+        compiler_params=params, interpret=interpret,
+        name="moe_grouped_down_relu2",
+    )(vis_g, vis_t, offsets, base, h, wd, place, gates)
+    return jnp.where(n_visits > 0, out, 0.0)

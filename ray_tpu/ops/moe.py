@@ -412,3 +412,59 @@ def held_experts_ffn(x: jax.Array, gates: jax.Array, took: jax.Array,
                        preferred_element_type=f32)
     return jnp.zeros((t, x.shape[1]), f32).at[tok].add(
         y * gate[:, None], mode="drop")
+
+
+def held_relu2_ffn(x: jax.Array, gates: jax.Array, took: jax.Array,
+                   wu: jax.Array, wd: jax.Array, *, picks: int, impl: str,
+                   base=0) -> jax.Array:
+    """`held_experts_ffn` for UNGATED experts, relu(x W_up)^2 W_down
+    (two matrices an expert, no gate: `mlp_hidden_act` "relu2"), through
+    the same layout and under the same `impl`. wu and wd: [S, F, H]
+    (W_up out by in: see `grouped_relu2`): the E held experts of this
+    layer are [base, base + E) of a stack of S >= E (several layers'
+    experts in one array, so that a stack that scans its layers hands
+    the kernels the array whole and an index; `base` may be traced).
+    "gather" here is a plain loop over the held experts (below). Returns
+    [T, H] float32. A function of its own BELOW `held_experts_ffn`, not
+    a static argument of it: that function's text stays the parent's,
+    because the SwiGLU cells' compiled kernels carry its lines."""
+    if impl not in HELD_IMPLS:
+        raise ValueError(f"held_relu2_ffn: impl {impl!r} is none of "
+                         f"{HELD_IMPLS}")
+    t, _ = x.shape
+    e = took.shape[1]
+    rows = t * min(picks, e)
+    place, offsets = ge.assignment_rows(took)
+    if impl in ("pallas", "pallas_interpret"):
+        return ge.grouped_relu2(x, gates, place, offsets, wu, wd, base,
+                                rows=rows,
+                                interpret=(impl == "pallas_interpret"))
+    f32 = jnp.float32
+    at = jnp.where(took, place, rows).reshape(-1)
+    tok = jnp.full((rows,), t, jnp.int32).at[at].set(
+        jnp.repeat(jnp.arange(t, dtype=jnp.int32), e), mode="drop")
+    gate = jnp.zeros((rows,), f32).at[at].set(gates.reshape(-1),
+                                              mode="drop")
+    xs = jnp.take(x, tok, axis=0, mode="clip")
+    # An expert at a time, each product over EVERY row and kept where the
+    # row is the expert's: 2 x 64 products where the kernels run the tiles
+    # that hold rows. Not `lax.ragged_dot` as the SwiGLU path's: W_up lies
+    # out by in, and turned for it XLA turns the whole STACK (a 4.5 GB
+    # copy at the published sizes, whatever is sliced first); and its TPU
+    # lowering refuses float32 rows against weights stored in bfloat16,
+    # which a check in float32 activations hands it
+    row = jnp.arange(rows, dtype=jnp.int32)[:, None]
+
+    def one(j, y):
+        w = lax.dynamic_index_in_dim(wu, base + j, 0, keepdims=False)
+        up = lax.dot_general(xs, w.astype(x.dtype), (((1,), (1,)), ((), ())),
+                             preferred_element_type=f32)
+        mid = jnp.square(jax.nn.relu(up)).astype(x.dtype)
+        w = lax.dynamic_index_in_dim(wd, base + j, 0, keepdims=False)
+        down = jnp.dot(mid, w.astype(x.dtype), preferred_element_type=f32)
+        return jnp.where((row >= offsets[j]) & (row < offsets[j + 1]),
+                         down, y)
+
+    y = lax.fori_loop(0, e, one, jnp.zeros((rows, x.shape[1]), f32))
+    return jnp.zeros((t, x.shape[1]), f32).at[tok].add(
+        y * gate[:, None], mode="drop")
