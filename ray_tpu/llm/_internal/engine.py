@@ -1992,12 +1992,12 @@ class InferenceEngine:
         return impl
 
     def _ctx_bucket(self, start: int) -> int:
-        """Smallest power-of-two page count covering `start` tokens;
-        the whole table for a family whose kernels read it whole
-        (`ModelFamily.whole_table_kernels`), off the gather path."""
+        """The context key of a ragged tick's program, in pages. The
+        gather path cuts the tables to it: the least power of two that
+        covers `start` tokens. A kernel reads a row's own pages off the
+        whole table: context or none, ONE program a token bucket."""
         need = self.allocator.pages_needed(start)
-        if (need and self.family.whole_table_kernels
-                and self._resolve_impl() != "gather"):
+        if need and self._resolve_impl() != "gather":
             return self.max_pages_per_seq
         b = 1
         while b < need:

@@ -71,12 +71,6 @@ class ModelFamily:
     storage_dtypes: Optional[Callable[[Any], Dict[str, Any]]] = None
     # engine options this family does not compose with: name -> reason
     refuses: Dict[str, str] = dataclasses.field(default_factory=dict)
-    # True: on the kernel path the forwards read `ctx_pages` only as
-    # zero or not (the kernels walk a row's own pages off the whole
-    # table), so the engine keeps ONE ragged program a token bucket
-    # there, not one a context bucket: those are one compile, but each
-    # was traced and lowered on its own at its first call
-    whole_table_kernels: bool = False
 
     def cache_row(self, cfg, impl: str, kv_kind: str = "f32") -> CacheRow:
         """The FIRST group's row, kept for its readers; `cache_groups`
@@ -193,8 +187,7 @@ def _families() -> Dict[type, ModelFamily]:
             work_counts=_llama_work_counts,
             span_counts=phi4flash.span_counts,
             storage_dtypes=phi4flash.storage_dtypes,
-            refuses=phi4flash.PHI4FLASH_REFUSES,
-            whole_table_kernels=True),
+            refuses=phi4flash.PHI4FLASH_REFUSES),
         nemotron_h.NemotronHConfig: ModelFamily(
             name="nemotron_h", init_params=nemotron_h.init_params,
             ragged_forward=nemotron_h.ragged_forward,
@@ -207,8 +200,7 @@ def _families() -> Dict[type, ModelFamily]:
             # the same counts of the same held-expert layer
             rider_summary=deepseek_v3.routing_summary,
             storage_dtypes=nemotron_h.storage_dtypes,
-            refuses=nemotron_h.NEMOTRON_H_REFUSES,
-            whole_table_kernels=True),
+            refuses=nemotron_h.NEMOTRON_H_REFUSES),
     }
 
 

@@ -77,7 +77,7 @@ def test_published_sizes_hold_the_issues_parameter_counts():
 def test_family_describes_a_page_group_and_a_state_group():
     cfg = nh.NemotronHConfig(**CUT)
     fam = family_of(cfg)
-    assert fam.name == "nemotron_h" and fam.whole_table_kernels
+    assert fam.name == "nemotron_h"
     assert fam.rider_len(cfg) == 7 * 64
     full, state = fam.cache_groups(cfg, "pallas")
     assert (full.name, full.layers, full.window) == ("full", (5, 12), None)

@@ -592,25 +592,3 @@ def test_cost_model_prices_the_state_and_the_shared_group():
     # ISSUE 36: 18 layers' matrix products, 2 x 1,964M a token
     assert own["gemm_flops_per_token"] == pytest.approx(2 * 1964e6,
                                                         rel=0.01)
-
-
-def test_kernel_path_keeps_one_program_a_token_bucket(served):
-    """`ModelFamily.whole_table_kernels`: the kernels read a row's own
-    pages off the whole table, so on the kernel path a tick's program is
-    keyed by context or none; the gather path, which cuts the tables,
-    keeps a program a context bucket, and so does every other family."""
-    import dataclasses as dc
-    from ray_tpu.models import llama
-    _, eng, _ = served
-    assert eng.family.whole_table_kernels
-    assert eng._resolve_impl() == "gather"
-    assert [eng._ctx_bucket(n) for n in (0, 1, 9, 33)] == [0, 1, 4, 16]
-    eng.config = dc.replace(eng.config, decode_impl="pallas_interpret")
-    try:
-        whole = eng.max_pages_per_seq
-        assert [eng._ctx_bucket(n) for n in (0, 1, 9, 33)] == [
-            0, whole, whole, whole]
-    finally:
-        eng.config = dc.replace(eng.config, decode_impl="auto")
-    for cfg in (llama.config("debug"),):
-        assert not family_of(cfg).whole_table_kernels

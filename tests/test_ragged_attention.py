@@ -17,6 +17,7 @@ Gates:
   compile counter flat (no bucket-churn recompile storms).
 """
 
+import dataclasses
 import zlib
 
 import numpy as np
@@ -441,6 +442,77 @@ def test_jit_cache_counter_stable_in_steady_state():
     st1 = eng.stats()["jit_cache"]
     assert st1["compiled_programs"] == st0["compiled_programs"]
     assert st1["ragged_buckets"] == st0["ragged_buckets"]
+
+
+# a small engine of each family: (model, what its cache groups need)
+_FAMILIES = {
+    "llama": ("debug", {}),
+    "deepseek_v3": ("deepseek_v3:debug", {}),
+    "trinity": ("trinity:debug", {"num_pages_by_group": {"window": 20}}),
+    "phi4flash": ("phi4flash:debug",
+                  {"num_pages_by_group": {"window": 20}}),
+    "nemotron_h": ("nemotron_h:tiny", {}),
+}
+
+
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
+def test_kernel_path_keeps_one_program_a_token_bucket(family):
+    """`_ctx_bucket`, the context key of a ragged tick's program: the
+    gather path cuts the tables to a power of two of pages and keeps a
+    program a bucket; every family's kernels read a row's own pages off
+    the whole table, so off the gather path the key is the whole table
+    or none. The rule reads the implementation that runs, no family's
+    flag."""
+    model, groups = _FAMILIES[family]
+    eng = InferenceEngine(EngineConfig(
+        model=model, num_pages=64, max_batch_size=2, page_size=4,
+        max_seq_len=256, max_prefill_tokens=8, **groups))
+    assert eng.family.name == family
+    assert eng._resolve_impl() == "gather"
+    assert [eng._ctx_bucket(n) for n in (0, 1, 9, 33)] == [0, 1, 4, 16]
+    # past the table's width the bucket is the table
+    assert eng._ctx_bucket(255) == eng.max_pages_per_seq == 64
+    eng.config = dataclasses.replace(eng.config,
+                                     decode_impl="pallas_interpret")
+    whole = eng.max_pages_per_seq
+    assert [eng._ctx_bucket(n) for n in (0, 1, 9, 33)] == [
+        0, whole, whole, whole]
+
+
+def test_contexts_of_three_buckets_share_one_kernel_program():
+    """Three prompts whose last chunks meet cached contexts of 2, 4 and
+    8 pages, every chunk one token bucket: under the kernels the engine
+    builds ONE ragged program with a context and one without, whatever
+    comes after the first prompt, where the gather path builds one a
+    context bucket; and a prompt's greedy tokens are those it gives
+    when it runs alone."""
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(2, 250, n).tolist() for n in (32, 48, 80)]
+    sp = dict(max_tokens=4, temperature=0.0)
+
+    def alone(eng, prompt):
+        return eng.generate([list(prompt)],
+                            SamplingParams(**sp))[0].output_tokens
+
+    eng = _engine(decode_impl="pallas_interpret")
+    whole = eng.max_pages_per_seq
+    outs = [alone(eng, prompts[0])]
+    jc = eng.stats()["jit_cache"]
+    assert sorted(eng._ragged_fns) == [(16, 0, True), (16, whole, True)]
+    assert jc["ragged_buckets"] == 2
+    outs += [alone(eng, p) for p in prompts[1:]]
+    # contexts of 4 and 8 pages landed on the program 2 pages built
+    assert eng.stats()["jit_cache"] == jc
+    assert sorted(eng._ragged_fns) == [(16, 0, True), (16, whole, True)]
+    gather = _engine(decode_impl="gather")
+    assert [alone(gather, p) for p in prompts] == outs
+    assert sorted(gather._ragged_fns) == [
+        (16, c, True) for c in (0, 2, 4, 8)]
+    # together: the same tokens, and still context or none a bucket
+    both = _engine(decode_impl="pallas_interpret")
+    reqs = both.generate([list(p) for p in prompts], SamplingParams(**sp))
+    assert [r.output_tokens for r in reqs] == outs
+    assert {c for _, c, _ in both._ragged_fns} <= {0, whole}
 
 
 def test_unified_step_multi_lora_mixed_batch():
