@@ -10,7 +10,7 @@ how its totals read in `stats()`, and the engine options the family
 does not compose with (refused at construction with the reason, never
 half-run). Nothing here imports the engine: the engine imports this.
 
-Five families: `llama` (dense RoPE/GQA/SwiGLU decoders, `LlamaConfig`,
+Six families: `llama` (dense RoPE/GQA/SwiGLU decoders, `LlamaConfig`,
 programs unchanged), `deepseek_v3` (latent attention over a latent
 cache, expert layers with the experts held here, `DeepseekV3Config`),
 `trinity` (sliding-window and full-attention layers over two page
@@ -18,11 +18,14 @@ groups, gated QK-normed GQA attention, held experts, `TrinityConfig`),
 `phi4flash` (Mamba layers whose state is kept a slot beside a
 window page group and ONE layer's pages that eight layers read,
 differential attention, a tied head, a cross-decoder that only sampling
-rows run, `Phi4FlashConfig`) and `nemotron_h` (Mamba-2 layers whose
+rows run, `Phi4FlashConfig`), `nemotron_h` (Mamba-2 layers whose
 2 MB state a slot a layer lies beside ONE small page group, ungated
 relu^2 held experts, layers that are a mixer or a feed-forward part
 alone, `NemotronHConfig`: the first family with BOTH a rider and a
-state group).
+state group) and `smallthinker` (a router that reads the layer's input
+ahead of attention, gated-ReLU held experts with no shared expert and
+no dense layer, 28 query heads over 4 on window and full layers over
+two merged-rows page groups, `SmallThinkerConfig`).
 
 A family with a STATE group (`cache_row.CacheGroup.state`) gets that
 group's arrays in `k_pages` / `v_pages` behind its page groups' pools
@@ -148,7 +151,7 @@ def _families() -> Dict[type, ModelFamily]:
     """Configuration type -> its family (built on first use: the model
     modules import jax)."""
     from . import (deepseek_v3, llama, llama_infer, nemotron_h, phi4flash,
-                   trinity)
+                   smallthinker, trinity)
     return {
         llama.LlamaConfig: ModelFamily(
             name="llama", init_params=llama.init_params,
@@ -201,6 +204,19 @@ def _families() -> Dict[type, ModelFamily]:
             rider_summary=deepseek_v3.routing_summary,
             storage_dtypes=nemotron_h.storage_dtypes,
             refuses=nemotron_h.NEMOTRON_H_REFUSES),
+        smallthinker.SmallThinkerConfig: ModelFamily(
+            name="smallthinker", init_params=smallthinker.init_params,
+            ragged_forward=smallthinker.ragged_forward,
+            decode_step=smallthinker.decode_step,
+            cache_groups=smallthinker.cache_groups,
+            # the full layers' kernel: the dense family's count
+            work_counts=_llama_work_counts,
+            span_counts=smallthinker.span_counts,
+            # every layer is an expert layer: [n_layers, n_held]
+            rider_len=lambda c: c.n_moe_layers * c.n_held,
+            # the same counts of the same held-expert layer
+            rider_summary=deepseek_v3.routing_summary,
+            refuses=smallthinker.SMALLTHINKER_REFUSES),
     }
 
 
@@ -238,7 +254,8 @@ def store_params(family: ModelFamily, cfg, params, shardings=None,
 
 def family_of(cfg) -> ModelFamily:
     """The family that serves `cfg` (a LlamaConfig, a DeepseekV3Config,
-    a TrinityConfig, a Phi4FlashConfig or a NemotronHConfig)."""
+    a TrinityConfig, a Phi4FlashConfig, a NemotronHConfig or a
+    SmallThinkerConfig)."""
     for kind, family in _families().items():
         if isinstance(cfg, kind):
             return family
@@ -248,17 +265,21 @@ def family_of(cfg) -> ModelFamily:
 def resolve_config(model):
     """A preset name or a family's configuration -> the configuration.
     Names are the dense family's presets, `deepseek_v3:<preset>`,
-    `trinity:<preset>`, `phi4flash:<preset>` or `nemotron_h:<preset>`."""
-    from . import deepseek_v3, llama, nemotron_h, phi4flash, trinity
+    `trinity:<preset>`, `phi4flash:<preset>`, `nemotron_h:<preset>` or
+    `smallthinker:<preset>`."""
+    from . import (deepseek_v3, llama, nemotron_h, phi4flash, smallthinker,
+                   trinity)
     if isinstance(model, (deepseek_v3.DeepseekV3Config,
                           trinity.TrinityConfig,
                           phi4flash.Phi4FlashConfig,
-                          nemotron_h.NemotronHConfig)):
+                          nemotron_h.NemotronHConfig,
+                          smallthinker.SmallThinkerConfig)):
         return model
     if isinstance(model, str) and ":" in model:
         family, preset = model.split(":", 1)
         named = {"deepseek_v3": deepseek_v3, "trinity": trinity,
-                 "phi4flash": phi4flash, "nemotron_h": nemotron_h}
+                 "phi4flash": phi4flash, "nemotron_h": nemotron_h,
+                 "smallthinker": smallthinker}
         if family in named:
             return named[family].config(preset)
     return llama.config(model)

@@ -373,3 +373,125 @@ def grouped_relu2(x: jax.Array, gates: jax.Array, place: jax.Array,
         name="moe_grouped_down_relu2",
     )(vis_g, vis_t, offsets, base, h, wd, place, gates)
     return jnp.where(n_visits > 0, out, 0.0)
+
+
+# --------------------------------------------------- gated ReLU experts
+# An expert of the form (relu(x W_gate) * (x W_up)) W_down (ReGLU: the
+# SmallThinker family), through the same layout, with two things the
+# families above did not need: the experts lie in a stack of several
+# layers' ([layers * E, H, F], `base` as in the relu^2 section: no
+# layer's experts are ever sliced out), and the visits are the CALLER's
+# (`reglu_visits`): that family's router reads the layer's input ahead of
+# attention, so the sorted rows and the tile visits exist before the
+# expert product does and are made where the picks are. Kept below
+# everything above, whose lines stay where they were.
+
+def reglu_visits(offsets: jax.Array, rows: int
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """`tile_visits` for `grouped_reglu`'s own row tile: offsets [E + 1]
+    from `assignment_rows`, `rows` the most assignments there can be."""
+    tm = row_tile(rows)
+    return tile_visits(offsets, -(-rows // tm) * tm, tm)
+
+
+def _up_reglu_kernel(vis_g, vis_t, off, base, x_ref, row_ref, wg_ref,
+                     wi_ref, h_ref, acc_g, acc_u, *, tm: int, exact):
+    """`_up_kernel` with relu for silu: h = relu(xs W_g[e]) * (xs
+    W_i[e])."""
+    del base                       # the index maps' alone
+    v, k = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _():
+        acc_g[...] = jnp.zeros_like(acc_g)
+        acc_u[...] = jnp.zeros_like(acc_u)
+
+    t = x_ref.shape[0]
+    here = (vis_t[v] * tm + lax.broadcasted_iota(jnp.int32, (tm, t), 0)
+            == row_ref[...]).astype(x_ref.dtype)
+    xs = jnp.dot(here, x_ref[...], precision=exact,
+                 preferred_element_type=jnp.float32).astype(x_ref.dtype)
+    acc_g[...] += jnp.dot(xs, wg_ref[...],
+                          preferred_element_type=jnp.float32)
+    acc_u[...] += jnp.dot(xs, wi_ref[...],
+                          preferred_element_type=jnp.float32)
+
+    @pl.when(k == pl.num_programs(2) - 1)
+    def _():
+        h = (jnp.maximum(acc_g[...], 0.0) * acc_u[...]).astype(h_ref.dtype)
+        first = (v == 0) | (vis_t[jnp.maximum(v - 1, 0)] != vis_t[v])
+        kept = jnp.where(first, jnp.zeros_like(h), h_ref[...])
+        h_ref[...] = jnp.where(
+            _own_rows(v, vis_g, vis_t, off, tm, h.shape), h, kept)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
+def grouped_reglu(x: jax.Array, gates: jax.Array, place: jax.Array,
+                  offsets: jax.Array, visits, wg: jax.Array,
+                  wi: jax.Array, wd: jax.Array, base: jax.Array, *,
+                  rows: int, interpret: bool = False) -> jax.Array:
+    """`grouped_swiglu` for ReGLU experts in a stack. x: [T, H]; gates,
+    place: [T, E]; offsets: [E + 1]; `visits`: `reglu_visits(offsets,
+    rows)`, made by the caller; wg/wi: [S, H, F], wd: [S, F, H], a stack
+    of S >= E experts of which [base, base + E) are this layer's (base:
+    an int32 scalar, traced or not) -> [T, H] float32."""
+    t, hid = x.shape
+    e = offsets.shape[0] - 1
+    ffn = wg.shape[2]
+    tm = row_tile(rows)
+    rows = -(-rows // tm) * tm
+    vis_g, vis_t, n_visits = visits
+    base = jnp.asarray(base, jnp.int32).reshape(1)
+    item = jnp.dtype(wg.dtype).itemsize
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT)
+    of_expert = lambda n, v, k, g, tl, off, b: (b[0] + g[v], k, n)
+    of_tile = lambda n, v, k, g, tl, off, b: (tl[v], n)
+    whole = lambda n, v, k, g, tl, off, b: (0, 0)
+
+    tn = _divisor(ffn, 2048)
+    tk = _divisor(hid, _WEIGHT_TILE_BYTES // (tn * item))
+    w_in = pl.BlockSpec((None, tk, tn), of_expert)
+    h = pl.pallas_call(
+        functools.partial(
+            _up_reglu_kernel, tm=tm,
+            exact=lax.Precision.HIGHEST if x.dtype == jnp.float32 else None),
+        out_shape=jax.ShapeDtypeStruct((rows, ffn), x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(ffn // tn, n_visits, hid // tk),
+            in_specs=[
+                pl.BlockSpec((t, tk),
+                             lambda n, v, k, g, tl, off, b: (0, k)),
+                pl.BlockSpec((None, 1, t),
+                             lambda n, v, k, g, tl, off, b: (g[v], 0, 0)),
+                w_in, w_in],
+            out_specs=pl.BlockSpec((tm, tn), of_tile),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)] * 2),
+        compiler_params=params, interpret=interpret,
+        name="moe_grouped_up_reglu",
+    )(vis_g, vis_t, offsets, base, x, place.T[:, None, :], wg, wi)
+
+    tk = _divisor(ffn, 4096)
+    tn = _divisor(hid, min(_WEIGHT_TILE_BYTES // (tk * item),
+                           _OUT_TILE_BYTES // (t * 4)))
+    out = pl.pallas_call(
+        functools.partial(_down_based_kernel, tm=tm),
+        out_shape=jax.ShapeDtypeStruct((t, hid), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(hid // tn, n_visits, ffn // tk),
+            in_specs=[
+                pl.BlockSpec((tm, tk),
+                             lambda n, v, k, g, tl, off, b: (tl[v], k)),
+                pl.BlockSpec((None, tk, tn), of_expert),
+                pl.BlockSpec((t, e), whole),
+                pl.BlockSpec((t, e), whole)],
+            out_specs=pl.BlockSpec(
+                (t, tn), lambda n, v, k, g, tl, off, b: (0, n)),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+        compiler_params=params, interpret=interpret,
+        name="moe_grouped_down_reglu",
+    )(vis_g, vis_t, offsets, base, h, wd, place, gates)
+    return jnp.where(n_visits > 0, out, 0.0)

@@ -468,3 +468,102 @@ def held_relu2_ffn(x: jax.Array, gates: jax.Array, took: jax.Array,
     y = lax.fori_loop(0, e, one, jnp.zeros((rows, x.shape[1]), f32))
     return jnp.zeros((t, x.shape[1]), f32).at[tok].add(
         y * gate[:, None], mode="drop")
+
+
+# ------------------------------------------- softmax-of-the-picks routing
+# SmallThinker's router (`moe_primary_router_apply_softmax`,
+# `norm_topk_prob`) and its gated-ReLU held experts: the serving path of
+# models/smallthinker.py. Below everything above, whose lines the other
+# families' compiled kernels carry.
+
+def softmax_pick_routing(x: jax.Array, router_w: jax.Array, *, top_k: int
+                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: [T, H]; router_w: [H, E] -> (gate weights [T, top_k] float32,
+    expert indices [T, top_k] int32, the logits [T, E] float32).
+
+    logits = x W in float32 at the highest precision; the picks are the
+    `top_k` largest LOGITS (ties go to the lower index); the weights a
+    softmax over the picked logits alone, which is the softmax over all
+    E with the picks renormalised.
+
+    Why this is not `router_probs` + `top_k_routing` (the training
+    paths', at the top of this file), which compute the same weights:
+    those form the softmax over all E first (the auxiliary loss wants
+    the probabilities) at the default matmul precision, and pick on the
+    probabilities, where two logits a rounding apart can tie. Serving is
+    held to a float32 reference pick for pick, so it picks on the logits
+    themselves, at the highest precision, hands the logits back for
+    that comparison, and has no use for the E-wide softmax. Neither can
+    call the other without changing what it computes, and the lines
+    above this section do not move (a kernel's payload carries them)."""
+    with jax.default_matmul_precision("highest"):
+        logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    top, idx = lax.top_k(logits, top_k)
+    return jax.nn.softmax(top, axis=-1), idx.astype(jnp.int32), logits
+
+
+def held_reglu_plan(took: jax.Array, *, picks: int, impl: str):
+    """What `held_reglu_ffn` needs of a tick's assignments besides the
+    gates, from `took` [T, E] alone: (the row of each assignment [T, E],
+    the groups' offsets [E + 1], the kernels' tile visits or None on the
+    gather path). A function of its own so that a layer whose router
+    runs ahead of its attention makes it THERE, with the picks."""
+    if impl not in HELD_IMPLS:
+        raise ValueError(f"held_reglu_plan: impl {impl!r} is none of "
+                         f"{HELD_IMPLS}")
+    t, e = took.shape
+    place, offsets = ge.assignment_rows(took)
+    visits = (ge.reglu_visits(offsets, t * min(picks, e))
+              if impl in ("pallas", "pallas_interpret") else None)
+    return place, offsets, visits
+
+
+def held_reglu_ffn(x: jax.Array, gates: jax.Array, took: jax.Array,
+                   wg: jax.Array, wi: jax.Array, wd: jax.Array, *,
+                   picks: int, impl: str, base=0, plan=None) -> jax.Array:
+    """`held_experts_ffn` for GATED-ReLU experts, (relu(x W_g) * (x W_i))
+    W_d, through the same layout and under the same `impl`. wg/wi: [S,
+    H, F], wd: [S, F, H]: the E held experts of this layer are [base,
+    base + E) of a stack of S >= E (several layers' experts in one
+    array; `base` may be traced). `plan`: `held_reglu_plan(took, ...)`
+    where the caller made it ahead (default: made here). The
+    down-projection is computed whole: a row that ReLU zeroed is
+    multiplied all the same. "gather" is a plain loop over the held
+    experts, each product over every row and kept where the row is the
+    expert's (`held_relu2_ffn` says why not `lax.ragged_dot`: it would
+    want the layer's experts sliced out of the stack, a copy). Returns
+    [T, H] float32."""
+    if impl not in HELD_IMPLS:
+        raise ValueError(f"held_reglu_ffn: impl {impl!r} is none of "
+                         f"{HELD_IMPLS}")
+    t, _ = x.shape
+    e = took.shape[1]
+    rows = t * min(picks, e)
+    place, offsets, visits = plan or held_reglu_plan(
+        took, picks=picks, impl=impl)
+    if impl in ("pallas", "pallas_interpret"):
+        return ge.grouped_reglu(x, gates, place, offsets, visits, wg, wi,
+                                wd, base, rows=rows,
+                                interpret=(impl == "pallas_interpret"))
+    f32 = jnp.float32
+    at = jnp.where(took, place, rows).reshape(-1)
+    tok = jnp.full((rows,), t, jnp.int32).at[at].set(
+        jnp.repeat(jnp.arange(t, dtype=jnp.int32), e), mode="drop")
+    gate = jnp.zeros((rows,), f32).at[at].set(gates.reshape(-1),
+                                              mode="drop")
+    xs = jnp.take(x, tok, axis=0, mode="clip")
+    row = jnp.arange(rows, dtype=jnp.int32)[:, None]
+
+    def one(j, y):
+        of = lambda w: lax.dynamic_index_in_dim(
+            w, base + j, 0, keepdims=False).astype(x.dtype)
+        g = jnp.dot(xs, of(wg), preferred_element_type=f32)
+        u = jnp.dot(xs, of(wi), preferred_element_type=f32)
+        mid = (jax.nn.relu(g) * u).astype(x.dtype)
+        down = jnp.dot(mid, of(wd), preferred_element_type=f32)
+        return jnp.where((row >= offsets[j]) & (row < offsets[j + 1]),
+                         down, y)
+
+    y = lax.fori_loop(0, e, one, jnp.zeros((rows, x.shape[1]), f32))
+    return jnp.zeros((t, x.shape[1]), f32).at[tok].add(
+        y * gate[:, None], mode="drop")
