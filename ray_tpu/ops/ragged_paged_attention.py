@@ -231,15 +231,15 @@ def ragged_gather_paged_blocked(
 
 def ragged_attention_dense_oracle(
         q, dense_k, dense_v, k_new, v_new, slot_ids, positions, valid,
-        start) -> np.ndarray:
+        start, window: Optional[int] = None) -> np.ndarray:
     """CPU-exact dense reference for the ragged op (numpy, per-token
     loops — slow and obviously correct; the property tests' ground
     truth).
 
     dense_k/dense_v: [B, max_ctx, KVH, D] each slot's cached KV in
     position order (row p = the KV written at absolute position p);
-    everything else as in ragged_prefill_decode_attention. Output rows
-    for invalid tokens are zero.
+    everything else as in ragged_prefill_decode_attention, `window`
+    too. Output rows for invalid tokens are zero.
     """
     q = np.asarray(q, np.float32)
     dense_k = np.asarray(dense_k, np.float32)
@@ -258,11 +258,13 @@ def ragged_attention_dense_oracle(
         if not valid[i]:
             continue
         s = int(slot_ids[i])
-        keys = [dense_k[s, :start[s]]]                 # [n_ctx, KVH, D]
-        vals = [dense_v[s, :start[s]]]
+        # the first position a window still covers
+        lo = 0 if window is None else max(int(positions[i]) - window + 1, 0)
+        keys = [dense_k[s, lo:start[s]]]               # [n_ctx, KVH, D]
+        vals = [dense_v[s, lo:start[s]]]
         mates = [j for j in range(t)
                  if valid[j] and slot_ids[j] == s
-                 and positions[j] <= positions[i]]
+                 and lo <= positions[j] <= positions[i]]
         keys.append(k_new[mates])
         vals.append(v_new[mates])
         kk = np.repeat(np.concatenate(keys), group, axis=1)  # [n, H, D]
@@ -401,8 +403,23 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
     tick costs its items: steps past the live count do nothing at all.
 
     Online-softmax state (m/l/acc, float32) lives in scratch for the
-    item. An item with at most `small` tokens (a decode row, a chunk's
-    tail) runs every flash step on its first `small` rows only.
+    item; m and l lie on all 128 lanes of their row, as wide as a score
+    tile and an accumulator row, so that subtracting the maximum and
+    rescaling the accumulator broadcast nothing across lanes (a
+    statistic one lane wide cost a lane permute a vector register at
+    each use, and the kernel ran at the pace of those). An item with at
+    most `small` tokens (a decode row, a chunk's tail) runs every flash
+    step on its first `small` rows only.
+
+    What a KV block costs between its DMA and its two MXU products is
+    kept small: the mask's query offsets are made once an item
+    (`tok_scr`) and a block's mask is two compares against them, and
+    bf16 pages reach the MXU's head-major operands through
+    `split_heads` with no float32 copy of the block. The softmax scale
+    stays a multiply of the float32 scores: 128 ** -0.5 is no power of
+    two, so q x scale rounded to bf16 is another operand (and every
+    logits check another draw of its near-ties), for a fiftieth of a
+    head's flash step.
 
     A block's rows past the slot's segment belong to the next slots of
     the flat batch (or to the padding): they are computed under the
@@ -437,25 +454,65 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
     which a [page, KVH, D] page pads to the next one in HBM (1.6 x the
     bytes) and which a page DMA cannot slice. A token's write is still
     KVH adjacent rows; a head's keys of a context block are every
-    KVH-th row of it, read with a strided load from a float32 copy of
-    the block (two more scratch buffers, last in `rest`). The in-batch
-    K and V stay [T, KVH, D], their heads padded to the tile by the
-    wrapper.
+    KVH-th row of it. The in-batch K and V stay [T, KVH, D], their
+    heads padded to the tile by the wrapper.
+
+    Either pool form is, in VMEM, a block of (token, head) rows, and a
+    head's rows are a strided load off a reshaped view of the page
+    buffer. Which load is a STATIC fact of the arguments: bf16 pools
+    with an even number of heads (every cell) read 32-bit words, two
+    adjacent heads each (`split_heads`); a quantized pool needs
+    float32 for its dequant and casts the block whole; anything else
+    (float32 pools, an odd head count: the CPU tests) loads a head's
+    rows in the pool's own type, the tile form by way of the block's
+    float32 value.
     """
     if quantized:
         (ks_hbm, vs_hbm, kn_hbm, vn_hbm, o_hbm, q_vmem, kn_vmem,
          vn_vmem, o_vmem, k_vmem, v_vmem, ks_vmem, vs_vmem, kv_sem,
-         io_sem, qh_scr, kh_scr, vh_scr, m_scr, l_scr, acc_scr) = rest
+         io_sem, qh_scr, kh_scr, vh_scr, m_scr, l_scr, acc_scr,
+         tok_scr) = rest
     else:
         (kn_hbm, vn_hbm, o_hbm, q_vmem, kn_vmem, vn_vmem, o_vmem,
          k_vmem, v_vmem, kv_sem, io_sem, qh_scr, kh_scr, vh_scr, m_scr,
-         l_scr, acc_scr) = rest[:17]
-        kf_scr, vf_scr = rest[17:] if merged_rows else (None, None)
+         l_scr, acc_scr, tok_scr) = rest
     it = pl.program_id(0)
     slot = items_ref[0, it]
     bk = page_size * ppb
     d = q_vmem.shape[-1]
     cdt = q_vmem.dtype                     # the MXU's operand type
+    bf16 = jnp.dtype(jnp.bfloat16)
+
+    def lanes(x, n):
+        """A statistic (rows, 128), the row's value on every lane, as
+        (rows, n): itself beside a score tile of 128 keys or an
+        accumulator row of 128 lanes, which is every shape on the chip."""
+        return x if x.shape[1] == n else jnp.broadcast_to(
+            x[:, :1], (x.shape[0], n))
+
+    def paired(ref):
+        """Whether split_heads reads `ref`'s rows: bf16 all the way to
+        the MXU and an even number of heads a token (the in-batch
+        rows' heads are kvh or kvh padded to the 8-row tile)."""
+        return (not quantized and cdt == bf16 and ref.dtype == bf16
+                and kvh % 2 == 0)
+
+    def split_heads(rows, n_tok, dst):
+        """rows: a ref of bf16 (token, head) rows, two adjacent heads
+        of a token in one 32-bit word of its bitcast view. Head h's
+        n_tok rows go to dst[h, :n_tok] with no float32 copy of the
+        block: one strided load of words a PAIR of heads, the low half
+        shifted up and the high half masked out (a bf16 value is the
+        upper half of the float32 of the same value, so the cast back
+        is exact and is the pack the MXU's operand wants)."""
+        heads = rows.shape[0] // n_tok
+        words = rows.bitcast(jnp.uint32)   # (n_tok * heads // 2, d)
+        for pair in range(kvh // 2):
+            w = words[pl.ds(pair, n_tok, stride=heads // 2), :]
+            lo = pltpu.bitcast(w << 16, jnp.float32)
+            hi = pltpu.bitcast(w & jnp.uint32(0xFFFF0000), jnp.float32)
+            dst[2 * pair, :n_tok] = lo.astype(cdt)
+            dst[2 * pair + 1, :n_tok] = hi.astype(cdt)
 
     @pl.when(slot >= 0)
     def _item():
@@ -506,6 +563,11 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
         for h in range(kvh):
             qh_scr[h] = q_vmem[:, h * group:(h + 1) * group, :].reshape(
                 q_blk * group, d)
+        # the mask's query offsets, once an item: score row i is the
+        # query at segment offset qoff + i // group, whatever the block
+        # (on every lane, so a block's mask is compares alone)
+        tok_scr[...] = qoff + jax.lax.broadcasted_iota(
+            jnp.int32, tok_scr.shape, 0) // group
 
         def flash_heads(r, keep):
             """One flash step per kv head on its first r score rows,
@@ -523,12 +585,12 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
                 m_prev = m_scr[h, :r]
                 m_new = jnp.maximum(m_prev,
                                     jnp.max(s, axis=1, keepdims=True))
-                p = jnp.exp(s - m_new)
+                p = jnp.exp(s - lanes(m_new, s.shape[1]))
                 corr = jnp.exp(m_prev - m_new)
                 l_scr[h, :r] = (l_scr[h, :r] * corr
                                 + jnp.sum(p, axis=1, keepdims=True))
                 acc_scr[h, :r] = (
-                    acc_scr[h, :r] * corr + jax.lax.dot_general(
+                    acc_scr[h, :r] * lanes(corr, d) + jax.lax.dot_general(
                         p.astype(cdt), vh_scr[h],
                         (((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32))
@@ -548,17 +610,21 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
             pl.when(blk + 1 < n_ctx)(
                 lambda: page_dma(blk + 1, 1 - buf, True))
             page_dma(blk, buf, False)
-            if merged_rows:
-                # (ppb, page * kvh, D): head h's keys are rows h, h +
-                # kvh, ... of the block
-                kf_scr[...] = k_vmem[buf].reshape(bk * kvh, d).astype(
-                    jnp.float32)
-                vf_scr[...] = v_vmem[buf].reshape(bk * kvh, d).astype(
-                    jnp.float32)
+            # pages keep a token's heads together; the MXU wants one
+            # head's keys together. Either pool form is (bk * kvh, D)
+            # rows in (token, head) order
+            if paired(k_vmem) or merged_rows:
+                k_rows = k_vmem.at[buf].reshape(bk * kvh, d)
+                v_rows = v_vmem.at[buf].reshape(bk * kvh, d)
+                if paired(k_vmem):
+                    split_heads(k_rows, bk, kh_scr)
+                    split_heads(v_rows, bk, vh_scr)
+                    return
+                # head h's keys are rows h, h + kvh, ... of the block
                 for h in range(kvh):
                     every = pl.ds(h, bk, stride=kvh)
-                    kh_scr[h, :bk] = kf_scr[every, :].astype(cdt)
-                    vh_scr[h, :bk] = vf_scr[every, :].astype(cdt)
+                    kh_scr[h, :bk] = k_rows[every, :].astype(cdt)
+                    vh_scr[h, :bk] = v_rows[every, :].astype(cdt)
                 return
             kb = k_vmem[buf].astype(jnp.float32)   # (ppb, page, kvh, D)
             vb = v_vmem[buf].astype(jnp.float32)
@@ -567,8 +633,6 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
                 # rows that rode the same DMA wave as their pages
                 kb = kb * ks_vmem[buf][..., None]
                 vb = vb * vs_vmem[buf][..., None]
-            # pages keep a token's heads together; the MXU wants one
-            # head's keys together
             for h in range(kvh):
                 kh_scr[h, :bk] = kb[:, :, h].reshape(bk, d).astype(cdt)
                 vh_scr[h, :bk] = vb[:, :, h].reshape(bk, d).astype(cdt)
@@ -584,6 +648,11 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
                 c.start()
             for c in copies:
                 c.wait()
+            if paired(kn_vmem):
+                rows = q_blk * kn_vmem.shape[1]
+                split_heads(kn_vmem.reshape(rows, d), q_blk, kh_scr)
+                split_heads(vn_vmem.reshape(rows, d), q_blk, vh_scr)
+                return
             kn = kn_vmem[...].astype(jnp.float32)      # (q_blk, kvh, D)
             vn = vn_vmem[...].astype(jnp.float32)
             for h in range(kvh):
@@ -602,26 +671,27 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
                 pl.when(is_ctx)(lambda: load_ctx(blk))
             pl.when(jnp.logical_not(is_ctx))(lambda: load_new(jb))
             first_key = jnp.where(is_ctx, blk * bk, jb * q_blk)
-            n_keys = jnp.where(is_ctx, bk, q_blk)
-            end = jnp.where(is_ctx, ctx_len, qlen)
-            # context keys precede every query; in-batch keys are causal
-            ahead = jnp.where(is_ctx, 1 << 30, 0)
+            # the block's columns that hold keys of the slot: none past
+            # the kind of block's width, none past the context's or the
+            # segment's end
+            width = jnp.minimum(jnp.where(is_ctx, bk, q_blk),
+                                jnp.where(is_ctx, ctx_len, qlen)
+                                - first_key)
+            # context keys precede every query; in-batch keys are
+            # causal: column j holds segment offset first_key + j
+            ahead = jnp.where(is_ctx, 1 << 30, 1 - first_key)
             # a query at segment offset i sits ctx_len + i into the
             # sequence; a context key's offset is its position
-            behind = jnp.where(is_ctx, ctx_len, 0)
+            behind = jnp.where(is_ctx, ctx_len, 0) - first_key
 
             def keep(r):
-                # query offset per score row / key offset per column,
-                # in the slot's context or segment
-                shape = (r, kh_scr.shape[1])
-                i_tok = qoff + jax.lax.broadcasted_iota(
-                    jnp.int32, shape, 0) // group
-                col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-                key = first_key + col
-                kept = ((col < n_keys) & (key < end)
-                        & (key <= i_tok + ahead))
+                # tok_scr: query offset per score row; a column is a
+                # key offset less first_key
+                tok = tok_scr[:r]
+                col = jax.lax.broadcasted_iota(jnp.int32, tok.shape, 1)
+                kept = col < jnp.minimum(tok + ahead, width)
                 if window is not None:
-                    kept = kept & (key > i_tok + behind - window)
+                    kept = kept & (col > tok + (behind - window))
                 return kept
 
             # the rows the item holds: its first `small` tokens' or
@@ -639,7 +709,7 @@ def _ragged_paged_kernel(items_ref, segs_ref, tables_ref, q_hbm, k_hbm,
         # rows the sweep left alone (past `small`) have l == 0 and
         # acc == 0: the epsilon floor makes them exact zeros
         for h in range(kvh):
-            out = acc_scr[h] / jnp.maximum(l_scr[h], 1e-30)
+            out = acc_scr[h] / jnp.maximum(lanes(l_scr[h], d), 1e-30)
             o_vmem[:, h * group:(h + 1) * group, :] = out.reshape(
                 q_blk, group, d).astype(o_vmem.dtype)
         o_copy = pltpu.make_async_copy(
@@ -723,14 +793,16 @@ def _vmem_limit(q_blk: int, h: int, kvh: int, group: int, d: int,
                 n_keys: int, itemsize: int) -> dict:
     """`vmem_limit_bytes` for a geometry whose scratch outgrows the
     default: the q and output blocks, a kv head's queries, the
-    accumulator and the two statistics (one lane used of 128) grow with
-    q_blk x heads, so 48 query heads over 8 kv heads (a group of 6)
-    want ~15 MiB where a group of 2 wants ~6. Nothing for a geometry
-    inside the default: its compiler parameters are what they were."""
+    accumulator, the two statistics (a row's on all 128 lanes) and the
+    mask's query offsets grow with q_blk x heads, so 48 query heads
+    over 8 kv heads (a group of 6) want ~15 MiB where a group of 2
+    wants ~6. Nothing for a geometry inside the default: its compiler
+    parameters are what they were."""
     r = q_blk * group
     need = (2 * q_blk * h * d * itemsize            # q, output blocks
             + kvh * r * d * itemsize                # q per kv head
             + kvh * r * d * 4 + 2 * kvh * r * 128 * 4   # acc, m, l
+            + r * n_keys * 4                        # query offsets
             + 2 * kvh * n_keys * d * itemsize       # k, v per kv head
             + _KV_VMEM_BYTES + 2 * q_blk * kvh * d * itemsize)
     if need <= _VMEM_DEFAULT * 3 // 4:
@@ -808,13 +880,11 @@ def _ragged_call(items, segs, tables, q, k_pages, v_pages, k_new, v_new,
         pltpu.VMEM((kvh, r, d), q.dtype),              # q per kv head
         pltpu.VMEM((kvh, n_keys, d), q.dtype),         # k, v per kv head
         pltpu.VMEM((kvh, n_keys, d), q.dtype),
-        pltpu.VMEM((kvh, r, 1), jnp.float32),          # m
-        pltpu.VMEM((kvh, r, 1), jnp.float32),          # l
+        pltpu.VMEM((kvh, r, 128), jnp.float32),        # m, l: a row's
+        pltpu.VMEM((kvh, r, 128), jnp.float32),        # on all 128 lanes
         pltpu.VMEM((kvh, r, d), jnp.float32),          # acc
+        pltpu.VMEM((r, n_keys), jnp.int32),            # query offsets
     ]
-    if merged_rows:
-        # a context block's rows in float32, for the strided loads
-        scratch += [pltpu.VMEM((ppb * page_size * kvh, d), jnp.float32)] * 2
 
     out = pl.pallas_call(
         functools.partial(

@@ -18,6 +18,7 @@ Gates:
 """
 
 import dataclasses
+import re
 import zlib
 
 import numpy as np
@@ -116,10 +117,51 @@ def _kernel_out(c, **kw):
         jnp.asarray(c["k_new"]), jnp.asarray(c["v_new"]), **kw))
 
 
-def _oracle_out(c):
+def _oracle_out(c, window=None):
     return ragged_attention_dense_oracle(
         c["q"], c["dense_k"], c["dense_v"], c["k_new"], c["v_new"],
-        c["slot_ids"], c["positions"], c["valid"], c["start"])
+        c["slot_ids"], c["positions"], c["valid"], c["start"], window)
+
+
+# The cells' head geometries, in the cells' type: bf16 pools with an even
+# number of kv heads reach the MXU through `split_heads` (a bitcast view
+# of the page block, a strided load of 32-bit words a pair of heads), in
+# both pool forms; the interpreter runs that path as the chip does.
+_TRINITY = dict(kvh=8, group=6)                      # tile form
+_SMALLTHINKER = dict(kvh=4, group=7, merged=True)    # merged rows
+_NEMOTRON = dict(kvh=2, group=16, merged=True)
+_PHI4FLASH = dict(kvh=10, group=4, merged=True)
+
+
+def _typed_case(rng, segs, *, kvh, group, merged=False, d=128,
+                dtype=jnp.bfloat16, **kw):
+    """A `_ragged_case` in the kernel's `dtype`, its pools merged-rows if
+    asked, and the same case with those VALUES in float32 for the
+    oracle."""
+    c = _ragged_case(rng, segs, kvh=kvh, group=group, d=d, **kw)
+    floats = [k for k, v in c.items() if v.dtype == np.float32]
+    chip = dict(c, **{k: jnp.asarray(c[k], dtype) for k in floats})
+    c.update({k: np.asarray(chip[k].astype(jnp.float32)) for k in floats})
+    if merged:
+        pages, page = c["k_pages"].shape[:2]
+        for k in ("k_pages", "v_pages"):
+            chip[k] = chip[k].reshape(pages, page * kvh, d)
+    return chip, c
+
+
+def _bf16_agrees(name, segs, geo, window=None, **case_kw):
+    """The interpreted kernel on bf16 arrays against the float32 oracle
+    on the same values: the operands' and the output's rounding is all
+    that parts them."""
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    chip, c = _typed_case(rng, segs, **geo, **case_kw)
+    out = _kernel_out(chip, interpret=True, window=window,
+                      merged_rows=geo.get("merged", False)
+                      ).astype(np.float32)
+    ref = _oracle_out(c, window)
+    ok = c["valid"]
+    np.testing.assert_allclose(out[ok], ref[ok], rtol=3e-2, atol=3e-2)
+    assert np.all(np.isfinite(out)) and not out[~ok].any()
 
 
 @pytest.mark.parametrize("name,segs,pad,kvh,group", [
@@ -137,8 +179,24 @@ def _oracle_out(c):
     ("padding_rows", [(5, 1), (0, 4)], 7, 2, 2),
     # a slot with zero tokens this tick + nothing but padding rows
     ("all_padding", [(0, 0)], 6, 2, 2),
+    # the cells' geometries in bf16 (kvh names the geometry)
+    ("trinity_mixed", [(7, 1), (0, 5), (12, 1), (4, 6)], 0, _TRINITY, 0),
+    ("smallthinker_mixed", [(7, 1), (0, 5), (12, 1), (4, 6)], 3,
+     _SMALLTHINKER, 0),
+    ("nemotron_decode_only", [(5, 1), (11, 1), (3, 1), (8, 1)], 0,
+     _NEMOTRON, 0),
+    ("phi4flash_partial_last_page", [(5, 3), (9, 1), (1, 2), (6, 1)], 0,
+     _PHI4FLASH, 0),
+    # a window whose lower edge falls inside a context block (keys
+    # 201..300 of a decode row at 300), group 6; and inside the
+    # in-batch keys, group 16 on merged rows
+    ("window_edge_in_a_context_block", [(300, 1), (200, 5)], 2, _TRINITY,
+     100),
+    ("window_edge_in_the_batch", [(300, 20), (0, 20)], 0, _NEMOTRON, 8),
 ])
 def test_pallas_ragged_kernel_matches_oracle(name, segs, pad, kvh, group):
+    if isinstance(kvh, dict):
+        return _bf16_agrees(name, segs, kvh, window=group or None, pad=pad)
     rng = np.random.default_rng(zlib.crc32(name.encode()))
     c = _ragged_case(rng, segs, pad=pad, kvh=kvh, group=group)
     out = _kernel_out(c, interpret=True)
@@ -170,12 +228,29 @@ _WORK_LIST_EDGES = [
     # partial block, which is the most a list can hold
     ("item_list_full", [(4, 129), (0, 129), (7, 1), (2, 1)], 0, 0),
     ("padding_between_the_bound_and_the_tokens", [(3, 140)], 9, 0),
+    # bf16 at the cells' geometries (`extra` names geometry and window).
+    # A 140-token chunk at 300 cached tokens: its first item sweeps two
+    # interior context blocks, the boundary block that holds keys
+    # 256..299 and its diagonal block; its second item (12 rows: the
+    # `few` body) a whole in-batch block under the diagonal one
+    ("interior_boundary_and_diagonal_blocks", [(300, 140), (130, 3)], 0,
+     (_TRINITY, None)),
+    # the same sweep under a window that cuts inside block 1
+    ("window_sweep_starts_inside_a_block", [(300, 140), (130, 3)], 5,
+     (_SMALLTHINKER, 200)),
+    ("merged_rows_two_heads_chunk_over_blocks",
+     [(6, 1), (20, 200), (2, 1)], 0, (_NEMOTRON, None)),
+    ("merged_rows_ten_heads_context_off_the_block",
+     [(130, 3), (257, 1)], 0, (_PHI4FLASH, None)),
 ]
 
 
 @pytest.mark.parametrize("name,segs,pad,extra", _WORK_LIST_EDGES,
                          ids=[c[0] for c in _WORK_LIST_EDGES])
 def test_pallas_ragged_kernel_work_list_edges(name, segs, pad, extra):
+    if isinstance(extra, tuple):
+        geo, window = extra
+        return _bf16_agrees(name, segs, geo, window=window, pad=pad)
     rng = np.random.default_rng(zlib.crc32(name.encode()))
     c = _ragged_case(rng, segs, pad=pad, extra_pages=extra)
     t = len(c["valid"])
@@ -210,6 +285,9 @@ def test_pallas_ragged_kernel_work_list_edges(name, segs, pad, extra):
     (2, 0, -1),      # 7 pages of 2
     (1, 30, 13),     # 13 pages of 1, the table cut to what exists
     (4, 0, 4),       # the static ctx_pages bound covering the data
+    # bf16 pairs of heads off blocks narrower than 128 keys
+    (4, 0, _TRINITY), (2, 150, _SMALLTHINKER), (1, 30, _NEMOTRON),
+    (4, 150, _PHI4FLASH),
 ])
 def test_pallas_ragged_kernel_blocking_invariance(page_size, pad,
                                                   ctx_pages):
@@ -217,12 +295,44 @@ def test_pallas_ragged_kernel_blocking_invariance(page_size, pad,
     sizes the kernel derives, it agrees with the oracle; and the static
     ctx_pages bound does not change the math when it covers the live
     data."""
+    if isinstance(ctx_pages, dict):
+        return _bf16_agrees("blocking", [(7, 1), (0, 5), (12, 1), (4, 6)],
+                            ctx_pages, page_size=page_size, pad=pad)
     rng = np.random.default_rng(12)
     c = _ragged_case(rng, [(7, 1), (0, 5), (12, 1), (4, 6)],
                      page_size=page_size, pad=pad)
     out = _kernel_out(c, interpret=True, ctx_pages=ctx_pages)
     np.testing.assert_allclose(out[c["valid"]], _oracle_out(c)[c["valid"]],
                                rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("dtype,merged,paired", [
+    ("bfloat16", False, True), ("bfloat16", True, True),
+    ("float32", False, False), ("float32", True, False)])
+def test_only_bf16_pages_take_the_paired_load(dtype, merged, paired):
+    """Which load path a kernel takes is a static fact of its arguments,
+    and the interpreter runs the one the chip runs: bf16 pools read
+    words of two heads (a shift for the low half: kvh / 2 pairs, K and
+    V, context and in-batch), and nothing casts a page block to float32
+    whole; float32 pools have no words to split."""
+    kvh, page, d = 4, 4, 16
+    arr, c = _typed_case(np.random.default_rng(3), [(7, 1), (4, 6)],
+                         kvh=kvh, group=2, merged=merged, d=d,
+                         dtype=dtype, page_size=page)
+    arr = {k: jnp.asarray(v) for k, v in arr.items()}
+    text = str(jax.make_jaxpr(
+        lambda a: ragged_paged_attention_pallas(
+            a["q"], a["k_pages"], a["v_pages"], a["tables"],
+            a["slot_ids"], a["positions"], a["valid"], a["start"],
+            a["k_new"], a["v_new"], interpret=True,
+            merged_rows=merged))(arr))
+    assert text.count("shift_left") == (4 * kvh // 2 if paired else 0)
+    # ... a float32 value as large as a page block of 3 pages
+    whole = c["tables"].shape[1] * page * kvh * d
+    casts = re.findall(r"f32\[([\d,]+)\] = convert_element_type", text)
+    sizes = {int(np.prod([int(n) for n in dims.split(",")]))
+             for dims in casts}
+    assert whole not in sizes
 
 
 @pytest.mark.parametrize("t,page_size,table,kvh,expect", [

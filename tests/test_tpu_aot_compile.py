@@ -736,6 +736,50 @@ def test_both_attention_kernels_compile_at_smallthinkers_shapes(
     assert name in compiled.as_text()
 
 
+# the five head geometries the cells hand the work-list kernel (query
+# heads over K/V rows of 128): every one reads its bf16 pages through
+# `split_heads`, 32-bit words of two adjacent heads by a strided load
+# off a bitcast view of the page buffer
+PAIRED_GEOMETRIES = [
+    # cell, kv heads, query heads a kv head
+    ("trinity-mixed", 8, 6), ("chat-open", 8, 2),
+    ("smallthinker-assist", 4, 8), ("nemotron-agent", 2, 16),
+    ("phi4flash-reason", 10, 4),
+]
+
+
+@pytest.mark.parametrize("merged", [False, True], ids=["tile", "rows"])
+@pytest.mark.parametrize("cell,kvh,group", PAIRED_GEOMETRIES,
+                         ids=[g[0] for g in PAIRED_GEOMETRIES])
+def test_paired_head_loads_compile_in_both_pool_forms(v5e, cell, kvh,
+                                                      group, merged):
+    """The reshaped, bitcast view of a page block and its strided load
+    of words (stride kvh / 2: 4, 4, 2, 1 and 5) pass Mosaic for either
+    pool form at a chunk's 128 query rows, over a table 1,024 pages
+    wide. Ten heads in the tile form are the one refusal, and it is the
+    page DMA's (`test_ten_heads_a_page_are_refused_by_the_compiler`)."""
+    S = _on(v5e[0])
+    T, d, pages = 512, 128, 20000
+    pool = S((pages, PAGE * kvh, d) if merged else (pages, PAGE, kvh, d),
+             jnp.bfloat16)
+    new = S((T, kvh, d), jnp.bfloat16)
+    i32 = lambda *shape: S(shape, jnp.int32)
+
+    def run(q, kp, vp, tables, slots, pos, valid, start, kn, vn):
+        return ragged_paged_attention_pallas(
+            q, kp, vp, tables, slots, pos, valid, start, kn, vn,
+            merged_rows=merged)
+
+    lowered = jax.jit(run).lower(
+        S((T, kvh * group, d), jnp.bfloat16), pool, pool, i32(32, 1024),
+        i32(T), i32(T), S((T,), jnp.bool_), i32(32), new, new)
+    if kvh == 10 and not merged:
+        with pytest.raises(Exception, match="aligned to tiling"):
+            lowered.compile()
+        return
+    assert "ragged_paged_attention" in lowered.compile().as_text()
+
+
 def test_28_query_heads_are_refused_by_the_compiler(v5e):
     """Why `smallthinker._attend_padded` exists: the kernels move a
     tick's queries in tiles of 8 heads, and 28 are three and a half."""
