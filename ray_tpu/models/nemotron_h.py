@@ -625,7 +625,8 @@ def ragged_forward(cfg: NemotronHConfig, params: Dict[str, Any],
          jnp.arange(len(units), dtype=jnp.int32),
          jnp.asarray(np.maximum(a_idx, 0), jnp.int32),
          jnp.asarray([a is not None for _, a, _ in units])))
-    # the tick's K and V rows go into the pool once, after the stack
+    # the tick's K and V rows go into the pool once, after the stack: one
+    # scatter of single rows a pool (scope `kv_write`)
     own = page_tables[slot_ids]
     at = np.asarray(starred)                         # static
     pool_k = scatter_rows(pool_k, ks[at], own, positions, valid)

@@ -557,25 +557,35 @@ def scatter_rows(pool: jax.Array, rows: jax.Array,
                  valid: jax.Array) -> jax.Array:
     """Write a tick's rows into one group's merged-rows pool. pool: [L,
     P, page * heads, Dp]; rows: [L, N, heads, d]; each token's OWN table
-    in page_tables [N, max_pages]; invalid rows go to the scratch page.
-    One scatter of [heads, Dp] windows, each at its token's first row,
-    over the pool flattened to [L * P * page * heads, Dp]."""
+    in page_tables [N, max_pages]; invalid rows go to the scratch page
+    (`num_pages - 1`), where they may collide.
+
+    ONE scatter of SINGLE Dp-lane rows, L * N * heads of them, into the
+    pool flattened to [L * P * page * heads, Dp], under scope
+    `kv_write`: XLA:TPU runs it as one native `scatter` on the donated
+    pool. Single rows and not a token's [heads, Dp] WINDOW: a scatter
+    of windows it runs as a serial `while` of one
+    `dynamic-update-slice` a (layer, token), 3.7 us a trip, 17 ms for
+    the K rows of a 512-token tick in `smallthinker-assist`'s window
+    group (PERF.md section 6, PR 46). The rows a token (10, 4, 2) and
+    the layers a call are the arguments' shapes: one path for every
+    family. A valid token's page comes from its own table and its row
+    from `positions % page`, so no index is past the end; `mode="clip"`
+    only keeps the compiler from assuming it."""
     l, num_pages, per_page, w = pool.shape
     kvh = rows.shape[2]
     page = per_page // kvh
-    page_idx = jnp.take_along_axis(
-        page_tables, (positions // page)[:, None], axis=1)[:, 0]
-    page_idx = jnp.where(valid, page_idx, num_pages - 1)
-    at = (page_idx * page + positions % page) * kvh               # [N]
-    at = (jnp.arange(l, dtype=at.dtype)[:, None] * (num_pages * per_page)
-          + at[None, :]).reshape(-1, 1)                           # [L*N]
-    new = _fit_lanes(rows, w).reshape(-1, kvh, w).astype(pool.dtype)
-    dims = jax.lax.ScatterDimensionNumbers(
-        update_window_dims=(1, 2), inserted_window_dims=(),
-        scatter_dims_to_operand_dims=(0,))
-    return jax.lax.scatter(
-        pool.reshape(-1, w), at, new, dims, indices_are_sorted=False,
-        unique_indices=False, mode="clip").reshape(pool.shape)
+    with jax.named_scope("kv_write"):
+        page_idx = jnp.take_along_axis(
+            page_tables, (positions // page)[:, None], axis=1)[:, 0]
+        page_idx = jnp.where(valid, page_idx, num_pages - 1)
+        at = (page_idx * page + positions % page) * kvh           # [N]
+        at = (jnp.arange(l, dtype=at.dtype)[:, None, None]
+              * (num_pages * per_page) + at[None, :, None]
+              + jnp.arange(kvh, dtype=at.dtype))             # [L, N, heads]
+        new = _fit_lanes(rows, w).reshape(-1, w).astype(pool.dtype)
+        return pool.reshape(-1, w).at[at.reshape(-1)].set(
+            new, mode="clip").reshape(pool.shape)
 
 
 def _attend_fn(cfg: Phi4FlashConfig, impl: str, pools, tables,

@@ -48,8 +48,9 @@ How it runs here:
   [page x 4 rows, 128] (`CacheRow.layout` "rows", as the phi4flash and
   nemotron_h families' pages): a [16, 4, 128] page would be padded to
   [16, 8, 128] in device memory. So the attention over the cache and
-  the scatter of a tick's rows are phi4flash's merged-rows ones
-  (`_attend_fn`, `scatter_rows`), not Trinity's token-layout ones;
+  the write of a tick's rows are phi4flash's merged-rows ones
+  (`_attend_fn`, `scatter_rows`: one scatter of single 128-lane rows a
+  pool, scope `kv_write`), not Trinity's token-layout ones;
   rope is Trinity's (`trinity.rope_cos_sin`, `deepseek_v3._rope`:
   rotate-half).
 - The stack is a list of one tree a layer and the forward a loop over
@@ -501,7 +502,8 @@ def ragged_forward(cfg: SmallThinkerConfig, params: Dict[str, Any],
         vs.append(v)
         counts.append(routing.counts)
     ks, vs = jnp.stack(ks), jnp.stack(vs)
-    # a tick's rows go into the pools once, after the stack
+    # a tick's rows go into the pools once, after the stack: one scatter
+    # of single rows a pool (scope `kv_write`)
     new_k, new_v = [], []
     for g, kind in enumerate((FULL, SLIDING)[:len(k_pages)]):
         of = np.asarray(cfg.layers_of(kind))         # static

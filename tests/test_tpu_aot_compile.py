@@ -156,6 +156,61 @@ def test_dense_forwards_copy_no_pool_at_chat_opens_sizes(v5e, T):
     assert mem.alias_size_in_bytes >= 3.2e9
 
 
+def _row_write_is_one_scatter(text, calls, most=None):
+    """A tick program's text: each call of `scatter_rows` is one
+    `scatter` under scope `kv_write`, none of them a `while`; at most
+    `most` instructions in all, read off PR 46's tree (+1.5%), so that
+    a later form of the write does not unroll it by layer, slab or head
+    (refused PR 45's multiplied a program's instructions, and warm
+    set-up rose 25 s in `phi4flash-reason`)."""
+    lines = text.splitlines()
+    writes = [x for x in lines if " scatter(" in x and "kv_write" in x]
+    assert len(writes) == calls, len(writes)
+    loops = [x.strip()[:160] for x in lines if " while(" in x
+             and ("kv_write" in x or "/scatter" in x)]
+    assert not loops, loops
+    if most is not None:
+        assert sum(" = " in x for x in lines) <= most
+
+
+# rows a token, layers a call, pages, table width: a group of each of the
+# three merged-rows families at its cell's sizes (benchmarks/configs)
+ROW_WRITES = {
+    "phi4flash-window": (10, 8, 4608, 512),
+    "phi4flash-full": (10, 1, 24576, 512),
+    "smallthinker-window": (4, 9, 8192, 1024),
+    "nemotron": (2, 2, 32768, 1088),
+}
+
+
+@pytest.mark.parametrize("T", [64, 512], ids=["decode", "chunk"])
+@pytest.mark.parametrize("group", list(ROW_WRITES))
+def test_row_write_is_one_scatter_at_the_cells_geometries(v5e, group, T):
+    """`phi4flash.scatter_rows` alone (PR 46): ONE native `scatter` of
+    single 128-lane rows on the donated pool, no `while` (a scatter of
+    [rows, 128] windows compiles to a serial loop of one
+    `dynamic-update-slice` a (layer, token): 17 ms of a 512-token tick
+    in `smallthinker-assist`), and no copy of the pool."""
+    from ray_tpu.models.phi4flash import scatter_rows
+    kvh, layers, pages, width = ROW_WRITES[group]
+    S = _on(v5e[0])
+    pool = S((layers, pages, PAGE * kvh, 128), jnp.bfloat16)
+    compiled = jax.jit(scatter_rows, donate_argnums=0).lower(
+        pool, S((layers, T, kvh, 128), jnp.bfloat16),
+        S((T, width), jnp.int32), S((T,), jnp.int32),
+        S((T,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    _row_write_is_one_scatter(text, 1)
+    assert " while(" not in text
+    flat = f"bf16[{layers * pages * PAGE * kvh},128]"
+    copies = [line.strip()[:120] for line in text.splitlines()
+              if f" = {flat}" in line and " copy(" in line]
+    assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert mem.alias_size_in_bytes == layers * pages * PAGE * kvh * 256
+
+
 @pytest.mark.parametrize("head_dim", [64, 128])
 def test_flash_fwd_bwd_compiles_for_v5e(v5e, head_dim):
     S = _on(v5e[0])
@@ -418,6 +473,8 @@ def test_phi4flashs_scanned_forwards_compile_at_the_cells_sizes(v5e, T,
         *args).compile()
     text = compiled.as_text()
     assert "ssm_ragged_scan" in text and text.count(" while(") >= 2
+    # K and V of the two page groups (PR 46)
+    _row_write_is_one_scatter(text, 4, 5610 if T else 4890)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < temp_mb << 20
     # the pools and the state are updated in place
@@ -642,6 +699,8 @@ def test_nemotrons_scanned_forwards_compile_at_the_cells_sizes(v5e, T,
     for kernel in ("ssd_ragged_scan", "moe_grouped_up_relu2",
                    "ragged_paged_attention"):
         assert kernel in text, kernel
+    # K and V of the one page group (PR 46)
+    _row_write_is_one_scatter(text, 2, 3550 if T else 2985)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < temp_mb << 20
     # the pool and the state are updated in place
@@ -870,6 +929,8 @@ def test_smallthinkers_forwards_compile_at_the_cells_sizes(v5e, T,
     for kernel in ("moe_grouped_up_reglu", "moe_grouped_down_reglu",
                    "ragged_paged_attention", "ragged_window_attention"):
         assert kernel in text, kernel
+    # K and V of the two page groups (PR 46)
+    _row_write_is_one_scatter(text, 4, 8280 if T else 7750)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < temp_mb << 20
     # both groups' pools are updated in place
