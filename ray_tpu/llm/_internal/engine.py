@@ -1445,14 +1445,16 @@ class InferenceEngine:
     def _ragged_fn(self, t_bucket: int, ctx_pages: int,
                    all_greedy: bool):
         """Jitted ragged tick: the ragged forward over the flat token
-        batch + per-slot sampling, cached per (token-count bucket,
-        context-pages bucket, all_greedy). all_greedy is a STATIC jit
-        arg — keying the cache on it too keeps the compile counter
-        honest (a greedy<->sampled flip builds a second program for
-        the same shape bucket and must count as one). Attention impl
-        comes from the SAME
-        resolver as the decode program (auto -> Pallas ragged kernel
-        on TPU, dense gather on CPU, pallas_interpret for tests).
+        batch + per-slot sampling, cached per (token bucket, context
+        key, all_greedy). The context key is `_ctx_bucket`'s: on a
+        kernel path the whole table or none (ONE program a token bucket,
+        with a context or without), on the gather path a power-of-two
+        bucket of context pages. all_greedy is a STATIC jit arg — keying
+        the cache on it too keeps the compile counter honest (a
+        greedy<->sampled flip builds a second program for the same
+        shape bucket and must count as one). Attention impl comes from
+        the SAME resolver as the decode program (auto -> Pallas ragged
+        kernel on TPU, dense gather on CPU, pallas_interpret for tests).
 
         Host state arrives PACKED — tok_meta (5, T) int32 rows
         tokens/slot_ids/positions/valid/lora_idx, slot_meta (4, B)

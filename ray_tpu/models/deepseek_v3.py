@@ -57,6 +57,7 @@ from ..ops import mla_attention as mla_ops
 from ..ops.moe import (held_experts_ffn, held_gates, platform_impl,
                        sigmoid_group_routing)
 from .llama import rms_norm
+from .paged_common import one_token_tick, refuse, rope, swiglu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -306,17 +307,6 @@ def rope_cos_sin(cfg: DeepseekV3Config, positions: jax.Array):
     return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
-def _rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """x: [T, ..., d] rotate-half; cos/sin: [T, d/2]."""
-    d = x.shape[-1]
-    while cos.ndim < x.ndim:
-        cos, sin = cos[:, None], sin[:, None]
-    x1 = x[..., :d // 2].astype(jnp.float32)
-    x2 = x[..., d // 2:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
 # --------------------------------------------------------------------- layers
 
 def mla_project(cfg: DeepseekV3Config, layer, x, cos, sin):
@@ -328,11 +318,11 @@ def mla_project(cfg: DeepseekV3Config, layer, x, cos, sin):
     cq = rms_norm(y @ layer["wqa"], layer["q_norm"], cfg.norm_eps)
     q = (cq @ layer["wqb"]).reshape(t, cfg.n_heads, cfg.qk_head_dim)
     q_nope = q[..., :cfg.qk_nope_head_dim]
-    q_pe = _rope(q[..., cfg.qk_nope_head_dim:], cos, sin)
+    q_pe = rope(q[..., cfg.qk_nope_head_dim:], cos, sin)
     kv = y @ layer["wkva"]
     c_kv = rms_norm(kv[:, :cfg.kv_lora_rank], layer["kv_norm"],
                     cfg.norm_eps)
-    k_pe = _rope(kv[:, cfg.kv_lora_rank:], cos, sin)
+    k_pe = rope(kv[:, cfg.kv_lora_rank:], cos, sin)
     q_lat = jnp.einsum("thn,chn->thc", q_nope, layer["wkb"],
                        preferred_element_type=jnp.float32).astype(dt)
     return (jnp.concatenate([q_lat, q_pe], axis=-1),
@@ -345,10 +335,6 @@ def mla_output(cfg: DeepseekV3Config, layer, o_lat):
     o = jnp.einsum("thc,chv->thv", o_lat, layer["wvb"],
                    preferred_element_type=jnp.float32).astype(cfg.dtype)
     return o.reshape(o.shape[0], -1) @ layer["wo"]
-
-
-def swiglu(w, y):
-    return (jax.nn.silu(y @ w["wg"]) * (y @ w["wi"])) @ w["wd"]
 
 
 def moe_block(cfg: DeepseekV3Config, layer, y, valid=None,
@@ -373,33 +359,11 @@ def moe_block(cfg: DeepseekV3Config, layer, y, valid=None,
         out = swiglu(layer["shared"], y)
     with jax.named_scope("moe_experts"):
         ex = layer["experts"]
-        routed = held_experts_ffn(y, gates, took, ex["wg"], ex["wi"],
-                                  ex["wd"], picks=cfg.moe_top_k,
+        routed = held_experts_ffn(y, gates, took, (ex["wg"], ex["wi"]),
+                                  ex["wd"], act="swiglu",
+                                  picks=cfg.moe_top_k,
                                   impl=impl or platform_impl())
     return out + routed.astype(out.dtype), counts
-
-
-def routing_summary(cfg: DeepseekV3Config, landed, tokens_routed: int
-                    ) -> Dict[str, Any]:
-    """What `stats()["moe"]` shows of the forwards' expert counts summed
-    since start-up (`landed`: n_moe_layers * n_held ints): tokens routed
-    (each through every expert layer), assignments that landed on the
-    experts held here by layer and expert, how many held experts
-    received any, and the busiest one's load over the mean load of a
-    held expert."""
-    landed = landed.reshape(cfg.n_moe_layers, cfg.n_held)
-    total = int(landed.sum())
-    mean = total / max(landed.size, 1)
-    return {
-        "experts_held": list(cfg.held),
-        "expert_layers": cfg.n_moe_layers,
-        "tokens_routed": tokens_routed,
-        "assignments_landed": total,
-        "experts_with_tokens": int((landed != 0).sum()),
-        "busiest_over_mean": (round(float(landed.max()) / mean, 4)
-                              if total else 0.0),
-        "landed": landed.tolist(),
-    }
 
 
 # ------------------------------------------------------------------- forwards
@@ -430,12 +394,6 @@ def _stack(cfg: DeepseekV3Config, params, x, positions, valid, attend,
     return (x, jnp.stack(rows),
             jnp.stack(counts) if counts
             else jnp.zeros((0, cfg.n_held), jnp.int32))
-
-
-def _refuse(**given):
-    for name, value in given.items():
-        if value is not None and value != "f32":
-            raise ValueError(f"the DeepSeek-V3 forwards take no {name}")
 
 
 def cache_attention(cfg: DeepseekV3Config, impl: str, pool: jax.Array,
@@ -481,8 +439,8 @@ def ragged_forward(cfg: DeepseekV3Config, params: Dict[str, Any],
     None (there is no second pool) and comes back as given. Returns
     (last-token logits per slot [B, V] float32, pool, None, expert
     counts [n_moe_layers, n_held] int32)."""
-    _refuse(lora=lora, mesh=mesh, kv_kind=kv_kind, k_scales=k_scales,
-            v_scales=v_scales)
+    refuse("DeepSeek-V3", lora=lora, mesh=mesh, kv_kind=kv_kind,
+           k_scales=k_scales, v_scales=v_scales)
     del lora_idx
     pool = k_pages
     with jax.named_scope("embed"):
@@ -500,20 +458,4 @@ def ragged_forward(cfg: DeepseekV3Config, params: Dict[str, Any],
     return logits, pool, v_pages, counts
 
 
-def decode_step(cfg: DeepseekV3Config, params: Dict[str, Any],
-                tokens: jax.Array, positions: jax.Array,
-                k_pages: jax.Array, v_pages, page_tables: jax.Array,
-                active: jax.Array, impl: str = "gather", mesh=None,
-                lora=None, lora_idx=None, kv_kind: str = "f32",
-                k_scales=None, v_scales=None):
-    """One decode step for the whole batch: the ragged tick of one token
-    a slot (slot b's token at positions[b], inactive slots invalid),
-    through the same attention. Contract of `llama_infer.decode_step`;
-    returns (logits [B, V] float32, pool, None, expert counts)."""
-    b = tokens.shape[0]
-    slots = jnp.arange(b, dtype=jnp.int32)
-    return ragged_forward(
-        cfg, params, tokens, slots, positions, active, positions, slots,
-        k_pages, v_pages, page_tables, ctx_pages=-1, lora=lora,
-        lora_idx=lora_idx, impl=impl, mesh=mesh, kv_kind=kv_kind,
-        k_scales=k_scales, v_scales=v_scales)
+decode_step = one_token_tick(ragged_forward)

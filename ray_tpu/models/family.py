@@ -10,22 +10,8 @@ how its totals read in `stats()`, and the engine options the family
 does not compose with (refused at construction with the reason, never
 half-run). Nothing here imports the engine: the engine imports this.
 
-Six families: `llama` (dense RoPE/GQA/SwiGLU decoders, `LlamaConfig`,
-programs unchanged), `deepseek_v3` (latent attention over a latent
-cache, expert layers with the experts held here, `DeepseekV3Config`),
-`trinity` (sliding-window and full-attention layers over two page
-groups, gated QK-normed GQA attention, held experts, `TrinityConfig`),
-`phi4flash` (Mamba layers whose state is kept a slot beside a
-window page group and ONE layer's pages that eight layers read,
-differential attention, a tied head, a cross-decoder that only sampling
-rows run, `Phi4FlashConfig`), `nemotron_h` (Mamba-2 layers whose
-2 MB state a slot a layer lies beside ONE small page group, ungated
-relu^2 held experts, layers that are a mixer or a feed-forward part
-alone, `NemotronHConfig`: the first family with BOTH a rider and a
-state group) and `smallthinker` (a router that reads the layer's input
-ahead of attention, gated-ReLU held experts with no shared expert and
-no dense layer, 28 query heads over 4 on window and full layers over
-two merged-rows page groups, `SmallThinkerConfig`).
+The families are the entries of `FAMILIES`; each module's own docstring
+describes its model.
 
 A family with a STATE group (`cache_row.CacheGroup.state`) gets that
 group's arrays in `k_pages` / `v_pages` behind its page groups' pools
@@ -37,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib
 from typing import Any, Callable, Dict, Optional, Tuple
 
 from .cache_row import CacheGroup, CacheRow
@@ -146,78 +133,82 @@ DEEPSEEK_REFUSES = {
 }
 
 
+# family name -> (its module under `models/`, its configuration type
+# there): the ONE place the families are listed. `family_of`,
+# `resolve_config` and `_families` read it, so a new family is an entry
+# here and its `ModelFamily` in `_families`
+FAMILIES: Dict[str, Tuple[str, str]] = {
+    "llama": ("llama", "LlamaConfig"),
+    "deepseek_v3": ("deepseek_v3", "DeepseekV3Config"),
+    "trinity": ("trinity", "TrinityConfig"),
+    "phi4flash": ("phi4flash", "Phi4FlashConfig"),
+    "nemotron_h": ("nemotron_h", "NemotronHConfig"),
+    "smallthinker": ("smallthinker", "SmallThinkerConfig"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _modules() -> Dict[str, Any]:
+    """Family name -> its module (imported on first use: the model
+    modules import jax)."""
+    return {name: importlib.import_module(f".{module}", __package__)
+            for name, (module, _) in FAMILIES.items()}
+
+
+def _from_module(name: str, module, **parts) -> ModelFamily:
+    """The family of a module that names its parts as the later families'
+    modules do; `parts` are the ones it names otherwise or has besides.
+    `work_counts` is the dense family's unless given: the full layers'
+    (or the one attention kind's) kernel is the dense family's."""
+    for part in ("init_params", "ragged_forward", "decode_step",
+                 "cache_groups"):
+        if part not in parts:
+            parts[part] = getattr(module, part)
+    parts.setdefault("work_counts", _llama_work_counts)
+    return ModelFamily(name=name, **parts)
+
+
 @functools.lru_cache(maxsize=None)
 def _families() -> Dict[type, ModelFamily]:
-    """Configuration type -> its family (built on first use: the model
-    modules import jax)."""
-    from . import (deepseek_v3, llama, llama_infer, nemotron_h, phi4flash,
-                   smallthinker, trinity)
-    return {
-        llama.LlamaConfig: ModelFamily(
-            name="llama", init_params=llama.init_params,
+    """Configuration type -> its family."""
+    from . import llama_infer, paged_common
+    m = _modules()
+    trinity, phi4flash = m["trinity"], m["phi4flash"]
+    nemotron_h, smallthinker = m["nemotron_h"], m["smallthinker"]
+    # a family with held experts: the counts of the assignments landed
+    # ride the readback, [n_moe_layers, n_held], and are summed alike
+    held = dict(rider_len=paged_common.held_rider_len,
+                rider_summary=paged_common.routing_summary)
+    families = (
+        _from_module(
+            "llama", m["llama"],
             ragged_forward=llama_infer.ragged_forward,
             decode_step=llama_infer.decode_step,
             cache_groups=_llama_cache_groups,
-            work_counts=_llama_work_counts,
             storage_dtypes=llama_infer.storage_dtypes),
-        deepseek_v3.DeepseekV3Config: ModelFamily(
-            name="deepseek_v3", init_params=deepseek_v3.init_params,
-            ragged_forward=deepseek_v3.ragged_forward,
-            decode_step=deepseek_v3.decode_step,
+        _from_module(
+            "deepseek_v3", m["deepseek_v3"],
             cache_groups=_deepseek_cache_groups,
             work_counts=_deepseek_work_counts,
-            rider_len=lambda c: c.n_moe_layers * c.n_held,
-            rider_summary=deepseek_v3.routing_summary,
-            refuses=DEEPSEEK_REFUSES),
-        trinity.TrinityConfig: ModelFamily(
-            name="trinity", init_params=trinity.init_params,
-            ragged_forward=trinity.ragged_forward,
-            decode_step=trinity.decode_step,
-            cache_groups=trinity.cache_groups,
-            # the full layers' kernel: the dense family's count
-            work_counts=_llama_work_counts,
-            span_counts=trinity.span_counts,
-            rider_len=lambda c: c.n_moe_layers * c.n_held,
-            # the same counts of the same held-expert layer
-            rider_summary=deepseek_v3.routing_summary,
-            refuses=trinity.TRINITY_REFUSES),
-        phi4flash.Phi4FlashConfig: ModelFamily(
-            name="phi4flash", init_params=phi4flash.init_stacked,
-            ragged_forward=phi4flash.ragged_forward,
-            decode_step=phi4flash.decode_step,
-            cache_groups=phi4flash.cache_groups,
-            # the full layer's kernel: the dense family's count
-            work_counts=_llama_work_counts,
+            refuses=DEEPSEEK_REFUSES, **held),
+        _from_module(
+            "trinity", trinity, span_counts=trinity.span_counts,
+            refuses=trinity.TRINITY_REFUSES, **held),
+        _from_module(
+            "phi4flash", phi4flash, init_params=phi4flash.init_stacked,
             span_counts=phi4flash.span_counts,
             storage_dtypes=phi4flash.storage_dtypes,
             refuses=phi4flash.PHI4FLASH_REFUSES),
-        nemotron_h.NemotronHConfig: ModelFamily(
-            name="nemotron_h", init_params=nemotron_h.init_params,
-            ragged_forward=nemotron_h.ragged_forward,
-            decode_step=nemotron_h.decode_step,
-            cache_groups=nemotron_h.cache_groups,
-            # the attention layers' kernel: the dense family's count
-            work_counts=_llama_work_counts,
-            span_counts=nemotron_h.span_counts,
-            rider_len=lambda c: c.n_moe_layers * c.n_held,
-            # the same counts of the same held-expert layer
-            rider_summary=deepseek_v3.routing_summary,
+        _from_module(
+            "nemotron_h", nemotron_h, span_counts=nemotron_h.span_counts,
             storage_dtypes=nemotron_h.storage_dtypes,
-            refuses=nemotron_h.NEMOTRON_H_REFUSES),
-        smallthinker.SmallThinkerConfig: ModelFamily(
-            name="smallthinker", init_params=smallthinker.init_params,
-            ragged_forward=smallthinker.ragged_forward,
-            decode_step=smallthinker.decode_step,
-            cache_groups=smallthinker.cache_groups,
-            # the full layers' kernel: the dense family's count
-            work_counts=_llama_work_counts,
+            refuses=nemotron_h.NEMOTRON_H_REFUSES, **held),
+        _from_module(
+            "smallthinker", smallthinker,
             span_counts=smallthinker.span_counts,
-            # every layer is an expert layer: [n_layers, n_held]
-            rider_len=lambda c: c.n_moe_layers * c.n_held,
-            # the same counts of the same held-expert layer
-            rider_summary=deepseek_v3.routing_summary,
-            refuses=smallthinker.SMALLTHINKER_REFUSES),
-    }
+            refuses=smallthinker.SMALLTHINKER_REFUSES, **held),
+    )
+    return {getattr(m[f.name], FAMILIES[f.name][1]): f for f in families}
 
 
 def store_params(family: ModelFamily, cfg, params, shardings=None,
@@ -253,9 +244,8 @@ def store_params(family: ModelFamily, cfg, params, shardings=None,
 
 
 def family_of(cfg) -> ModelFamily:
-    """The family that serves `cfg` (a LlamaConfig, a DeepseekV3Config,
-    a TrinityConfig, a Phi4FlashConfig, a NemotronHConfig or a
-    SmallThinkerConfig)."""
+    """The family that serves `cfg`, a configuration of one of
+    `FAMILIES`' types."""
     for kind, family in _families().items():
         if isinstance(cfg, kind):
             return family
@@ -263,23 +253,12 @@ def family_of(cfg) -> ModelFamily:
 
 
 def resolve_config(model):
-    """A preset name or a family's configuration -> the configuration.
-    Names are the dense family's presets, `deepseek_v3:<preset>`,
-    `trinity:<preset>`, `phi4flash:<preset>`, `nemotron_h:<preset>` or
-    `smallthinker:<preset>`."""
-    from . import (deepseek_v3, llama, nemotron_h, phi4flash, smallthinker,
-                   trinity)
-    if isinstance(model, (deepseek_v3.DeepseekV3Config,
-                          trinity.TrinityConfig,
-                          phi4flash.Phi4FlashConfig,
-                          nemotron_h.NemotronHConfig,
-                          smallthinker.SmallThinkerConfig)):
+    """A family's configuration, `<family>:<preset>` or a preset name of
+    the dense family's -> the configuration."""
+    if isinstance(model, tuple(_families())):
         return model
     if isinstance(model, str) and ":" in model:
         family, preset = model.split(":", 1)
-        named = {"deepseek_v3": deepseek_v3, "trinity": trinity,
-                 "phi4flash": phi4flash, "nemotron_h": nemotron_h,
-                 "smallthinker": smallthinker}
-        if family in named:
-            return named[family].config(preset)
-    return llama.config(model)
+        if family in FAMILIES:
+            return _modules()[family].config(preset)
+    return _modules()["llama"].config(model)

@@ -58,8 +58,8 @@ How it runs here:
   program holds each kind's body once, not 16 times. The held experts of
   every expert layer lie in ONE array [layers x held, F, H] that the
   grouped kernels take whole with the layer's first expert as an index
-  (`ops/moe.held_relu2_ffn`): a scan that sliced a layer's experts out
-  would copy 0.64 GB a layer a tick.
+  (`ops/moe.held_experts_ffn`, `base`): a scan that sliced a layer's
+  experts out would copy 0.64 GB a layer a tick.
 - W_up of the experts is stored out by in ([F, H], as `nn.Linear` keeps
   it): the expert width 1856 is no whole number of 128-lane vectors,
   and as an array's minor dim XLA would pad it in a copy of the stack
@@ -90,12 +90,13 @@ import numpy as np
 
 from ..ops import selective_scan as ssm
 from ..ops import ssd_scan
-from ..ops.moe import (held_gates, held_relu2_ffn, platform_impl,
+from ..ops.moe import (held_experts_ffn, held_gates, platform_impl,
                        sigmoid_group_routing)
 from ..ops.paged_attention import pool_head_dim
 from .cache_row import CacheGroup, CacheRow, StateRow
 from .llama import rms_norm
-from .phi4flash import _attend_fn, scatter_rows
+from .paged_common import attend_fn, one_token_tick, refuse
+from .paged_common import scatter_merged_rows as scatter_rows
 
 MAMBA, EXPERTS, ATTN = "M", "E", "*"
 PUBLISHED_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -533,9 +534,9 @@ def moe_block(cfg: NemotronHConfig, layer, y, valid=None,
         out = mid.astype(cfg.dtype) @ layer["shared_down"]
     with jax.named_scope("moe_experts"):
         ex = experts or layer
-        routed = held_relu2_ffn(y, gates, took, ex["up"], ex["down"],
-                                picks=cfg.moe_top_k,
-                                impl=impl or platform_impl(), base=base)
+        routed = held_experts_ffn(y, gates, took, (ex["up"],), ex["down"],
+                                  act="relu2", picks=cfg.moe_top_k,
+                                  impl=impl or platform_impl(), base=base)
     return out + routed.astype(out.dtype), counts
 
 
@@ -548,12 +549,6 @@ def attention_mixer(cfg: NemotronHConfig, layer, u: jax.Array, attend, gi):
     v = (u @ layer["wv"]).reshape(t, cfg.n_kv_heads, cfg.head_dim)
     o = attend(q, k, v, 0, gi, None)
     return o.reshape(t, -1).astype(cfg.dtype) @ layer["wo"], k, v
-
-
-def _refuse(**given):
-    for name, value in given.items():
-        if value is not None and value != "f32":
-            raise ValueError(f"the NemotronH forwards take no {name}")
 
 
 def ragged_forward(cfg: NemotronHConfig, params: Dict[str, Any],
@@ -574,15 +569,15 @@ def ragged_forward(cfg: NemotronHConfig, params: Dict[str, Any],
     logits per slot [B, V] float32, the k tuple, the v tuple, expert
     counts [expert layers, n_held] int32), the state of the slots that
     had tokens advanced to their runs' ends."""
-    _refuse(lora=lora, mesh=mesh, kv_kind=kv_kind, k_scales=k_scales,
-            v_scales=v_scales)
+    refuse("NemotronH", lora=lora, mesh=mesh, kv_kind=kv_kind,
+           k_scales=k_scales, v_scales=v_scales)
     del lora_idx
     (pool_k, conv), (pool_v, scan) = k_pages, v_pages
     t = tokens.shape[0]
     marks = ssm.segment_marks(slot_ids, positions, valid, start, last_idx)
     tick = (slot_ids, valid, last_idx)
-    attend = _attend_fn(cfg, impl, ((pool_k, pool_v),), (page_tables,),
-                        slot_ids, positions, valid, start, ctx_pages)
+    attend = attend_fn(impl, ((pool_k, pool_v),), (page_tables,), slot_ids,
+                       positions, valid, start, ctx_pages, merged_rows=True)
     with jax.named_scope("embed"):
         x = params["embed"][tokens].astype(cfg.dtype)
     units = cfg.units
@@ -638,24 +633,7 @@ def ragged_forward(cfg: NemotronHConfig, params: Dict[str, Any],
     return logits, (pool_k, conv), (pool_v, scan), counts
 
 
-def decode_step(cfg: NemotronHConfig, params: Dict[str, Any],
-                tokens: jax.Array, positions: jax.Array, k_pages,
-                v_pages, page_tables, active: jax.Array,
-                impl: str = "gather", mesh=None, lora=None,
-                lora_idx=None, kv_kind: str = "f32", k_scales=None,
-                v_scales=None):
-    """One decode step for the whole batch: the ragged tick of one token
-    a slot (slot b's token at positions[b], inactive slots invalid: their
-    state is left alone), through the same attention, scan and experts.
-    Contract of `llama_infer.decode_step`; returns (logits [B, V]
-    float32, the k tuple, the v tuple, expert counts)."""
-    b = tokens.shape[0]
-    slots = jnp.arange(b, dtype=jnp.int32)
-    return ragged_forward(
-        cfg, params, tokens, slots, positions, active, positions, slots,
-        k_pages, v_pages, page_tables, ctx_pages=-1, lora=lora,
-        lora_idx=lora_idx, impl=impl, mesh=mesh, kv_kind=kv_kind,
-        k_scales=k_scales, v_scales=v_scales)
+decode_step = one_token_tick(ragged_forward)
 
 
 def span_counts(cfg: NemotronHConfig, segs, decode) -> Dict[str, int]:

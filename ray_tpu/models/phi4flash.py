@@ -98,11 +98,12 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import ragged_paged_attention as rpa
 from ..ops import selective_scan as ssm
-from ..ops.paged_attention import _fit_lanes, pool_head_dim
+from ..ops.paged_attention import pool_head_dim
 from .cache_row import CacheGroup, CacheRow, StateRow
-from .trinity import span_counts as _window_counts
+from .paged_common import (attend_fn, one_token_tick, refuse,
+                           window_span_counts)
+from .paged_common import scatter_merged_rows as scatter_rows
 
 MAMBA, SWA, FULL, GMU, CROSS = "mamba", "swa", "full", "gmu", "cross"
 PAIR = 2            # heads a differential pair; K/V heads a pool row
@@ -552,75 +553,12 @@ def mamba_mixer(cfg: Phi4FlashConfig, layer, u: jax.Array, marks, tick,
         scan_all
 
 
-def scatter_rows(pool: jax.Array, rows: jax.Array,
-                 page_tables: jax.Array, positions: jax.Array,
-                 valid: jax.Array) -> jax.Array:
-    """Write a tick's rows into one group's merged-rows pool. pool: [L,
-    P, page * heads, Dp]; rows: [L, N, heads, d]; each token's OWN table
-    in page_tables [N, max_pages]; invalid rows go to the scratch page
-    (`num_pages - 1`), where they may collide.
-
-    ONE scatter of SINGLE Dp-lane rows, L * N * heads of them, into the
-    pool flattened to [L * P * page * heads, Dp], under scope
-    `kv_write`: XLA:TPU runs it as one native `scatter` on the donated
-    pool. Single rows and not a token's [heads, Dp] WINDOW: a scatter
-    of windows it runs as a serial `while` of one
-    `dynamic-update-slice` a (layer, token), 3.7 us a trip, 17 ms for
-    the K rows of a 512-token tick in `smallthinker-assist`'s window
-    group (PERF.md section 6, PR 46). The rows a token (10, 4, 2) and
-    the layers a call are the arguments' shapes: one path for every
-    family. A valid token's page comes from its own table and its row
-    from `positions % page`, so no index is past the end; `mode="clip"`
-    only keeps the compiler from assuming it."""
-    l, num_pages, per_page, w = pool.shape
-    kvh = rows.shape[2]
-    page = per_page // kvh
-    with jax.named_scope("kv_write"):
-        page_idx = jnp.take_along_axis(
-            page_tables, (positions // page)[:, None], axis=1)[:, 0]
-        page_idx = jnp.where(valid, page_idx, num_pages - 1)
-        at = (page_idx * page + positions % page) * kvh           # [N]
-        at = (jnp.arange(l, dtype=at.dtype)[:, None, None]
-              * (num_pages * per_page) + at[None, :, None]
-              + jnp.arange(kvh, dtype=at.dtype))             # [L, N, heads]
-        new = _fit_lanes(rows, w).reshape(-1, w).astype(pool.dtype)
-        return pool.reshape(-1, w).at[at.reshape(-1)].set(
-            new, mode="clip").reshape(pool.shape)
-
-
-def _attend_fn(cfg: Phi4FlashConfig, impl: str, pools, tables,
-               slot_ids, positions, valid, start, ctx_pages: int):
-    """attend(q [T, heads, 128], k, v [T, kv rows, 128], group, index in
-    the group, window) -> o for one set of queries against group
-    `group`'s pools (`pools[group]` = (K pool, V pool)) and the queries'
-    own k and v: the work-list kernels, or the dense gather. The work
-    list is built once for every layer."""
-    if impl in ("pallas", "pallas_interpret"):
-        work = rpa.ragged_work_list(slot_ids, valid, start,
-                                    rpa.ragged_q_block(slot_ids.shape[0]))
-
-        def attend(q, k, v, g, gi, window):
-            kp, vp = pools[g]
-            flat = lambda pool: pool.reshape((-1,) + pool.shape[2:])
-            return rpa.ragged_paged_attention_pallas(
-                q, flat(kp), flat(vp), tables[g] + gi * kp.shape[1],
-                slot_ids, positions, valid, start, k, v,
-                ctx_pages=ctx_pages, work=work, window=window,
-                interpret=(impl == "pallas_interpret"), merged_rows=True)
-    else:
-        def attend(q, k, v, g, gi, window):
-            kp, vp = pools[g]
-            tab = tables[g] if ctx_pages < 0 else tables[g][:, :ctx_pages]
-            return rpa.ragged_gather_paged_blocked(
-                q, kp, vp, gi, tab, slot_ids, positions, valid, start,
-                k, v, window=window, merged_rows=True)
-    return attend
-
-
-def _refuse(**given):
-    for name, value in given.items():
-        if value is not None and value != "f32":
-            raise ValueError(f"the Phi4Flash forwards take no {name}")
+def _attend_fn(cfg: Phi4FlashConfig, impl: str, *tick):
+    """`paged_common.attend_fn` over merged-rows pools (`tick`: pools,
+    tables, slot_ids, positions, valid, start, ctx_pages), under the
+    signature this family's callers know."""
+    del cfg
+    return attend_fn(impl, *tick, merged_rows=True)
 
 
 def ragged_forward(cfg: Phi4FlashConfig, params: Dict[str, Any],
@@ -642,8 +580,8 @@ def ragged_forward(cfg: Phi4FlashConfig, params: Dict[str, Any],
     Returns (last-token logits per slot [B, V] float32,
     the k tuple, the v tuple), the state of the slots that had tokens
     advanced to their runs' ends."""
-    _refuse(lora=lora, mesh=mesh, kv_kind=kv_kind, k_scales=k_scales,
-            v_scales=v_scales)
+    refuse("Phi4Flash", lora=lora, mesh=mesh, kv_kind=kv_kind,
+           k_scales=k_scales, v_scales=v_scales)
     del lora_idx
     (full_k, win_k, conv), (full_v, win_v, scan) = k_pages, v_pages
     t, b = tokens.shape[0], start.shape[0]
@@ -746,24 +684,7 @@ def ragged_forward(cfg: Phi4FlashConfig, params: Dict[str, Any],
     return logits, (full_k, win_k, conv), (full_v, win_v, scan)
 
 
-def decode_step(cfg: Phi4FlashConfig, params: Dict[str, Any],
-                tokens: jax.Array, positions: jax.Array, k_pages,
-                v_pages, page_tables, active: jax.Array,
-                impl: str = "gather", mesh=None, lora=None,
-                lora_idx=None, kv_kind: str = "f32", k_scales=None,
-                v_scales=None):
-    """One decode step for the whole batch: the ragged tick of one token
-    a slot (slot b's token at positions[b], inactive slots invalid: their
-    state is left alone), through the same attention and the same scan.
-    Contract of `llama_infer.decode_step`; returns (logits [B, V]
-    float32, the k tuple, the v tuple)."""
-    b = tokens.shape[0]
-    slots = jnp.arange(b, dtype=jnp.int32)
-    return ragged_forward(
-        cfg, params, tokens, slots, positions, active, positions, slots,
-        k_pages, v_pages, page_tables, ctx_pages=-1, lora=lora,
-        lora_idx=lora_idx, impl=impl, mesh=mesh, kv_kind=kv_kind,
-        k_scales=k_scales, v_scales=v_scales)
+decode_step = one_token_tick(ragged_forward)
 
 
 def span_counts(cfg: Phi4FlashConfig, segs, decode) -> Dict[str, int]:
@@ -773,6 +694,7 @@ def span_counts(cfg: Phi4FlashConfig, segs, decode) -> Dict[str, int]:
     `ssm_rows`, the rows whose state a layer reads and writes;
     `cross_tokens`, the tokens the cross-decoder ran on (one a row);
     and the window layers' `win_kv_tokens`, `win_attn_pairs` and
-    `win_decode_pairs` (`trinity.span_counts`' rule, this window)."""
+    `win_decode_pairs` (`paged_common.window_span_counts`)."""
     return {"ssm_tokens": sum(n for _, n in segs), "ssm_rows": len(segs),
-            "cross_tokens": len(segs), **_window_counts(cfg, segs, decode)}
+            "cross_tokens": len(segs),
+            **window_span_counts(cfg, segs, decode)}

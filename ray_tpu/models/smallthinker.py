@@ -39,7 +39,7 @@ How it runs here:
   expert and the grouped kernels' tile visits depend on the layer's
   input alone, so `_layer` makes them BEFORE it calls attention (scope
   `moe_router`, outside and ahead of `attn`) and hands them to
-  `ops/moe.held_reglu_ffn` after. Whether the compiler then runs them
+  `ops/moe.held_experts_ffn` after. Whether the compiler then runs them
   under attention is its business.
 - The cache is two GROUPS (`cache_groups`): `full` FIRST (layer 0 is a
   full layer; whole contexts: the engine's `slot.pages`), then `window`
@@ -48,11 +48,10 @@ How it runs here:
   [page x 4 rows, 128] (`CacheRow.layout` "rows", as the phi4flash and
   nemotron_h families' pages): a [16, 4, 128] page would be padded to
   [16, 8, 128] in device memory. So the attention over the cache and
-  the write of a tick's rows are phi4flash's merged-rows ones
-  (`_attend_fn`, `scatter_rows`: one scatter of single 128-lane rows a
-  pool, scope `kv_write`), not Trinity's token-layout ones;
-  rope is Trinity's (`trinity.rope_cos_sin`, `deepseek_v3._rope`:
-  rotate-half).
+  the write of a tick's rows are the merged-rows ones of
+  `paged_common` (`attend_fn`, `scatter_merged_rows`: one scatter of
+  single 128-lane rows a pool, scope `kv_write`); rope is rotate-half
+  (`paged_common.rope_cos_sin`, `rope`).
 - The stack is a list of one tree a layer and the forward a loop over
   it (Trinity's way, not a `lax.scan` over periods): a layer's
   attention matrices are taken whole with no slice out of a stack, the
@@ -79,20 +78,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.moe import (held_gates, held_reglu_ffn, held_reglu_plan,
+from ..ops.moe import (held_experts_ffn, held_gates, held_plan,
                        platform_impl, softmax_pick_routing)
 from ..ops.paged_attention import pool_head_dim
 from .cache_row import CacheGroup, CacheRow
-from .deepseek_v3 import _rope
 from .llama import rms_norm
-from .phi4flash import _attend_fn, scatter_rows
-# `span_counts`: what the dispatch span carries of a tick's window
-# layers (`win_kv_tokens`, `win_attn_pairs`, `win_decode_pairs`) is
-# Trinity's, by the same rule of the same window; it reads
-# `cfg.sliding_window` alone
-# ... and so are the rope's angles (`head_dim` and `rope_theta` alone)
-from .trinity import (FULL, SLIDING, rope_cos_sin,  # noqa: F401
-                      span_counts)
+from .paged_common import (FULL, SLIDING, attend_fn, one_token_tick,
+                           refuse, rope, rope_cos_sin)
+from .paged_common import scatter_merged_rows as scatter_rows
+from .paged_common import window_span_counts as span_counts  # noqa: F401
 
 PERIOD = (0, 1, 1, 1)     # the published layouts: full, then three window
 
@@ -347,7 +341,7 @@ class Routing(NamedTuple):
     the picks' weights and indices [T, top_k], the logits [T, E]
     float32, the held experts' gate matrix and assignment mask [T,
     held], the assignments landed on each held expert [held] int32, and
-    the expert product's plan (`ops/moe.held_reglu_plan`: each
+    the expert product's plan (`ops/moe.held_plan`: each
     assignment's row, the groups' offsets, the kernels' tile visits)."""
     w: jax.Array
     idx: jax.Array
@@ -376,8 +370,8 @@ def plan_picks(cfg: SmallThinkerConfig, w, idx, logits, valid=None,
     assignments and the expert product's plan."""
     lo, hi = cfg.held
     gates, took, counts = held_gates(idx, w, lo, hi, valid)
-    plan = held_reglu_plan(took, picks=cfg.moe_top_k,
-                           impl=impl or platform_impl())
+    plan = held_plan(took, picks=cfg.moe_top_k,
+                     impl=impl or platform_impl())
     return Routing(w, idx, logits, gates, took, counts, plan)
 
 
@@ -388,10 +382,11 @@ def experts(cfg: SmallThinkerConfig, stacks, y, routing: Routing, base=0,
     "wd"} of which [base, base + n_held) are this layer's. `impl` is the
     forward's; a caller with no engine (a check of one block) leaves it
     out and gets `ops/moe.platform_impl()`."""
-    return held_reglu_ffn(y, routing.gates, routing.took, stacks["wg"],
-                          stacks["wi"], stacks["wd"], picks=cfg.moe_top_k,
-                          impl=impl or platform_impl(), base=base,
-                          plan=routing.plan)
+    return held_experts_ffn(y, routing.gates, routing.took,
+                            (stacks["wg"], stacks["wi"]), stacks["wd"],
+                            act="reglu", picks=cfg.moe_top_k,
+                            impl=impl or platform_impl(), base=base,
+                            plan=routing.plan)
 
 
 def attn_project(cfg: SmallThinkerConfig, layer, x, roped: int, cos, sin):
@@ -403,7 +398,7 @@ def attn_project(cfg: SmallThinkerConfig, layer, x, roped: int, cos, sin):
     k = (y @ layer["wk"]).reshape(t, cfg.n_kv_heads, cfg.head_dim)
     v = (y @ layer["wv"]).reshape(t, cfg.n_kv_heads, cfg.head_dim)
     if roped:
-        q, k = _rope(q, cos, sin), _rope(k, cos, sin)
+        q, k = rope(q, cos, sin), rope(k, cos, sin)
     return q, k, v
 
 
@@ -463,12 +458,6 @@ def _layer(cfg: SmallThinkerConfig, params, li: int, x, cos, sin, valid,
     return x, k, v, routing
 
 
-def _refuse(**given):
-    for name, value in given.items():
-        if value is not None and value != "f32":
-            raise ValueError(f"the SmallThinker forwards take no {name}")
-
-
 def ragged_forward(cfg: SmallThinkerConfig, params: Dict[str, Any],
                    tokens: jax.Array, slot_ids: jax.Array,
                    positions: jax.Array, valid: jax.Array,
@@ -485,14 +474,14 @@ def ragged_forward(cfg: SmallThinkerConfig, params: Dict[str, Any],
     hands the tables stacked [groups, B, max_pages]: indexed alike).
     Returns (last-token logits per slot [B, V] float32, k pools, v
     pools, expert counts [n_layers, n_held] int32)."""
-    _refuse(lora=lora, mesh=mesh, kv_kind=kv_kind, k_scales=k_scales,
-            v_scales=v_scales)
+    refuse("SmallThinker", lora=lora, mesh=mesh, kv_kind=kv_kind,
+           k_scales=k_scales, v_scales=v_scales)
     del lora_idx
     with jax.named_scope("embed"):
         x = params["embed"][tokens].astype(cfg.dtype)
-    attend = _attend_fn(cfg, impl, tuple(zip(k_pages, v_pages)),
-                        page_tables, slot_ids, positions, valid, start,
-                        ctx_pages)
+    attend = attend_fn(impl, tuple(zip(k_pages, v_pages)), page_tables,
+                       slot_ids, positions, valid, start, ctx_pages,
+                       merged_rows=True)
     cos, sin = rope_cos_sin(cfg, positions)
     ks, vs, counts = [], [], []
     for li in range(cfg.n_layers):
@@ -519,22 +508,4 @@ def ragged_forward(cfg: SmallThinkerConfig, params: Dict[str, Any],
     return logits, tuple(new_k), tuple(new_v), jnp.stack(counts)
 
 
-def decode_step(cfg: SmallThinkerConfig, params: Dict[str, Any],
-                tokens: jax.Array, positions: jax.Array, k_pages,
-                v_pages, page_tables, active: jax.Array,
-                impl: str = "gather", mesh=None, lora=None,
-                lora_idx=None, kv_kind: str = "f32", k_scales=None,
-                v_scales=None):
-    """One decode step for the whole batch: the ragged tick of one token
-    a slot (slot b's token at positions[b], inactive slots invalid),
-    through the same attention and the same experts. Contract of
-    `llama_infer.decode_step`; returns (logits [B, V] float32, k pools,
-    v pools, expert counts)."""
-    b = tokens.shape[0]
-    slots = jnp.arange(b, dtype=jnp.int32)
-    return ragged_forward(
-        cfg, params, tokens, slots, positions, active, positions, slots,
-        k_pages, v_pages, page_tables, ctx_pages=-1, lora=lora,
-        lora_idx=lora_idx, impl=impl, mesh=mesh, kv_kind=kv_kind,
-        k_scales=k_scales, v_scales=v_scales)
-
+decode_step = one_token_tick(ragged_forward)

@@ -65,15 +65,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import ragged_paged_attention as rpa
 from ..ops.moe import (held_experts_ffn, held_gates, platform_impl,
                        sigmoid_group_routing)
-from ..ops.paged_attention import _fit_lanes
 from .cache_row import CacheGroup, CacheRow
-from .deepseek_v3 import _rope, swiglu
 from .llama import rms_norm
-
-SLIDING, FULL = "sliding_attention", "full_attention"
+from .paged_common import (FULL, SLIDING, attend_fn, one_token_tick,
+                           refuse, rope, rope_cos_sin, swiglu)
+from .paged_common import scatter_token_rows as scatter_rows
+from .paged_common import window_span_counts as span_counts  # noqa: F401
 
 
 @dataclasses.dataclass(frozen=True)
@@ -313,15 +312,6 @@ def init_params(cfg: TrinityConfig, key: jax.Array) -> Dict[str, Any]:
 
 # --------------------------------------------------------------------- layers
 
-def rope_cos_sin(cfg: TrinityConfig, positions: jax.Array):
-    """positions [T] -> cos, sin [T, head_dim / 2] float32."""
-    d = cfg.head_dim
-    inv = 1.0 / cfg.rope_theta ** (
-        jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = positions.astype(jnp.float32)[:, None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
-
-
 def attn_project(cfg: TrinityConfig, layer, x, kind: str, cos, sin):
     """x: [T, H] -> (q [T, heads, d], k, v [T, kv heads, d], the output
     gate's logits [T, heads * d]), q and k normed and, on a window
@@ -336,7 +326,7 @@ def attn_project(cfg: TrinityConfig, layer, x, kind: str, cos, sin):
         q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
         k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
     if kind == SLIDING:
-        q, k = _rope(q, cos, sin), _rope(k, cos, sin)
+        q, k = rope(q, cos, sin), rope(k, cos, sin)
     return q, k, v, g
 
 
@@ -355,9 +345,10 @@ def moe_block(cfg: TrinityConfig, layer, y, valid=None,
     """y: [T, H] normalised -> (the expert layer's output [T, H]: the
     shared expert plus the held experts' part of the routed sum; the
     assignments of `valid` rows landed on each held expert [n_held]
-    int32). One routing group of all the experts. `impl` is the forward's (`_stack` passes
-    the engine's); a caller with no engine (a check of one block)
-    leaves it out and gets `ops/moe.platform_impl()`."""
+    int32). One routing group of all the experts. `impl` is the
+    forward's (`_stack` passes the engine's); a caller with no engine (a
+    check of one block) leaves it out and gets
+    `ops/moe.platform_impl()`."""
     lo, hi = cfg.held
     with jax.named_scope("moe_router"):
         w, idx = sigmoid_group_routing(
@@ -369,8 +360,9 @@ def moe_block(cfg: TrinityConfig, layer, y, valid=None,
         out = swiglu(layer["shared"], y)
     with jax.named_scope("moe_experts"):
         ex = layer["experts"]
-        routed = held_experts_ffn(y, gates, took, ex["wg"], ex["wi"],
-                                  ex["wd"], picks=cfg.moe_top_k,
+        routed = held_experts_ffn(y, gates, took, (ex["wg"], ex["wi"]),
+                                  ex["wd"], act="swiglu",
+                                  picks=cfg.moe_top_k,
                                   impl=impl or platform_impl())
     return out + routed.astype(out.dtype), counts
 
@@ -413,69 +405,21 @@ def _stack(cfg: TrinityConfig, params, x, positions, valid, attend,
             else jnp.zeros((0, cfg.n_held), jnp.int32))
 
 
-def _refuse(**given):
-    for name, value in given.items():
-        if value is not None and value != "f32":
-            raise ValueError(f"the Trinity forwards take no {name}")
-
-
-def scatter_rows(pool: jax.Array, rows: jax.Array,
-                 page_tables: jax.Array, positions: jax.Array,
-                 valid: jax.Array) -> jax.Array:
-    """Write a tick's rows into one group's pool. pool: [L, P, page,
-    kv heads, Dp]; rows: [L, N, kv heads, d]; each token's OWN table in
-    page_tables [N, max_pages]; invalid rows go to the scratch page.
-    One scatter with one index dim over the pool flattened to
-    [L * P * page, kv heads, Dp] (`mla_attention.scatter_latent`)."""
-    l, num_pages, page, kvh, w = pool.shape
-    page_idx = jnp.take_along_axis(
-        page_tables, (positions // page)[:, None], axis=1)[:, 0]
-    page_idx = jnp.where(valid, page_idx, num_pages - 1)
-    at = page_idx * page + positions % page                       # [N]
-    at = (jnp.arange(l, dtype=at.dtype)[:, None] * (num_pages * page)
-          + at[None, :]).reshape(-1)                              # [L*N]
-    new = _fit_lanes(rows, w).reshape(-1, kvh, w).astype(pool.dtype)
-    return pool.reshape(-1, kvh, w).at[at].set(new).reshape(pool.shape)
-
-
 def cache_attention(cfg: TrinityConfig, impl: str, k_pools, v_pools,
                     page_tables, slot_ids: jax.Array,
                     positions: jax.Array, valid: jax.Array,
                     start: jax.Array, ctx_pages: int = -1):
     """attend(q, k, v, kind, index in the kind's group) -> o [T, heads,
-    d] for one tick: the queries against the cached rows of that layer
-    in its group's pools and the tick's own k and v, by the work-list
-    kernel (`ragged_paged_attention` on a full layer,
-    `ragged_window_attention` on a window layer) or by the dense gather
-    as `impl` says. The kernel's work list is built once, for every
-    layer; it gets a group's pools whole, flattened over layers, and a
-    table shifted to the layer's pages."""
+    d] for one tick: `paged_common.attend_fn` over this family's
+    token-layout pools, a layer's kind named to its group (full 0,
+    window 1) and its window."""
+    attend = attend_fn(impl, tuple(zip(k_pools, v_pools)), page_tables,
+                       slot_ids, positions, valid, start, ctx_pages,
+                       merged_rows=False)
     group_of = {FULL: 0, SLIDING: 1}
     window_of = {FULL: None, SLIDING: cfg.sliding_window}
-    if impl in ("pallas", "pallas_interpret"):
-        work = rpa.ragged_work_list(slot_ids, valid, start,
-                                    rpa.ragged_q_block(slot_ids.shape[0]))
-        flat = lambda pool: pool.reshape((-1,) + pool.shape[2:])
-        kf = [flat(p) for p in k_pools]
-        vf = [flat(p) for p in v_pools]
-
-        def attend(q, k, v, kind, gi):
-            g = group_of[kind]
-            tables = page_tables[g] + gi * k_pools[g].shape[1]
-            return rpa.ragged_paged_attention_pallas(
-                q, kf[g], vf[g], tables, slot_ids, positions, valid,
-                start, k, v, ctx_pages=ctx_pages, work=work,
-                window=window_of[kind],
-                interpret=(impl == "pallas_interpret"))
-    else:
-        def attend(q, k, v, kind, gi):
-            g = group_of[kind]
-            tables = (page_tables[g] if ctx_pages < 0
-                      else page_tables[g][:, :ctx_pages])
-            return rpa.ragged_gather_paged_blocked(
-                q, k_pools[g], v_pools[g], gi, tables, slot_ids,
-                positions, valid, start, k, v, window=window_of[kind])
-    return attend
+    return lambda q, k, v, kind, gi: attend(
+        q, k, v, group_of[kind], gi, window_of[kind])
 
 
 def ragged_forward(cfg: TrinityConfig, params: Dict[str, Any],
@@ -494,8 +438,8 @@ def ragged_forward(cfg: TrinityConfig, params: Dict[str, Any],
     hands the tables stacked [groups, B, max_pages]: indexed alike). Returns
     (last-token logits per slot [B, V] float32, k pools, v pools,
     expert counts [n_moe_layers, n_held] int32)."""
-    _refuse(lora=lora, mesh=mesh, kv_kind=kv_kind, k_scales=k_scales,
-            v_scales=v_scales)
+    refuse("Trinity", lora=lora, mesh=mesh, kv_kind=kv_kind,
+           k_scales=k_scales, v_scales=v_scales)
     del lora_idx
     with jax.named_scope("embed"):
         x = (params["embed"][tokens].astype(jnp.float32)
@@ -519,43 +463,4 @@ def ragged_forward(cfg: TrinityConfig, params: Dict[str, Any],
     return logits, tuple(new_k), tuple(new_v), counts
 
 
-def decode_step(cfg: TrinityConfig, params: Dict[str, Any],
-                tokens: jax.Array, positions: jax.Array, k_pages,
-                v_pages, page_tables, active: jax.Array,
-                impl: str = "gather", mesh=None, lora=None,
-                lora_idx=None, kv_kind: str = "f32", k_scales=None,
-                v_scales=None):
-    """One decode step for the whole batch: the ragged tick of one token
-    a slot (slot b's token at positions[b], inactive slots invalid),
-    through the same attention, so that ONE kernel knows the window and
-    a decode row costs the keys in its window, not the table's width.
-    Contract of `llama_infer.decode_step`; returns (logits [B, V]
-    float32, k pools, v pools, expert counts)."""
-    b = tokens.shape[0]
-    slots = jnp.arange(b, dtype=jnp.int32)
-    return ragged_forward(
-        cfg, params, tokens, slots, positions, active, positions, slots,
-        k_pages, v_pages, page_tables, ctx_pages=-1, lora=lora,
-        lora_idx=lora_idx, impl=impl, mesh=mesh, kv_kind=kv_kind,
-        k_scales=k_scales, v_scales=v_scales)
-
-
-def span_counts(cfg: TrinityConfig, segs, decode) -> Dict[str, int]:
-    """What the dispatch span carries of a tick's window layers, from
-    the plan: `segs` = [(cached tokens, tokens this tick)] a row,
-    `decode` = which rows are decode rows. `win_kv_tokens`: the keys
-    inside their windows that the rows read (each row's union over its
-    queries); `win_attn_pairs`: the (query, key) pairs a window layer
-    keeps; `win_decode_pairs`: the decode rows' part of them."""
-    w = cfg.sliding_window
-    kv = pairs = dec = 0
-    for (pos0, n), is_dec in zip(segs, decode):
-        kv += min(pos0 + n, n + w - 1)
-        # query i of the row keeps min(pos0 + i + 1, w) keys
-        full = max(min(w - pos0, n), 0)          # queries not yet cut
-        kept = (full * pos0 + full * (full + 1) // 2) + (n - full) * w
-        pairs += kept
-        if is_dec:
-            dec += kept
-    return {"win_kv_tokens": kv, "win_attn_pairs": pairs,
-            "win_decode_pairs": dec}
+decode_step = one_token_tick(ragged_forward)

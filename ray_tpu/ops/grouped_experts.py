@@ -1,5 +1,6 @@
-"""Grouped SwiGLU over expert-sorted rows: the Pallas kernels behind
-`ops/moe.held_experts_ffn` on the chip.
+"""Grouped expert feed-forward over expert-sorted rows: the Pallas
+kernels behind `ops/moe.held_experts_ffn` on the chip, one pair for
+every form of expert (`FORMS`: SwiGLU, gated ReLU, ungated relu^2).
 
 A tick's assignments (token t picked expert e, held here) are laid out
 sorted by expert, then token (`assignment_rows`): group e is rows
@@ -12,18 +13,21 @@ hold assignments and fetches the weight tiles of the experts that got
 any; an expert nobody picked is never read. (The layout and the masked
 store of a shared tile are those of MegaBlocks' grouped product,
 `jax.experimental.pallas.ops.tpu.megablox`; here the way from the tokens
-to the rows, the SwiGLU and the way back are fused in, so nothing of the
-row bound's size is gathered, scattered or kept outside the kernels.)
+to the rows, the activation and the way back are fused in, so nothing of
+the row bound's size is gathered, scattered or kept outside the kernels.)
 
-Two kernels a layer:
+Two kernels a layer, named by the form (`moe_grouped_up`,
+`moe_grouped_up_reglu`, `moe_grouped_up_relu2`, and `_down` alike: the
+benchmark's readers key on the names):
 
-- `moe_grouped_up`: the tile's rows of x, fetched by a one-hot product
-  against the expert's column of `assignment_rows` (exact), then
-  h = silu(xs W_g[e]) * (xs W_i[e]), two float32 accumulators over the
-  hidden tiles, stored in the operands' type. The first visit of a row
-  tile writes zeros to the rows that are not its own, so a visited tile
-  never holds stale bytes.
-- `moe_grouped_down`: y = h W_d[e] in float32, the rows of other groups
+- `moe_grouped_up*`: the tile's rows of x, fetched by a one-hot product
+  against the expert's column of `assignment_rows` (exact), then the
+  form's activation over its up products (SwiGLU: h = silu(xs W_g[e]) *
+  (xs W_i[e])), one float32 accumulator a matrix over the hidden tiles,
+  stored in the operands' type. The first visit of a row tile writes
+  zeros to the rows that are not its own, so a visited tile never holds
+  stale bytes.
+- `moe_grouped_down*`: y = h W_d[e] in float32, the rows of other groups
   zeroed, then straight back to the tokens: out[t] += gate[t, e] *
   y[row of (t, e)], by the same one-hot the other way round (y split
   into three bfloat16 parts, so the product is exact in float32) and
@@ -38,7 +42,7 @@ thousands of tokens a call would want them tiled over T.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Callable, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -103,6 +107,17 @@ def tile_visits(offsets: jax.Array, rows: int, tm: int
             jnp.clip(base + v, 0, tiles - 1).astype(jnp.int32), upto[-1])
 
 
+def row_visits(offsets: jax.Array, rows: int
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """`tile_visits` at `grouped_ffn`'s own row tile: offsets [E + 1]
+    from `assignment_rows`, `rows` the most assignments there can be. A
+    function of its own for a layer whose router runs ahead of its
+    attention: the visits exist before the expert product does and are
+    made where the picks are."""
+    tm = row_tile(rows)
+    return tile_visits(offsets, -(-rows // tm) * tm, tm)
+
+
 def _own_rows(v, vis_g, vis_t, off, tm: int, shape):
     """Mask [tm, tn] of the visit's rows that belong to its expert."""
     g = vis_g[v]
@@ -110,14 +125,44 @@ def _own_rows(v, vis_g, vis_t, off, tm: int, shape):
     return (row >= off[g]) & (row < off[g + 1])
 
 
-def _up_kernel(vis_g, vis_t, off, x_ref, row_ref, wg_ref, wi_ref, h_ref,
-               acc_g, acc_u, *, tm: int, exact):
+class Form(NamedTuple):
+    """One expert's feed-forward form: the matrices of its up
+    projection (2: a gate and an up matrix, 1: an up matrix alone),
+    whether they are stored out by in ([S, F, H], as `nn.Linear` keeps
+    them: Nemotron-H's, whose expert width 1856 is no whole number of
+    128-lane vectors and as an array's minor dim would be padded by XLA
+    in a COPY of the stack before the kernel), the activation over the
+    float32 products, and what the kernels' `name=` carries of it."""
+    n_up: int
+    out_by_in: bool
+    activate: Callable[..., jax.Array]
+    suffix: str
+
+
+FORMS = {
+    # silu(x W_g) * (x W_i): DeepSeek-V3, Trinity
+    "swiglu": Form(2, False, lambda g, u: jax.nn.silu(g) * u, ""),
+    # relu(x W_g) * (x W_i): SmallThinker
+    "reglu": Form(2, False, lambda g, u: jnp.maximum(g, 0.0) * u, "_reglu"),
+    # relu(x W_up)^2, no gate matrix (`mlp_hidden_act` "relu2"): Nemotron-H
+    "relu2": Form(1, True, lambda u: jnp.square(jnp.maximum(u, 0.0)),
+                  "_relu2"),
+}
+
+
+def _up_kernel(vis_g, vis_t, off, base, x_ref, row_ref, *refs, tm: int,
+               exact, form: Form):
+    """refs: the form's up matrices' tiles, the output tile, one float32
+    accumulator a matrix."""
+    del base                       # the index maps' alone
+    w_refs, h_ref, accs = (refs[:form.n_up], refs[form.n_up],
+                           refs[form.n_up + 1:])
     v, k = pl.program_id(1), pl.program_id(2)
 
     @pl.when(k == 0)
     def _():
-        acc_g[...] = jnp.zeros_like(acc_g)
-        acc_u[...] = jnp.zeros_like(acc_u)
+        for acc in accs:
+            acc[...] = jnp.zeros_like(acc)  # jaxlint: disable=JL004 -- a Pallas scratch ref: a store in the kernel, not a Python mutation
 
     # the tile's rows of x, fetched by a one-hot product: row i is the
     # token whose assignment to this expert sits at row i (exact: one 1
@@ -127,14 +172,14 @@ def _up_kernel(vis_g, vis_t, off, x_ref, row_ref, wg_ref, wi_ref, h_ref,
             == row_ref[...]).astype(x_ref.dtype)
     xs = jnp.dot(here, x_ref[...], precision=exact,
                  preferred_element_type=jnp.float32).astype(x_ref.dtype)
-    acc_g[...] += jnp.dot(xs, wg_ref[...],
-                          preferred_element_type=jnp.float32)
-    acc_u[...] += jnp.dot(xs, wi_ref[...],
-                          preferred_element_type=jnp.float32)
+    over = (((1,), (1 if form.out_by_in else 0,)), ((), ()))
+    for w_ref, acc in zip(w_refs, accs):
+        acc[...] += lax.dot_general(  # jaxlint: disable=JL004 -- a Pallas scratch ref: a store in the kernel, not a Python mutation
+            xs, w_ref[...], over, preferred_element_type=jnp.float32)
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _():
-        h = (jax.nn.silu(acc_g[...]) * acc_u[...]).astype(h_ref.dtype)
+        h = form.activate(*(acc[...] for acc in accs)).astype(h_ref.dtype)
         first = (v == 0) | (vis_t[jnp.maximum(v - 1, 0)] != vis_t[v])
         kept = jnp.where(first, jnp.zeros_like(h), h_ref[...])
         h_ref[...] = jnp.where(
@@ -148,8 +193,9 @@ def _column(ref, g):
     return jnp.sum(jnp.where(mine, a, 0), axis=1, keepdims=True)
 
 
-def _down_kernel(vis_g, vis_t, off, h_ref, wd_ref, place_ref, gates_ref,
-                 out_ref, acc, *, tm: int):
+def _down_kernel(vis_g, vis_t, off, base, h_ref, wd_ref, place_ref,
+                 gates_ref, out_ref, acc, *, tm: int):
+    del base                       # the index maps' alone
     v, k = pl.program_id(1), pl.program_id(2)
 
     @pl.when((v == 0) & (k == 0))
@@ -181,53 +227,65 @@ def _down_kernel(vis_g, vis_t, off, h_ref, wd_ref, place_ref, gates_ref,
         out_ref[...] += _column(gates_ref, vis_g[v]) * back
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
-def grouped_swiglu(x: jax.Array, gates: jax.Array, place: jax.Array,
-                   offsets: jax.Array, wg: jax.Array, wi: jax.Array,
-                   wd: jax.Array, *, rows: int, interpret: bool = False
-                   ) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("act", "rows", "interpret"))
+def grouped_ffn(x: jax.Array, gates: jax.Array, place: jax.Array,
+                offsets: jax.Array, visits, ups: Tuple[jax.Array, ...],
+                wd: jax.Array, base: jax.Array, *, act: str, rows: int,
+                interpret: bool = False) -> jax.Array:
     """x: [T, H]; gates: [T, E] float32; place: [T, E] and offsets:
-    [E + 1] from `assignment_rows`; wg/wi: [E, H, F], wd: [E, F, H];
-    `rows`: the most assignments there can be -> [T, H] float32: for
-    each token the gate-weighted sum of the SwiGLU of its assignments.
-    Tiles follow the operands' shapes."""
+    [E + 1] from `assignment_rows`; `visits`: `row_visits(offsets,
+    rows)`; `act`: a key of `FORMS`; ups: that form's up matrices, [S,
+    H, F] each (out by in: [S, F, H]), wd: [S, F, H], a stack of S >= E
+    experts of which [base, base + E) are this layer's (base: an int32
+    scalar, traced or not; it rides with the prefetched scalars and
+    shifts the weights' block index alone, so a stack of several layers'
+    experts is never sliced, which would copy it); `rows`: the most
+    assignments there can be -> [T, H] float32: for each token the
+    gate-weighted sum of its assignments' feed-forward. Tiles follow the
+    operands' shapes; the kernels are named by the form
+    (`moe_grouped_up` / `_down` + `Form.suffix`)."""
+    form = FORMS[act]
     t, hid = x.shape
-    e, _, ffn = wg.shape
+    e = offsets.shape[0] - 1
+    ffn = wd.shape[1]
     tm = row_tile(rows)
     rows = -(-rows // tm) * tm
-    vis_g, vis_t, n_visits = tile_visits(offsets, rows, tm)
-    item = jnp.dtype(wg.dtype).itemsize
+    vis_g, vis_t, n_visits = visits
+    base = jnp.asarray(base, jnp.int32).reshape(1)
+    item = jnp.dtype(ups[0].dtype).itemsize
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         vmem_limit_bytes=_VMEM_LIMIT)
     # block indices of a grid step (column tile n, visit v, depth tile k)
-    # from the prefetched visit lists: the visit's expert and row tile
-    of_expert = lambda n, v, k, g, tl, off: (g[v], k, n)
-    of_tile = lambda n, v, k, g, tl, off: (tl[v], n)
-    whole = lambda n, v, k, g, tl, off: (0, 0)
+    # from the prefetched scalars: the visit's expert and row tile
+    of_expert = lambda n, v, k, g, tl, off, b: (b[0] + g[v], k, n)
+    of_expert_t = lambda n, v, k, g, tl, off, b: (b[0] + g[v], n, k)
+    of_tile = lambda n, v, k, g, tl, off, b: (tl[v], n)
+    whole = lambda n, v, k, g, tl, off, b: (0, 0)
 
     tn = _divisor(ffn, 2048)
     tk = _divisor(hid, _WEIGHT_TILE_BYTES // (tn * item))
-    w_in = pl.BlockSpec((None, tk, tn), of_expert)
+    w_up = (pl.BlockSpec((None, tn, tk), of_expert_t) if form.out_by_in
+            else pl.BlockSpec((None, tk, tn), of_expert))
     h = pl.pallas_call(
         functools.partial(
-            _up_kernel, tm=tm,
+            _up_kernel, tm=tm, form=form,
             exact=lax.Precision.HIGHEST if x.dtype == jnp.float32 else None),
         out_shape=jax.ShapeDtypeStruct((rows, ffn), x.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(ffn // tn, n_visits, hid // tk),
             in_specs=[
                 pl.BlockSpec((t, tk),
-                             lambda n, v, k, g, tl, off: (0, k)),
+                             lambda n, v, k, g, tl, off, b: (0, k)),
                 pl.BlockSpec((None, 1, t),
-                             lambda n, v, k, g, tl, off: (g[v], 0, 0)),
-                w_in, w_in],
+                             lambda n, v, k, g, tl, off, b: (g[v], 0, 0)),
+                *[w_up] * form.n_up],
             out_specs=pl.BlockSpec((tm, tn), of_tile),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)] * 2),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)] * form.n_up),
         compiler_params=params, interpret=interpret,
-        name="moe_grouped_up",
-    )(vis_g, vis_t, offsets, x, place.T[:, None, :], wg, wi)
+        name="moe_grouped_up" + form.suffix,
+    )(vis_g, vis_t, offsets, base, x, place.T[:, None, :], *ups)
 
     # the expert width whole where it fits: every step then ends in the
     # way back to the tokens, under the next weight tile's fetch (with
@@ -240,258 +298,19 @@ def grouped_swiglu(x: jax.Array, gates: jax.Array, place: jax.Array,
         functools.partial(_down_kernel, tm=tm),
         out_shape=jax.ShapeDtypeStruct((t, hid), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(hid // tn, n_visits, ffn // tk),
             in_specs=[
                 pl.BlockSpec((tm, tk),
-                             lambda n, v, k, g, tl, off: (tl[v], k)),
+                             lambda n, v, k, g, tl, off, b: (tl[v], k)),
                 pl.BlockSpec((None, tk, tn), of_expert),
                 pl.BlockSpec((t, e), whole),
                 pl.BlockSpec((t, e), whole)],
             out_specs=pl.BlockSpec(
-                (t, tn), lambda n, v, k, g, tl, off: (0, n)),
+                (t, tn), lambda n, v, k, g, tl, off, b: (0, n)),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
         compiler_params=params, interpret=interpret,
-        name="moe_grouped_down",
-    )(vis_g, vis_t, offsets, h, wd, place, gates)
+        name="moe_grouped_down" + form.suffix,
+    )(vis_g, vis_t, offsets, base, h, wd, place, gates)
     # no visit, no store: a tick that sent nothing here reads as zeros
-    return jnp.where(n_visits > 0, out, 0.0)
-
-
-# ------------------------------------------------------ ungated experts
-# An expert of the form act(x W_up) W_down with act = relu(.)^2 and NO
-# gate matrix (`mlp_hidden_act` "relu2": the Nemotron-H family), through
-# the same layout: `assignment_rows`, `tile_visits`, only the experts hit
-# are read, the way back by `_down_kernel` as it is. The experts may lie
-# in a STACK of several layers' ([layers * E, H, F]): `base`, the first
-# of this layer's, rides with the prefetched scalars and shifts the
-# weights' block index alone, so a stack that scans its layers never
-# slices (copies) a layer's experts out. Kept below the SwiGLU kernels,
-# whose lines (a Mosaic module keeps them) stay where they were.
-
-def _up_relu2_kernel(vis_g, vis_t, off, base, x_ref, row_ref, wu_ref,
-                     h_ref, acc, *, tm: int, exact):
-    """`_up_kernel` with one matrix: h = relu(xs W_up[e])^2."""
-    del base                       # the index maps' alone
-    v, k = pl.program_id(1), pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _():
-        acc[...] = jnp.zeros_like(acc)
-
-    t = x_ref.shape[0]
-    here = (vis_t[v] * tm + lax.broadcasted_iota(jnp.int32, (tm, t), 0)
-            == row_ref[...]).astype(x_ref.dtype)
-    xs = jnp.dot(here, x_ref[...], precision=exact,
-                 preferred_element_type=jnp.float32).astype(x_ref.dtype)
-    acc[...] += lax.dot_general(xs, wu_ref[...], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-
-    @pl.when(k == pl.num_programs(2) - 1)
-    def _():
-        h = jnp.square(jnp.maximum(acc[...], 0.0)).astype(h_ref.dtype)
-        first = (v == 0) | (vis_t[jnp.maximum(v - 1, 0)] != vis_t[v])
-        kept = jnp.where(first, jnp.zeros_like(h), h_ref[...])
-        h_ref[...] = jnp.where(
-            _own_rows(v, vis_g, vis_t, off, tm, h.shape), h, kept)
-
-
-def _down_based_kernel(vis_g, vis_t, off, base, *refs, tm: int):
-    del base
-    _down_kernel(vis_g, vis_t, off, *refs, tm=tm)
-
-
-@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
-def grouped_relu2(x: jax.Array, gates: jax.Array, place: jax.Array,
-                  offsets: jax.Array, wu: jax.Array, wd: jax.Array,
-                  base: jax.Array, *, rows: int, interpret: bool = False
-                  ) -> jax.Array:
-    """`grouped_swiglu` for ungated relu^2 experts. x: [T, H]; gates,
-    place: [T, E]; offsets: [E + 1]; wu: [S, F, H] (out by in, as
-    `nn.Linear` keeps it: an expert width that is no whole number of
-    128-lane vectors, 1856, is then no array's minor dim, which XLA
-    would pad in a COPY of the stack before the kernel), wd: [S, F, H],
-    a stack of S >= E experts of which [base, base + E) are this
-    layer's (base: an int32 scalar, traced or not) -> [T, H] float32."""
-    t, hid = x.shape
-    e = offsets.shape[0] - 1
-    ffn = wu.shape[1]
-    tm = row_tile(rows)
-    rows = -(-rows // tm) * tm
-    vis_g, vis_t, n_visits = tile_visits(offsets, rows, tm)
-    base = jnp.asarray(base, jnp.int32).reshape(1)
-    item = jnp.dtype(wu.dtype).itemsize
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        vmem_limit_bytes=_VMEM_LIMIT)
-    of_expert = lambda n, v, k, g, tl, off, b: (b[0] + g[v], k, n)
-    of_expert_t = lambda n, v, k, g, tl, off, b: (b[0] + g[v], n, k)
-    of_tile = lambda n, v, k, g, tl, off, b: (tl[v], n)
-    whole = lambda n, v, k, g, tl, off, b: (0, 0)
-
-    tn = _divisor(ffn, 2048)
-    tk = _divisor(hid, _WEIGHT_TILE_BYTES // (tn * item))
-    h = pl.pallas_call(
-        functools.partial(
-            _up_relu2_kernel, tm=tm,
-            exact=lax.Precision.HIGHEST if x.dtype == jnp.float32 else None),
-        out_shape=jax.ShapeDtypeStruct((rows, ffn), x.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(ffn // tn, n_visits, hid // tk),
-            in_specs=[
-                pl.BlockSpec((t, tk),
-                             lambda n, v, k, g, tl, off, b: (0, k)),
-                pl.BlockSpec((None, 1, t),
-                             lambda n, v, k, g, tl, off, b: (g[v], 0, 0)),
-                pl.BlockSpec((None, tn, tk), of_expert_t)],
-            out_specs=pl.BlockSpec((tm, tn), of_tile),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
-        compiler_params=params, interpret=interpret,
-        name="moe_grouped_up_relu2",
-    )(vis_g, vis_t, offsets, base, x, place.T[:, None, :], wu)
-
-    tk = _divisor(ffn, 4096)
-    tn = _divisor(hid, min(_WEIGHT_TILE_BYTES // (tk * item),
-                           _OUT_TILE_BYTES // (t * 4)))
-    out = pl.pallas_call(
-        functools.partial(_down_based_kernel, tm=tm),
-        out_shape=jax.ShapeDtypeStruct((t, hid), jnp.float32),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(hid // tn, n_visits, ffn // tk),
-            in_specs=[
-                pl.BlockSpec((tm, tk),
-                             lambda n, v, k, g, tl, off, b: (tl[v], k)),
-                pl.BlockSpec((None, tk, tn), of_expert),
-                pl.BlockSpec((t, e), whole),
-                pl.BlockSpec((t, e), whole)],
-            out_specs=pl.BlockSpec(
-                (t, tn), lambda n, v, k, g, tl, off, b: (0, n)),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
-        compiler_params=params, interpret=interpret,
-        name="moe_grouped_down_relu2",
-    )(vis_g, vis_t, offsets, base, h, wd, place, gates)
-    return jnp.where(n_visits > 0, out, 0.0)
-
-
-# --------------------------------------------------- gated ReLU experts
-# An expert of the form (relu(x W_gate) * (x W_up)) W_down (ReGLU: the
-# SmallThinker family), through the same layout, with two things the
-# families above did not need: the experts lie in a stack of several
-# layers' ([layers * E, H, F], `base` as in the relu^2 section: no
-# layer's experts are ever sliced out), and the visits are the CALLER's
-# (`reglu_visits`): that family's router reads the layer's input ahead of
-# attention, so the sorted rows and the tile visits exist before the
-# expert product does and are made where the picks are. Kept below
-# everything above, whose lines stay where they were.
-
-def reglu_visits(offsets: jax.Array, rows: int
-                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """`tile_visits` for `grouped_reglu`'s own row tile: offsets [E + 1]
-    from `assignment_rows`, `rows` the most assignments there can be."""
-    tm = row_tile(rows)
-    return tile_visits(offsets, -(-rows // tm) * tm, tm)
-
-
-def _up_reglu_kernel(vis_g, vis_t, off, base, x_ref, row_ref, wg_ref,
-                     wi_ref, h_ref, acc_g, acc_u, *, tm: int, exact):
-    """`_up_kernel` with relu for silu: h = relu(xs W_g[e]) * (xs
-    W_i[e])."""
-    del base                       # the index maps' alone
-    v, k = pl.program_id(1), pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _():
-        acc_g[...] = jnp.zeros_like(acc_g)
-        acc_u[...] = jnp.zeros_like(acc_u)
-
-    t = x_ref.shape[0]
-    here = (vis_t[v] * tm + lax.broadcasted_iota(jnp.int32, (tm, t), 0)
-            == row_ref[...]).astype(x_ref.dtype)
-    xs = jnp.dot(here, x_ref[...], precision=exact,
-                 preferred_element_type=jnp.float32).astype(x_ref.dtype)
-    acc_g[...] += jnp.dot(xs, wg_ref[...],
-                          preferred_element_type=jnp.float32)
-    acc_u[...] += jnp.dot(xs, wi_ref[...],
-                          preferred_element_type=jnp.float32)
-
-    @pl.when(k == pl.num_programs(2) - 1)
-    def _():
-        h = (jnp.maximum(acc_g[...], 0.0) * acc_u[...]).astype(h_ref.dtype)
-        first = (v == 0) | (vis_t[jnp.maximum(v - 1, 0)] != vis_t[v])
-        kept = jnp.where(first, jnp.zeros_like(h), h_ref[...])
-        h_ref[...] = jnp.where(
-            _own_rows(v, vis_g, vis_t, off, tm, h.shape), h, kept)
-
-
-@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
-def grouped_reglu(x: jax.Array, gates: jax.Array, place: jax.Array,
-                  offsets: jax.Array, visits, wg: jax.Array,
-                  wi: jax.Array, wd: jax.Array, base: jax.Array, *,
-                  rows: int, interpret: bool = False) -> jax.Array:
-    """`grouped_swiglu` for ReGLU experts in a stack. x: [T, H]; gates,
-    place: [T, E]; offsets: [E + 1]; `visits`: `reglu_visits(offsets,
-    rows)`, made by the caller; wg/wi: [S, H, F], wd: [S, F, H], a stack
-    of S >= E experts of which [base, base + E) are this layer's (base:
-    an int32 scalar, traced or not) -> [T, H] float32."""
-    t, hid = x.shape
-    e = offsets.shape[0] - 1
-    ffn = wg.shape[2]
-    tm = row_tile(rows)
-    rows = -(-rows // tm) * tm
-    vis_g, vis_t, n_visits = visits
-    base = jnp.asarray(base, jnp.int32).reshape(1)
-    item = jnp.dtype(wg.dtype).itemsize
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        vmem_limit_bytes=_VMEM_LIMIT)
-    of_expert = lambda n, v, k, g, tl, off, b: (b[0] + g[v], k, n)
-    of_tile = lambda n, v, k, g, tl, off, b: (tl[v], n)
-    whole = lambda n, v, k, g, tl, off, b: (0, 0)
-
-    tn = _divisor(ffn, 2048)
-    tk = _divisor(hid, _WEIGHT_TILE_BYTES // (tn * item))
-    w_in = pl.BlockSpec((None, tk, tn), of_expert)
-    h = pl.pallas_call(
-        functools.partial(
-            _up_reglu_kernel, tm=tm,
-            exact=lax.Precision.HIGHEST if x.dtype == jnp.float32 else None),
-        out_shape=jax.ShapeDtypeStruct((rows, ffn), x.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(ffn // tn, n_visits, hid // tk),
-            in_specs=[
-                pl.BlockSpec((t, tk),
-                             lambda n, v, k, g, tl, off, b: (0, k)),
-                pl.BlockSpec((None, 1, t),
-                             lambda n, v, k, g, tl, off, b: (g[v], 0, 0)),
-                w_in, w_in],
-            out_specs=pl.BlockSpec((tm, tn), of_tile),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)] * 2),
-        compiler_params=params, interpret=interpret,
-        name="moe_grouped_up_reglu",
-    )(vis_g, vis_t, offsets, base, x, place.T[:, None, :], wg, wi)
-
-    tk = _divisor(ffn, 4096)
-    tn = _divisor(hid, min(_WEIGHT_TILE_BYTES // (tk * item),
-                           _OUT_TILE_BYTES // (t * 4)))
-    out = pl.pallas_call(
-        functools.partial(_down_based_kernel, tm=tm),
-        out_shape=jax.ShapeDtypeStruct((t, hid), jnp.float32),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(hid // tn, n_visits, ffn // tk),
-            in_specs=[
-                pl.BlockSpec((tm, tk),
-                             lambda n, v, k, g, tl, off, b: (tl[v], k)),
-                pl.BlockSpec((None, tk, tn), of_expert),
-                pl.BlockSpec((t, e), whole),
-                pl.BlockSpec((t, e), whole)],
-            out_specs=pl.BlockSpec(
-                (t, tn), lambda n, v, k, g, tl, off, b: (0, n)),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
-        compiler_params=params, interpret=interpret,
-        name="moe_grouped_down_reglu",
-    )(vis_g, vis_t, offsets, base, h, wd, place, gates)
     return jnp.where(n_visits > 0, out, 0.0)

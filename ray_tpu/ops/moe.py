@@ -356,46 +356,76 @@ def platform_impl() -> str:
     return "gather" if jax.devices()[0].platform == "cpu" else "pallas"
 
 
+def held_plan(took: jax.Array, *, picks: int, impl: str):
+    """What `held_experts_ffn` needs of a tick's assignments besides the
+    gates, from `took` [T, E] alone: (the row of each assignment [T, E],
+    the groups' offsets [E + 1], the kernels' tile visits or None on the
+    gather path). A function of its own so that a layer whose router
+    runs ahead of its attention makes it THERE, with the picks."""
+    if impl not in HELD_IMPLS:
+        raise ValueError(f"held_plan: impl {impl!r} is none of "
+                         f"{HELD_IMPLS}")
+    t, e = took.shape
+    place, offsets = ge.assignment_rows(took)
+    visits = (ge.row_visits(offsets, t * min(picks, e))
+              if impl in ("pallas", "pallas_interpret") else None)
+    return place, offsets, visits
+
+
 def held_experts_ffn(x: jax.Array, gates: jax.Array, took: jax.Array,
-                     wg: jax.Array, wi: jax.Array, wd: jax.Array, *,
-                     picks: int, impl: str) -> jax.Array:
+                     ups: Tuple[jax.Array, ...], wd: jax.Array, *, act: str,
+                     picks: int, impl: str, base=0, plan=None) -> jax.Array:
     """The held experts' part of an expert layer's output: for each
-    token the gate-weighted sum of the SwiGLU of those of its picks that
-    are held here. x: [T, H]; gates and took: [T, E] from `held_gates`;
-    wg/wi: [E, H, F], wd: [E, F, H]; `picks`: the router's picks a
-    token. Returns [T, H] float32. What absent experts would add is left
-    out.
+    token the gate-weighted sum of the feed-forward of those of its picks
+    that are held here. x: [T, H]; gates and took: [T, E] from
+    `held_gates`; `act`: the experts' form, a key of
+    `grouped_experts.FORMS` ("swiglu": silu(x W_g) * (x W_i), "reglu":
+    relu(x W_g) * (x W_i), "relu2": relu(x W_up)^2 with no gate matrix);
+    `ups`: that form's up matrices, (wg, wi) [S, H, F] or (w_up,) [S, F,
+    H] (the ungated form's lies out by in: `grouped_experts.Form`); wd:
+    [S, F, H]; `picks`: the router's picks a token. The E held experts
+    of this layer are [base, base + E) of a stack of S >= E (several
+    layers' experts in one array, so that a stack that scans its layers
+    hands the kernels the array whole and an index; `base` may be
+    traced). `plan`: `held_plan(took, ...)` where the caller made it
+    ahead (default: made here). Returns [T, H] float32. What absent
+    experts would add is left out.
 
     One grouped matrix product a projection over the tick's assignments
     sorted by held expert (`grouped_experts.assignment_rows`): the rows
     of x that picked expert e form group e of at most T * min(picks, E)
-    rows, through gate and up, the SwiGLU cast to the operands' type,
-    and down in float32, each row times its gate added to its token. Its
-    size is what the tick's router sent here: rows past the last
-    assignment are not computed and an expert that received nothing is
-    not read; every assignment is computed, there is no capacity and
+    rows, through the up matrices, the activation cast to the operands'
+    type, and down in float32 (whole: a row that ReLU zeroed is
+    multiplied all the same), each row times its gate added to its
+    token. Its size is what the tick's router sent here: rows past the
+    last assignment are not computed and an expert that received nothing
+    is not read; every assignment is computed, there is no capacity and
     nothing is dropped.
 
     `impl` is the caller's, resolved (the engine's `_resolve_impl`, or
     `platform_impl` for a caller with no engine), and splits chip from
     host as the attention kernels' does: "pallas" is
-    `grouped_experts.grouped_swiglu` (its grid visits the row tiles
-    that hold assignments), "pallas_interpret" the same kernels
-    interpreted, "gather" `lax.ragged_dot` over the same sorted rows:
-    what a CPU runs and what the checks hold the kernels to, on the
-    chip 2-3 x the kernels' time at 512 tokens and not a serving
-    path. Any other value is refused."""
+    `grouped_experts.grouped_ffn` (its grid visits the row tiles that
+    hold assignments), "pallas_interpret" the same kernels interpreted,
+    "gather" the reference over the same sorted rows: what a CPU runs
+    and what the checks hold the kernels to, on the chip 2-3 x the
+    kernels' time at 512 tokens and not a serving path. Any other value
+    is refused."""
     if impl not in HELD_IMPLS:
         raise ValueError(f"held_experts_ffn: impl {impl!r} is none of "
                          f"{HELD_IMPLS}")
-    t, _ = x.shape
+    if act not in ge.FORMS:
+        raise ValueError(f"held_experts_ffn: act {act!r} is none of "
+                         f"{tuple(ge.FORMS)}")
+    form = ge.FORMS[act]
+    t, hid = x.shape
     e = took.shape[1]
     rows = t * min(picks, e)
-    place, offsets = ge.assignment_rows(took)
+    place, offsets, visits = plan or held_plan(took, picks=picks, impl=impl)
     if impl in ("pallas", "pallas_interpret"):
-        return ge.grouped_swiglu(x, gates, place, offsets, wg, wi, wd,
-                                 rows=rows,
-                                 interpret=(impl == "pallas_interpret"))
+        return ge.grouped_ffn(x, gates, place, offsets, visits, tuple(ups),
+                              wd, base, act=act, rows=rows,
+                              interpret=(impl == "pallas_interpret"))
     f32 = jnp.float32
     # the same rows, the other way round: each row's token and gate (a
     # row past the last assignment is token T, dropped on the way back)
@@ -404,77 +434,45 @@ def held_experts_ffn(x: jax.Array, gates: jax.Array, took: jax.Array,
         jnp.repeat(jnp.arange(t, dtype=jnp.int32), e), mode="drop")
     gate = jnp.zeros((rows,), f32).at[at].set(gates.reshape(-1),
                                               mode="drop")
-    sizes = offsets[1:] - offsets[:-1]
     xs = jnp.take(x, tok, axis=0, mode="clip")
-    g = lax.ragged_dot(xs, wg, sizes, preferred_element_type=f32)
-    u = lax.ragged_dot(xs, wi, sizes, preferred_element_type=f32)
-    y = lax.ragged_dot((jax.nn.silu(g) * u).astype(x.dtype), wd, sizes,
-                       preferred_element_type=f32)
-    return jnp.zeros((t, x.shape[1]), f32).at[tok].add(
-        y * gate[:, None], mode="drop")
+    if act == "swiglu":
+        # one layer's experts (S = E): `lax.ragged_dot` over the groups
+        sizes = offsets[1:] - offsets[:-1]
+        mid = form.activate(*(lax.ragged_dot(
+            xs, w, sizes, preferred_element_type=f32) for w in ups))
+        y = lax.ragged_dot(mid.astype(x.dtype), wd, sizes,
+                           preferred_element_type=f32)
+    else:
+        # An expert at a time, each product over EVERY row and kept where
+        # the row is the expert's: 2 x E products where the kernels run
+        # the tiles that hold rows. Not `lax.ragged_dot`: it would want
+        # the layer's experts sliced out of the stack, a copy; turned for
+        # an up matrix that lies out by in XLA turns the whole STACK (a
+        # 4.5 GB copy at Nemotron-H's published sizes, whatever is sliced
+        # first); and its TPU lowering refuses float32 rows against
+        # weights stored in bfloat16, which a check in float32
+        # activations hands it
+        row = jnp.arange(rows, dtype=jnp.int32)[:, None]
+        over = (((1,), (1 if form.out_by_in else 0,)), ((), ()))
 
+        def one(j, y):
+            of = lambda w: lax.dynamic_index_in_dim(
+                w, base + j, 0, keepdims=False).astype(x.dtype)
+            mid = form.activate(*(lax.dot_general(
+                xs, of(w), over, preferred_element_type=f32) for w in ups))
+            down = jnp.dot(mid.astype(x.dtype), of(wd),
+                           preferred_element_type=f32)
+            return jnp.where((row >= offsets[j]) & (row < offsets[j + 1]),
+                             down, y)
 
-def held_relu2_ffn(x: jax.Array, gates: jax.Array, took: jax.Array,
-                   wu: jax.Array, wd: jax.Array, *, picks: int, impl: str,
-                   base=0) -> jax.Array:
-    """`held_experts_ffn` for UNGATED experts, relu(x W_up)^2 W_down
-    (two matrices an expert, no gate: `mlp_hidden_act` "relu2"), through
-    the same layout and under the same `impl`. wu and wd: [S, F, H]
-    (W_up out by in: see `grouped_relu2`): the E held experts of this
-    layer are [base, base + E) of a stack of S >= E (several layers'
-    experts in one array, so that a stack that scans its layers hands
-    the kernels the array whole and an index; `base` may be traced).
-    "gather" here is a plain loop over the held experts (below). Returns
-    [T, H] float32. A function of its own BELOW `held_experts_ffn`, not
-    a static argument of it: that function's text stays the parent's,
-    because the SwiGLU cells' compiled kernels carry its lines."""
-    if impl not in HELD_IMPLS:
-        raise ValueError(f"held_relu2_ffn: impl {impl!r} is none of "
-                         f"{HELD_IMPLS}")
-    t, _ = x.shape
-    e = took.shape[1]
-    rows = t * min(picks, e)
-    place, offsets = ge.assignment_rows(took)
-    if impl in ("pallas", "pallas_interpret"):
-        return ge.grouped_relu2(x, gates, place, offsets, wu, wd, base,
-                                rows=rows,
-                                interpret=(impl == "pallas_interpret"))
-    f32 = jnp.float32
-    at = jnp.where(took, place, rows).reshape(-1)
-    tok = jnp.full((rows,), t, jnp.int32).at[at].set(
-        jnp.repeat(jnp.arange(t, dtype=jnp.int32), e), mode="drop")
-    gate = jnp.zeros((rows,), f32).at[at].set(gates.reshape(-1),
-                                              mode="drop")
-    xs = jnp.take(x, tok, axis=0, mode="clip")
-    # An expert at a time, each product over EVERY row and kept where the
-    # row is the expert's: 2 x 64 products where the kernels run the tiles
-    # that hold rows. Not `lax.ragged_dot` as the SwiGLU path's: W_up lies
-    # out by in, and turned for it XLA turns the whole STACK (a 4.5 GB
-    # copy at the published sizes, whatever is sliced first); and its TPU
-    # lowering refuses float32 rows against weights stored in bfloat16,
-    # which a check in float32 activations hands it
-    row = jnp.arange(rows, dtype=jnp.int32)[:, None]
-
-    def one(j, y):
-        w = lax.dynamic_index_in_dim(wu, base + j, 0, keepdims=False)
-        up = lax.dot_general(xs, w.astype(x.dtype), (((1,), (1,)), ((), ())),
-                             preferred_element_type=f32)
-        mid = jnp.square(jax.nn.relu(up)).astype(x.dtype)
-        w = lax.dynamic_index_in_dim(wd, base + j, 0, keepdims=False)
-        down = jnp.dot(mid, w.astype(x.dtype), preferred_element_type=f32)
-        return jnp.where((row >= offsets[j]) & (row < offsets[j + 1]),
-                         down, y)
-
-    y = lax.fori_loop(0, e, one, jnp.zeros((rows, x.shape[1]), f32))
-    return jnp.zeros((t, x.shape[1]), f32).at[tok].add(
-        y * gate[:, None], mode="drop")
+        y = lax.fori_loop(0, e, one, jnp.zeros((rows, hid), f32))
+    return jnp.zeros((t, hid), f32).at[tok].add(y * gate[:, None],
+                                                mode="drop")
 
 
 # ------------------------------------------- softmax-of-the-picks routing
 # SmallThinker's router (`moe_primary_router_apply_softmax`,
-# `norm_topk_prob`) and its gated-ReLU held experts: the serving path of
-# models/smallthinker.py. Below everything above, whose lines the other
-# families' compiled kernels carry.
+# `norm_topk_prob`): the serving path of models/smallthinker.py.
 
 def softmax_pick_routing(x: jax.Array, router_w: jax.Array, *, top_k: int
                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -494,76 +492,8 @@ def softmax_pick_routing(x: jax.Array, router_w: jax.Array, *, top_k: int
     held to a float32 reference pick for pick, so it picks on the logits
     themselves, at the highest precision, hands the logits back for
     that comparison, and has no use for the E-wide softmax. Neither can
-    call the other without changing what it computes, and the lines
-    above this section do not move (a kernel's payload carries them)."""
+    call the other without changing what it computes."""
     with jax.default_matmul_precision("highest"):
         logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
     top, idx = lax.top_k(logits, top_k)
     return jax.nn.softmax(top, axis=-1), idx.astype(jnp.int32), logits
-
-
-def held_reglu_plan(took: jax.Array, *, picks: int, impl: str):
-    """What `held_reglu_ffn` needs of a tick's assignments besides the
-    gates, from `took` [T, E] alone: (the row of each assignment [T, E],
-    the groups' offsets [E + 1], the kernels' tile visits or None on the
-    gather path). A function of its own so that a layer whose router
-    runs ahead of its attention makes it THERE, with the picks."""
-    if impl not in HELD_IMPLS:
-        raise ValueError(f"held_reglu_plan: impl {impl!r} is none of "
-                         f"{HELD_IMPLS}")
-    t, e = took.shape
-    place, offsets = ge.assignment_rows(took)
-    visits = (ge.reglu_visits(offsets, t * min(picks, e))
-              if impl in ("pallas", "pallas_interpret") else None)
-    return place, offsets, visits
-
-
-def held_reglu_ffn(x: jax.Array, gates: jax.Array, took: jax.Array,
-                   wg: jax.Array, wi: jax.Array, wd: jax.Array, *,
-                   picks: int, impl: str, base=0, plan=None) -> jax.Array:
-    """`held_experts_ffn` for GATED-ReLU experts, (relu(x W_g) * (x W_i))
-    W_d, through the same layout and under the same `impl`. wg/wi: [S,
-    H, F], wd: [S, F, H]: the E held experts of this layer are [base,
-    base + E) of a stack of S >= E (several layers' experts in one
-    array; `base` may be traced). `plan`: `held_reglu_plan(took, ...)`
-    where the caller made it ahead (default: made here). The
-    down-projection is computed whole: a row that ReLU zeroed is
-    multiplied all the same. "gather" is a plain loop over the held
-    experts, each product over every row and kept where the row is the
-    expert's (`held_relu2_ffn` says why not `lax.ragged_dot`: it would
-    want the layer's experts sliced out of the stack, a copy). Returns
-    [T, H] float32."""
-    if impl not in HELD_IMPLS:
-        raise ValueError(f"held_reglu_ffn: impl {impl!r} is none of "
-                         f"{HELD_IMPLS}")
-    t, _ = x.shape
-    e = took.shape[1]
-    rows = t * min(picks, e)
-    place, offsets, visits = plan or held_reglu_plan(
-        took, picks=picks, impl=impl)
-    if impl in ("pallas", "pallas_interpret"):
-        return ge.grouped_reglu(x, gates, place, offsets, visits, wg, wi,
-                                wd, base, rows=rows,
-                                interpret=(impl == "pallas_interpret"))
-    f32 = jnp.float32
-    at = jnp.where(took, place, rows).reshape(-1)
-    tok = jnp.full((rows,), t, jnp.int32).at[at].set(
-        jnp.repeat(jnp.arange(t, dtype=jnp.int32), e), mode="drop")
-    gate = jnp.zeros((rows,), f32).at[at].set(gates.reshape(-1),
-                                              mode="drop")
-    xs = jnp.take(x, tok, axis=0, mode="clip")
-    row = jnp.arange(rows, dtype=jnp.int32)[:, None]
-
-    def one(j, y):
-        of = lambda w: lax.dynamic_index_in_dim(
-            w, base + j, 0, keepdims=False).astype(x.dtype)
-        g = jnp.dot(xs, of(wg), preferred_element_type=f32)
-        u = jnp.dot(xs, of(wi), preferred_element_type=f32)
-        mid = (jax.nn.relu(g) * u).astype(x.dtype)
-        down = jnp.dot(mid, of(wd), preferred_element_type=f32)
-        return jnp.where((row >= offsets[j]) & (row < offsets[j + 1]),
-                         down, y)
-
-    y = lax.fori_loop(0, e, one, jnp.zeros((rows, x.shape[1]), f32))
-    return jnp.zeros((t, x.shape[1]), f32).at[tok].add(
-        y * gate[:, None], mode="drop")

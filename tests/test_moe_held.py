@@ -24,7 +24,21 @@ def _weights(rng, e=HELD, h=H, f=F):
                                jnp.float32)
 
 
-def _loop(x, idx, w, valid, wg, wi, wd):
+# each form's activation over its up products, in float64; the ungated
+# one has wg alone as its up matrix
+ACTS = {"swiglu": lambda g, u: g / (1 + np.exp(-g)) * u,
+        "reglu": lambda g, u: np.maximum(g, 0) * u,
+        "relu2": lambda g, u: np.maximum(g, 0) ** 2}
+
+
+def _ffn(x, gates, took, wg, wi, wd, act="swiglu", **kw):
+    """`held_experts_ffn` on matrices kept in by out: the ungated form's
+    up matrix is handed over out by in, as it is stored."""
+    ups = (jnp.swapaxes(wg, 1, 2),) if act == "relu2" else (wg, wi)
+    return moe.held_experts_ffn(x, gates, took, ups, wd, act=act, **kw)
+
+
+def _loop(x, idx, w, valid, wg, wi, wd, act="swiglu"):
     """Token by token, pick by pick, in float64."""
     x, wg, wi, wd = (np.asarray(a, np.float64) for a in (x, wg, wi, wd))
     out = np.zeros_like(x)
@@ -33,7 +47,7 @@ def _loop(x, idx, w, valid, wg, wi, wd):
             e = int(idx[t, j])
             if valid[t] and 0 <= e < wg.shape[0]:
                 g, u = x[t] @ wg[e], x[t] @ wi[e]
-                out[t] += w[t, j] * ((g / (1 + np.exp(-g)) * u) @ wd[e])
+                out[t] += w[t, j] * (ACTS[act](g, u) @ wd[e])
     return out
 
 
@@ -62,11 +76,22 @@ def _picks(rng, t, how):
     return idx.astype(np.int32), valid
 
 
+# SwiGLU through every size and routing; the other two forms share all
+# but the up kernel's matrices and activation, so they take the cases
+# that reach a branch of their own there: experts nobody picked beside
+# one tile half full (8, one_expert), every row tile full (64 x 4 picks,
+# all_held), two tiles with padding rows between the groups (96, padding)
+CASES = ([(t, how, "swiglu") for t in (8, 64, 96, 512)
+          for how in ("balanced", "one_expert", "none_held", "padding",
+                      "random", "all_held")]
+         + [(t, how, act) for act in ("reglu", "relu2")
+            for t, how in ((8, "one_expert"), (64, "all_held"),
+                           (96, "padding"))])
+
+
 @pytest.mark.parametrize("impl", IMPLS)
-@pytest.mark.parametrize("how", ["balanced", "one_expert", "none_held",
-                                 "padding", "random", "all_held"])
-@pytest.mark.parametrize("t", [8, 64, 96, 512])
-def test_held_experts_ffn_is_the_loop_over_picks(t, how, impl):
+@pytest.mark.parametrize("t,how,act", CASES)
+def test_held_experts_ffn_is_the_loop_over_picks(t, how, act, impl):
     rng = np.random.default_rng(t)
     wg, wi, wd = _weights(rng)
     x = jnp.asarray(rng.normal(size=(t, H)), jnp.float32)
@@ -76,11 +101,10 @@ def test_held_experts_ffn_is_the_loop_over_picks(t, how, impl):
         jnp.asarray(idx), jnp.asarray(w), 0, HELD, jnp.asarray(valid))
     landed = ((idx < HELD) & valid[:, None]).sum()
     assert int(counts.sum()) == landed == int(took.sum())
-    got = moe.held_experts_ffn(x, gates, took, wg, wi, wd, picks=PICKS,
-                               impl=impl)
+    got = _ffn(x, gates, took, wg, wi, wd, act, picks=PICKS, impl=impl)
     assert got.shape == (t, H) and got.dtype == jnp.float32
     np.testing.assert_allclose(
-        np.asarray(got), _loop(x, idx, w, valid, wg, wi, wd),
+        np.asarray(got), _loop(x, idx, w, valid, wg, wi, wd, act),
         rtol=2e-4, atol=2e-4)
     if how == "none_held":
         assert not np.asarray(got).any()
@@ -100,8 +124,7 @@ def test_96_rows_on_one_expert_nothing_dropped(impl):
     idx = jnp.zeros((t, 1), jnp.int32)
     gates, took, counts = moe.held_gates(idx, jnp.ones((t, 1)), 0, e)
     assert counts.tolist() == [96, 0, 0, 0]
-    got = moe.held_experts_ffn(x, gates, took, wg, wi, wd, picks=1,
-                               impl=impl)
+    got = _ffn(x, gates, took, wg, wi, wd, picks=1, impl=impl)
     want = (jax.nn.silu(x @ wg[0]) * (x @ wi[0])) @ wd[0]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -121,8 +144,7 @@ def test_a_row_bound_that_is_no_multiple_of_the_tile(t, picks, impl):
     valid = np.ones(t, bool)
     gates, took, _ = moe.held_gates(jnp.asarray(idx), jnp.asarray(w), 0,
                                     HELD)
-    got = moe.held_experts_ffn(x, gates, took, wg, wi, wd, picks=picks,
-                               impl=impl)
+    got = _ffn(x, gates, took, wg, wi, wd, picks=picks, impl=impl)
     np.testing.assert_allclose(
         np.asarray(got), _loop(x, idx, w, valid, wg, wi, wd),
         rtol=2e-4, atol=2e-4)
@@ -146,8 +168,8 @@ def test_an_expert_nobody_picked_is_not_part_of_the_result(how):
     assert unpicked.any()
 
     def run(*ws):
-        return np.asarray(moe.held_experts_ffn(
-            x, gates, took, *ws, picks=PICKS, impl="pallas_interpret"))
+        return np.asarray(_ffn(x, gates, took, *ws, picks=PICKS,
+                               impl="pallas_interpret"))
 
     clean = run(wg, wi, wd)
     dirty = run(*(jnp.where(unpicked, jnp.nan, a) for a in (wg, wi, wd)))
@@ -197,8 +219,7 @@ def test_an_impl_that_is_none_of_the_three_is_refused(impl):
         jnp.zeros((8, PICKS), jnp.int32), jnp.ones((8, PICKS), jnp.float32),
         0, HELD)
     with pytest.raises(ValueError, match="impl"):
-        moe.held_experts_ffn(x, gates, took, *_weights(rng), picks=PICKS,
-                             impl=impl)
+        _ffn(x, gates, took, *_weights(rng), picks=PICKS, impl=impl)
 
 
 def test_platform_impl_is_what_the_engine_resolves_auto_to():

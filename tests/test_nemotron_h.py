@@ -287,8 +287,8 @@ def test_relu2_grouped_path_is_the_dense_sum(impl, base):
     w = jnp.array(rng.uniform(0.1, 1.0, (t, 3)), jnp.float32)
     valid = jnp.arange(t) < 20
     gates, took, counts = moe.held_gates(idx, w, 2, 2 + e, valid)
-    got = moe.held_relu2_ffn(x, gates, took, wu, wd, picks=3, impl=impl,
-                             base=base)
+    got = moe.held_experts_ffn(x, gates, took, (wu,), wd, act="relu2",
+                               picks=3, impl=impl, base=base)
     want = _dense_relu2(x, gates, wu[base:base + e], wd[base:base + e])
     assert _rel(got, want) < 2e-6
     assert int(counts.sum()) == int(took.sum()) > 0
@@ -306,8 +306,8 @@ def test_swiglu_path_is_what_it_was(impl):
                     jnp.int32)
     w = jnp.array(rng.uniform(0.1, 1.0, (t, 3)), jnp.float32)
     gates, took, _ = moe.held_gates(idx, w, 0, e)
-    got = moe.held_experts_ffn(x, gates, took, wg, wi, wd, picks=3,
-                               impl=impl)
+    got = moe.held_experts_ffn(x, gates, took, (wg, wi), wd, act="swiglu",
+                               picks=3, impl=impl)
     xs = np.asarray(x, np.float64)
     want = np.zeros_like(xs)
     for j in range(e):

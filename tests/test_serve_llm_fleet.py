@@ -2045,25 +2045,43 @@ def test_e2e_hung_replica_stall_watchdog_fails_over(fleet_servers):
     """The ISSUE 9 motivating case the probes alone can't save a
     client from: a replica that HANGS mid-stream (no raise, no
     end-of-stream). The relay's stall watchdog detects the silence,
-    fails over, and the transcript is still token-exact."""
+    fails over, and the transcript is still token-exact.
+
+    The watchdog is a one-second timer, and the only silence it may
+    meet is the hung replica's: a first call of a program compiles for
+    longer than that on a loaded machine, on the victim ahead of the
+    hang or on the survivor after it, and the watchdog then evicted a
+    healthy replica. So the scenario is walked once before it counts,
+    with the stream SEVERED at the same chunk (a failover that waits on
+    no timer) under the default, generous watchdog, on a prompt of the
+    same length that shares no cached page with the real one: both
+    replicas have built every program the timed pass meets."""
     gen = 10
     victim = "r1"
-    schedules = {rid: ChaosSchedule() for rid in fleet_servers}
-    schedules[victim].stall_stream(
-        after_chunks=2, method="completions_stream_tokens")
-    fleet = FleetManager(
-        [ChaosReplicaClient(LocalReplicaClient(rid, srv),
-                            schedules[rid])
-         for rid, srv in fleet_servers.items()],
-        router=RouterConfig(prefix_depth=64, spill_waiting=64),
-        autoscale=AutoscaleConfig(min_replicas=2, max_replicas=2),
-        health=HealthConfig(stream_stall_timeout_s=1.0,
-                            open_cooldown_s=300.0),
-        model_id="m")
-    prompt = _prompt_routed_to(fleet, victim, "H")
-    body = {"prompt": prompt, "max_tokens": gen}
 
-    async def main():
+    def chaos_fleet(fault, **health):
+        schedules = {rid: ChaosSchedule() for rid in fleet_servers}
+        getattr(schedules[victim], fault)(
+            after_chunks=2, method="completions_stream_tokens")
+        return schedules, FleetManager(
+            [ChaosReplicaClient(LocalReplicaClient(rid, srv),
+                                schedules[rid])
+             for rid, srv in fleet_servers.items()],
+            router=RouterConfig(prefix_depth=64, spill_waiting=64),
+            autoscale=AutoscaleConfig(min_replicas=2, max_replicas=2),
+            health=HealthConfig(open_cooldown_s=300.0, **health),
+            model_id="m")
+
+    def routed_to_victim(fleet, salt):
+        # the salt leads, so two salts share no page of the prefix
+        # cache; the counter's width is fixed, so they share a length
+        return next(
+            p for p in (f"{salt}{i:03d} hung replica probe"
+                        for i in range(1000))
+            if fleet.router.ring.preferred(
+                prefix_fingerprint({"prompt": p}, 64))[0] == victim)
+
+    async def stream(fleet, body):
         chunks = []
         async for c in fleet.dispatch_stream("completions_stream",
                                              dict(body)):
@@ -2071,7 +2089,21 @@ def test_e2e_hung_replica_stall_watchdog_fails_over(fleet_servers):
         _cancel_pumps(fleet_servers)
         return chunks
 
-    chunks = asyncio.run(main())
+    rehearsal, fleet = chaos_fleet("sever_stream")
+    asyncio.run(stream(fleet, {
+        "prompt": routed_to_victim(fleet, "W"), "max_tokens": gen}))
+    assert [f["kind"] for f in rehearsal[victim].fired] \
+        == ["stream_sever"]
+
+    schedules, fleet = chaos_fleet("stall_stream",
+                                   stream_stall_timeout_s=1.0)
+    body = {"prompt": routed_to_victim(fleet, "H"), "max_tokens": gen}
+    built = {rid: srv.engine.compiles
+             for rid, srv in fleet_servers.items()}
+    chunks = asyncio.run(stream(fleet, body))
+    # the rehearsal was one: the timed pass built no program
+    assert built == {rid: srv.engine.compiles
+                     for rid, srv in fleet_servers.items()}
     toks, _, reason = _sse_transcript(chunks)
     assert reason in ("length", "stop")
     assert len(toks) == gen

@@ -333,24 +333,28 @@ def test_reglu_grouped_path_is_the_dense_sum(impl, base):
     valid = jnp.arange(t) < 20                  # four rows of padding
     gates, took, counts = moe.held_gates(idx, w, 0, e, valid)
     assert int(counts.sum()) == 20 * k
-    for plan in (None, moe.held_reglu_plan(took, picks=k, impl=impl)):
-        got = moe.held_reglu_ffn(x, gates, took, wg, wi, wd, picks=k,
-                                 impl=impl, base=base, plan=plan)
+    for plan in (None, moe.held_plan(took, picks=k, impl=impl)):
+        got = moe.held_experts_ffn(x, gates, took, (wg, wi), wd,
+                                   act="reglu", picks=k, impl=impl,
+                                   base=base, plan=plan)
         want = _dense_reglu(x, gates, wg[base:base + e],
                             wi[base:base + e], wd[base:base + e])
         assert got.dtype == jnp.float32
         assert _rel(got, want) < 2e-6
         assert not np.asarray(got)[20:].any()   # padding rows: nothing
     with pytest.raises(ValueError, match="none of"):
-        moe.held_reglu_ffn(x, gates, took, wg, wi, wd, picks=k, impl="x")
+        moe.held_experts_ffn(x, gates, took, (wg, wi), wd, act="reglu",
+                             picks=k, impl="x")
     with pytest.raises(ValueError, match="none of"):
-        moe.held_reglu_plan(took, picks=k, impl="x")
+        moe.held_experts_ffn(x, gates, took, (wg, wi), wd, act="gelu",
+                             picks=k, impl=impl)
+    with pytest.raises(ValueError, match="none of"):
+        moe.held_plan(took, picks=k, impl="x")
 
 
 @pytest.mark.parametrize("impl", ["gather", "pallas_interpret"])
 def test_swiglu_and_relu2_paths_are_what_they_were(impl):
-    """The two older expert forms through the same layout, untouched by
-    the new section below them."""
+    """The two older expert forms through the same entry."""
     t, h, f, e, k = 16, 64, 32, 4, 2
     ks = jax.random.split(jax.random.PRNGKey(5), 6)
     x = jax.random.normal(ks[0], (t, h))
@@ -360,13 +364,14 @@ def test_swiglu_and_relu2_paths_are_what_they_were(impl):
     w, idx, _ = moe.softmax_pick_routing(
         x, jax.random.normal(ks[4], (h, e)), top_k=k)
     gates, took, _ = moe.held_gates(idx, w, 0, e)
-    got = moe.held_experts_ffn(x, gates, took, wg, wi, wd, picks=k,
-                               impl=impl)
+    got = moe.held_experts_ffn(x, gates, took, (wg, wi), wd, act="swiglu",
+                               picks=k, impl=impl)
     want = sum(np.asarray(gates[:, j:j + 1]) * np.asarray(
         (jax.nn.silu(x @ wg[j]) * (x @ wi[j])) @ wd[j]) for j in range(e))
     assert _rel(got, want) < 2e-5
     wu = jnp.swapaxes(wi, 1, 2)                 # relu^2: W_up out by in
-    got = moe.held_relu2_ffn(x, gates, took, wu, wd, picks=k, impl=impl)
+    got = moe.held_experts_ffn(x, gates, took, (wu,), wd, act="relu2",
+                               picks=k, impl=impl)
     want = sum(np.asarray(gates[:, j:j + 1]) * np.asarray(
         jnp.square(jax.nn.relu(x @ wi[j])) @ wd[j]) for j in range(e))
     assert _rel(got, want) < 2e-5

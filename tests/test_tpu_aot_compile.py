@@ -535,8 +535,8 @@ def test_grouped_experts_compile_at_the_cells_shapes(v5e, T, picks,
     bf16 = jnp.bfloat16
 
     def run(x, gates, took, wg, wi, wd):
-        return held_experts_ffn(x, gates, took, wg, wi, wd, picks=picks,
-                                impl="pallas")
+        return held_experts_ffn(x, gates, took, (wg, wi), wd, act="swiglu",
+                                picks=picks, impl="pallas")
 
     compiled = jax.jit(run).lower(
         S((T, hidden), bf16), S((T, 16), jnp.float32),
@@ -642,19 +642,19 @@ def test_ssd_scan_kernel_compiles_at_nemotrons_shapes(v5e, T):
 
 @pytest.mark.parametrize("T", [64, 512])
 def test_relu2_grouped_experts_compile_at_nemotrons_shapes(v5e, T):
-    """`held_relu2_ffn` by the kernels over 64 held experts of width
-    1856 (no whole number of 128-lane vectors) out of a stack of seven
-    layers' 448, the layer's first expert a traced index: the experts
+    """`held_experts_ffn(act="relu2")` by the kernels over 64 held
+    experts of width 1856 (no whole number of 128-lane vectors) out of a
+    stack of seven layers' 448, the layer's first expert a traced index: the experts
     are read where they lie (W_up out by in: stored in by out, XLA pads
     1856 to 1920 in a 4.4 GB copy of the stack before the kernel)."""
-    from ray_tpu.ops.moe import held_relu2_ffn
+    from ray_tpu.ops.moe import held_experts_ffn
     S = _on(v5e[0])
     bf16 = jnp.bfloat16
     hidden, ffn, held, stack = 2688, 1856, 64, 7 * 64
 
     def run(x, gates, took, wu, wd, base):
-        return held_relu2_ffn(x, gates, took, wu, wd, picks=6,
-                              impl="pallas", base=base)
+        return held_experts_ffn(x, gates, took, (wu,), wd, act="relu2",
+                                picks=6, impl="pallas", base=base)
 
     compiled = jax.jit(run).lower(
         S((T, hidden), bf16), S((T, held), jnp.float32),
@@ -849,21 +849,22 @@ def test_28_query_heads_are_refused_by_the_compiler(v5e):
 
 @pytest.mark.parametrize("T", [16, 64, 512])
 def test_reglu_grouped_experts_compile_at_smallthinkers_shapes(v5e, T):
-    """`held_reglu_ffn` by the kernels over 64 held experts of width 768
-    out of the stack of twelve layers' 768 (3.0 GB a projection), the
-    layer's first expert a traced index, the plan made ahead by
-    `held_reglu_plan`: the experts are read where they lie, and no
+    """`held_experts_ffn(act="reglu")` by the kernels over 64 held
+    experts of width 768 out of the stack of twelve layers' 768 (3.0 GB a
+    projection), the layer's first expert a traced index, the plan made
+    ahead by `held_plan`: the experts are read where they lie, and no
     padded or sliced copy of a stack shows in the temporaries (PR 41's
     4.4 GB lesson)."""
-    from ray_tpu.ops.moe import held_reglu_ffn, held_reglu_plan
+    from ray_tpu.ops.moe import held_experts_ffn, held_plan
     S = _on(v5e[0])
     bf16 = jnp.bfloat16
     hidden, ffn, held, stack = 2560, 768, 64, 12 * 64
 
     def run(x, gates, took, wg, wi, wd, base):
-        plan = held_reglu_plan(took, picks=6, impl="pallas")
-        return held_reglu_ffn(x, gates, took, wg, wi, wd, picks=6,
-                              impl="pallas", base=base, plan=plan)
+        plan = held_plan(took, picks=6, impl="pallas")
+        return held_experts_ffn(x, gates, took, (wg, wi), wd, act="reglu",
+                                picks=6, impl="pallas", base=base,
+                                plan=plan)
 
     compiled = jax.jit(run).lower(
         S((T, hidden), bf16), S((T, held), jnp.float32),

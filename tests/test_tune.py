@@ -112,7 +112,38 @@ def test_median_stopping(ray_start):
     assert slow <= fast
 
 
-def test_pbt_exploits_and_perturbs(ray_start):
+def _capped_together(marks: str, population: int = 4, last: int = 7):
+    """A `_StepTrainable` of which no trial is done before `population`
+    actors have reached step `last` (each writes the step it finished to
+    a file of its own under `marks`), so the population is still running
+    when its members are scored. A perturbation needs two scored,
+    unfinished trials: under load the controller served one trial's
+    seven steps before another's actor was up, each trial in turn was
+    the only one scored, and `num_perturbations` stayed 0. Nothing
+    blocks: a trial that is ahead keeps stepping."""
+    import os
+    import uuid
+
+    class Capped(_StepTrainable):
+        def setup(self, config):
+            super().setup(config)
+            self.mark = os.path.join(marks, uuid.uuid4().hex)
+
+        def step(self):
+            result = super().step()
+            with open(self.mark + ".new", "w") as f:
+                f.write(str(self._iteration + 1))
+            os.replace(self.mark + ".new", self.mark)
+            reached = [int(open(os.path.join(marks, name)).read())
+                       for name in os.listdir(marks)
+                       if not name.endswith(".new")]
+            result["done"] = sum(at >= last for at in reached) >= population
+            return result
+
+    return Capped
+
+
+def test_pbt_exploits_and_perturbs(ray_start, tmp_path):
     scheduler = PopulationBasedTraining(
         metric="value", mode="max", perturbation_interval=2,
         hyperparam_mutations={"delta": tune.uniform(0.5, 3.0)}, seed=0)
@@ -123,14 +154,9 @@ def test_pbt_exploits_and_perturbs(ray_start):
                                max_concurrent_trials=4,
                                scheduler=scheduler,
                                time_budget_s=60))
-    # Cap experiment length: stop everything at iteration 8 via ASHA-less
-    # trainable done flag — use tune.run max_t through scheduler instead.
-    class Capped(_StepTrainable):
-        def step(self):
-            result = super().step()
-            result["done"] = self._iteration >= 7
-            return result
-    tuner._trainable = Capped
+    # Cap experiment length with the trainable's done flag, set once the
+    # whole population has run its steps
+    tuner._trainable = _capped_together(str(tmp_path))
     results = tuner.fit()
     assert scheduler.num_perturbations >= 1
     best = results.get_best_result()
@@ -166,7 +192,7 @@ def test_hyperband_promotes(ray_start):
     assert best.config["delta"] == 2.0
 
 
-def test_pb2_gp_directed_explore(ray_start):
+def test_pb2_gp_directed_explore(ray_start, tmp_path):
     """PB2 (reference: tune/schedulers/pb2.py): exploit configs come
     from the GP-UCB bandit within hyperparam_bounds, not random
     perturbation; the experiment still improves the population."""
@@ -175,12 +201,7 @@ def test_pb2_gp_directed_explore(ray_start):
     scheduler = PB2(
         metric="value", mode="max", perturbation_interval=2,
         hyperparam_bounds={"delta": (0.5, 3.0)}, seed=0)
-
-    class Capped(_StepTrainable):
-        def step(self):
-            result = super().step()
-            result["done"] = self._iteration >= 7
-            return result
+    Capped = _capped_together(str(tmp_path))
 
     tuner = Tuner(
         Capped,
