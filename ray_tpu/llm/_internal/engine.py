@@ -957,7 +957,9 @@ class InferenceEngine:
             self.v_pages = made[0][1] if crow.pools == 2 else None
         else:
             self.k_pages = tuple(m[0] for m in made)
-            self.v_pages = tuple(m[1] for m in made)
+            # (a latent group is one pool: None stands for its second)
+            self.v_pages = tuple(m[1] if len(m) > 1 else None
+                                 for m in made)
         self._key = self._dev(jax.random.PRNGKey(ec.seed + 1))
         # per-(token row, kv head) f32 scale pools beside the value
         # pools (None for f32 engines): [L, P, page, KVH], sharded on

@@ -407,7 +407,7 @@ def cache_attention(cfg: DeepseekV3Config, impl: str, pool: jax.Array,
     list is built once here, for every layer."""
     dv, scale = cfg.kv_lora_rank, cfg.softmax_scale
     if impl in ("pallas", "pallas_interpret"):
-        work = mla_ops.mla_work_list(slot_ids, valid, start)
+        work = mla_ops.mla_work_list(slot_ids, valid, start, cfg.n_heads)
 
         def attend(q, rows, li):
             return mla_ops.mla_ragged_attention_pallas(

@@ -17,6 +17,8 @@ A family with a STATE group (`cache_row.CacheGroup.state`) gets that
 group's arrays in `k_pages` / `v_pages` behind its page groups' pools
 (its first part / its second) and returns them in the same places; the
 forwards of a family without one take and return what they always did.
+A ONE-pool page group (a latent row) among several groups has None as
+its `v_pages` entry, as a one-group latent family has None for the whole.
 """
 
 from __future__ import annotations
@@ -144,6 +146,7 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
     "phi4flash": ("phi4flash", "Phi4FlashConfig"),
     "nemotron_h": ("nemotron_h", "NemotronHConfig"),
     "smallthinker": ("smallthinker", "SmallThinkerConfig"),
+    "kimi_linear": ("kimi_linear", "KimiLinearConfig"),
 }
 
 
@@ -175,6 +178,7 @@ def _families() -> Dict[type, ModelFamily]:
     m = _modules()
     trinity, phi4flash = m["trinity"], m["phi4flash"]
     nemotron_h, smallthinker = m["nemotron_h"], m["smallthinker"]
+    kimi_linear = m["kimi_linear"]
     # a family with held experts: the counts of the assignments landed
     # ride the readback, [n_moe_layers, n_held], and are summed alike
     held = dict(rider_len=paged_common.held_rider_len,
@@ -207,6 +211,12 @@ def _families() -> Dict[type, ModelFamily]:
             "smallthinker", smallthinker,
             span_counts=smallthinker.span_counts,
             refuses=smallthinker.SMALLTHINKER_REFUSES, **held),
+        _from_module(
+            "kimi_linear", kimi_linear,
+            work_counts=kimi_linear.work_counts,
+            span_counts=kimi_linear.span_counts,
+            storage_dtypes=kimi_linear.storage_dtypes,
+            refuses=kimi_linear.KIMI_LINEAR_REFUSES, **held),
     )
     return {getattr(m[f.name], FAMILIES[f.name][1]): f for f in families}
 

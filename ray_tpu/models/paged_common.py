@@ -216,3 +216,34 @@ def routing_summary(cfg, landed, tokens_routed: int) -> Dict[str, Any]:
                               if total else 0.0),
         "landed": landed.tolist(),
     }
+
+
+def latent_attend_fn(impl: str, pool: jax.Array, page_tables: jax.Array,
+                     slot_ids: jax.Array, positions: jax.Array,
+                     valid: jax.Array, start: jax.Array, ctx_pages: int, *,
+                     heads: int, width: int, dv: int, scale: float):
+    """attend(q, rows, index in the group) -> o_lat [T, heads, dv] for
+    one tick of a LATENT group (`ops/mla_attention.py`): absorbed
+    queries [T, `heads`, width] against the pool's cached rows of that
+    layer of the group and the tick's own `rows` [T, width], by the
+    kernel or by the dense gather as `impl` says. The kernel's work list
+    is built once here, for every layer; the pool goes in whole and the
+    layer as an index (a pool sliced by layer is copied first)."""
+    from ..ops import mla_attention as mla_ops
+    if impl in ("pallas", "pallas_interpret"):
+        work = mla_ops.mla_work_list(slot_ids, valid, start, heads)
+
+        def attend(q, rows, gi):
+            return mla_ops.mla_ragged_attention_pallas(
+                q, pool, gi, page_tables, slot_ids, positions, valid,
+                start, rows, dv=dv, scale=scale, ctx_pages=ctx_pages,
+                work=work, interpret=(impl == "pallas_interpret"))
+    else:
+        tables = (page_tables if ctx_pages < 0
+                  else page_tables[:, :ctx_pages])
+
+        def attend(q, rows, gi):
+            return mla_ops.mla_attention_gather_paged(
+                q, pool, gi, tables, rows, slot_ids, positions, valid,
+                start, width=width, dv=dv, scale=scale)
+    return attend

@@ -52,6 +52,12 @@ from .ragged_paged_attention import (KV_BLOCK, ragged_item_bound,
 # bound by the MXU; the q block, the f32 accumulator and the scores stay
 # under 6 MB of VMEM
 MLA_Q_BLOCK = 8
+# ... at the 128 heads the kernel was sized for. With fewer heads an item
+# takes more tokens, up to the same 1,024 rows and at most 32 tokens: at
+# 32 heads 8 tokens are 256 rows, and a 512-token chunk swept its
+# context 64 times where 16 do (a block-step costs ~1.4 us whatever its
+# rows: PERF.md section 6, PR 48)
+MLA_Q_ROWS, MLA_Q_MOST = 1024, 32
 # the tick's own rows are a 2-D [T, W] array whose row dim is tiled (16
 # bf16 rows a tile): a block of them is read from an aligned row, the
 # rows before the slot's segment masked
@@ -179,34 +185,38 @@ def mla_attention_gather_paged(q: jax.Array, pool: jax.Array, layer: int,
 
 # ------------------------------------------------------------ Pallas kernel
 
-def mla_q_block(t: int) -> int:
-    """Tokens per work item for a tick of `t` flat tokens."""
-    return max(min(MLA_Q_BLOCK, t), 1)
+def mla_q_block(t: int, heads: int = 128) -> int:
+    """Tokens per work item for a tick of `t` flat tokens of `heads`
+    query heads: MLA_Q_BLOCK at 128 heads, more with fewer heads (up to
+    MLA_Q_ROWS query rows an item and MLA_Q_MOST tokens)."""
+    blk = max(MLA_Q_BLOCK, min(MLA_Q_ROWS // max(heads, 1), MLA_Q_MOST))
+    return max(min(blk, t), 1)
 
 
-def mla_block_sizes(t: int, page_size: int, n_ctx_pages: int
-                    ) -> Tuple[int, int, int]:
+def mla_block_sizes(t: int, page_size: int, n_ctx_pages: int,
+                    heads: int = 128) -> Tuple[int, int, int]:
     """(tokens per item, pages per context block, in-batch keys per
     block) for a tick of `t` flat tokens over a table `n_ctx_pages`
     wide."""
     ppb = max(min(KV_BLOCK // page_size, n_ctx_pages), 1)
-    return mla_q_block(t), ppb, max(min(KV_BLOCK, t), _ROW_ALIGN)
+    return mla_q_block(t, heads), ppb, max(min(KV_BLOCK, t), _ROW_ALIGN)
 
 
 def mla_work_list(slot_ids: jax.Array, valid: jax.Array,
-                  start: jax.Array) -> Tuple[jax.Array, jax.Array]:
+                  start: jax.Array, heads: int = 128
+                  ) -> Tuple[jax.Array, jax.Array]:
     """The kernel's grid for a tick: `ragged_work_list` at this
     kernel's tokens per item. Built once a forward, for every layer."""
     return ragged_work_list(slot_ids, valid, start,
-                            mla_q_block(slot_ids.shape[0]))
+                            mla_q_block(slot_ids.shape[0], heads))
 
 
-def mla_work_counts(segs, t: int, page_size: int, n_ctx_pages: int
-                    ) -> Tuple[int, int]:
+def mla_work_counts(segs, t: int, page_size: int, n_ctx_pages: int,
+                    heads: int = 128) -> Tuple[int, int]:
     """Host-side count of what the kernel does for a tick whose slots
     hold `segs` = [(cached tokens, tokens this tick)]: (live items, KV
     blocks they visit)."""
-    q_blk, ppb, bkn = mla_block_sizes(t, page_size, n_ctx_pages)
+    q_blk, ppb, bkn = mla_block_sizes(t, page_size, n_ctx_pages, heads)
     bk = ppb * page_size
     items = blocks = 0
     for start, n in segs:
@@ -371,7 +381,7 @@ def mla_ragged_attention_pallas(
     adds exact zeros to every score. ctx_pages (static) says only
     whether any slot has a context (0 = none). Returns [T, H, dv]."""
     del positions
-    items, segs = (mla_work_list(slot_ids, valid, start)
+    items, segs = (mla_work_list(slot_ids, valid, start, q.shape[1])
                    if work is None else work)
     flat = _mla_call(items, segs, page_tables.astype(jnp.int32),
                      jnp.asarray(layer, jnp.int32).reshape(1), q, pool,
@@ -391,7 +401,7 @@ def _mla_call(items, segs, tables, layer, q, pool, new_rows, *, dv: int,
     t, heads, _ = q.shape
     n_layers, num_pages, page_size, _, w = pool.shape
     n_ctx_pages = tables.shape[1] if has_ctx else 0
-    q_blk, ppb, bkn = mla_block_sizes(t, page_size, n_ctx_pages)
+    q_blk, ppb, bkn = mla_block_sizes(t, page_size, n_ctx_pages, heads)
     assert items.shape[1] == ragged_item_bound(t, segs.shape[1], q_blk)
     # one block of rows past T keeps the last item's blocks in bounds
     qp = jnp.pad(_fit_lanes(q, w), ((0, q_blk), (0, 0), (0, 0)))
