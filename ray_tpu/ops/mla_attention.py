@@ -59,8 +59,8 @@ MLA_Q_BLOCK = 8
 # rows: PERF.md section 6, PR 48)
 MLA_Q_ROWS, MLA_Q_MOST = 1024, 32
 # the tick's own rows are a 2-D [T, W] array whose row dim is tiled (16
-# bf16 rows a tile): a block of them is read from an aligned row, the
-# rows before the slot's segment masked
+# bf16 rows a tile): a slot's blocks of them start at the aligned row at
+# or before its segment's first, the rows before the segment masked
 _ROW_ALIGN = 16
 
 
@@ -195,11 +195,12 @@ def mla_q_block(t: int, heads: int = 128) -> int:
 
 def mla_block_sizes(t: int, page_size: int, n_ctx_pages: int,
                     heads: int = 128) -> Tuple[int, int, int]:
-    """(tokens per item, pages per context block, in-batch keys per
+    """(tokens per item, pages per context block, in-batch rows per
     block) for a tick of `t` flat tokens over a table `n_ctx_pages`
     wide."""
     ppb = max(min(KV_BLOCK // page_size, n_ctx_pages), 1)
-    return mla_q_block(t, heads), ppb, max(min(KV_BLOCK, t), _ROW_ALIGN)
+    return (mla_q_block(t, heads), ppb,
+            min(KV_BLOCK, -(-t // _ROW_ALIGN) * _ROW_ALIGN))
 
 
 def mla_work_list(slot_ids: jax.Array, valid: jax.Array,
@@ -214,22 +215,25 @@ def mla_work_list(slot_ids: jax.Array, valid: jax.Array,
 def mla_work_counts(segs, t: int, page_size: int, n_ctx_pages: int,
                     heads: int = 128) -> Tuple[int, int]:
     """Host-side count of what the kernel does for a tick whose slots
-    hold `segs` = [(cached tokens, tokens this tick)]: (live items, KV
-    blocks they visit)."""
+    hold `segs` = [(cached tokens, tokens this tick)] in packing order:
+    (live items, KV blocks they visit). An in-batch block is bkn flat
+    rows from the aligned row at or before the segment's first."""
     q_blk, ppb, bkn = mla_block_sizes(t, page_size, n_ctx_pages, heads)
     bk = ppb * page_size
-    items = blocks = 0
+    items = blocks = first = 0
     for start, n in segs:
+        lead = first % _ROW_ALIGN
         for qoff in range(0, n, q_blk):
             items += 1
             blocks += (-(-start // bk)
-                       + -(-min(qoff + q_blk, n) // bkn))
+                       + -(-(lead + min(qoff + q_blk, n)) // bkn))
+        first += n
     return items, blocks
 
 
 def _mla_kernel(items_ref, segs_ref, tables_ref, layer_ref, q_hbm,
                 pool_hbm, new_hbm, o_hbm, q_vmem, o_vmem, kv_vmem, kn_vmem, kv_sem,
-                io_sem, m_scr, l_scr, acc_scr, *, page_size: int,
+                io_sem, m_scr, l_scr, acc_scr, tok_scr, *, page_size: int,
                 ppb: int, n_ctx_pages: int, q_blk: int, bkn: int,
                 heads: int, dv: int, scale: float):
     """Grid (n_items,): one step per work item (slot, block of q_blk of
@@ -237,17 +241,42 @@ def _mla_kernel(items_ref, segs_ref, tables_ref, layer_ref, q_hbm,
     (token, head) order; it sweeps the slot's cached rows in blocks of
     ppb pages (the next block in flight while this one is computed),
     then the tick's own rows of the slot up to the causal diagonal in
-    blocks of bkn keys, with an online softmax in float32 scratch, and
-    writes its q_blk x heads output rows at the item's flat row. Items
-    run in flat order; rows a block holds past the slot's segment are
-    computed under the key mask alone, stay finite, and are overwritten
-    by the next item (the wrapper zeroes invalid rows). An item of one
-    token (a decode row, a chunk's last token) runs on `heads` rows."""
+    blocks of bkn flat rows, with an online softmax in float32 scratch,
+    and writes its q_blk x heads output rows at the item's flat row.
+    Items run in flat order; rows a block holds past the slot's segment
+    are computed under the key mask alone, stay finite, and are
+    overwritten by the next item (the wrapper zeroes invalid rows). An
+    item of one token (a decode row, a chunk's last token) runs on
+    `heads` rows.
+
+    What a block costs beside its two MXU products is kept small, as in
+    ops/ragged_paged_attention.py: the running maximum and sum lie on
+    all 128 lanes of their row (`lanes`), so no use of them broadcasts
+    across lanes; the sum is kept a LANE (`l = l * corr + p`, one lane
+    reduction at the item's end) where every block is whole lane tiles
+    wide, which is every program of 128 tokens and more on the chip; a
+    context block's mask is one compare against a scalar and an
+    in-batch block's two against the rows' token offsets, made once an
+    item (`tok_scr`). A second body that left the mask off whole context
+    blocks was 17 bundles of 2,200 shorter and the kernel SLOWER with it
+    (1-5%, PERF.md section 6: the kernel's code is a quarter larger)."""
     it = pl.program_id(0)
     slot = items_ref[0, it]
     bk = page_size * ppb
     w = q_vmem.shape[-1]
     cdt = q_vmem.dtype
+    # the row's sum a lane: every block's scores are whole lane tiles
+    lane_l = bk % LANES == 0 and bkn % LANES == 0
+
+    def lanes(x, n):
+        """A statistic (rows, 128), the row's value on every lane, as
+        (rows, n): its first lanes, or copies of itself side by side
+        beside whole lane tiles, which is every shape on the chip."""
+        if n <= LANES:
+            return x[:, :n]
+        if n % LANES == 0:
+            return jnp.concatenate([x] * (n // LANES), axis=1)
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
     @pl.when(slot >= 0)
     def _item():
@@ -283,46 +312,53 @@ def _mla_kernel(items_ref, segs_ref, tables_ref, layer_ref, q_hbm,
         m_scr[...] = jnp.full_like(m_scr, -1e30)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
+        # the in-batch mask's query offsets, once an item: score row i
+        # is the token at segment offset qoff + i // heads, which sees
+        # no key past itself nor past the segment's last
+        tok_scr[...] = jnp.minimum(
+            qoff + jax.lax.broadcasted_iota(
+                jnp.int32, tok_scr.shape, 0) // heads, qlen - 1)
 
-        def flash(n_tok, keys, first_key, lo, end, ahead):
+        def flash(n_tok, keys, keep):
             """One flash step of the item's first n_tok tokens against
-            `keys` [n, w], whose first row is key `first_key` of the
-            slot's context or segment; a key attends iff it lies in
-            [lo, end) and not after the row's token (+ `ahead`)."""
-            r = n_tok * heads
+            `keys` [n, w]; keep(r, n) is the [r, n] mask of the scores
+            that count."""
+            r, n = n_tok * heads, keys.shape[0]
             q = q_vmem[:n_tok].reshape(r, w)
             s = jax.lax.dot_general(
                 q, keys, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            shape = (r, keys.shape[0])
-            i_tok = qoff + jax.lax.broadcasted_iota(
-                jnp.int32, shape, 0) // heads
-            key = first_key + jax.lax.broadcasted_iota(
-                jnp.int32, shape, 1)
-            s = jnp.where((key >= lo) & (key < end)
-                          & (key <= i_tok + ahead), s, -1e30)
+            s = jnp.where(keep(r, n), s, -1e30)
             m_prev = m_scr[:r]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
+            p = jnp.exp(s - lanes(m_new, n))
             corr = jnp.exp(m_prev - m_new)
-            l_scr[:r] = l_scr[:r] * corr + jnp.sum(p, axis=1,
-                                                   keepdims=True)
-            acc_scr[:r] = acc_scr[:r] * corr + jax.lax.dot_general(
-                p.astype(cdt), keys[:, :dv], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            if lane_l:
+                p_row = functools.reduce(jnp.add, [
+                    p[:, c:c + LANES] for c in range(0, n, LANES)])
+            else:
+                p_row = jnp.sum(p, axis=1, keepdims=True)
+            l_scr[:r] = l_scr[:r] * corr + p_row
+            acc_scr[:r] = (
+                acc_scr[:r] * lanes(corr, dv) + jax.lax.dot_general(
+                    p.astype(cdt), keys[:, :dv], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
             m_scr[:r] = m_new
 
         one = qlen - qoff <= 1
 
-        def step(keys, first_key, lo, end, ahead):
+        def step(keys, keep):
+            """The flash step on the rows the item holds: one token's
+            or all q_blk's."""
             if q_blk > 1:
-                pl.when(one)(lambda: flash(1, keys, first_key, lo, end,
-                                           ahead))
+                pl.when(one)(lambda: flash(1, keys, keep))
                 pl.when(jnp.logical_not(one))(
-                    lambda: flash(q_blk, keys, first_key, lo, end,
-                                  ahead))
+                    lambda: flash(q_blk, keys, keep))
             else:
-                flash(1, keys, first_key, lo, end, ahead)
+                flash(1, keys, keep)
+
+        def cols(r, n):
+            return jax.lax.broadcasted_iota(jnp.int32, (r, n), 1)
 
         if n_ctx_pages:
             def ctx_block(blk, carry):
@@ -330,32 +366,45 @@ def _mla_kernel(items_ref, segs_ref, tables_ref, layer_ref, q_hbm,
                 pl.when(blk + 1 < n_ctx)(
                     lambda: page_dma(blk + 1, 1 - buf, True))
                 page_dma(blk, buf, False)
-                # context keys precede every query of the tick
-                step(kv_vmem[buf].reshape(bk, w), blk * bk, 0, ctx_len,
-                     1 << 30)
+                # context keys precede every query of the tick: a
+                # block's columns count up to the cached length
+                left = ctx_len - blk * bk
+                step(kv_vmem[buf].reshape(bk, w),
+                     lambda r, n: cols(r, n) < left)
                 return carry
 
             jax.lax.fori_loop(0, n_ctx, ctx_block, 0)
 
+        # the tick's own rows are read in blocks of bkn FLAT rows from
+        # the aligned row at or before the segment's first: a block is
+        # one aligned read, and a key lies in one block
+        lead = first % _ROW_ALIGN
+
         def new_block(jb, carry):
-            base = first + jb * bkn
-            lead = base % _ROW_ALIGN
             c = pltpu.make_async_copy(
-                new_hbm.at[pl.ds(pl.multiple_of(base - lead, _ROW_ALIGN),
-                                 bkn + _ROW_ALIGN)], kn_vmem, io_sem)
+                new_hbm.at[pl.ds(pl.multiple_of(
+                    first - lead + jb * bkn, _ROW_ALIGN), bkn)],
+                kn_vmem, io_sem)
             c.start()
             c.wait()
-            # the aligned read brings `lead` rows of the block before
-            # and the rest of _ROW_ALIGN of the block after: a block's
-            # keys are its own bkn alone, or its neighbours' count twice
-            step(kn_vmem[...], jb * bkn - lead, jb * bkn,
-                 jnp.minimum(qlen, (jb + 1) * bkn), 0)
+            # column j holds segment offset j - before: none under 0
+            # (the rows ahead of the segment), none past the row's token
+            before = lead - jb * bkn
+
+            def keep(r, n):
+                col = cols(r, n)
+                return (col >= before) & (
+                    col <= lanes(tok_scr[:r], n) + before)
+
+            step(kn_vmem[...], keep)
             return carry
 
-        n_new = (jnp.minimum(qoff + q_blk, qlen) + bkn - 1) // bkn
+        n_new = (lead + jnp.minimum(qoff + q_blk, qlen) + bkn - 1) // bkn
         jax.lax.fori_loop(0, n_new, new_block, 0)
 
-        out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+        l = (jnp.sum(l_scr[...], axis=1, keepdims=True) if lane_l
+             else l_scr[:, :1])
+        out = acc_scr[...] / jnp.maximum(l, 1e-30)
         o_vmem[...] = out.reshape(q_blk, heads, dv).astype(o_vmem.dtype)
         o_copy = pltpu.make_async_copy(
             o_vmem, o_hbm.at[pl.ds(tok0, q_blk)], io_sem)
@@ -406,7 +455,7 @@ def _mla_call(items, segs, tables, layer, q, pool, new_rows, *, dv: int,
     # one block of rows past T keeps the last item's blocks in bounds
     qp = jnp.pad(_fit_lanes(q, w), ((0, q_blk), (0, 0), (0, 0)))
     newp = jnp.pad(_fit_lanes(new_rows, w).astype(pool.dtype),
-                   ((0, bkn + _ROW_ALIGN), (0, 0)))
+                   ((0, bkn), (0, 0)))
     rows = q_blk * heads
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
@@ -423,12 +472,16 @@ def _mla_call(items, segs, tables, layer, q, pool, new_rows, *, dv: int,
                 pltpu.VMEM((q_blk, heads, w), q.dtype),        # q block
                 pltpu.VMEM((q_blk, heads, dv), q.dtype),       # output
                 pltpu.VMEM((2, ppb, page_size, w), pool.dtype),
-                pltpu.VMEM((bkn + _ROW_ALIGN, w), pool.dtype),  # new rows
+                pltpu.VMEM((bkn, w), pool.dtype),              # new rows
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA,
-                pltpu.VMEM((rows, 1), jnp.float32),            # m
-                pltpu.VMEM((rows, 1), jnp.float32),            # l
+                # m, a row's value on all 128 lanes, and l, the same or
+                # the row's sum a lane (what a [rows, 1] array padded to
+                # its tile takes anyway)
+                pltpu.VMEM((rows, LANES), jnp.float32),
+                pltpu.VMEM((rows, LANES), jnp.float32),
                 pltpu.VMEM((rows, dv), jnp.float32),           # acc
+                pltpu.VMEM((rows, LANES), jnp.int32),          # tok
             ]),
         out_shape=jax.ShapeDtypeStruct((t + q_blk, heads, dv), q.dtype),
         # the items' output writes overlap and rely on their order

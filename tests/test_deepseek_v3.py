@@ -159,44 +159,8 @@ def test_absorbed_attention_equals_the_non_absorbed_form():
                                atol=2e-5)
 
 
-def test_kernel_interpret_matches_gather_on_a_mixed_tick():
-    rng = np.random.default_rng(5)
-    heads, w, dv, t = 4, 24, 16, 32
-    pool = jnp.asarray(rng.normal(size=(2, 40, PAGE, 1, w)), jnp.float32)
-    tables = _tables()
-    plan = [(0, 13, 1), (1, 5, 19), (2, 0, 4)]
-    slot, pos = np.zeros(t, np.int32), np.zeros(t, np.int32)
-    valid = np.zeros(t, bool)
-    cur = 0
-    for s, st, n in plan:
-        slot[cur:cur + n], pos[cur:cur + n] = s, np.arange(st, st + n)
-        valid[cur:cur + n] = True
-        cur += n
-    start = jnp.asarray([13, 5, 0, 0], jnp.int32)
-    q = jnp.asarray(rng.normal(size=(t, heads, w)), jnp.float32)
-    new = jnp.asarray(rng.normal(size=(t, w)), jnp.float32)
-    args = (jnp.asarray(slot), jnp.asarray(pos), jnp.asarray(valid), start)
-    want = mla_ops.mla_attention_gather(
-        q, _gather_latent(pool, tables, w)[1], new, *args, dv=dv,
-        scale=0.3)
-    got = mla_ops.mla_ragged_attention_pallas(
-        q, pool, 1, tables, *args, new, dv=dv, scale=0.3, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want) * valid[:, None, None],
-        atol=2e-6)
-    # the host's count of the kernel's work: 1 + ceil(19 / 8) + 1 items
-    segs = [(st, n) for _, st, n in plan]
-    assert mla_ops.mla_work_counts(segs, t, PAGE, MAXP)[0] == 5
-
-
-LONG_PAGE = 16      # the cell's page size: 8 pages a block of 128 keys
-
-
-def _long_tick(rng, t, plan, n_slots, pages_per_slot):
-    """Tables, token arrays and starts of a tick whose slots hold
-    contexts of several 128-key blocks."""
-    tables = (1 + np.arange(n_slots * pages_per_slot, dtype=np.int32)
-              ).reshape(n_slots, pages_per_slot)
+def _tick(plan, t, n_slots):
+    """Token arrays and starts of a tick packed in `plan`'s order."""
     slot, pos = np.zeros(t, np.int32), np.zeros(t, np.int32)
     valid = np.zeros(t, bool)
     start = np.zeros(n_slots, np.int32)
@@ -206,69 +170,182 @@ def _long_tick(rng, t, plan, n_slots, pages_per_slot):
         valid[cur:cur + n] = True
         start[s] = st
         cur += n
-    return (jnp.asarray(tables), jnp.asarray(slot), jnp.asarray(pos),
-            jnp.asarray(valid), jnp.asarray(start))
+    return (jnp.asarray(slot), jnp.asarray(pos), jnp.asarray(valid),
+            jnp.asarray(start))
 
 
-def test_kernel_interpret_sweeps_a_context_of_several_kv_blocks():
+def _kernel_against_gather(rng, pool, tables, args, layer, *, heads, w,
+                           dv, atol):
+    """The interpreted kernel beside the dense gather on one tick, at
+    random float32 queries and rows; returns the kernel's output."""
+    t = args[0].shape[0]
+    q = jnp.asarray(rng.normal(size=(t, heads, w)), jnp.float32)
+    new = jnp.asarray(rng.normal(size=(t, w)), jnp.float32)
+    want = mla_ops.mla_attention_gather(
+        q, _gather_latent(pool, tables, w)[layer], new, *args, dv=dv,
+        scale=0.3)
+    run = lambda tb: mla_ops.mla_ragged_attention_pallas(
+        q, pool, layer, tb, *args, new, dv=dv, scale=0.3, interpret=True)
+    got = run(tables)
+    valid = np.asarray(args[2])
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want) * valid[:, None, None],
+        atol=atol)
+    return got, run
+
+
+# heads x tokens an item, row width, value width: the debug preset's, a
+# value width of whole lane tiles (the cells' 512 of 640), and both
+# cells' items of 1,024 query rows (8 tokens x 128 heads, 32 x 32)
+@pytest.mark.parametrize("heads,w,dv", [
+    (4, 24, 16), (4, 640, 512), (128, 24, 16), (32, 24, 16)])
+def test_kernel_interpret_matches_gather_on_a_mixed_tick(heads, w, dv):
+    rng = np.random.default_rng(5)
+    t = 32
+    pool = jnp.asarray(rng.normal(size=(2, 40, PAGE, 1, w)), jnp.float32)
+    plan = [(0, 13, 1), (1, 5, 19), (2, 0, 4)]
+    args = _tick(plan, t, B)
+    _kernel_against_gather(rng, pool, _tables(), args, 1, heads=heads,
+                           w=w, dv=dv, atol=4e-6)
+    # the host's count of the kernel's work: 1 + ceil(19 / q_blk) + 1
+    segs = [(st, n) for _, st, n in plan]
+    q_blk = mla_ops.mla_q_block(t, heads)
+    assert mla_ops.mla_work_counts(segs, t, PAGE, MAXP, heads)[0] == (
+        2 + -(-19 // q_blk))
+
+
+LONG_PAGE = 16      # the cell's page size: 8 pages a block of 128 keys
+
+
+def _long_tables(n_slots, pages_per_slot):
+    return jnp.asarray((1 + np.arange(n_slots * pages_per_slot,
+                                      dtype=np.int32)
+                        ).reshape(n_slots, pages_per_slot))
+
+
+# the chunk's cached length: 4 blocks of 128 keys and 18 keys, 4 blocks
+# to the key (every context block whole: no mask anywhere), and one key
+# past them (a last block of one key)
+@pytest.mark.parametrize("chunk_ctx,ctx_blocks", [(530, 5), (512, 4),
+                                                   (513, 5)])
+def test_kernel_interpret_sweeps_a_context_of_several_kv_blocks(
+        chunk_ctx, ctx_blocks):
     """Contexts of 3, 4 and 5 blocks of 128 keys (8 pages a block): the
     double-buffered page DMA with its prefetch of the next block, a last
     block that is partly filled (the `last_page` clamp: 401 = 25 pages
-    and a token, 530 = 4 blocks and 18 keys), a chunk of three query
-    blocks against one, and decode rows, against the dense gather."""
+    and a token, 530 = 4 blocks and 18 keys) or filled to the key, a
+    chunk of three query blocks against one, and decode rows, against
+    the dense gather."""
     rng = np.random.default_rng(11)
     heads, w, dv, t, per = 4, 24, 16, 32, 40          # 640 tokens a slot
-    plan = [(0, 401, 1), (1, 530, 19), (2, 384, 1), (3, 0, 5)]
-    tables, *args = _long_tick(rng, t, plan, 4, per)
+    plan = [(0, 401, 1), (1, chunk_ctx, 19), (2, 384, 1), (3, 0, 5)]
+    tables, args = _long_tables(4, per), _tick(plan, t, 4)
     pool = jnp.asarray(rng.normal(size=(2, 4 * per + 2, LONG_PAGE, 1, w)),
                        jnp.float32)
-    q = jnp.asarray(rng.normal(size=(t, heads, w)), jnp.float32)
-    new = jnp.asarray(rng.normal(size=(t, w)), jnp.float32)
-    want = mla_ops.mla_attention_gather(
-        q, _gather_latent(pool, tables, w)[1], new, *args, dv=dv,
-        scale=0.3)
-    got = mla_ops.mla_ragged_attention_pallas(
-        q, pool, 1, tables, *args, new, dv=dv, scale=0.3, interpret=True)
-    valid = np.asarray(args[2])
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want) * valid[:, None, None],
-        atol=3e-6)
-    # the host's count: 1 + 3 + 1 + 1 items; context blocks 4 + 3 * 5 +
-    # 3 + 0, and one in-batch block an item
+    got, run = _kernel_against_gather(rng, pool, tables, args, 1,
+                                      heads=heads, w=w, dv=dv, atol=3e-6)
+    # the host's count: 1 + 1 + 1 + 1 items (19 tokens are one item at
+    # 4 heads); context blocks 4 + the chunk's + 3 + 0, and one
+    # in-batch block an item
     segs = [(st, n) for _, st, n in plan]
-    assert mla_ops.mla_work_counts(segs, t, LONG_PAGE, per) == (6, 22 + 6)
+    assert mla_ops.mla_work_counts(segs, t, LONG_PAGE, per, heads) == (
+        4, 7 + ctx_blocks + 4)
+    assert mla_ops.mla_work_counts(segs, t, LONG_PAGE, per) == (
+        6, 7 + 3 * ctx_blocks + 6)
     # a moved page table row or one key more or less of context shows
     moved = tables.at[1, 30].set(int(tables[0, 3]))
-    other = mla_ops.mla_ragged_attention_pallas(
-        q, pool, 1, moved, *args, new, dv=dv, scale=0.3, interpret=True)
-    assert float(jnp.abs(other - got)[1:20].max()) > 1e-3
+    assert float(jnp.abs(run(moved) - got)[1:20].max()) > 1e-3
 
 
-@pytest.mark.parametrize("lead_rows", [0, 1])
-def test_kernel_interpret_counts_each_in_batch_key_once(lead_rows):
-    """A chunk longer than one block of in-batch keys (128): the aligned
-    read of a block brings 16 rows more than its own, which belong to
-    the blocks beside it and must not be counted with it too. 300 tokens
-    are three blocks; with a decode row packed first the chunk starts
-    off the 16-row alignment (found on the chip at the cell's sizes, PR
-    27: every token past a chunk's first 128 saw 16 keys twice)."""
+# decode rows packed first, and the long chunk's length: 300 tokens are
+# items of whole blocks, of a diagonal block and a short last item; 257
+# end in an item of ONE token behind two whole blocks of in-batch keys
+@pytest.mark.parametrize("lead_rows,chunk,counts", [
+    (0, 300, None), (1, 300, None), (1, 257, (15, 45)), (2, 290, None)])
+def test_kernel_interpret_counts_each_in_batch_key_once(lead_rows, chunk,
+                                                        counts):
+    """A chunk longer than one block of in-batch keys (128): a block is
+    one aligned read of 128 flat rows, the first from the aligned row at
+    or before the chunk's first, and no key may be counted in two
+    blocks. With decode rows packed first the chunk starts off the
+    16-row alignment (found on the chip at the cell's sizes, PR 27:
+    every token past a chunk's first 128 saw 16 keys twice). At 512
+    flat tokens every block is a whole lane tile: the row's sum is kept
+    a lane, as on the chip."""
     rng = np.random.default_rng(14)
     heads, w, dv, t, per = 2, 24, 16, 512, 40
-    plan = [(0, 130, 1)] * lead_rows + [(1, 200, 300), (2, 0, 150)]
-    tables, *args = _long_tick(rng, t, plan, 4, per)
+    plan = [(0, 130, 1), (3, 77, 1)][:lead_rows] + [(1, 200, chunk),
+                                                    (2, 0, 150)]
+    tables, args = _long_tables(4, per), _tick(plan, t, 4)
     pool = jnp.asarray(rng.normal(size=(1, 4 * per + 2, LONG_PAGE, 1, w)),
                        jnp.float32)
-    q = jnp.asarray(rng.normal(size=(t, heads, w)), jnp.float32)
-    new = jnp.asarray(rng.normal(size=(t, w)), jnp.float32)
-    want = mla_ops.mla_attention_gather(
-        q, _gather_latent(pool, tables, w)[0], new, *args, dv=dv,
-        scale=0.3)
-    got = mla_ops.mla_ragged_attention_pallas(
-        q, pool, 0, tables, *args, new, dv=dv, scale=0.3, interpret=True)
-    valid = np.asarray(args[2])
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want) * valid[:, None, None],
-        atol=3e-6)
+    _kernel_against_gather(rng, pool, tables, args, 0, heads=heads, w=w,
+                           dv=dv, atol=3e-6)
+    if counts:
+        # items of 32 tokens (2 heads): 1 + 9 + 5; the decode row 2
+        # context blocks and 1 of its own; the chunk 9 x 2 and, one
+        # flat row off the alignment, 1 1 1 2 2 2 2 3 3 (15 were it
+        # aligned); the last chunk none and 1 1 1 2 2
+        segs = [(st, n) for _, st, n in plan]
+        assert mla_ops.mla_work_counts(segs, t, LONG_PAGE, per,
+                                       heads) == counts
+
+
+def _kernel_jaxpr(t, heads):
+    """The kernel's own jaxpr (the body of its pallas_call; its inputs
+    are the kernel's refs, scratch included) for a tick of t tokens."""
+    w, dv = 24, 16
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    traced = jax.make_jaxpr(
+        lambda q, pool, tables, slot, pos, valid, start, new:
+        mla_ops.mla_ragged_attention_pallas(
+            q, pool, 0, tables, slot, pos, valid, start, new, dv=dv,
+            scale=0.3))(
+        f32(t, heads, w), f32(1, 42, LONG_PAGE, 1, w), i32(4, 10), i32(t),
+        i32(t), jax.ShapeDtypeStruct((t,), jnp.bool_), i32(4), f32(t, w))
+
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn.params["jaxpr"]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found = find(sub)
+                if found is not None:
+                    return found
+
+    return find(traced.jaxpr)
+
+
+@pytest.mark.parametrize("t,heads", [(512, 128), (512, 32), (64, 128)])
+def test_kernel_keeps_its_statistics_lane_wide_and_divides_once(t, heads):
+    """What set the kernel's pace until PR 49, read off its jaxpr: the
+    running maximum and sum lie on all 128 lanes of their row (a
+    statistic ONE lane wide costs a lane broadcast a vector register at
+    each use), and no loop body (a flash step runs inside the context's
+    and the in-batch loop) divides a vector of integers: the rows' token
+    offsets are made once an item."""
+    kernel = _kernel_jaxpr(t, heads)
+    rows = mla_ops.mla_q_block(t, heads) * heads
+    shapes = [tuple(v.aval.shape) for v in kernel.invars]
+    assert shapes.count((rows, 128)) == 3          # m, l, the offsets
+    assert not [s for s in shapes if len(s) == 2 and s[1] == 1]
+
+    def vector_divisions(jaxpr, in_loop):
+        found = []
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            if (in_loop and name in ("div", "rem")
+                    and eqn.outvars[0].aval.shape
+                    and jnp.issubdtype(eqn.outvars[0].aval.dtype,
+                                       jnp.integer)):
+                found.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += vector_divisions(
+                    sub, in_loop or name in ("while", "scan"))
+        return found
+
+    assert vector_divisions(kernel, False) == []
 
 
 def test_gather_in_token_blocks_equals_the_whole_tick(monkeypatch):
@@ -277,7 +354,7 @@ def test_gather_in_token_blocks_equals_the_whole_tick(monkeypatch):
     rng = np.random.default_rng(12)
     heads, w, dv, t, per = 4, 24, 16, 32, 40
     plan = [(0, 401, 1), (1, 530, 19), (2, 384, 1), (3, 0, 5)]
-    tables, *args = _long_tick(rng, t, plan, 4, per)
+    tables, args = _long_tables(4, per), _tick(plan, t, 4)
     pool = jnp.asarray(rng.normal(size=(2, 4 * per + 2, LONG_PAGE, 1, 128)),
                        jnp.float32)
     q = jnp.asarray(rng.normal(size=(t, heads, w)), jnp.float32)

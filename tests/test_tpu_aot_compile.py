@@ -491,17 +491,28 @@ def test_a_group_of_two_keeps_its_compiler_parameters():
     assert 32 << 20 < big["vmem_limit_bytes"] <= 96 << 20
 
 
-@pytest.mark.parametrize("T,has_ctx", [(8, True), (64, True),
-                                       (512, True), (512, False)])
-def test_mla_kernel_compiles_at_the_cells_shapes(v5e, T, has_ctx):
+# (query heads, layers of the pool, slots, table width in pages): the
+# kernel's item is 1,024 query rows at both (8 tokens x 128, 32 x 32)
+_DSV3_LATENT, _KIMI_LATENT = (128, 5, 64, 512), (32, 7, 48, 1600)
+
+
+@pytest.mark.parametrize("T,has_ctx,cell", [
+    (8, True, _DSV3_LATENT), (64, True, _DSV3_LATENT),
+    (512, True, _DSV3_LATENT), (512, False, _DSV3_LATENT),
+    (512, True, _KIMI_LATENT), (48, True, _KIMI_LATENT)])
+def test_mla_kernel_compiles_at_the_cells_shapes(v5e, T, has_ctx, cell):
     """`mla_ragged_attention` at DeepSeek-V3's published widths as
     dsv3-longchat runs it: 128 heads on one latent row of 640 lanes
     (576 + padding), values its first 512, the WHOLE 5-layer pool of
     16,384 pages handed over with a traced layer index (no layer's
     slice is copied out), 64 slots, a table 512 pages wide; a decode
-    tick is T = 64. The 2-D new-row array is read at an aligned row:
-    Mosaic refuses an unaligned dynamic slice of a tiled dim."""
+    tick is T = 64. And as kimi-longdoc runs it: 32 heads on the same
+    row, a 7-layer pool, 48 slots, a table 1,600 pages wide, a decode
+    tick T = 48 (an in-batch block of 48 rows: no whole lane tile). The
+    2-D new-row array is read at an aligned row: Mosaic refuses an
+    unaligned dynamic slice of a tiled dim."""
     from ray_tpu.ops.mla_attention import mla_ragged_attention_pallas
+    heads, layers, n_slots, table = cell
     S = _on(v5e[0])
     i32 = lambda *shape: S(shape, jnp.int32)
 
@@ -511,9 +522,10 @@ def test_mla_kernel_compiles_at_the_cells_shapes(v5e, T, has_ctx):
             dv=512, scale=0.1147, ctx_pages=-1 if has_ctx else 0)
 
     compiled = jax.jit(run).lower(
-        S((T, 128, 576), jnp.bfloat16),
-        S((5, 16384, PAGE, 1, 640), jnp.bfloat16), i32(),
-        i32(64, 512), i32(T), i32(T), S((T,), jnp.bool_), i32(64),
+        S((T, heads, 576), jnp.bfloat16),
+        S((layers, 16384, PAGE, 1, 640), jnp.bfloat16), i32(),
+        i32(n_slots, table), i32(T), i32(T), S((T,), jnp.bool_),
+        i32(n_slots),
         S((T, 576), jnp.bfloat16)).compile()
     # the pool is read where it lies: no 1.68 GB copy of it, no 0.34 GB
     # copy of a layer of it
