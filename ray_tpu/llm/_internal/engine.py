@@ -66,7 +66,7 @@ class EngineConfig:
     num_pages_by_group: Optional[Dict[str, int]] = None
     max_seq_len: Optional[int] = None    # default: model max_seq
     seed: int = 0
-    # "auto": Pallas paged-decode kernel on TPU, dense gather elsewhere.
+    # "auto": the Pallas attention kernels on TPU, dense gather elsewhere.
     # Also accepts "gather" | "pallas" | "pallas_interpret".
     decode_impl: str = "auto"
     # Chunked prefill: a prompt advances at most this many tokens per

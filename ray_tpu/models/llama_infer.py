@@ -8,15 +8,15 @@ Two forwards, both designed to jit once per shape and stay compiled:
   (ops/paged_attention.py layout: [n_layers, num_pages, page_size,
   n_kv_heads, pool_head_dim]) plus the batch itself, every token's K/V
   scattered into the pool.
-- decode_step: one token per active sequence, paged attention over the
-  pool, new KV scattered in-place (donate the pools for true in-place
-  HBM updates under jit).
+- decode_step: one token per active sequence, new KV scattered in-place
+  (donate the pools for true in-place HBM updates under jit). On the
+  kernel path it is ragged_forward of one token a slot; the gather path
+  keeps a dense forward of its own.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -24,11 +24,11 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
 from ..ops.paged_attention import (gather_kv, gather_kv_quant,
-                                   paged_attention_on_gathered,
-                                   paged_decode_with_new_token, scatter_kv,
+                                   paged_attention_on_gathered, scatter_kv,
                                    scatter_kv_quant)
 from .llama import (LlamaConfig, param_logical_axes, rms_norm,
                     rope_frequencies)
+from .paged_common import one_token_tick
 
 
 def _rope_single(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -214,9 +214,10 @@ def lora_scan_xs(lora: Optional[dict]):
 
 
 def _whole_pools(*pools):
-    """What the kernel path of both forwards gives the layer scan in
-    place of the pools: each pool ([L, pages, page, KVH(, D)]; the
-    scale pools of quantized values too) viewed as [L * pages, ...], a
+    """What the kernel path of `ragged_forward` (and so of a decode
+    tick) gives the layer scan in place of the pools: each pool ([L,
+    pages, page, KVH(, D)]; the scale pools of quantized values too)
+    viewed as [L * pages, ...], a
     reshape of the two leading axes that moves no data, and every
     layer's first page in that view ([L], an xs of the scan). A layer's
     call gets the pools WHOLE and `page_tables + first page`: the
@@ -258,7 +259,7 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
     last valid token (logits source; 0 for slots with no tokens this
     tick — callers mask); lora_idx: per-TOKEN adapter index (T,).
 
-    impl (mirrors decode_step's kernel selection):
+    impl (decode_step takes the same three):
       "gather"            dense XLA fallback — gathers each token's
                           [ctx] context up front; O(T*ctx*KVH*D)
                           transient per layer.
@@ -406,6 +407,9 @@ def ragged_forward(cfg: LlamaConfig, params: Dict[str, Any],
 
 # -------------------------------------------------------------------- decode
 
+_one_token_tick = one_token_tick(ragged_forward)
+
+
 def decode_step(cfg: LlamaConfig, params: Dict[str, Any],
                 tokens: jax.Array, positions: jax.Array,
                 k_pages: jax.Array, v_pages: jax.Array,
@@ -426,30 +430,41 @@ def decode_step(cfg: LlamaConfig, params: Dict[str, Any],
     scattered in.
 
     impl:
-      "gather"            dense XLA fallback — gathers [B, max_ctx] KV up
-                          front; cost scales with max_pages.
-      "pallas"            stream pages through the Pallas decode kernel;
-                          cost scales with each sequence's actual length.
-      "pallas_interpret"  same kernel, interpreter mode (CPU tests).
+      "pallas"            the decode tick IS the ragged tick of one token
+                          a slot (`paged_common.one_token_tick`, as in
+                          every other family): `ragged_forward` with
+                          slot b's token at positions[b] and inactive
+                          slots invalid, so the work-list kernel steps
+                          over live rows only and a row costs the keys it
+                          has. Every other argument goes to
+                          `ragged_forward` under its contract there.
+      "pallas_interpret"  same, kernel in interpreter mode (CPU tests).
+      "gather"            dense XLA path, below: gathers [B, max_ctx] KV
+                          up front; cost scales with max_pages. What a
+                          CPU engine resolves "auto" to, and the side the
+                          kernel path is checked against.
 
     mesh: a jax Mesh with a 'tp' axis for tensor-parallel serving
     (params sharded on heads/mlp/vocab, KV pool on kv_heads — the
     reference places external vLLM TP workers via PGs,
-    vllm_models.py:123-159; here TP is in-program GSPMD). The gather
-    impl partitions end-to-end via GSPMD; the Pallas kernel is wrapped
-    in shard_map over 'tp' (attention is per-head: no collectives
-    inside, psum on the projections happens in the surrounding GSPMD
-    program).
+    vllm_models.py:123-159; here TP is in-program GSPMD, which
+    partitions the gather path end-to-end).
 
     kv_kind/k_scales/v_scales: quantized pools (ISSUE 16) — same
-    contract as ragged_forward: dequant-on-gather or fused-dequant
-    kernel on the read side, quantize-at-append on the write side, and
-    a (logits, k_pages, v_pages, k_scales, v_scales) return.
+    contract as ragged_forward: dequant-on-gather on the read side,
+    quantize-at-append on the write side, and a (logits, k_pages,
+    v_pages, k_scales, v_scales) return.
 
     psum_axis/logits_psum: explicit-tp mode — same contract as
     ragged_forward (caller already inside the shard_map, shard-local
     cfg/params/pools, mesh=None).
     """
+    if impl in ("pallas", "pallas_interpret"):
+        return _one_token_tick(
+            cfg, params, tokens, positions, k_pages, v_pages, page_tables,
+            active, impl=impl, mesh=mesh, lora=lora, lora_idx=lora_idx,
+            kv_kind=kv_kind, k_scales=k_scales, v_scales=v_scales,
+            psum_axis=psum_axis, logits_psum=logits_psum)
     b = tokens.shape[0]
     dt = cfg.dtype
     quantized = kv_kind != "f32"
@@ -457,67 +472,24 @@ def decode_step(cfg: LlamaConfig, params: Dict[str, Any],
         x = params["embed"].astype(dt)[tokens]       # (B, H)
         cos, sin = rope_frequencies(cfg, positions)  # (B, D/2)
 
-    use_kernel = impl in ("pallas", "pallas_interpret")
-    kernel_quant = use_kernel and quantized
-    if use_kernel:
-        # the scan carries each layer's first page, not its pages
-        pools, first_pages = _whole_pools(
-            k_pages, v_pages, *((k_scales, v_scales) if quantized else ()))
-        kv_xs = (first_pages,)
+    # One gather of the whole context for all layers, layer-major.
+    if quantized:
+        kv_xs = gather_kv_quant(
+            k_pages, v_pages, k_scales, v_scales, page_tables,
+            cfg.head_dim)
     else:
-        # One gather of the whole context for all layers, layer-major.
-        if quantized:
-            kv_xs = gather_kv_quant(
-                k_pages, v_pages, k_scales, v_scales, page_tables,
-                cfg.head_dim)
-        else:
-            kv_xs = gather_kv(k_pages, v_pages, page_tables, cfg.head_dim)
+        kv_xs = gather_kv(k_pages, v_pages, page_tables, cfg.head_dim)
 
     def layer_fn(x, inp):
-        layer, *kv_l, lora_l = inp
+        layer, k_l, v_l, lora_l = inp
 
         def attn_fn(q, k, v):
-            # The just-computed token's KV is not yet in the pages: the
-            # kernel path merges it with one extra online-softmax step,
-            # the gather path appends it to the dense context
-            # (append_len=1).
-            if not use_kernel:
-                k_l, v_l = kv_l
-                k_full = jnp.concatenate([k_l, k[:, None]], axis=1)
-                v_full = jnp.concatenate([v_l, v[:, None]], axis=1)
-                return paged_attention_on_gathered(
-                    q, k_full, v_full, positions, append_len=1)
-            (first_page,) = kv_l
-            base = functools.partial(
-                paged_decode_with_new_token,
-                interpret=(impl == "pallas_interpret"))
-            if kernel_quant:
-                # positional wrapper so shard_map's in_specs line up
-                def kernel(q_, kp, vp, tb, po, kn, vn, ksl, vsl):
-                    return base(q_, kp, vp, tb, po, kn, vn,
-                                k_scales=ksl, v_scales=vsl)
-            else:
-                kernel = base
-            if mesh is not None and mesh.shape.get("tp", 1) > 1:
-                # per-head attention: each tp shard runs the kernel on
-                # its local heads/kv-heads, no cross-shard comms
-                from jax.sharding import PartitionSpec as P
-                in_specs = [P(None, "tp", None),          # q (B,H,D)
-                            P(None, None, "tp", None),    # k pool
-                            P(None, None, "tp", None),    # v pool
-                            P(None, None),                # tables
-                            P(None),                      # positions
-                            P(None, "tp", None),          # new k
-                            P(None, "tp", None)]          # new v
-                if kernel_quant:
-                    # scale blocks shard on kv heads like their pages
-                    in_specs += [P(None, None, "tp"),     # k scales
-                                 P(None, None, "tp")]     # v scales
-                kernel = jax.shard_map(
-                    kernel, mesh=mesh, in_specs=tuple(in_specs),
-                    out_specs=P(None, "tp", None), check_vma=False)
-            return kernel(q, *pools[:2], page_tables + first_page,
-                          positions, k, v, *pools[2:])
+            # The just-computed token's KV is not yet in the pages: it
+            # is appended to the dense context (append_len=1).
+            k_full = jnp.concatenate([k_l, k[:, None]], axis=1)
+            v_full = jnp.concatenate([v_l, v[:, None]], axis=1)
+            return paged_attention_on_gathered(
+                q, k_full, v_full, positions, append_len=1)
 
         return _layer_body(cfg, dt, x, layer, lora_l, lora_idx, (b,),
                            lambda a: _rope_single(a, cos, sin),

@@ -115,23 +115,21 @@ def attend_fn(impl: str, pools, tables, slot_ids, positions, valid, start,
 
 
 def one_token_tick(ragged_forward):
-    """The `decode_step` of a family whose decode tick IS its ragged tick
-    of one token a slot (slot b's token at positions[b], inactive slots
-    invalid: they write nothing and their state is left alone), through
-    the same attention, experts and scans, so that ONE kernel knows a
-    window and a decode row costs the keys it may see. Contract of
-    `llama_infer.decode_step`; returns what `ragged_forward` returns."""
+    """The `decode_step` of every family: its decode tick IS its ragged
+    tick of one token a slot (slot b's token at positions[b], inactive
+    slots invalid: they write nothing and their state is left alone),
+    through the same attention, experts and scans, so that ONE kernel
+    knows a window and a decode row costs the keys it may see. Keyword
+    arguments (impl, mesh, lora, lora_idx, the quantized pools' kv_kind
+    and scales, the dense family's explicit-tp pair) go to
+    `ragged_forward` as they come; returns what it returns."""
     def decode_step(cfg, params: Dict[str, Any], tokens: jax.Array,
                     positions: jax.Array, k_pages, v_pages, page_tables,
-                    active: jax.Array, impl: str = "gather", mesh=None,
-                    lora=None, lora_idx=None, kv_kind: str = "f32",
-                    k_scales=None, v_scales=None):
+                    active: jax.Array, **kw):
         slots = jnp.arange(tokens.shape[0], dtype=jnp.int32)
         return ragged_forward(
             cfg, params, tokens, slots, positions, active, positions, slots,
-            k_pages, v_pages, page_tables, ctx_pages=-1, lora=lora,
-            lora_idx=lora_idx, impl=impl, mesh=mesh, kv_kind=kv_kind,
-            k_scales=k_scales, v_scales=v_scales)
+            k_pages, v_pages, page_tables, ctx_pages=-1, **kw)
     return decode_step
 
 

@@ -20,15 +20,21 @@ lane-aligned, so pools the kernels read are allocated with head_dim
 padded to 128 lanes (`pool_head_dim`); every function here takes the
 row width from the pool it is handed.
 
-Two decode paths:
-- XLA fallback: gather pages into dense [B, ctx] KV then masked attention
-  (cost scales with max_pages, not actual context).
-- Pallas kernel (`paged_decode_attention`): stream each sequence's pages
-  through VMEM with online softmax. Page indices come from the
-  scalar-prefetched page table, so the BlockSpec DMAs exactly the pages a
-  sequence owns; grid steps past the end of a sequence re-map to the same
-  page (Pallas elides the repeat DMA) and skip compute — decode cost
-  scales with the tokens actually cached.
+What serving programs use of this module: the pool layout, the gather
+path (`gather_kv`, `paged_attention_on_gathered`: the dense decode tick
+of a CPU engine and the side every kernel is checked against) and the
+row writes (`scatter_kv`, `scatter_kv_quant`). A decode tick on the
+kernel path is the ragged tick of one token a slot in every family
+(`models/paged_common.one_token_tick`, `ops/ragged_paged_attention.py`).
+
+The two Pallas decode kernels (`paged_decode_attention`: `paged_decode`
+a page a grid step, `paged_decode_mp` a block of pages;
+`paged_decode_with_new_token` around them) have no caller in a serving
+program and are kept with their kernel tests: they step a grid of slots
+x page-table width and pay for every step, live slot or not. Page
+indices come from the scalar-prefetched page table, so the BlockSpec DMAs
+exactly the pages a sequence owns; grid steps past the end of a sequence
+re-map to the same page (Pallas elides the repeat DMA) and skip compute.
 """
 
 from __future__ import annotations

@@ -196,3 +196,73 @@ def test_multipage_kernel_matches_dense_gather():
     np.testing.assert_allclose(
         np.asarray(out).reshape(B, H, D)[1:], np.asarray(ref)[1:],
         atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("kind", ["f32", "int8"])
+@pytest.mark.parametrize("B", [3, 8, 32])
+def test_decode_step_is_the_ragged_tick_of_one_token_a_slot(B, kind):
+    """On the kernel path the dense family's decode tick IS
+    `ragged_forward` of one token a slot (PR 50), bit for bit: logits,
+    pools and scale pools, with a LoRA adapter a slot and some slots
+    inactive, whose pool rows nobody writes. And it stays within the
+    band of the gather path, which keeps a dense forward of its own."""
+    cfg = llama.config("debug", dtype=jnp.float32)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(50 + B)
+    page_size, max_pages = 16, 4
+    num_pages = B * max_pages + 1           # the last: the scratch page
+    shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads,
+             cfg.head_dim)
+    k_pages = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    v_pages = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    kw = {}
+    if kind != "f32":
+        k_pages, k_scales = kv_quant.quantize_rows(k_pages, kind)
+        v_pages, v_scales = kv_quant.quantize_rows(v_pages, kind)
+        kw = dict(kv_kind=kind, k_scales=k_scales, v_scales=v_scales)
+    tables = jnp.asarray(
+        rng.permutation(B * max_pages).reshape(B, max_pages), jnp.int32)
+    # contexts from none to one short of the table's width
+    cached = jnp.asarray(
+        rng.integers(0, max_pages * page_size - 1, B), jnp.int32)
+    active = jnp.asarray(np.arange(B) % 3 != 1)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, B), jnp.int32)
+    adapters, rank = 3, 4                   # adapter 0: the zero adapter
+    stack = lambda i, o: {
+        "a": jnp.asarray(rng.normal(size=(cfg.n_layers, adapters, i, rank)),
+                         jnp.float32).at[:, 0].set(0.0) * 0.1,
+        "b": jnp.asarray(rng.normal(size=(cfg.n_layers, adapters, rank, o)),
+                         jnp.float32).at[:, 0].set(0.0) * 0.1}
+    kw["lora"] = {"wq": stack(cfg.hidden, cfg.q_dim),
+                  "wo": stack(cfg.q_dim, cfg.hidden)}
+    kw["lora_idx"] = jnp.asarray(np.arange(B) % adapters, jnp.int32)
+
+    out = decode_step(cfg, params, tokens, cached, k_pages, v_pages, tables,
+                      active, impl="pallas_interpret", **kw)
+    slots = jnp.arange(B, dtype=jnp.int32)
+    same = ragged_forward(
+        cfg, params, tokens, slots, cached, active, cached, slots,
+        k_pages, v_pages, tables, ctx_pages=-1, impl="pallas_interpret",
+        **kw)
+    ref = decode_step(cfg, params, tokens, cached, k_pages, v_pages, tables,
+                      active, impl="gather", **kw)
+    assert len(out) == len(same) == len(ref) == (3 if kind == "f32" else 5)
+    act = np.asarray(active)
+    for o, s, r in zip(out, same, ref):
+        assert o.shape == s.shape == r.shape and o.dtype == s.dtype == r.dtype
+        o, s, r = (np.asarray(a, np.float32) for a in (o, s, r))
+        np.testing.assert_array_equal(o, s)
+        # an inactive slot's logits are nobody's to read, and its rows
+        # go to the scratch page
+        o, r = (o[act], r[act]) if o.ndim == 2 else (o[:, :-1], r[:, :-1])
+        np.testing.assert_allclose(r, o, atol=1e-4, rtol=1e-4)
+    # an inactive slot's row of the pools is as it was
+    pos = np.asarray(cached)
+    page = np.asarray(tables)[np.arange(B), pos // page_size]
+    for before, after in zip((k_pages, v_pages), out[1:3]):
+        before, after = np.asarray(before), np.asarray(after)
+        np.testing.assert_array_equal(
+            after[:, page[~act], pos[~act] % page_size],
+            before[:, page[~act], pos[~act] % page_size])
+        assert (after[:, page[act], pos[act] % page_size]
+                != before[:, page[act], pos[act] % page_size]).any()

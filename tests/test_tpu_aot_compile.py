@@ -87,9 +87,10 @@ SERVE_CONFIGS = {
 
 @pytest.mark.parametrize("name", list(SERVE_CONFIGS))
 def test_serving_forwards_compile_for_v5e(v5e, name):
-    """ragged_forward (mixed ticks) and decode_step (pure-decode ticks,
-    the multi-page kernel) at published widths, two layers deep (the
-    layer scan makes depth irrelevant to what compiles)."""
+    """ragged_forward (mixed ticks) and decode_step (pure-decode ticks:
+    the same forward at one token a slot) at published widths, two
+    layers deep (the layer scan makes depth irrelevant to what
+    compiles)."""
     cfg = dataclasses.replace(SERVE_CONFIGS[name], n_layers=2)
     S = _on(v5e[0])
     params = _param_structs(cfg, S)
@@ -101,7 +102,6 @@ def test_serving_forwards_compile_for_v5e(v5e, name):
         ragged_forward, cfg, ctx_pages=16, impl="pallas")
     ).lower(params, i32(T), i32(T), i32(T), S((T,), jnp.bool_),
             i32(BATCH), i32(BATCH), k, v, tables).compile()
-    assert TABLE >= 16          # >= pages_per_block: the multi-page kernel
     jax.jit(functools.partial(decode_step, cfg, impl="pallas")).lower(
         params, i32(BATCH), i32(BATCH), k, v, tables,
         S((BATCH,), jnp.bool_)).compile()
@@ -109,12 +109,12 @@ def test_serving_forwards_compile_for_v5e(v5e, name):
 
 @pytest.mark.parametrize("T", [0, 512])
 def test_dense_forwards_copy_no_pool_at_chat_opens_sizes(v5e, T):
-    """The decode tick (T 0) and the 512-token ragged program at
-    `chat-open`'s sizes, read from the cell's own file: the kernels get
-    the pools whole, so no layer's pages ([2048, 16, 8, 128] bf16,
-    67 MB) are copied out before them. A pool that is an xs of the
-    layer scan costs 64.4 and 113.0 MB of temporaries; as compiled they
-    are 0.45 and 0.81 MB."""
+    """The decode tick (T 0: the ragged tick of one token a slot) and
+    the 512-token ragged program at `chat-open`'s sizes, read from the
+    cell's own file: the kernel gets the pools whole, so no layer's
+    pages ([2048, 16, 8, 128] bf16, 67 MB) are copied out before it. A
+    pool that is an xs of the layer scan costs 64.4 and 113.0 MB of
+    temporaries; as compiled they are 0.61 and 0.81 MB."""
     with open(os.path.join(os.path.dirname(__file__), os.pardir,
                            "benchmarks", "configs",
                            "internlm2_5-1_8b.json")) as f:
@@ -144,8 +144,9 @@ def test_dense_forwards_copy_no_pool_at_chat_opens_sizes(v5e, T):
         donate = (4, 5)
     compiled = jax.jit(run, donate_argnums=donate).lower(*args).compile()
     text = compiled.as_text()
-    assert ("paged_decode_mp" if T == 0
-            else "ragged_paged_attention") in text
+    # the decode tick is the ragged tick of one token a slot (PR 50)
+    assert "ragged_paged_attention" in text
+    assert "paged_decode" not in text
     layer_pages = f"bf16[{pages},{page},{cfg.n_kv_heads},{cfg.head_dim}]"
     copies = [line.strip()[:120] for line in text.splitlines()
               if f" = {layer_pages}" in line]
@@ -154,6 +155,22 @@ def test_dense_forwards_copy_no_pool_at_chat_opens_sizes(v5e, T):
     assert mem.temp_size_in_bytes < 16 << 20
     # both pools are still updated in place
     assert mem.alias_size_in_bytes >= 3.2e9
+
+
+@pytest.mark.parametrize("b", [1, 4])
+def test_dense_decode_tick_compiles_under_the_smallest_token_bucket(v5e, b):
+    """A small engine's decode tick is a ragged tick of fewer tokens
+    than the smallest bucket a ragged program has (8): Mosaic takes the
+    work-list kernel at 1 and 4 query rows as it stands."""
+    cfg = dataclasses.replace(SERVE_CONFIGS["1b"], n_layers=2)
+    S = _on(v5e[0])
+    k, v = _pools(cfg, S)
+    i32 = lambda *shape: S(shape, jnp.int32)
+    text = jax.jit(functools.partial(decode_step, cfg, impl="pallas")).lower(
+        _param_structs(cfg, S), i32(b), i32(b), k, v, i32(b, TABLE),
+        S((b,), jnp.bool_)).compile().as_text()
+    assert "ragged_paged_attention" in text
+    assert "paged_decode" not in text
 
 
 def _row_write_is_one_scatter(text, calls, most=None):
