@@ -147,6 +147,7 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
     "nemotron_h": ("nemotron_h", "NemotronHConfig"),
     "smallthinker": ("smallthinker", "SmallThinkerConfig"),
     "kimi_linear": ("kimi_linear", "KimiLinearConfig"),
+    "granite_hybrid": ("granite_hybrid", "GraniteHybridConfig"),
 }
 
 
@@ -178,7 +179,7 @@ def _families() -> Dict[type, ModelFamily]:
     m = _modules()
     trinity, phi4flash = m["trinity"], m["phi4flash"]
     nemotron_h, smallthinker = m["nemotron_h"], m["smallthinker"]
-    kimi_linear = m["kimi_linear"]
+    kimi_linear, granite_hybrid = m["kimi_linear"], m["granite_hybrid"]
     # a family with held experts: the counts of the assignments landed
     # ride the readback, [n_moe_layers, n_held], and are summed alike
     held = dict(rider_len=paged_common.held_rider_len,
@@ -217,6 +218,11 @@ def _families() -> Dict[type, ModelFamily]:
             span_counts=kimi_linear.span_counts,
             storage_dtypes=kimi_linear.storage_dtypes,
             refuses=kimi_linear.KIMI_LINEAR_REFUSES, **held),
+        _from_module(
+            "granite_hybrid", granite_hybrid,
+            span_counts=granite_hybrid.span_counts,
+            storage_dtypes=granite_hybrid.storage_dtypes,
+            refuses=granite_hybrid.GRANITE_HYBRID_REFUSES),
     )
     return {getattr(m[f.name], FAMILIES[f.name][1]): f for f in families}
 

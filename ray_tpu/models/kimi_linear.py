@@ -97,7 +97,8 @@ from ..ops.moe import (held_experts_ffn, held_gates, platform_impl,
                        sigmoid_group_routing)
 from .cache_row import CacheGroup, CacheRow, StateRow
 from .llama import rms_norm
-from .paged_common import latent_attend_fn, one_token_tick, refuse, swiglu
+from .paged_common import (latent_attend_fn, one_token_tick, refuse,
+                           state_span_counts, swiglu)
 
 KDA, MLA = "K", "M"
 PUBLISHED_KDA = (1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19, 21, 22,
@@ -751,10 +752,6 @@ def work_counts(segs, t, page_size, n_ctx_pages, geometry):
                            heads=MLA_Q_MOST)
 
 
-def span_counts(cfg: KimiLinearConfig, segs, decode) -> Dict[str, int]:
-    """What the dispatch span carries besides the usual counts, from
-    the plan (`segs` = [(cached tokens, tokens this tick)] a row):
-    `ssm_tokens`, the tokens through each KDA layer's scan, and
-    `ssm_rows`, the rows whose state a layer reads and writes."""
-    del decode
-    return {"ssm_tokens": sum(n for _, n in segs), "ssm_rows": len(segs)}
+# what the dispatch span carries besides the usual counts: `ssm_tokens`
+# (the tokens through each KDA layer's scan) and `ssm_rows`
+span_counts = state_span_counts

@@ -89,13 +89,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import selective_scan as ssm
-from ..ops import ssd_scan
 from ..ops.moe import (held_experts_ffn, held_gates, platform_impl,
                        sigmoid_group_routing)
 from ..ops.paged_attention import pool_head_dim
 from .cache_row import CacheGroup, CacheRow, StateRow
 from .llama import rms_norm
-from .paged_common import attend_fn, one_token_tick, refuse
+from .paged_common import attend_fn
+from .paged_common import mamba2_mixer as mamba_mixer
+from .paged_common import one_token_tick, refuse, state_span_counts
 from .paged_common import scatter_merged_rows as scatter_rows
 
 MAMBA, EXPERTS, ATTN = "M", "E", "*"
@@ -466,52 +467,6 @@ def relu2(x: jax.Array) -> jax.Array:
     return jnp.square(jax.nn.relu(x))
 
 
-def gated_group_norm(cfg: NemotronHConfig, y: jax.Array, z: jax.Array,
-                     weight: jax.Array) -> jax.Array:
-    """y, z: [T, d_inner] -> the gate FIRST (y silu(z)), then RMSNorm
-    over each of the `n_groups` groups of channels separately, times the
-    weight; float32 inside, `cfg.dtype` out."""
-    f32 = jnp.float32
-    t = y.shape[0]
-    g = (y.astype(f32) * jax.nn.silu(z.astype(f32))).reshape(
-        t, cfg.n_groups, -1)
-    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
-                          + cfg.norm_eps)
-    return (g.reshape(t, -1) * weight).astype(cfg.dtype)
-
-
-def mamba_mixer(cfg: NemotronHConfig, layer, u: jax.Array, marks, tick,
-                conv_all: jax.Array, ssm_all: jax.Array, gi, impl: str):
-    """u: [T, H] normalised -> (the mixer's output [T, H], the conv
-    inputs and the scan state with layer `gi`'s rows of this tick's
-    slots replaced)."""
-    slot_ids, valid, last_idx = tick
-    t, b = u.shape[0], conv_all.shape[1]
-    e, k, hm = cfg.d_inner, cfg.d_conv, cfg.mamba_heads
-    gn = cfg.n_groups * cfg.ssm_state
-    z, xbc, dt = jnp.split(u @ layer["in_proj"], [e, e + cfg.conv_dim],
-                           axis=-1)
-    with jax.named_scope("conv"):
-        stored = jax.lax.dynamic_index_in_dim(conv_all, gi, 0, False)
-        xc, conv_new = ssm.causal_conv_ragged(
-            xbc, layer["conv_w"], layer["conv_b"], slot_ids, last_idx,
-            marks, stored.reshape(b, k - 1, cfg.conv_dim))
-        conv_all = jax.lax.dynamic_update_index_in_dim(
-            conv_all, conv_new.reshape(b, -1), gi, 0)
-        xbc = jax.nn.silu(xc).astype(cfg.dtype)
-    x, bm, cm = jnp.split(xbc, [e, e + gn], axis=-1)
-    delta = jax.nn.softplus(dt.astype(jnp.float32) + layer["dt_bias"])
-    with jax.named_scope("ssd_scan"):
-        y, ssm_all = ssd_scan.ssd_ragged_scan(
-            x.reshape(t, hm, cfg.mamba_head_dim), delta,
-            -jnp.exp(layer["a_log"]),
-            bm.reshape(t, cfg.n_groups, cfg.ssm_state),
-            cm.reshape(t, cfg.n_groups, cfg.ssm_state), layer["d_skip"],
-            marks, slot_ids, valid, last_idx, ssm_all, gi, impl=impl)
-    y = gated_group_norm(cfg, y.reshape(t, e), z, layer["norm"])
-    return y @ layer["out_proj"], conv_all, ssm_all
-
-
 def moe_block(cfg: NemotronHConfig, layer, y, valid=None,
               impl: Optional[str] = None, experts=None, base=0):
     """y: [T, H] normalised -> (the expert layer's output [T, H]: the
@@ -636,10 +591,6 @@ def ragged_forward(cfg: NemotronHConfig, params: Dict[str, Any],
 decode_step = one_token_tick(ragged_forward)
 
 
-def span_counts(cfg: NemotronHConfig, segs, decode) -> Dict[str, int]:
-    """What the dispatch span carries besides the usual counts, from
-    the plan (`segs` = [(cached tokens, tokens this tick)] a row):
-    `ssm_tokens`, the tokens through each Mamba layer's scan, and
-    `ssm_rows`, the rows whose state a layer reads and writes."""
-    del decode
-    return {"ssm_tokens": sum(n for _, n in segs), "ssm_rows": len(segs)}
+# what the dispatch span carries besides the usual counts: `ssm_tokens`
+# and `ssm_rows`
+span_counts = state_span_counts

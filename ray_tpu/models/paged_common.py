@@ -1,7 +1,9 @@
 """What more than one model family's serving forwards use: rotate-half
-rope, SwiGLU, the window layers' dispatch-span counts, the refusal of
+rope, SwiGLU, the window layers' and the recurrent layers' dispatch-span
+counts, the refusal of
 the dense family's arguments, attention over a tick's page groups, the
-decode step as the ragged tick of one token a slot, the write of a
+Mamba-2 mixer of the two families that have one, the decode step as the
+ragged tick of one token a slot, the write of a
 tick's rows into a group's pool (one function a pool layout), and what
 `stats()` shows of held experts' counts.
 
@@ -18,6 +20,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import ragged_paged_attention as rpa
+from ..ops import selective_scan as ssm
+from ..ops import ssd_scan
 from ..ops.paged_attention import _fit_lanes
 
 # a layer's kind in a stack of sliding-window and full-attention layers
@@ -112,6 +116,67 @@ def attend_fn(impl: str, pools, tables, slot_ids, positions, valid, start,
                 q, kp, vp, gi, tab, slot_ids, positions, valid, start,
                 k, v, window=window, merged_rows=merged_rows)
     return attend
+
+
+def gated_group_norm(cfg, y: jax.Array, z: jax.Array,
+                     weight: jax.Array) -> jax.Array:
+    """y, z: [T, d_inner] -> the gate FIRST (y silu(z)), then RMSNorm
+    over each of the `cfg.n_groups` groups of channels separately (one
+    group: over them all), times the weight; float32 inside, `cfg.dtype`
+    out."""
+    f32 = jnp.float32
+    t = y.shape[0]
+    g = (y.astype(f32) * jax.nn.silu(z.astype(f32))).reshape(
+        t, cfg.n_groups, -1)
+    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+    return (g.reshape(t, -1) * weight).astype(cfg.dtype)
+
+
+def mamba2_mixer(cfg, layer, u: jax.Array, marks, tick,
+                 conv_all: jax.Array, ssm_all: jax.Array, gi, impl: str,
+                 conv_scope: str = "conv"):
+    """A Mamba-2 (SSD) mixer on a ragged tick. u: [T, H] normalised ->
+    (the mixer's output [T, H], the conv inputs and the scan state with
+    layer `gi`'s rows of this tick's slots replaced). `cfg` names the
+    sizes as `NemotronHConfig` does (`d_inner`, `conv_dim`, `d_conv`,
+    `mamba_heads`, `mamba_head_dim`, `n_groups`, `ssm_state`); `layer`
+    holds in_proj, conv_w, conv_b, dt_bias, a_log, d_skip, norm,
+    out_proj. Delta is not clamped."""
+    slot_ids, valid, last_idx = tick
+    t, b = u.shape[0], conv_all.shape[1]
+    e, k, hm = cfg.d_inner, cfg.d_conv, cfg.mamba_heads
+    gn = cfg.n_groups * cfg.ssm_state
+    z, xbc, dt = jnp.split(u @ layer["in_proj"], [e, e + cfg.conv_dim],
+                           axis=-1)
+    with jax.named_scope(conv_scope):
+        stored = jax.lax.dynamic_index_in_dim(conv_all, gi, 0, False)
+        xc, conv_new = ssm.causal_conv_ragged(
+            xbc, layer["conv_w"], layer["conv_b"], slot_ids, last_idx,
+            marks, stored.reshape(b, k - 1, cfg.conv_dim))
+        conv_all = jax.lax.dynamic_update_index_in_dim(
+            conv_all, conv_new.reshape(b, -1), gi, 0)
+        xbc = jax.nn.silu(xc).astype(cfg.dtype)
+    x, bm, cm = jnp.split(xbc, [e, e + gn], axis=-1)
+    delta = jax.nn.softplus(dt.astype(jnp.float32) + layer["dt_bias"])
+    with jax.named_scope("ssd_scan"):
+        y, ssm_all = ssd_scan.ssd_ragged_scan(
+            x.reshape(t, hm, cfg.mamba_head_dim), delta,
+            -jnp.exp(layer["a_log"]),
+            bm.reshape(t, cfg.n_groups, cfg.ssm_state),
+            cm.reshape(t, cfg.n_groups, cfg.ssm_state), layer["d_skip"],
+            marks, slot_ids, valid, last_idx, ssm_all, gi, impl=impl)
+    y = gated_group_norm(cfg, y.reshape(t, e), z, layer["norm"])
+    return y @ layer["out_proj"], conv_all, ssm_all
+
+
+def state_span_counts(cfg, segs, decode) -> Dict[str, int]:
+    """What the dispatch span carries of a tick's recurrent layers, from
+    the plan (`segs` = [(cached tokens, tokens this tick)] a row):
+    `ssm_tokens`, the tokens through each such layer's scan, and
+    `ssm_rows`, the rows whose state a layer reads and writes."""
+    del cfg, decode
+    return {"ssm_tokens": sum(n for _, n in segs), "ssm_rows": len(segs)}
 
 
 def one_token_tick(ragged_forward):

@@ -102,7 +102,7 @@ from ..ops import selective_scan as ssm
 from ..ops.paged_attention import pool_head_dim
 from .cache_row import CacheGroup, CacheRow, StateRow
 from .paged_common import (attend_fn, one_token_tick, refuse,
-                           window_span_counts)
+                           state_span_counts, window_span_counts)
 from .paged_common import scatter_merged_rows as scatter_rows
 
 MAMBA, SWA, FULL, GMU, CROSS = "mamba", "swa", "full", "gmu", "cross"
@@ -695,6 +695,6 @@ def span_counts(cfg: Phi4FlashConfig, segs, decode) -> Dict[str, int]:
     `cross_tokens`, the tokens the cross-decoder ran on (one a row);
     and the window layers' `win_kv_tokens`, `win_attn_pairs` and
     `win_decode_pairs` (`paged_common.window_span_counts`)."""
-    return {"ssm_tokens": sum(n for _, n in segs), "ssm_rows": len(segs),
+    return {**state_span_counts(cfg, segs, decode),
             "cross_tokens": len(segs),
             **window_span_counts(cfg, segs, decode)}

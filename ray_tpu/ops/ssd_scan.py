@@ -36,17 +36,23 @@ impl (the names the attention ops take):
 - "pallas" / "pallas_interpret": `ssd_ragged_scan`. The tick is cut into
   SEGMENTS: the pieces of runs inside chunks of `T_CHUNK` tokens, in
   order (`segments`: a table on the device, read by scalar prefetch).
-  The grid is (groups, segments), the second bound the tick's own count.
-  A step takes the segment's chunk of x, dt, B (its group's alone:
-  `[T, G, N]` is never broadcast a head) and C, and its SLOT's state of
-  the group's heads as a block picked by the prefetched slot id; the
-  output state aliases the input, so the states of slots without a run
-  are never read nor written, and a run's state block stays in VMEM from
-  its first segment to its last (the output block is the carry). Two
-  bodies: a segment of ONE token (a decode row) updates its state on the
-  vector unit, the row's x turned to a column by a diagonal mask, and is
-  bound by the state's way in and out; a longer segment runs the form
-  above as matrix products over its chunk under row masks.
+  The grid is (head tiles, segments), the second bound the tick's own
+  count. A TILE is `head_tile(H, G)` heads of ONE group: a whole group
+  where a group has at most `TILE_HEADS` = 8 heads (G = 8 over 64
+  heads: tile t is group t, 8 tiles), and 8 heads of a larger group (G =
+  1 over 64 heads: 8 tiles, every one reading the one group's B and C),
+  so that a step's state block is [8, P, N] (256 KB at the published
+  sizes) whatever G is. A step takes the segment's chunk of x, dt, B
+  (its tile's group's alone: `[T, G, N]` is never broadcast a head) and
+  C, and its SLOT's state of the tile's heads as a block picked by the
+  prefetched slot id; the output state aliases the input, so the states
+  of slots without a run are never read nor written, and a run's state
+  block stays in VMEM from its first segment to its last (the output
+  block is the carry). Two bodies: a segment of ONE token (a decode row)
+  updates its state on the vector unit, the row's x turned to a column
+  by a diagonal mask, and is bound by the state's way in and out; a
+  longer segment runs the form above as matrix products over its chunk
+  under row masks.
 """
 
 from __future__ import annotations
@@ -64,6 +70,7 @@ from .selective_scan import Marks
 
 SUBLANES = 8
 T_CHUNK = 128                  # tokens a chunk: the published kernel's
+TILE_HEADS = 8                 # the most heads a grid step's state holds
 KERNEL_NAME = "ssd_ragged_scan"
 _HI = lax.Precision.HIGHEST
 
@@ -88,6 +95,18 @@ def segments(marks: Marks, slot_ids: jax.Array, valid: jax.Array,
             marks.first[start], n)
 
 
+def head_tile(h_all: int, groups: int) -> int:
+    """Heads a grid step takes: a whole group, or `TILE_HEADS` of a
+    group that has more (which then has to be whole tiles)."""
+    heads = h_all // groups
+    if heads <= TILE_HEADS:
+        return heads
+    if heads % TILE_HEADS:
+        raise ValueError(f"a group of {heads} heads is no whole number of "
+                         f"tiles of {TILE_HEADS}")
+    return TILE_HEADS
+
+
 def _column(a, j):
     """Column j of a [rows, width] value as [rows, 1]: a masked sum
     over lanes (a one-lane slice has no layout a broadcast takes)."""
@@ -99,7 +118,7 @@ def _ssd_kernel(chunk_ref, row_ref, len_ref, slot_ref, first_ref, lay_ref,
                 x_ref, xt_ref, dt_ref, dtt_ref, cs_ref, cst_ref, b_ref,
                 c_ref, a_ref, s_in_ref, y_ref, s_out_ref, *, heads: int,
                 p: int):
-    """One segment of one group's heads. x: [Q, heads * P]; xt: its
+    """One segment of one tile's heads. x: [Q, heads * P]; xt: its
     transpose; dt, cs (the chunk's running sum of dt A): [Q, heads] and
     transposed; b, c: [Q, N]; a: [1, heads]; the state blocks
     [heads, P, N]."""
@@ -190,18 +209,25 @@ def _ssd_call(seg, layer, a, x, dt, cs, b, c, state, *, interpret: bool):  # jax
     t, hp = x.shape
     groups, _, n = b.shape
     h_all = dt.shape[1]
-    heads = h_all // groups
+    heads = head_tile(h_all, groups)
+    tiles = h_all // heads
+    per = tiles // groups                  # tiles a group
+    # a tile's group: the tile itself where a group is one tile (the
+    # index map then holds no division)
+    grp = (lambda g: g) if per == 1 else (lambda g: g // per)
     p = hp // h_all
     q = min(T_CHUNK, t)
     # the layer's index rides with the prefetched scalars: a stack
     # that scans its layers hands a traced one
     lay = jnp.asarray(layer, jnp.int32).reshape(1)
-    by_group = lambda m: m.reshape(t, groups, heads).transpose(1, 0, 2)
-    dt_g, cs_g = by_group(dt), by_group(cs)                 # [G, T, heads]
+    by_tile = lambda m: m.reshape(t, tiles, heads).transpose(1, 0, 2)
+    dt_g, cs_g = by_tile(dt), by_tile(cs)                   # [tiles, T, heads]
     tok = lambda width: pl.BlockSpec(
         (None, q, width), lambda g, i, ch, *_: (g, ch[i], 0))
     tok_t = lambda depth: pl.BlockSpec(
         (None, depth, q), lambda g, i, ch, *_: (g, 0, ch[i]))
+    of_group = pl.BlockSpec(
+        (None, q, n), lambda g, i, ch, *_: (grp(g), ch[i], 0))
     rows = pl.BlockSpec(
         (None, None, heads, p, n),
         lambda g, i, ch, r, ln, sl, fi, lay: (lay[0], sl[i], g, 0, 0))
@@ -210,12 +236,12 @@ def _ssd_call(seg, layer, a, x, dt, cs, b, c, state, *, interpret: bool):  # jax
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
-            grid=(groups, n_seg),
+            grid=(tiles, n_seg),
             in_specs=[
                 pl.BlockSpec((q, heads * p), lambda g, i, ch, *_: (ch[i], g)),
                 pl.BlockSpec((heads * p, q), lambda g, i, ch, *_: (g, ch[i])),
                 tok(heads), tok_t(heads), tok(heads), tok_t(heads),
-                tok(n), tok(n),
+                of_group, of_group,
                 pl.BlockSpec((None, 1, heads), lambda g, i, *_: (g, 0, 0)),
                 rows],
             out_specs=[
@@ -232,7 +258,7 @@ def _ssd_call(seg, layer, a, x, dt, cs, b, c, state, *, interpret: bool):  # jax
         name=KERNEL_NAME,
     )(chunk_of, row_of, length, slot_of, first_of, lay,
       x, x.T, dt_g, dt_g.transpose(0, 2, 1), cs_g, cs_g.transpose(0, 2, 1),
-      b, c, a.reshape(groups, 1, heads), state)
+      b, c, a.reshape(tiles, 1, heads), state)
     return y, state
 
 

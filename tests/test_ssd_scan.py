@@ -2,7 +2,8 @@
 tick) against the recurrence stepped one token at a time in numpy: the
 plain `jax.numpy` path and the interpreted kernel, on runs that straddle
 chunks, one-token runs, a run that continues stored state, one that
-starts from zeros over a slot that held something, and padding."""
+starts from zeros over a slot that held something, and padding; with
+groups that are one head tile each and with ONE group cut into tiles."""
 
 import numpy as np
 import pytest
@@ -14,6 +15,21 @@ from ray_tpu.ops import ssd_scan
 from ray_tpu.ops.selective_scan import segment_marks
 
 H, P, G, N, B = 4, 8, 2, 16, 6
+# (heads, groups): two groups of two heads (a tile a group, under 8 heads);
+# ONE group of 16 heads (two tiles of 8 that read the same B and C:
+# granite-4.0-h-micro's layout at a quarter of its heads); eight groups of
+# 8 (a tile a group: Nemotron's layout)
+GEOMETRIES = {"2 groups of 2": (4, 2), "1 group of 16": (16, 1),
+              "8 groups of 8": (64, 8)}
+
+
+@pytest.fixture
+def geometry(request, monkeypatch):
+    """The module's H and G for one test."""
+    h, g = GEOMETRIES[request.param]
+    monkeypatch.setattr(request.module, "H", h)
+    monkeypatch.setattr(request.module, "G", g)
+    return h, g
 
 
 def _inputs(seed, t):
@@ -92,8 +108,12 @@ CASES = {
 
 
 @pytest.mark.parametrize("impl", ["gather", "pallas_interpret"])
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_ragged_scan_matches_the_stepped_recurrence(case, impl):
+@pytest.mark.parametrize("case,geometry", [
+    (case, "2 groups of 2") for case in sorted(CASES)] + [
+    # ragged ticks that mix chunks and one-token rows, at head tiles
+    (case, name) for case in ("mixed", "straddle")
+    for name in ("1 group of 16", "8 groups of 8")], indirect=["geometry"])
+def test_ragged_scan_matches_the_stepped_recurrence(case, geometry, impl):
     runs, t = CASES[case]
     inp = _inputs(3, t)
     layer = 1
@@ -152,3 +172,60 @@ def test_segments_cut_runs_at_chunk_boundaries():
     assert row[:n].tolist() == [0, 1, 0, 13, 113, 114]
     assert slot[:n].tolist() == [3, 0, 0, 2, 4, 5]
     assert first[:n].tolist() == [1, 1, 0, 2, 1, 1]
+
+
+def _lowered_call(h, g, t=16):
+    """The kernel's `pallas_call` as `_ssd_call` traces it for `h` heads
+    in `g` groups: its grid, its block shapes, and the primitives of each
+    operand's index map."""
+    f32 = jnp.float32
+    seg = tuple(jnp.zeros((4,), jnp.int32) for _ in range(5)) + (
+        jnp.int32(2),)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: ssd_scan._ssd_call(seg, 0, *a, interpret=True))(
+        jnp.zeros((h,), f32), jnp.zeros((t, h * P), f32),
+        jnp.zeros((t, h), f32), jnp.zeros((t, h), f32),
+        jnp.zeros((g, t, N), f32), jnp.zeros((g, t, N), f32),
+        jnp.zeros((2, B, h, P, N), f32))
+
+    def find(jp):
+        for e in jp.eqns:
+            if e.primitive.name == "pallas_call":
+                return e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                got = find(sub)
+                if got is not None:
+                    return got
+    def names(jp):
+        out = set()
+        for e in jp.eqns:
+            out.add(e.primitive.name)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                out |= names(sub)
+        return out
+    mapping = find(jaxpr.jaxpr).params["grid_mapping"]
+    blocks = [tuple(bm.block_shape) for bm in mapping.block_mappings]
+    maps = [names(bm.index_map_jaxpr.jaxpr)
+            for bm in mapping.block_mappings]
+    return mapping.grid, blocks, maps
+
+
+def test_eight_groups_of_eight_lower_to_the_grid_they_had():
+    """G = 8 over 64 heads (`nemotron-agent`): the first grid axis is the
+    8 groups, a step's state block a group's 8 heads, and no index map
+    divides (a tile IS its group), as before head tiles. G = 1 over 64
+    heads: the same grid and the same blocks, B and C picked by tile //
+    8."""
+    grid8, blocks8, maps8 = _lowered_call(64, 8)
+    grid1, blocks1, maps1 = _lowered_call(64, 1)
+    assert grid8[0] == grid1[0] == 8
+    assert blocks8 == blocks1
+    state = [b for b in blocks8 if len(b) == 5]
+    assert len(state) == 2 and all(b[2].block_size == 8 for b in state)
+    divides = lambda maps: [m for m in maps if m - {"get"}]
+    assert not divides(maps8)                  # a scalar read, no more
+    assert len(divides(maps1)) == 2            # B and C
+    assert ssd_scan.head_tile(64, 8) == ssd_scan.head_tile(64, 1) == 8
+    assert ssd_scan.head_tile(4, 2) == 2
+    with pytest.raises(ValueError, match="whole number of tiles"):
+        ssd_scan.head_tile(12, 1)

@@ -1136,3 +1136,102 @@ def test_kimis_gather_path_fits_beside_the_engine_when_donated(v5e):
     args = _kimi_args(S, cfg, fam, 512, "pallas")
     compiled = jax.jit(run, donate_argnums=(7, 8)).lower(*args).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 600 << 20
+
+
+# ---- the GraniteHybrid family (`granite-concurrent`) --------------------
+
+@pytest.mark.parametrize("T", [48, 512])
+def test_ssd_scan_kernel_compiles_at_one_group_of_64_heads(v5e, T):
+    """`ssd_ragged_scan` at 64 heads of 64 in ONE group, N 128, 48 slots,
+    the 36 layers' state whole and aliased in place: the grid's first
+    axis is eight head tiles, a step's state block [8, 64, 128] as at
+    Nemotron's eight groups; the decode tick's T and a chunk's."""
+    from ray_tpu.ops import selective_scan as ssm
+    from ray_tpu.ops import ssd_scan
+    S = _on(v5e[0])
+    h, p, g, n, b = 64, 64, 1, 128, 48
+    f32 = lambda *shape: S(shape, jnp.float32)
+    i32 = lambda *shape: S(shape, jnp.int32)
+
+    def run(x, dt, a, bm, cm, d, slots, valid, first, last, last_idx,
+            state):
+        marks = ssm.Marks(first, first, last, last_idx >= 0)
+        return ssd_scan.ssd_ragged_scan(
+            x, dt, a, bm, cm, d, marks, slots, valid, last_idx, state, 20,
+            impl="pallas")
+
+    compiled = jax.jit(run, donate_argnums=11).lower(
+        S((T, h, p), jnp.bfloat16), f32(T, h), f32(h), f32(T, g, n),
+        f32(T, g, n), f32(h), i32(T), S((T,), jnp.bool_), i32(T), i32(T),
+        i32(b), f32(36, b, h, p, n)).compile()
+    assert "ssd_ragged_scan" in compiled.as_text()
+    # in place: the 3.6 GB of state is not copied beside itself
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert mem.alias_size_in_bytes > 3.6e9
+
+
+def _granite_args(S, cfg, fam, T):
+    """The forwards' arguments at `granite-concurrent`'s engine: 48
+    slots, 12,288 pages of 16, a table 192 pages wide."""
+    b, page, pages, width = 48, 16, 12288, 192
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(lambda k: fam.init_params(cfg, k),
+                       jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    made = [tuple(S(shape, dt) for shape, dt in g.array_shapes(
+        pages, page, b)) for g in fam.cache_groups(cfg, "pallas")]
+    kp, vp = tuple(m[0] for m in made), tuple(m[1] for m in made)
+    tables = S((b, width), jnp.int32)
+    i32 = lambda n: S((n,), jnp.int32)
+    if T:
+        return (params, i32(T), i32(T), i32(T), S((T,), jnp.bool_),
+                i32(b), i32(b), kp, vp, tables)
+    return (params, i32(b), i32(b), S((b,), jnp.bool_), kp, vp, tables)
+
+
+@pytest.mark.parametrize("T,impl,temp_mb", [
+    (0, "pallas", 64), (512, "pallas", 112), (0, "gather", 224)],
+    ids=["decode", "chunk", "the checks' gather decode"])
+def test_granites_scanned_forwards_copy_no_state(v5e, T, impl, temp_mb):
+    """The whole model at the published widths and `granite-concurrent`'s
+    pools (T 0: the decode tick of 48 slots): 40 layers as ONE scan over
+    36 units with a cond on the attention layer that takes the residual
+    stream alone. The state (3.67 GB) and the pools (1.6 GB) are the
+    scan's carry, donated, aliased and updated in place: a copy of the
+    state would show in the temporaries (42, 75 and 145 MB as compiled;
+    with the state handed through the cond's branches the attention
+    branch copied it, `copy` of f32[36,48,64,64,128], 1.2 GB of
+    temporaries). The gather path is the checks' other implementation,
+    donated as `checks_granite_hybrid._ticks` hands it."""
+    from ray_tpu.models import granite_hybrid
+    from ray_tpu.models.family import family_of
+    S = _on(v5e[0])
+    cfg = granite_hybrid.GraniteHybridConfig()
+    fam = family_of(cfg)
+    args = _granite_args(S, cfg, fam, T)
+    if T:
+        def run(params, tok, slot, pos, valid, start, last, kp, vp, tables):
+            return fam.ragged_forward(
+                cfg, params, tok, slot, pos, valid, start, last, kp, vp,
+                tables, ctx_pages=tables.shape[1], impl=impl)
+    else:
+        def run(params, tok, pos, active, kp, vp, tables):
+            return fam.decode_step(cfg, params, tok, pos, kp, vp, tables,
+                                   active, impl=impl)
+    n = len(args)
+    compiled = jax.jit(run, donate_argnums=(n - 3, n - 2)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    if impl == "pallas":
+        for kernel in ("ssd_ragged_scan", "ragged_paged_attention"):
+            assert kernel in text, kernel
+    # nothing of the state's shape is made anew
+    assert " copy(" not in "".join(
+        line for line in text.splitlines()
+        if "= f32[36,48,64,64,128]" in line)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < temp_mb << 20
+    assert mem.alias_size_in_bytes > 5.2e9
+    # weights, pools and state: what the configuration's file reckons
+    assert mem.argument_size_in_bytes == pytest.approx(11.665e9, rel=0.003)
