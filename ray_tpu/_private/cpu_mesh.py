@@ -24,13 +24,18 @@ def apply_cpu_mesh_env(env: MutableMapping[str, str],
     XLA:CPU has no ``ragged-all-to-all``; the recipe also throws the
     explicit switch that makes ``ops/ragged_exchange`` emulate it
     (without the switch the op is the native collective or an error,
-    on any backend). And the CPU tier keeps no persistent compile
-    cache: tests stay hermetic and write nothing into the checkout
-    (``util/compile_cache`` is for the processes that own a chip).
+    on any backend). And the CPU tier writes no compile cache into the
+    checkout: the persistent cache is off unless *env* already places
+    one (``JAX_COMPILATION_CACHE_DIR``), as ``tests/conftest.py`` does
+    with a directory that lives for one run of the tests.
+    ``__graft_entry__.dryrun_multichip`` places none and keeps none
+    (``util/compile_cache``'s ``<checkout>/.jax_cache`` is for the
+    processes that own a chip).
     """
     env["JAX_PLATFORMS"] = "cpu"
     env["RAY_TPU_RAGGED_EMULATE"] = "1"
-    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+    if not env.get("JAX_COMPILATION_CACHE_DIR"):
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     flags = env.get("XLA_FLAGS", "")
     if keep_existing_count and _COUNT_FLAG in flags:
         return env
@@ -50,4 +55,6 @@ def force_cpu_mesh(n_devices: int = 8) -> None:
         import jax
 
         jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_enable_compilation_cache", False)
+        jax.config.update(
+            "jax_enable_compilation_cache",
+            os.environ.get("JAX_ENABLE_COMPILATION_CACHE") != "false")

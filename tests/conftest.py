@@ -6,11 +6,31 @@ multi-host simulation is `xla_force_host_platform_device_count=8`
 """
 
 import os
+import shutil
 import sys
+import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from ray_tpu._private.cpu_mesh import force_cpu_mesh
+
+# The compile cache of ONE run. Nearly every test jits the same tiny
+# programs again (an engine's closures on a debug preset), once a test
+# in each of xdist's workers; kept for the length of the run, a program
+# is compiled once. The controller (or the single process) makes the
+# directory, outside the checkout, before xdist starts its workers; they
+# and every child process inherit it through the variable jax itself
+# reads (`util/compile_cache.ensure_compile_cache` honours it too), and
+# `pytest_sessionfinish` removes it. A run starts from an empty cache,
+# writes nothing into the checkout and leaves nothing behind.
+_RUN_CACHE = None
+if "PYTEST_XDIST_WORKER" not in os.environ:
+    _RUN_CACHE = tempfile.mkdtemp(prefix="ray_tpu_test_jax_cache_")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = _RUN_CACHE
+    os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "true"
+# keep every program however quick its compile: measured on the whole
+# run against 0.1 s (CHANGES.md, PR 53)
+os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
 
 force_cpu_mesh(8)
 
@@ -26,6 +46,48 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: long-running; excluded from tier-1 CI")
+
+
+def pytest_sessionfinish(session):
+    if _RUN_CACHE is not None:
+        shutil.rmtree(_RUN_CACHE, ignore_errors=True)
+
+
+@pytest.fixture(autouse=True)
+def _no_profiler_session_left_open(request):
+    """jax has ONE profiler session a process. An engine flags a slow
+    tick on a loaded host, arms a capture of the next four, and the test
+    ends first: the session stays open in that xdist worker, and every
+    later test there that captures waits a minute behind it and fails
+    (three of `test_llm_telemetry.py` in a run of PR 53). Close it, and
+    say who left it."""
+    yield
+    from ray_tpu.util import profiling
+    if profiling.session_open():
+        import jax
+        try:
+            jax.profiler.stop_trace()
+        except RuntimeError:     # its owner closed it meanwhile
+            return
+        import warnings
+        warnings.warn(f"{request.node.nodeid} left a jax profiler session "
+                      "open; closed")
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """For a test that times a REAL compile: the run's cache off for
+    this test alone (a hit is a retrieval, milliseconds)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    # jax reads the switch once a process and remembers: reset_cache
+    # makes it read again
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
 
 
 @pytest.fixture()

@@ -88,10 +88,32 @@ def _pack(rng, cfg, history, plan, t):
                  (toks, slot, pos, valid, start, last))
 
 
-def _reference_last(cfg, params, history):
+@functools.lru_cache(maxsize=None)
+def _tick_fn(cfg, impl, ctx_pages):
+    """The family's forwards as ONE program each, as the engine runs
+    them (eagerly, every primitive of a forward is compiled by itself);
+    `ctx_pages` None is the decode tick."""
+    if ctx_pages is None:
+        return jax.jit(functools.partial(ds.decode_step, cfg, impl=impl))
+    return jax.jit(functools.partial(ds.ragged_forward, cfg,
+                                     ctx_pages=ctx_pages, impl=impl))
+
+
+def _reference_rows(cfg, params, seqs):
+    """The reference's logits of every sequence, [len(seqs), n, vocab].
+    It is causal, so all go in padded to ONE length n and a row is read
+    at its own position (eagerly, each primitive of the reference is
+    compiled again at every new length: most of these tests' time)."""
     m = model_dict(cfg)
+    n = -(-max(map(len, seqs)) // 32) * 32
     return np.stack([np.asarray(ref.logits(
-        m, params, jnp.asarray(h), cfg.held)[-1]) for h in history])
+        m, params, jnp.asarray(list(s) + [0] * (n - len(s))), cfg.held))
+        for s in seqs])
+
+
+def _reference_last(cfg, params, history):
+    rows = _reference_rows(cfg, params, history)
+    return np.stack([r[len(h) - 1] for r, h in zip(rows, history)])
 
 
 @pytest.mark.parametrize("impl", ["gather", "pallas_interpret"])
@@ -110,13 +132,13 @@ def test_ragged_tick_then_decode_through_the_cache_match_the_reference(
     rng = np.random.default_rng(0)
     history = [[], [], []]
     batch = _pack(rng, cfg, history, [(0, 0, 21), (1, 0, 9)], 32)
-    _, pool, none, _ = ds.ragged_forward(
-        cfg, params, *batch, pool, None, tables, ctx_pages=0, impl=impl)
+    _, pool, none, _ = _tick_fn(cfg, impl, 0)(
+        params, *batch, pool, None, tables)
     assert none is None                    # one pool: no second one back
     batch = _pack(rng, cfg, history, [(0, 21, 1), (1, 9, 13), (2, 0, 7)],
                   32)
-    logits, pool, _, counts = ds.ragged_forward(
-        cfg, params, *batch, pool, None, tables, ctx_pages=8, impl=impl)
+    logits, pool, _, counts = _tick_fn(cfg, impl, 8)(
+        params, *batch, pool, None, tables)
     want = _reference_last(cfg, params, history)
     np.testing.assert_allclose(np.asarray(logits)[:3], want, atol=2e-5)
     # 21 valid tokens, 4 picks each, 8 of 16 experts held: some land
@@ -128,9 +150,9 @@ def test_ragged_tick_then_decode_through_the_cache_match_the_reference(
     posn[:3] = [len(h) for h in history]
     for s in range(3):
         history[s].append(int(toks[s]))
-    logits, pool, _, counts = ds.decode_step(
-        cfg, params, jnp.asarray(toks, jnp.int32), jnp.asarray(posn),
-        pool, None, tables, jnp.asarray(np.arange(B) < 3), impl=impl)
+    logits, pool, _, counts = _tick_fn(cfg, impl, None)(
+        params, jnp.asarray(toks, jnp.int32), jnp.asarray(posn),
+        pool, None, tables, jnp.asarray(np.arange(B) < 3))
     want = _reference_last(cfg, params, history)
     np.testing.assert_allclose(np.asarray(logits)[:3], want, atol=2e-5)
     # the inactive slot's token routes nowhere
@@ -617,17 +639,18 @@ def test_engine_serves_the_family_and_counts_its_experts():
                for n in (5, 23, 40)]
     outs = eng.generate(prompts, SamplingParams(max_tokens=5,
                                                 temperature=0.0))
-    # greedy continuation against the reference, token by token
-    m = model_dict(cfg)
-    for req, prompt in zip(outs, prompts):
-        seq = list(prompt)
-        for tok in req.output_tokens:
-            lg = np.asarray(ref.logits(m, eng.params, jnp.asarray(seq),
-                                       cfg.held)[-1])
+    # greedy continuation against the reference, token by token: the
+    # reference is causal, so ONE call on a request's whole sequence has
+    # the row that predicted each generated token (a call a token, at a
+    # new length each, compiled every primitive again)
+    every = _reference_rows(cfg, eng.params, [
+        list(p) + list(r.output_tokens) for r, p in zip(outs, prompts)])
+    for req, prompt, rows in zip(outs, prompts, every):
+        for i, tok in enumerate(req.output_tokens):
+            lg = rows[len(prompt) - 1 + i]
             top2 = np.sort(lg)[-2:]
             if top2[1] - top2[0] > 1e-3:    # not a rounding tie
                 assert tok == int(lg.argmax())
-            seq.append(tok)
     st = eng.stats()
     assert st["cache_row"]["kind"] == "latent"
     assert st["kv_page_bytes"] == 3 * 24 * 4 * 4

@@ -371,13 +371,13 @@ def test_anomaly_unwarmed_never_triggers():
 
 # ------------------------------------------------ anomaly: engine e2e
 
-def _steady_engine(**over):
+def _steady_engine(z_threshold=6.0, **over):
     """Warmed engine in steady decode with a FAST anomaly warmup.
     Batch 4 with 3 warm requests: one slot stays free, so the test's
     injected long prompt admits (and recompiles) immediately."""
     eng = make_engine(
         max_batch_size=4, num_pages=128,
-        anomaly={"warmup_ticks": 16, "z_threshold": 6.0,
+        anomaly={"warmup_ticks": 16, "z_threshold": z_threshold,
                  "min_wall_ms": 0.0,
                  "profile_min_interval_s": 0.0,
                  "dump_min_interval_s": 0.0},
@@ -395,12 +395,21 @@ def _steady_engine(**over):
     return eng
 
 
-def test_forced_recompile_produces_classified_capture():
+def test_forced_recompile_produces_classified_capture(no_compile_cache):
     """Acceptance criterion: an injected stall (forced recompile — a
     cold prefill bucket mid-steady-state) produces a classified
     tick_anomaly event, an auto-armed profile capture, and a black-box
-    bundle in the spool."""
-    eng = _steady_engine()
+    bundle in the spool.
+
+    What it races on: the stall against the baseline's spread. The stall
+    is a REAL compile (the run's compile cache is off here: a hit is
+    milliseconds and no stall), and the threshold is 3, not 6: beside
+    five other workers the warm ticks' log-residuals spread to a MAD of
+    ~0.5, where z >= 6 asks for a tick ~85 x the median and a debug
+    model's compile is ~80 x; z >= 3 asks for ~9 x. The detector's own
+    sensitivity is tested above on given walls; this test is about what
+    follows a flag."""
+    eng = _steady_engine(z_threshold=3.0)
     assert eng.anomaly.stats()["warmed"]
     base_anoms = eng.anomaly.anomalies_total
     # force a recompile: a prompt far past every warmed bucket

@@ -1591,7 +1591,7 @@ def test_ingress_relay_terminates_sse_on_exhausted_failover(
     assert any("choices" in d for d in docs)
 
 
-def test_e2e_anomaly_capture_fetchable_via_fleet():
+def test_e2e_anomaly_capture_fetchable_via_fleet(no_compile_cache):
     """ISSUE 13 acceptance: an injected stall (forced recompile — a
     cold prefill bucket mid-steady-state) on one replica produces a
     CLASSIFIED tick_anomaly event, an auto-armed profile capture, and
@@ -1616,8 +1616,11 @@ def test_e2e_anomaly_capture_fetchable_via_fleet():
                 max_prefill_tokens=16,
                 metrics_model_id=tag, metrics_replica_id=rid,
                 # fast warmup + no capture rate limits: the test
-                # injects exactly one stall and wants its evidence
+                # injects exactly one stall and wants its evidence (a
+                # REAL compile, `no_compile_cache`, judged at z >= 3: why,
+                # `test_forced_recompile_produces_classified_capture`)
                 anomaly={"warmup_ticks": 16, "min_wall_ms": 0.0,
+                         "z_threshold": 3.0,
                          "profile_min_interval_s": 0.0,
                          "dump_min_interval_s": 0.0}),
         })

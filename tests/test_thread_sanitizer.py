@@ -18,6 +18,7 @@ nothing observable.
 """
 
 import threading
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -236,8 +237,12 @@ def test_concurrent_adds_never_lost():
     errs = []
 
     def pump():
+        # until every request is through, not for a count of ticks: an
+        # empty tick costs microseconds, and on a loaded host 400 of
+        # them were over before the adding thread got its turn
+        deadline = time.monotonic() + 110
         try:
-            for _ in range(400):
+            while time.monotonic() < deadline:
                 eng.step()
                 if all(r.finished for r in reqs):
                     return

@@ -8,6 +8,7 @@ window of 8 and sequences past two windows, in float32, so that an edge
 off by one key fails exactly."""
 
 import dataclasses
+import functools
 import zlib
 
 import jax
@@ -104,6 +105,30 @@ def _pools(cfg, impl, pages):
     return groups, make(), make()
 
 
+@functools.lru_cache(maxsize=None)
+def _tick_fn(cfg, impl, ctx_pages):
+    """The family's forwards as ONE program each, as the engine runs
+    them (eagerly, every primitive of a forward is compiled by itself:
+    714 programs a test); `ctx_pages` None is the decode tick."""
+    if ctx_pages is None:
+        return jax.jit(functools.partial(trinity.decode_step, cfg,
+                                         impl=impl))
+    return jax.jit(functools.partial(trinity.ragged_forward, cfg,
+                                     ctx_pages=ctx_pages, impl=impl))
+
+
+def _reference_logits(cfg, model, params, seqs, **kw):
+    """The reference's logits of each sequence, [len(s), vocab] each. It
+    is causal, so all go in padded to ONE length and are cut back
+    (eagerly, each primitive of the reference is compiled again at every
+    new length)."""
+    n = -(-max(map(len, seqs)) // 16) * 16
+    return [np.asarray(ref.logits(
+        model, params, jnp.array(np.pad(np.asarray(s, np.int32),
+                                        (0, n - len(s)))),
+        cfg.held, **kw))[:len(s)] for s in seqs]
+
+
 def _tick(cfg, params, impl, kp, vp, tables, rows, seqs, t, slots):
     """One ragged tick. rows: [(slot, first position, tokens)] of the
     sequences `seqs`. Returns (logits by slot, kp, vp)."""
@@ -122,11 +147,10 @@ def _tick(cfg, params, impl, kp, vp, tables, rows, seqs, t, slots):
         start[s], last[s] = pos0, cur + n - 1
         cur += n
     has_ctx = any(pos0 for _, pos0, _ in rows)
-    lg, kp, vp, _ = trinity.ragged_forward(
-        cfg, params, jnp.array(tok), jnp.array(sid), jnp.array(pos),
+    lg, kp, vp, _ = _tick_fn(cfg, impl, -1 if has_ctx else 0)(
+        params, jnp.array(tok), jnp.array(sid), jnp.array(pos),
         jnp.array(valid), jnp.array(start), jnp.array(last), kp, vp,
-        tuple(jnp.array(t_) for t_ in tables),
-        ctx_pages=-1 if has_ctx else 0, impl=impl)
+        tuple(jnp.array(t_) for t_ in tables))
     return np.asarray(lg), kp, vp
 
 
@@ -143,8 +167,7 @@ def test_ragged_ticks_through_both_groups_match_the_reference(impl):
     model = program_trinity.published_keys(cfg)
     rng = np.random.default_rng(0)
     seqs = [rng.integers(1, 255, n).astype(np.int32) for n in (41, 24)]
-    want = [np.asarray(ref.logits(model, params, jnp.array(s), cfg.held))
-            for s in seqs]
+    want = _reference_logits(cfg, model, params, seqs)
     slots, t = 2, 32
     # the window group holds what the two reserve and no page more (9
     # pages: 8 + 17 + 8 tokens; 7: all 25), where they claim 18 in all
@@ -178,10 +201,10 @@ def test_ragged_ticks_through_both_groups_match_the_reference(impl):
     assert win.returned >= 8 and reused > 0
     # the decode tick: one token a slot, the ragged tick of T = slots
     toks = jnp.array([seqs[0][40], seqs[1][23]], jnp.int32)
-    lg, _, _, counts = trinity.decode_step(
-        cfg, params, toks, jnp.array(done, jnp.int32), kp, vp,
+    lg, _, _, counts = _tick_fn(cfg, impl, None)(
+        params, toks, jnp.array(done, jnp.int32), kp, vp,
         tuple(jnp.array(t_) for t_ in cache.tables),
-        jnp.ones(slots, bool), impl=impl)
+        jnp.ones(slots, bool))
     for s, p in enumerate(done):
         assert _rel(lg[s], want[s][p], want[s]) < 2e-5
     assert counts.shape == (cfg.n_moe_layers, cfg.n_held)
@@ -190,12 +213,12 @@ def test_ragged_ticks_through_both_groups_match_the_reference(impl):
                            ("rope_on_full", 0.1), ("no_gate", 0.3),
                            ("no_qk_norm", 0.3), ("no_route_scale", 0.1),
                            ("no_embed_scale", 0.3)):
-        off = np.asarray(ref.logits(model, params, jnp.array(seqs[0]),
-                                    cfg.held, variant=(variant,)))
+        off, = _reference_logits(cfg, model, params, seqs[:1],
+                                 variant=(variant,))
         assert _rel(off[-8:], want[0][-8:]) > least, variant
     one_off = {**model, "sliding_window": 7}
-    assert _rel(np.asarray(ref.logits(one_off, params, jnp.array(seqs[0]),
-                                      cfg.held))[-8:], want[0][-8:]) > 1e-2
+    off, = _reference_logits(cfg, one_off, params, seqs[:1])
+    assert _rel(off[-8:], want[0][-8:]) > 1e-2
 
 
 def test_engine_greedy_tokens_are_the_references():
@@ -212,10 +235,9 @@ def test_engine_greedy_tokens_are_the_references():
     rng = np.random.default_rng(1)
     prompts = [rng.integers(1, 255, n).tolist() for n in (5, 21, 30, 17)]
     outs = eng.generate(prompts, SamplingParams(max_tokens=12))
-    for req in outs:
-        seq = np.asarray(req.prompt_tokens + req.output_tokens, np.int32)
-        lg = np.asarray(ref.logits(model, eng.params, jnp.array(seq),
-                                   cfg.held))
+    every = _reference_logits(cfg, model, eng.params, [
+        req.prompt_tokens + req.output_tokens for req in outs])
+    for req, lg in zip(outs, every):
         n = len(req.prompt_tokens)
         for i, tok in enumerate(req.output_tokens):
             row = lg[n + i - 1]
