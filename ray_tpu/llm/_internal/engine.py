@@ -3132,8 +3132,10 @@ class InferenceEngine:
                     "engine.step", tick=self.ticks + 1) as span:
                 touched = self._step_locked()
                 # work=0: the device idles after this tick for want of
-                # requests, not for the host
-                span.set_metadata(work=int(self._step_end is not None))
+                # requests, not for the host; with it `gaps` booked at
+                # the call's close, `gap_max_ms` and `gap_cause`
+                self.telemetry.annotate_call(
+                    span, work=int(self._step_end is not None))
             if ps is not None and ps["live"]:
                 self._held(self._profile_tick_end)
             return touched
@@ -3149,7 +3151,7 @@ class InferenceEngine:
         try:
             t0 = time.perf_counter()
             self.ticks += 1
-            self.telemetry.tick = self.ticks
+            self.telemetry.open_call(self.ticks)
             compiles0 = self.compiles
             # phase time already in the table was spent by an
             # out-of-step drain (abort, LoRA registration) since
@@ -3269,6 +3271,14 @@ class InferenceEngine:
             c.get("prefill_tokens", 0), phases_ms,
             self.compiles - compiles0, c.get("attn_items", 0),
             c.get("attn_kv_blocks", 0)))
+        # the gap ledger: the tokens this call surfaced reach their
+        # streams when it returns, and their gaps are booked here. A
+        # capture counts while its trace is live or being written: one
+        # that waits for its start costs a tick nothing
+        ps = self._profile
+        self.telemetry.close_call(
+            end, gap, c.get("prefill_tokens", 0),
+            ps is not None and ps["state"] in ("running", "writing"))
         self._tick_carried = None
         for k in ph:
             ph[k] = 0.0
@@ -4333,7 +4343,8 @@ class InferenceEngine:
                 len(sorted_vals) - 1)
         return sorted_vals[i]
 
-    def _tick_times_summary_locked(self) -> Dict[str, Any]:
+    def _tick_times_summary(self, ticks, now: float, lagged: int,
+                            drains: int) -> Dict[str, Any]:
         """Tick-pipeline telemetry over the recent window (512 ticks).
         device_ms is time BLOCKED in the sanctioned readback — the
         un-hidden device share of a tick — so overlap_ratio
@@ -4344,29 +4355,32 @@ class InferenceEngine:
         wedging tick or periodic stall moves the p99 long before it
         moves the mean.
 
-        Caller holds _step_lock (stats() takes it ONCE around the
-        whole mutable-state snapshot; the lock is non-reentrant so
-        this helper must not retake it). The lock matters: the pump's
-        executor thread appends per tick, and iterating a deque being
-        mutated raises RuntimeError mid-/stats request."""
-        ticks = tuple(self._tick_times)
+        `ticks` is the ring copied to a tuple, `now` the clock of its
+        records' `start` and `lagged` and `drains` the counters, all
+        read together under _step_lock by stats()
+        (the pump's executor thread appends per tick, and iterating a
+        deque being mutated raises RuntimeError mid-/stats request).
+        The five sorts below run AFTER the lock's release: under it
+        they were tens of milliseconds of every live stream's gaps
+        each time a monitor asked (ISSUE 55)."""
         n = len(ticks)
         wall = sum(t[0] for t in ticks)
         host = sum(t[1] for t in ticks)
         dev = sum(t[2] for t in ticks)
         out = {
             "window": n,
-            # the clock of every `start` below, read now: a reader with
-            # two snapshots knows which records fall between them
-            "now": time.perf_counter(),
+            # the clock of every `start` below, read with the ring: a
+            # reader with two snapshots knows which records fall
+            # between them
+            "now": now,
             "longest": self._longest_ticks(ticks),
             "wall_ms_avg": round(wall / n, 3) if n else 0.0,
             "host_ms_avg": round(host / n, 3) if n else 0.0,
             "device_ms_avg": round(dev / n, 3) if n else 0.0,
             "overlap_ratio": (round(max(0.0, 1.0 - dev / wall), 3)
                               if wall > 0 else 0.0),
-            "lagged_ticks": self._lagged_ticks,
-            "drains": self._drains,
+            "lagged_ticks": lagged,
+            "drains": drains,
             "async_readback": self._async,
         }
         for i, name in enumerate(("wall_ms", "host_ms", "device_ms")):
@@ -4462,8 +4476,11 @@ class InferenceEngine:
                 # occupancy
                 "lanes": self._lane_counts_locked(),
                 # tick-pipeline telemetry (ISSUE 4): wall vs host-fold
-                # vs blocked-readback per tick + lag/drain counters
-                "tick_times": self._tick_times_summary_locked(),
+                # vs blocked-readback per tick + lag/drain counters.
+                # The ring is copied here and summarised after release
+                "tick_times": (tuple(self._tick_times),
+                               time.perf_counter(),
+                               self._lagged_ticks, self._drains),
                 # where a tick's wall goes (ISSUE 25): monotone seconds
                 # per named phase (TICK_PHASES; `other` is wall that no
                 # phase covers) and between ticks while work remained
@@ -4493,6 +4510,7 @@ class InferenceEngine:
             # cache group (the one that gates admission), and
             # `cache_groups`: each group's row, layers, window, pages
             alloc_stats = self.cache.stats()
+        snap["tick_times"] = self._tick_times_summary(*snap["tick_times"])
         return {
             **snap,
             # per-dispatch perf accounting (ISSUE 11): rolling

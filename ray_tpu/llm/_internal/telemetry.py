@@ -14,8 +14,11 @@ instrumentation enabled): recording adds ZERO device syncs and ZERO
 extra dispatches. Every timestamp here comes from host-side events the
 engine already has — admission bookkeeping and the (possibly lagged)
 fold — so TTFT/ITL are HOST-VISIBLE latencies: with async_readback a
-token's timestamp is when its fold landed, one tick after dispatch,
-which is exactly when a streaming client could first see it.
+token's timestamp is when its fold landed, one tick after dispatch.
+A gap between two tokens (ITL) is taken between the ENDS of the
+`engine.step` calls that surfaced them, which is when the pump hands a
+call's tokens to their streams, and booked there once, to the cause
+that made it (the gap ledger, GAP_CAUSES below; ISSUE 55).
 
 Three pieces:
 - EngineTelemetry — per-request lifecycle timelines (queued → admitted
@@ -37,6 +40,7 @@ from __future__ import annotations
 
 import collections
 import itertools
+import math
 import os
 import threading
 import time
@@ -68,6 +72,39 @@ _MAX_CHUNK_MARKS = 128       # prefill-chunk marks kept per request
 # anchor so cross-process traces still align on epoch timestamps.
 _now = time.monotonic
 _wall = tracing.mono_to_epoch
+
+# The gap ledger (ISSUE 55): every gap between two streamed tokens of a
+# request is booked once, when the engine closes the call that surfaced
+# the later token, to the one cause that made it. First match wins, in
+# this order (`EngineTelemetry.close_call`):
+#   same_tick  both tokens surfaced in one call: one chunk, a gap of 0
+#   capture    a profile capture's trace was live or being written at
+#              the close of a call it spans (one armed and waiting for
+#              its start costs a tick nothing and is not booked)
+#   held       the time outside every call is over half the gap, or a
+#              decode / refill gap is over 4 x that cause's running mean
+#   ragged     a call it spans dispatched prefill tokens
+#   refill     none of the above and two or more calls, or a call that
+#              drained the pipeline (a retirement: it waits out the tick
+#              in flight AND its successor): the two-deep pipeline
+#              emptied and filling again, around an in-step fold or a
+#              drain
+#   decode     one call that drained nothing: a decode tick
+GAP_CAUSES = ("same_tick", "capture", "held", "ragged", "refill",
+              "decode")
+GAP_BUCKETS_PER_OCTAVE = 16  # a bucket is a factor of 2**(1/16): 4.4%
+_HELD_OUTSIDE = 0.5          # of a gap outside every call: held
+_HELD_FACTOR = 4.0           # x a cause's running mean: held too ...
+_HELD_AFTER = 64             # ... once the cause holds this many gaps
+
+
+def gap_bucket(gap_s: float) -> int:
+    """The histogram bucket of a gap: 0 holds [0, 1 us), bucket b >= 1
+    holds [2**((b-1)/16), 2**(b/16)) microseconds."""
+    us = gap_s * 1e6
+    if us < 1.0:
+        return 0
+    return int(GAP_BUCKETS_PER_OCTAVE * math.log2(us)) + 1
 
 
 def _build_metrics() -> Dict[str, Any]:
@@ -302,7 +339,8 @@ class _Timeline:
     __slots__ = ("rid", "tid", "queued", "admitted", "first_token",
                  "last_token", "finished", "reason", "prompt_len",
                  "cached_tokens", "n_tokens", "chunks", "lora",
-                 "trace", "batch", "admitted_tick", "first_token_tick")
+                 "trace", "batch", "admitted_tick", "first_token_tick",
+                 "last_call", "last_between")
 
     def __init__(self, rid: str, tid: int, queued: float,
                  prompt_len: int, lora: Optional[str],
@@ -313,7 +351,12 @@ class _Timeline:
         self.queued = queued
         self.admitted: Optional[float] = None
         self.first_token: Optional[float] = None
+        # the gap ledger's marks of the newest token: the end of the
+        # call that surfaced it (the engine's clock), that call's
+        # number, and the between-calls total at that end
         self.last_token: Optional[float] = None
+        self.last_call = 0
+        self.last_between = 0.0
         self.finished: Optional[float] = None
         self.reason: Optional[str] = None
         self.prompt_len = prompt_len
@@ -407,6 +450,20 @@ class EngineTelemetry:
                       "e2e": 0.0}
         self._counts = {"ttft": 0, "itl": 0, "queue": 0, "e2e": 0}
         self._bad = {"ttft": 0, "queue": 0, "e2e": 0}
+        # the gap ledger (GAP_CAUSES above): per cause [n, seconds,
+        # between_s, {bucket: n}], all monotone and summed over causes
+        # the "itl" sum and count above; the tokens surfaced since the
+        # last call closed; the newest call that dispatched prefill
+        # tokens, that closed under a live capture, and that drained
+        # the pipeline; the seconds outside every call so far; and what
+        # the call just closed booked (`engine.step`'s span arguments)
+        self._gaps = {c: [0, 0.0, 0.0, {}] for c in GAP_CAUSES}
+        self._surfaced: List[_Timeline] = []
+        self._last_ragged_call = 0
+        self._last_capture_call = 0
+        self._last_drain_call = 0
+        self._between_s = 0.0
+        self.call_gaps: Dict[str, Any] = {}
         # batch lane (ISSUE 14): the preemptible bulk tier's own
         # token/finish aggregates — its requests never touch the SLO
         # sums/bad counts above (the watchdog's burn and the
@@ -481,11 +538,13 @@ class EngineTelemetry:
 
     def on_token(self, req) -> None:
         """One host-visible output token (runs per token per fold —
-        the hottest entry point; keep it a few dict ops)."""
+        the hottest entry point; keep it a few dict ops). Its gap to
+        the request's token before is booked when the engine closes
+        the call that surfaces it (`close_call`)."""
         if not self.enabled:
             return
         now = _now()
-        first = gap = None
+        first = None
         batch = False
         with self._lock:
             t = self._live.get(req.request_id)
@@ -503,27 +562,106 @@ class EngineTelemetry:
                     t.first_token = now
                     t.first_token_tick = self.tick
                 self._batch_tokens += 1
-            elif t.first_token is None:
-                t.first_token = now
-                t.first_token_tick = self.tick
-                first = max(now - t.queued, 0.0)
-                self._sums["ttft"] += first
-                self._counts["ttft"] += 1
-                if first > self.slo_targets["ttft"]:
-                    self._bad["ttft"] += 1
             else:
-                gap = max(now - t.last_token, 0.0)
-                self._sums["itl"] += gap
-                self._counts["itl"] += 1
-            t.last_token = now
+                if t.first_token is None:
+                    t.first_token = now
+                    t.first_token_tick = self.tick
+                    first = max(now - t.queued, 0.0)
+                    self._sums["ttft"] += first
+                    self._counts["ttft"] += 1
+                    if first > self.slo_targets["ttft"]:
+                        self._bad["ttft"] += 1
+                self._surfaced.append(t)
             self._generated_tokens += 1
         if first is not None:
             self._m["ttft"].observe(first, self._tags)
-        if gap is not None:
-            self._m["itl"].observe(gap, self._tags)
         self._m["generated_tokens"].inc(1, self._tags)
         if batch:
             self._m["batch_tokens"].inc(1, self._tags)
+
+    def open_call(self, tick: int) -> None:
+        """`engine.step` begins call number `tick`."""
+        self.tick = tick
+        self.call_gaps = {}
+
+    def annotate_call(self, span, **args) -> None:
+        """`engine.step`'s span arguments at the call's end: the
+        engine's own and what the call booked (`gaps`, and where one was
+        over 0 `gap_max_ms` and `gap_cause`). Unpacked here and not in
+        `step()`: a `**` in that function, which is on the stack of
+        every program's first call, cost each ragged program's lowering
+        0.3 s on the chip's host (PERF.md section 6, PR 55)."""
+        span.set_metadata(**args, **self.call_gaps)
+
+    def close_call(self, end: float, between_s: float,
+                   prefill_tokens: int, capture: bool) -> None:
+        """The engine closes a call at `end` (its own clock): every
+        token surfaced since the last close reaches its stream when
+        this call returns, so `end` is its stamp, and its gap to the
+        request's token before is booked once, to one cause (GAP_CAUSES,
+        first match wins). `between_s` is the time before this call
+        that lay outside every call while work remained,
+        `prefill_tokens` what the call's program carried beside the
+        decode rows, and `capture` whether a profile capture's trace is
+        live or being written at this close (its states last ticks, so
+        one reading a call finds them). Host arithmetic on values the
+        tick already has: no device value is read."""
+        if not self.enabled:
+            return
+        n = self.tick
+        booked: List[float] = []
+        worst, worst_cause = 0.0, ""
+        with self._lock:
+            self._between_s += between_s
+            if prefill_tokens:
+                self._last_ragged_call = n
+            if capture:
+                self._last_capture_call = n
+            surfaced, self._surfaced = self._surfaced, []
+            gaps, outside_now = self._gaps, self._between_s
+            capture_at, ragged_at, drain_at = (
+                self._last_capture_call, self._last_ragged_call,
+                self._last_drain_call)
+            for t in surfaced:
+                if t.last_token is not None:
+                    gap = max(end - t.last_token, 0.0)
+                    a = t.last_call
+                    outside = outside_now - t.last_between
+                    if a == n:
+                        cause = "same_tick"
+                    elif capture_at > a:
+                        cause = "capture"
+                    elif outside > _HELD_OUTSIDE * gap:
+                        cause = "held"
+                    elif ragged_at > a:
+                        cause = "ragged"
+                    else:
+                        cause = ("refill" if n - a >= 2 or drain_at > a
+                                 else "decode")
+                        held_n, held_s = gaps[cause][:2]
+                        if (held_n >= _HELD_AFTER
+                                and gap * held_n > _HELD_FACTOR * held_s):
+                            cause = "held"
+                    row = gaps[cause]
+                    row[0] += 1
+                    row[1] += gap
+                    row[2] += outside
+                    b = gap_bucket(gap)
+                    row[3][b] = row[3].get(b, 0) + 1
+                    booked.append(gap)
+                    if gap > worst:
+                        worst, worst_cause = gap, cause
+                t.last_token = end
+                t.last_call = n
+                t.last_between = outside_now
+            self._sums["itl"] += sum(booked)
+            self._counts["itl"] += len(booked)
+        self.call_gaps = {"gaps": len(booked)}
+        if worst_cause:
+            self.call_gaps.update(gap_max_ms=round(worst * 1e3, 3),
+                                  gap_cause=worst_cause)
+        for gap in booked:
+            self._m["itl"].observe(gap, self._tags)
 
     def on_finished(self, req, reason: str,
                     cost: Optional[Dict[str, Any]] = None) -> None:
@@ -572,6 +710,8 @@ class EngineTelemetry:
     def on_drain(self, cause: str) -> None:
         if not self.enabled:
             return
+        # the gap ledger: a call that drains waits out two programs
+        self._last_drain_call = self.tick
         self._m["drains"].inc(1, self._tags)
         self.recorder.record("drain", cause=cause)
 
@@ -783,6 +923,14 @@ class EngineTelemetry:
                 "budget_utilization": round(
                     self._budget_used / self._budget_total, 3)
                     if self._budget_total else 0.0,
+                # the gap ledger: what made the gaps "itl" sums, by
+                # cause; `between_s` is the part of `seconds` outside
+                # every call, `hist` counts by `gap_bucket`
+                "gaps": {c: {"n": n, "seconds": round(s, 6),
+                             "between_s": round(b, 6),
+                             "hist": {str(k): v
+                                      for k, v in sorted(h.items())}}
+                         for c, (n, s, b, h) in self._gaps.items()},
                 # batch lane (ISSUE 14): the preemptible tier's own
                 # totals — EXCLUDED from every latency family above
                 "batch": {
@@ -914,4 +1062,4 @@ class EngineTelemetry:
 
 
 __all__ = ["EngineTelemetry", "FlightRecorder", "LATENCY_BOUNDARIES",
-           "DEFAULT_SLO_TARGETS"]
+           "DEFAULT_SLO_TARGETS", "GAP_CAUSES", "gap_bucket"]
